@@ -45,21 +45,9 @@ def base_parser(description: str) -> argparse.ArgumentParser:
                         "docs/TUNING_RUNBOOK.md §0.8). With telemetry on, "
                         "the summary gains a telemetry.relayout_plan "
                         "block of the planner's decisions")
-    p.add_argument("--compile-cache", metavar="DIR",
-                   # heatlint: disable=HL005 -- read before `import heat_tpu`:
-                   # bootstrap() must set the cache dir env BEFORE the package
-                   # (which reads it at import) loads
-                   default=os.environ.get("HEAT_TPU_COMPILE_CACHE") or None,
-                   help="persistent on-disk XLA compilation cache directory "
-                        "(default: $HEAT_TPU_COMPILE_CACHE). Repeated sweep "
-                        "processes over the same workload skip backend "
-                        "compiles entirely — compile_seconds in the summary "
-                        "drops to the cache-deserialization cost "
-                        "(docs/TUNING_RUNBOOK.md)")
     p.add_argument("--tune-db", metavar="DIR",
                    # heatlint: disable=HL005 -- read before `import heat_tpu`:
-                   # mirrors --compile-cache, the env must be set before the
-                   # backend probe / package import
+                   # the env must be set before the package import
                    default=os.environ.get("HEAT_TPU_TUNE_DB") or None,
                    help="persistent tuning-DB directory (default: "
                         "$HEAT_TPU_TUNE_DB). Arms the autotuner "
@@ -74,13 +62,8 @@ def bootstrap(args):
     """Apply --mesh BEFORE jax initializes a backend, then import heat_tpu."""
     if getattr(args, "plan", None):
         os.environ["HEAT_TPU_RELAYOUT_PLAN"] = args.plan
-    if getattr(args, "compile_cache", None):
-        # FIRST, before anything imports heat_tpu (force_virtual_cpu_mesh
-        # below already does): program_cache reads the env at import and
-        # wires jax's persistent compilation cache from it
-        os.environ["HEAT_TPU_COMPILE_CACHE"] = args.compile_cache
     if getattr(args, "tune_db", None):
-        # same ordering contract as the compile cache; --tune-db arms
+        # set before anything imports heat_tpu; --tune-db arms
         # the autotuner UNLESS the environment already pins
         # HEAT_TPU_AUTOTUNE (an explicit =0 must keep a baseline run
         # untuned even when HEAT_TPU_TUNE_DB is exported globally)
@@ -94,6 +77,10 @@ def bootstrap(args):
 
         force_virtual_cpu_mesh(args.mesh)
     import heat_tpu as ht
+
+    # repeated sweep processes deserialize instead of recompiling: JAX's
+    # persistent cache at $JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache
+    ht.program_cache.enable_persistent_cache()
 
     if getattr(args, "audit", False):
         # ground-truth collective accounting rides on the telemetry event

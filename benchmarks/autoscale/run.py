@@ -143,9 +143,9 @@ def add_args(p):
                         "and the replacement (the bounded-ticks verdict)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--workdir", default=None,
-                   help="checkpoint + shared-cache directory (default: a "
-                        "fresh temp dir; every phase shares one compile "
-                        "cache within the run)")
+                   help="checkpoint + log directory (default: a fresh "
+                        "temp dir; the compile cache is JAX's persistent "
+                        "one, shared by every replica)")
     p.add_argument("--artifact", default=None,
                    help="append the emitted JSONL lines to this file")
 
@@ -155,9 +155,8 @@ def _emit(lines, obj):
     lines.append(obj)
 
 
-def _pool_env(args, workdir):
+def _pool_env(args):
     env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
         "HEAT_TPU_SERVE_MAX_BATCH": "4",
         "HEAT_TPU_SERVE_MAX_WAIT_MS": str(args.wait_ms),
         "HEAT_TPU_SERVE_QUEUE_MAX": str(args.queue_max),
@@ -265,7 +264,7 @@ def _controller(args, pool, router, **over):
     return AutoscaleController(pool, router, **kw)
 
 
-def _profile_phase(args, ckpt, workdir, log_dir, profile):
+def _profile_phase(args, ckpt, log_dir, profile):
     from heat_tpu.serve.net import ReplicaPool, Router
     from heat_tpu.telemetry.cluster import SLO
 
@@ -278,7 +277,7 @@ def _profile_phase(args, ckpt, workdir, log_dir, profile):
     t0 = time.perf_counter()
     pool = ReplicaPool(
         ckpt, args.min_replicas, mesh=args.replica_mesh,
-        env=_pool_env(args, workdir),
+        env=_pool_env(args),
         log_dir=os.path.join(log_dir, f"as_{profile}"),
     ).start()
     router = Router(
@@ -333,7 +332,7 @@ def _profile_phase(args, ckpt, workdir, log_dir, profile):
         pool.close()
 
 
-def _two_tenant_phase(args, ckpt, workdir, log_dir, features):
+def _two_tenant_phase(args, ckpt, log_dir, features):
     from heat_tpu.serve.net import ReplicaPool, Router
 
     n_lat = max(1, int(args.tenant_duration * args.latency_rate))
@@ -350,7 +349,7 @@ def _two_tenant_phase(args, ckpt, workdir, log_dir, features):
                                         args.seed + 8)
     pool = ReplicaPool(
         ckpt, args.tenant_replicas, mesh=args.replica_mesh,
-        env=_pool_env(args, workdir),
+        env=_pool_env(args),
         log_dir=os.path.join(log_dir, "two_tenant"),
     ).start()
     router = Router(
@@ -411,10 +410,10 @@ def _two_tenant_phase(args, ckpt, workdir, log_dir, features):
         pool.close()
 
 
-def _hedge_phase(args, ckpt, workdir, log_dir):
+def _hedge_phase(args, ckpt, log_dir):
     from heat_tpu.serve.net import ReplicaPool, Router
 
-    env = _pool_env(args, workdir)
+    env = _pool_env(args)
     pool = ReplicaPool(
         ckpt, 1, mesh=args.replica_mesh, env=env,
         log_dir=os.path.join(log_dir, "hedge"),
@@ -475,12 +474,12 @@ def _hedge_phase(args, ckpt, workdir, log_dir):
         pool.close()
 
 
-def _chaos_phase(args, ckpt, workdir, log_dir):
+def _chaos_phase(args, ckpt, log_dir):
     from heat_tpu.serve.net import ReplicaPool, Router
 
     pool = ReplicaPool(
         ckpt, args.chaos_replicas, mesh=args.replica_mesh,
-        env=_pool_env(args, workdir),
+        env=_pool_env(args),
         log_dir=os.path.join(log_dir, "chaos"),
     ).start()
     router = Router(
@@ -594,24 +593,24 @@ def main():
 
     profile_rows = []
     for profile in [s.strip() for s in args.profiles.split(",") if s.strip()]:
-        row = _profile_phase(args, ckpt, workdir, log_dir, profile)
+        row = _profile_phase(args, ckpt, log_dir, profile)
         profile_rows.append(row)
         _emit(lines, {"autoscale_row": row})
 
     two_tenant = None
     if args.two_tenant:
-        two_tenant = _two_tenant_phase(args, ckpt, workdir, log_dir,
+        two_tenant = _two_tenant_phase(args, ckpt, log_dir,
                                        features)
         _emit(lines, {"two_tenant": two_tenant})
 
     hedge = None
     if args.hedge:
-        hedge = _hedge_phase(args, ckpt, workdir, log_dir)
+        hedge = _hedge_phase(args, ckpt, log_dir)
         _emit(lines, {"hedge": hedge})
 
     chaos = None
     if args.chaos:
-        chaos = _chaos_phase(args, ckpt, workdir, log_dir)
+        chaos = _chaos_phase(args, ckpt, log_dir)
         _emit(lines, {"chaos": chaos})
 
     summary = {
@@ -654,72 +653,6 @@ def main():
         with open(args.artifact, "a") as f:
             for obj in lines:
                 f.write(json.dumps(obj) + "\n")
-
-
-def bench_field(duration=8.0, peak_rate=60.0, mesh=2):
-    """The ``autoscale`` detail row for bench.py summaries
-    (docs/BENCHMARKS.md): a QUICK step-profile probe — one cdist
-    endpoint, controller between 1 and 2 replicas — reporting the
-    scale-up/drain trail and the replica-seconds ratio vs static max.
-    Replica processes always run virtual CPU meshes, so the row carries
-    its own ``on_chip``/``cpu_fallback`` verdict (the bench-honesty
-    contract)."""
-    import heat_tpu as ht
-    from heat_tpu.serve.net import AutoscaleController, ReplicaPool, Router
-
-    workdir = tempfile.mkdtemp(prefix="heat_tpu_autoscale_probe_")
-    ckpt = os.path.join(workdir, "endpoints.ckpt")
-    rng = np.random.default_rng(0)
-    y = rng.standard_normal((128, 16)).astype(np.float32)
-    server = ht.serve.Server()
-    server.register("cdist", ht.serve.cdist_query(y))
-    server.save(ckpt)
-    server.close()
-    offs = profiles.schedule("step", duration, peak_rate, seed=0)
-    reqs = loadgen.make_requests({"cdist": 16}, len(offs), 0, max_rows=1)
-    env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
-        "HEAT_TPU_SERVE_MAX_BATCH": "4",
-        "HEAT_TPU_SERVE_QUEUE_MAX": "256",
-        "HEAT_TPU_SERVE_MAX_WAIT_MS": "25",
-    }
-    t0 = time.perf_counter()
-    pool = ReplicaPool(
-        ckpt, 1, mesh=mesh, env=env,
-        log_dir=os.path.join(workdir, "logs"),
-    ).start()
-    router = Router(pool, workers=8, max_inflight=1, retry_in_flight=True)
-    ctrl = AutoscaleController(
-        pool, router, min_replicas=1, max_replicas=2,
-        backlog_high=4.0, backlog_ticks=2, idle_low=0.5, idle_ticks=6,
-        up_cooldown_s=1.0, down_cooldown_s=2.0, tick_interval_s=0.2,
-    ).start()
-    try:
-        rep = _drive(router, reqs, offs, streams=2)
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline and _live(pool) > 1:
-            time.sleep(0.2)
-        ctrl.stop()
-        wall = time.perf_counter() - t0
-        cstats = ctrl.stats()
-        ratio = (
-            round(2 * wall / cstats["replica_seconds"], 2)
-            if cstats["replica_seconds"] else None
-        )
-        return {
-            "scale_ups": cstats["scale_ups"],
-            "scale_downs": cstats["scale_downs"],
-            "failed": rep["failed"],
-            "p99_s": _p99(router, "cdist"),
-            "replica_seconds_ratio": ratio,
-            "drained_to_min": _live(pool) <= 1,
-            "on_chip": False,
-            "cpu_fallback": CPU_FALLBACK_REASON,
-        }
-    finally:
-        ctrl.stop()
-        router.close()
-        pool.close()
 
 
 if __name__ == "__main__":

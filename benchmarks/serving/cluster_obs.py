@@ -84,9 +84,8 @@ def _emit(lines, obj):
     lines.append(obj)
 
 
-def _pool_env(args, workdir, extra=None):
+def _pool_env(args, extra=None):
     env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
         "HEAT_TPU_SERVE_MAX_BATCH": str(args.max_batch),
         "HEAT_TPU_SERVE_MAX_WAIT_MS": str(args.wait_ms),
         "HEAT_TPU_SERVE_QUEUE_MAX": str(args.queue_max),
@@ -186,7 +185,7 @@ def main():
                   log_name="pool"):
         pool = ReplicaPool(
             ckpt, args.replicas, mesh=args.replica_mesh,
-            env=_pool_env(args, workdir, extra_env),
+            env=_pool_env(args, extra_env),
             log_dir=os.path.join(workdir, f"logs_{log_name}"),
         ).start()
         router = Router(pool, workers=8, slos=slos)

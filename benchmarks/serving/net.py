@@ -121,9 +121,9 @@ def add_args(p):
                         "surviving replicas must absorb it)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--workdir", default=None,
-                   help="checkpoint + shared-cache directory (default: a "
-                        "fresh temp dir — every replica count still shares "
-                        "one compile cache within the run)")
+                   help="checkpoint + log directory (default: a fresh "
+                        "temp dir; the compile cache is JAX's persistent "
+                        "one, shared by every replica)")
     p.add_argument("--artifact", default=None,
                    help="append the emitted JSONL lines to this file")
 
@@ -133,15 +133,14 @@ def _emit(lines, obj):
     lines.append(obj)
 
 
-def _pool_env(args, workdir):
+def _pool_env(args):
     env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
         "HEAT_TPU_SERVE_MAX_BATCH": str(args.max_batch),
         "HEAT_TPU_SERVE_MAX_WAIT_MS": str(args.wait_ms),
         "HEAT_TPU_SERVE_QUEUE_MAX": str(args.queue_max),
     }
-    # the tuning DB rides along exactly like the compile cache when the
-    # parent run is armed (docs/AUTOTUNE.md): replicas start tuned
+    # the tuning DB rides along when the parent run is armed
+    # (docs/AUTOTUNE.md): replicas start tuned
     # heatlint: disable=HL005 -- pass-through of the parent's already-set
     # env into the replica subprocess env dict, not a knob read
     for var in ("HEAT_TPU_TUNE_DB", "HEAT_TPU_AUTOTUNE",
@@ -151,12 +150,12 @@ def _pool_env(args, workdir):
     return env
 
 
-def _spawn(args, ckpt, n, workdir, log_dir):
+def _spawn(args, ckpt, n, log_dir):
     from heat_tpu.serve.net import ReplicaPool, Router
 
     t0 = time.perf_counter()
     pool = ReplicaPool(
-        ckpt, n, mesh=args.replica_mesh, env=_pool_env(args, workdir),
+        ckpt, n, mesh=args.replica_mesh, env=_pool_env(args),
         log_dir=log_dir,
     ).start()
     router = Router(
@@ -287,7 +286,7 @@ def main():
     digest_probe = None
     for n in replicas_list:
         pool, router, spawn_wall = _spawn(
-            args, ckpt, n, workdir, os.path.join(log_dir, f"r{n}")
+            args, ckpt, n, os.path.join(log_dir, f"r{n}")
         )
         try:
             if digest_probe is None:
@@ -339,7 +338,7 @@ def main():
         n = max(replicas_list)
         rate = args.chaos_rate or args.rate / 2
         pool, router, _ = _spawn(
-            args, ckpt, n, workdir, os.path.join(log_dir, "chaos")
+            args, ckpt, n, os.path.join(log_dir, "chaos")
         )
         try:
             result = {}
@@ -434,62 +433,6 @@ def main():
         with open(args.artifact, "a") as f:
             for obj in lines:
                 f.write(json.dumps(obj) + "\n")
-
-
-def bench_field(replicas=(1, 2), requests=60, rate=80.0, mesh=4):
-    """The ``serving_net`` detail row for bench.py summaries
-    (docs/BENCHMARKS.md): a QUICK replica-scaling probe — tiny endpoint
-    set, ``replicas`` pool sizes at equal offered load — reporting the
-    QPS table and scale factor. Replicas always run virtual CPU meshes,
-    so the row carries its own ``on_chip``/``cpu_fallback`` verdict
-    regardless of the parent bench's backend (the bench-honesty
-    contract)."""
-    import heat_tpu as ht
-    from benchmarks.serving import loadgen
-    from heat_tpu.serve.net import ReplicaPool, Router
-
-    workdir = tempfile.mkdtemp(prefix="heat_tpu_srvnet_probe_")
-    ckpt = os.path.join(workdir, "endpoints.ckpt")
-    rng = np.random.default_rng(0)
-    y = rng.standard_normal((128, 16)).astype(np.float32)
-    server = ht.serve.Server()
-    server.register("cdist", ht.serve.cdist_query(y))
-    server.save(ckpt)
-    server.close()
-    reqs = loadgen.make_requests({"cdist": 16}, requests, 0, max_rows=1)
-    env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
-        "HEAT_TPU_SERVE_MAX_BATCH": "4",
-        "HEAT_TPU_SERVE_QUEUE_MAX": "64",
-        # the committed-artifact pacing regime (see the r12 artifact):
-        # per-replica throughput bounded by the gather window + one
-        # in-flight batch, so the scale factor measures the
-        # architecture, not host CPU contention
-        "HEAT_TPU_SERVE_MAX_WAIT_MS": "25",
-    }
-    out = {
-        "qps": {}, "p99_s": {},
-        "on_chip": False, "cpu_fallback": CPU_FALLBACK_REASON,
-    }
-    for n in replicas:
-        pool = ReplicaPool(
-            ckpt, int(n), mesh=mesh, env=env,
-            log_dir=os.path.join(workdir, f"logs_r{n}"),
-        ).start()
-        router = Router(pool, workers=8, max_inflight=1)
-        try:
-            report = loadgen.run_open_loop(router, reqs, rate, streams=2)
-            out["qps"][str(n)] = report["achieved_qps"]
-            out["p99_s"][str(n)] = report["latency"].get("p99_s")
-        finally:
-            router.close()
-            pool.close()
-    first, last = str(replicas[0]), str(replicas[-1])
-    if out["qps"].get(first):
-        out["scale_factor"] = round(
-            out["qps"][last] / out["qps"][first], 2
-        )
-    return out
 
 
 if __name__ == "__main__":

@@ -88,8 +88,8 @@ def add_args(p):
                    help="stream-fit phase only (no subprocess pool)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--workdir", default=None,
-                   help="data/checkpoint/shared-cache directory (default: "
-                        "a fresh temp dir)")
+                   help="data/checkpoint directory (default: a fresh "
+                        "temp dir)")
     p.add_argument("--artifact", default=None,
                    help="append the emitted JSONL lines to this file")
 
@@ -240,7 +240,6 @@ def _rolling(ht, args, lines, workdir):
 
     ckpts = _versioned_checkpoints(ht, args, workdir)
     env = {
-        "HEAT_TPU_COMPILE_CACHE": os.path.join(workdir, "xla_cache"),
         "HEAT_TPU_SERVE_MAX_BATCH": "4",
         "HEAT_TPU_SERVE_QUEUE_MAX": "64",
     }

@@ -1,0 +1,708 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+One process, no child process, every chip JAX finds. Drives the main path
+once through the entry points a user calls (``import heat_tpu as ht``) at
+the full width of the configurations ``bench.py`` measures, checks every
+result, and exits 0 only if every stage passed on a TPU. Claims no speed:
+wall times are printed as information only, and every stage ends in
+``jax.block_until_ready``.
+
+    python chip_smoke.py                 # on a TPU host; anything else fails
+    python chip_smoke.py --rehearse-cpu  # tiny sizes, CPU, Pallas interpreter
+
+Stages (one JSON line each, naming platform, device kind and device count):
+
+device   the default backend is a TPU whose ``device_kind`` has a row in the
+         peak table (``heat_tpu.chip_peaks``). JAX falls back to the CPU with
+         a warning when libtpu fails to initialise, so the assertion is ours.
+train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
+         d_model 1,024, 16 heads, batch 8 x 1,024 tokens, bf16, flash
+         attention per batch shard, remat) through the path of
+         examples/nn/lm_training.py: a ``program_cache.cached_program`` step,
+         batch on ``comm.sharding(0, 2)``, AdamW. Five steps on one seeded
+         batch: loss finite and lower at the end, nothing traced or compiled
+         after step one; every Mosaic call takes one chip's share of the
+         batch; on several chips, batch, parameters and optimizer state have
+         shards on every chip and per-chip peak memory is of one size.
+array    the reference's workloads at bench.py's sizes on split DNDarrays,
+         each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
+         bf16 matmul; cdist and rbf of 16384 x 128 (GEMM form); five Lloyd
+         iterations of KMeans(64) on 2M x 64; five Lasso sweeps. What mean,
+         var, cdist, rbf and KMeans.fit dispatched is read back from JAX's
+         own dump of the modules it lowered: each holds a Mosaic call of
+         its Pallas kernel, so no gate sent the call to the XLA form.
+kernels  each of the six Pallas kernels lowered at its production block
+         sizes with ``interpret`` left to the library, the lowering checked
+         for a Mosaic custom call (nothing resolved ``interpret=True``),
+         run, and compared with the XLA form it replaces.
+serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
+         (bench.py's serving configuration), warmed up; 32 requests; answers
+         equal ``km.predict``; nothing compiled after warm-up.
+
+Tolerances. TPU matmuls at default precision round their operands to
+bfloat16 (relative 2^-9); the K-family distance GEMMs and the Pallas cdist /
+Lloyd kernels use the three-pass bf16 split product (about 2^-16 of
+|x||y|). Each check below states the bound it uses and prints the error it
+saw. Oracles for matmul and cdist take a row subsample (rows are
+independent); moments, KMeans and Lasso are statistics of every row, so
+their oracles read the whole array on the host.
+"""
+
+import argparse
+import gc
+import json
+import os
+import re
+import sys
+import tempfile
+import time
+import traceback
+
+import numpy as np
+
+FULL = dict(
+    lm=dict(vocab=32768, d_model=1024, heads=16, layers=12, batch=8, seq=1024),
+    steps=5,
+    moments_rows=8_000_000,
+    matmul_n=8192,
+    cdist_rows=16384, cdist_k=128,
+    kmeans_rows=2_000_000, kmeans_k=64, iters=5,
+    attn_fwd=(4, 4096, 8, 128), attn_bwd=(8, 1024, 16, 64),
+    kernel_rows=1 << 20, lloyd_rows=1 << 18, int8_n=2048,
+    serve_rows=200_000, serve_k=16, requests=32, request_rows=16,
+)
+# the rehearsal keeps every shape rule (divisible by 4 devices, block
+# clamping) and nothing of the size
+TINY = dict(
+    lm=dict(vocab=256, d_model=64, heads=4, layers=2, batch=8, seq=128),
+    steps=5,
+    moments_rows=4096,
+    matmul_n=256,
+    cdist_rows=512, cdist_k=32,
+    kmeans_rows=4096, kmeans_k=8, iters=3,
+    attn_fwd=(1, 256, 2, 64), attn_bwd=(1, 256, 2, 64),
+    kernel_rows=2048, lloyd_rows=2048, int8_n=256,
+    serve_rows=2048, serve_k=4, requests=8, request_rows=4,
+)
+FEATURES = 64
+ORACLE_ROWS = 64
+
+
+def _err(got, ref):
+    return float(np.max(np.abs(np.asarray(got, np.float64) - ref)))
+
+
+def _check(cond, what):
+    if not cond:
+        raise AssertionError(what)
+
+
+def _on_all_devices(arr, devices):
+    return {s.device for s in arr.addressable_shards} == set(devices)
+
+
+# -- train --------------------------------------------------------------------
+
+
+def stage_train(ht, cfg, devices, on_tpu):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+    from heat_tpu.nn import TransformerLM
+
+    c = cfg["lm"]
+    comm = ht.get_comm()
+    arch = dict(
+        vocab_size=c["vocab"], d_model=c["d_model"], num_heads=c["heads"],
+        num_layers=c["layers"], max_len=c["seq"], dtype=jnp.bfloat16,
+    )
+    lm = TransformerLM(attn_impl="flash", remat=True, comm=comm, **arch)
+    opt = optax.adamw(3e-4)
+    key = (c["d_model"], c["layers"], c["vocab"])
+    replicated = comm.replicated()
+
+    # parameters do not depend on the attention core or the batch: draw them
+    # through the XLA twin on one short row, straight onto every chip
+    twin = TransformerLM(attn_impl="local", **arch)
+    init = program_cache.cached_program(
+        "smoke.lm_init", key,
+        lambda: lambda k: twin.init(k, jnp.zeros((1, 8), jnp.int32)),
+        comm=comm, out_shardings=replicated,
+    )
+    params = init(jax.random.PRNGKey(0))
+    opt_state = program_cache.cached_program(
+        "smoke.lm_opt_init", key, lambda: opt.init, comm=comm,
+        out_shardings=replicated,
+    )(params)
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+
+    def loss_fn(p, toks):
+        logits = lm.apply(p, toks)
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1].astype(jnp.float32), toks[:, 1:]
+        ).mean()
+
+    def step_fn(p, s, toks):
+        l, g = jax.value_and_grad(loss_fn)(p, toks)
+        u, s = opt.update(g, s, p)
+        return optax.apply_updates(p, u), s, l
+
+    step = program_cache.cached_program(
+        "smoke.lm_train_step", key, lambda: step_fn, comm=comm,
+    )
+    toks = np.random.default_rng(0).integers(
+        0, c["vocab"], (c["batch"], c["seq"]), dtype=np.int32
+    )
+    batch = jax.device_put(toks, comm.sharding(0, 2))
+
+    t0 = time.perf_counter()
+    params, opt_state, l = step(params, opt_state, batch)
+    losses = [float(jax.block_until_ready(l))]
+    first_step = time.perf_counter() - t0
+    misses = program_cache.stats()["sites"]["smoke.lm_train_step"]["misses"]
+    t0 = time.perf_counter()
+    with telemetry.CompileWatcher() as w:
+        for _ in range(cfg["steps"] - 1):
+            params, opt_state, l = step(params, opt_state, batch)
+            losses.append(float(jax.block_until_ready(l)))
+    later_steps = time.perf_counter() - t0
+    site = program_cache.stats()["sites"]["smoke.lm_train_step"]
+
+    _check(all(np.isfinite(losses)), f"loss not finite: {losses}")
+    _check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    _check(
+        w.events == 0 and site["misses"] == misses == 1,
+        f"compiled after step one: {dict(w.counts)}, registry {site}",
+    )
+    leaves = jax.tree.leaves((params, opt_state))
+    _check(
+        _on_all_devices(batch, devices)
+        and all(_on_all_devices(l, devices) for l in leaves),
+        "batch, parameters or optimizer state miss a chip",
+    )
+    if on_tpu:
+        # flash attention ran per batch shard: every Mosaic call in the
+        # step takes batch / chips rows, never the gathered batch
+        rows = set(re.findall(
+            r"@tpu_custom_call\(.*: \(tensor<(\d+)x",
+            step.lower(params, opt_state, batch).as_text(),
+        ))
+        _check(
+            rows == {str(c["batch"] // len(devices))},
+            f"Mosaic calls take batches of {sorted(rows)} rows, want "
+            f"{c['batch'] // len(devices)} per chip",
+        )
+    stats = [d.memory_stats() or {} for d in devices]
+    peaks = [int(s.get("peak_bytes_in_use", 0)) for s in stats]
+    if len(devices) > 1 and all(peaks):
+        _check(
+            max(peaks) - min(peaks) <= 0.1 * max(peaks),
+            f"per-chip peak memory is not of one size: {peaks}",
+        )
+    return dict(
+        params=n_params, losses=[round(v, 4) for v in losses],
+        first_step_seconds=round(first_step, 2),
+        later_steps_seconds=round(later_steps, 2),
+        peak_bytes_in_use=peaks,
+    )
+
+
+# -- array --------------------------------------------------------------------
+
+
+def _moments(ht, cfg, devices, took_mosaic):
+    import jax
+
+    from heat_tpu.core.pallas_moments import _moments_kernel
+
+    n = cfg["moments_rows"]
+    x = ht.random.randn(n, FEATURES, dtype=ht.float32, split=0)
+    _check(_on_all_devices(x.larray, devices), "split array misses a chip")
+    mu = ht.mean(x, axis=0)
+    took_mosaic("mean", _moments_kernel)
+    # var dispatches the program mean has just lowered and would find it in
+    # jit's memory: forget it, so that var's own dispatch is lowered too
+    jax.clear_caches()
+    var = ht.var(x, axis=0)
+    took_mosaic("var", _moments_kernel)
+    jax.block_until_ready((mu.larray, var.larray))
+    xh = x.numpy()
+    mu64 = xh.mean(axis=0, dtype=np.float64)
+    var64 = np.zeros(FEATURES)
+    for lo in range(0, n, 1 << 20):
+        var64 += ((xh[lo:lo + (1 << 20)] - mu64) ** 2).sum(axis=0)
+    var64 /= n
+    e_mu, e_var = _err(mu.numpy(), mu64), _err(var.numpy(), var64)
+    # f32 Welford carry over n/1024 row blocks of unit-variance data
+    _check(e_mu <= 1e-4 and e_var <= 1e-3, f"moments off: {e_mu}, {e_var}")
+    return dict(mean_err=e_mu, var_err=e_var)
+
+
+def _matmul(ht, cfg, rows):
+    import jax
+
+    n = cfg["matmul_n"]
+    a = (ht.random.randn(n, n, dtype=ht.float32, split=0) / np.sqrt(n)).astype(
+        ht.bfloat16
+    )
+    b = ht.random.randn(n, n, dtype=ht.float32, split=0).astype(ht.bfloat16)
+    out = ht.matmul(a, b)
+    jax.block_until_ready(out.larray)
+    idx = rows(n)
+    ref = a.numpy()[idx].astype(np.float64) @ b.numpy().astype(np.float64)
+    err = _err(out.numpy()[idx], ref)
+    # operands are exact in the oracle; f32 accumulation, bf16 result
+    # (relative 2^-9) — bound at 2^-7 of the largest entry
+    bound = float(np.abs(ref).max()) * 2.0 ** -7
+    _check(err <= bound, f"matmul off: {err} > {bound}")
+    return dict(matmul_err=err, matmul_bound=bound)
+
+
+def _cdist(ht, cfg, rows, took_mosaic):
+    import jax
+
+    from heat_tpu.spatial.pallas_cdist import _kernel
+
+    m, k = cfg["cdist_rows"], cfg["cdist_k"]
+    x = ht.random.rand(m, k, dtype=ht.float32, split=0)
+    y = ht.random.rand(m, k, dtype=ht.float32, split=0)
+    sigma = 4.0
+    dist = ht.spatial.cdist(x, y, quadratic_expansion=True)
+    took_mosaic("cdist", _kernel)
+    kern = ht.spatial.rbf(x, y, sigma=sigma, quadratic_expansion=True)
+    took_mosaic("rbf", _kernel)
+    jax.block_until_ready((dist.larray, kern.larray))
+    idx = rows(m)
+    xs, yh = x.numpy()[idx].astype(np.float64), y.numpy().astype(np.float64)
+    d2 = (xs * xs).sum(1)[:, None] + (yh * yh).sum(1)[None, :] - 2.0 * xs @ yh.T
+    e_d = _err(np.asarray(dist.larray[idx]), np.sqrt(d2))
+    e_k = _err(np.asarray(kern.larray[idx]), np.exp(-d2 / (2 * sigma * sigma)))
+    # bf16x3 dot: ~2^-16 of |x||y| (~43 at k=128) on d2, halved again by
+    # the square root at d ~ 4.6
+    _check(e_d <= 1e-3 and e_k <= 1e-4, f"cdist off: {e_d}, rbf {e_k}")
+    return dict(cdist_err=e_d, rbf_err=e_k)
+
+
+def _lloyd64(x, centers, iters):
+    """Float64 Lloyd iterations; an empty cluster keeps its center."""
+    k = centers.shape[0]
+    for _ in range(iters):
+        d2 = (centers * centers).sum(1)[None, :] - 2.0 * x @ centers.T
+        lab = d2.argmin(1)
+        cnt = np.bincount(lab, minlength=k)
+        sums = np.stack(
+            [np.bincount(lab, weights=x[:, j], minlength=k)
+             for j in range(x.shape[1])], axis=1,
+        )
+        centers = np.where(
+            cnt[:, None] > 0, sums / np.maximum(cnt, 1)[:, None], centers
+        )
+    return centers
+
+
+def _lasso64(x, y, lam, sweeps):
+    """Float64 coordinate descent, intercept first, as lasso._cd_sweep."""
+    n = x.shape[0]
+    xb = np.concatenate([np.ones((n, 1)), x], axis=1)
+    z = (xb * xb).mean(0)
+    theta = np.zeros(xb.shape[1])
+    for _ in range(sweeps):
+        y_est = xb @ theta
+        for j in range(xb.shape[1]):
+            xj = xb[:, j]
+            rho = (xj * (y - y_est + theta[j] * xj)).mean()
+            soft = np.sign(rho) * max(abs(rho) - lam, 0.0)
+            new = (rho if j == 0 else soft) / max(z[j], 1e-30)
+            y_est += (new - theta[j]) * xj
+            theta[j] = new
+    return theta
+
+
+def _kmeans_lasso(ht, cfg, took_mosaic):
+    import jax
+
+    from heat_tpu.cluster.pallas_lloyd import _lloyd_kernel
+
+    n, k, iters = cfg["kmeans_rows"], cfg["kmeans_k"], cfg["iters"]
+    x = ht.random.randn(n, FEATURES, dtype=ht.float32, split=0)
+    xh = x.numpy()
+    x64 = xh.astype(np.float64)
+
+    km = ht.cluster.KMeans(
+        n_clusters=k, init=ht.array(xh[:k]), max_iter=iters, tol=0.0
+    )
+    km.fit(x)
+    took_mosaic("KMeans.fit", _lloyd_kernel)
+    jax.block_until_ready(km.cluster_centers_.larray)
+    _check(km.n_iter_ == iters, f"Lloyd ran {km.n_iter_} of {iters} iterations")
+    e_c = _err(km.cluster_centers_.numpy(), _lloyd64(x64, x64[:k], iters))
+    # unstructured data: rows within the bf16x3 score error of a Voronoi
+    # face (~1e-4 of them) may change side; each moves a center of ~n/k
+    # rows by ~|x|k/n
+    _check(e_c <= 5e-3, f"KMeans centers off: {e_c}")
+
+    w = ht.random.randn(FEATURES, 1, dtype=ht.float32)
+    y = ht.matmul(x, w)
+    lam = 0.01
+    est = ht.regression.Lasso(lam=lam, max_iter=iters, tol=0.0)
+    est.fit(x, y)
+    jax.block_until_ready(est.theta.larray)
+    theta64 = _lasso64(x64, y.numpy().astype(np.float64)[:, 0], lam, iters)
+    e_t = _err(est.theta.numpy().ravel(), theta64)
+    # each sweep restarts from theta @ x at TPU default matmul precision
+    # (operands rounded to bf16, 2^-9)
+    bound = 2e-2 * float(np.abs(theta64).max())
+    _check(e_t <= bound, f"Lasso coefficients off: {e_t} > {bound}")
+    return dict(kmeans_err=e_c, lasso_err=e_t, lasso_bound=bound)
+
+
+def stage_array(ht, cfg, devices, on_tpu):
+    import jax
+
+    ht.random.seed(0)
+    rng = np.random.default_rng(1)
+
+    def rows(n):
+        return np.sort(rng.choice(n, min(ORACLE_ROWS, n), replace=False))
+
+    # JAX writes every module it hands to the compiler (or looks up in the
+    # persistent cache) under jax_dump_ir_to: the lowered text of what the
+    # user's call dispatched, not of what this script thinks it dispatches
+    read = set()
+
+    def took_mosaic(call, kernel):
+        """Among the modules lowered since the last look there is a Mosaic
+        custom call of ``kernel``: the call took the Pallas path, compiled.
+        (Off the TPU the library's gates choose the XLA forms.)"""
+        new = set(os.listdir(ir_dir)) - read
+        read.update(new)
+        _check(new, f"{call} lowered no module")
+        text = "".join(open(os.path.join(ir_dir, f)).read() for f in new)
+        _check(
+            not on_tpu or re.search(
+                rf'@tpu_custom_call\(.*kernel_name = "{kernel.__name__}"', text
+            ),
+            f"{call}: no Mosaic call of {kernel.__name__} in the "
+            f"{len(new)} modules it lowered",
+        )
+
+    out = {}
+    with tempfile.TemporaryDirectory() as ir_dir:
+        jax.config.update("jax_dump_ir_to", ir_dir)
+        try:
+            for part in (
+                lambda: _moments(ht, cfg, devices, took_mosaic),
+                lambda: _matmul(ht, cfg, rows),
+                lambda: _cdist(ht, cfg, rows, took_mosaic),
+                lambda: _kmeans_lasso(ht, cfg, took_mosaic),
+            ):
+                out.update(part())
+                gc.collect()  # the next workload gets the chip's memory back
+        finally:
+            jax.config.update(
+                "jax_dump_ir_to", os.environ.get("JAX_DUMP_IR_TO", "")
+            )
+    return {k: float(f"{v:.3g}") for k, v in out.items()}
+
+
+# -- kernels ------------------------------------------------------------------
+
+
+def stage_kernels(cfg, on_tpu):
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu.cluster import kmeans as _kmeans
+    from heat_tpu.cluster.pallas_lloyd import lloyd_fit_pallas
+    from heat_tpu.core import program_cache
+    from heat_tpu.core.linalg.quant import int8_matmul, quantize_int8
+    from heat_tpu.core.pallas_moments import column_moments
+    from heat_tpu.parallel import flash_attention, local_attention
+    from heat_tpu.spatial import distance as _distance
+    from heat_tpu.spatial.pallas_cdist import euclid_pallas
+
+    # On a TPU nobody names the interpreter: flash attention and the int8
+    # GEMM choose by backend, the other kernels compile unless told
+    # otherwise, and the lowering shows which side each took.
+    rehearse = {} if on_tpu else {"interpret": True}
+    key = jax.random.PRNGKey(2)
+    report, wrong = {}, []
+
+    def run(name, fn, ref_fn, args, bound):
+        """Lower ``fn``, require the Mosaic custom call, run it, and bound
+        its distance from the XLA form. Every kernel reports; the stage
+        fails at the end if any was wrong."""
+        prog = program_cache.cached_program(f"smoke.{name}", (), lambda: fn)
+        lowered = prog.lower(*args)
+        if on_tpu and "tpu_custom_call" not in lowered.as_text():
+            wrong.append(f"{name}: no Mosaic custom call in the lowering")
+        got = jax.block_until_ready(lowered.compile()(*args))
+        ref = jax.block_until_ready(
+            program_cache.cached_program(
+                f"smoke.{name}_xla", (), lambda: ref_fn
+            )(*args)
+        )
+        err = max(
+            _err(g, np.asarray(r, np.float64))
+            for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref))
+        )
+        if not (np.isfinite(err) and err <= bound):
+            wrong.append(f"{name}: {err} > {bound}")
+        report[name] = float(f"{err:.3g}")
+
+    def qkv(shape):
+        return tuple(
+            jax.random.normal(k, shape, jnp.bfloat16)
+            for k in jax.random.split(key, 3)
+        )
+
+    # bf16 attention: p and the output round to bf16 (2^-9 of O(1) values)
+    run(
+        "flash_fwd",
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        lambda q, k, v: local_attention(q, k, v, causal=True),
+        qkv(cfg["attn_fwd"]), 3e-2,
+    )
+
+    def attn_grads(attend):
+        def loss(q, k, v):
+            return attend(q, k, v).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    for impl in ("two_pass", "fused"):
+        run(
+            f"flash_bwd_{impl}",
+            attn_grads(lambda q, k, v, impl=impl: flash_attention(
+                q, k, v, causal=True, bwd_impl=impl)),
+            attn_grads(lambda q, k, v: local_attention(q, k, v, causal=True)),
+            qkv(cfg["attn_bwd"]), 1e-1,
+        )
+
+    m, k = cfg["cdist_rows"], cfg["cdist_k"]
+    x = jax.random.uniform(key, (min(m, 2048), k), jnp.float32)
+    y = jax.random.uniform(jax.random.fold_in(key, 1), (m, k), jnp.float32)
+    gamma = 1.0 / 32.0
+    # both sides are HIGH-class (bf16x3) dots; see _cdist for the bound
+    run(
+        "cdist_dist",
+        lambda x, y: euclid_pallas(x, y, **rehearse),
+        _distance._quadratic_euclidean, (x, y), 1e-3,
+    )
+    run(
+        "cdist_rbf",
+        lambda x, y: euclid_pallas(
+            x, y, gamma, epilogue="rbf", **rehearse),
+        lambda x, y: jnp.exp(
+            -gamma * _distance._quadratic_euclidean(x, y) ** 2),
+        (x, y), 1e-4,
+    )
+
+    # separated blobs, one start in each: no row sits near a Voronoi face,
+    # so the two programs assign alike and differ by f32 summation order
+    n, kc, iters = cfg["lloyd_rows"], cfg["kmeans_k"], cfg["iters"]
+    means = 4.0 * jax.random.normal(jax.random.fold_in(key, 3), (kc, FEATURES))
+    xs = jax.random.normal(key, (n, FEATURES), jnp.float32) + jnp.tile(
+        means, (n // kc, 1)
+    ).astype(jnp.float32)
+    tol = jnp.float32(0.0)
+    run(
+        "lloyd",
+        lambda xs, c0: lloyd_fit_pallas(
+            xs, c0, n, iters, tol, **rehearse)[0],
+        lambda xs, c0: _kmeans._lloyd_fit(
+            xs, jnp.ones((n,), jnp.float32), c0, iters, tol)[0],
+        (xs, xs[:kc]), 1e-3,
+    )
+
+    n = cfg["kernel_rows"]
+    xm = jax.random.normal(key, (n, FEATURES), jnp.float32) + 3.0
+    def kernel_moments(xm):
+        mean, m2 = column_moments(xm, n, **rehearse)
+        return mean, m2 / n
+
+    run(
+        "moments", kernel_moments,
+        lambda xm: (xm.mean(0), ((xm - xm.mean(0)) ** 2).mean(0)),
+        (xm,), 1e-4,  # f32 sums over n rows: mean 3, variance 1
+    )
+
+    n = cfg["int8_n"]
+    qa, sa = quantize_int8(jax.random.normal(key, (n, n), jnp.float32), axis=1)
+    qb, sb = quantize_int8(
+        jax.random.normal(jax.random.fold_in(key, 2), (n, n), jnp.float32), axis=0
+    )
+    run(
+        "int8_gemm",
+        int8_matmul,
+        lambda qa, sa, qb, sb: jax.lax.dot_general(
+            qa, qb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        ).astype(jnp.float32) * (sa * sb),
+        (qa, sa, qb, sb), 1e-3,  # exact i32 products; one f32 rescale each
+    )
+    _check(not wrong, f"{wrong}; all kernels: {report}")
+    return report
+
+
+# -- serve --------------------------------------------------------------------
+
+
+def stage_serve(ht, cfg):
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+
+    ht.random.seed(3)
+    k = cfg["serve_k"]
+    km = ht.cluster.KMeans(n_clusters=k, max_iter=10, random_state=0)
+    km.fit(ht.random.randn(cfg["serve_rows"], FEATURES, dtype=ht.float32, split=0))
+    rng = np.random.default_rng(4)
+    payloads = [
+        rng.standard_normal((cfg["request_rows"], FEATURES)).astype(np.float32)
+        for _ in range(cfg["requests"])
+    ]
+    queries = np.concatenate(payloads)
+    want = np.asarray(km.predict(ht.array(queries)).numpy())
+
+    server = ht.serve.Server(max_batch=64)
+    try:
+        server.register("kmeans", ht.serve.kmeans_predict(km))
+        warm = server.warmup()
+        before = program_cache.site_stats("serve.")["misses"]
+        with telemetry.CompileWatcher() as w:
+            futures = [server.submit("kmeans", p) for p in payloads]
+            got = np.concatenate([np.asarray(f.result(120)) for f in futures])
+        after = program_cache.site_stats("serve.")["misses"]
+    finally:
+        server.close()
+    _check(
+        w.events == 0 and after == before,
+        f"compiled after warm-up: {dict(w.counts)}, misses {before}->{after}",
+    )
+    # the served kernel scores rows in the exact broadcast form, predict in
+    # the HIGH-precision GEMM form: they may part only on a row that the
+    # float64 oracle calls a tie (two nearest centers within 1e-4 relative)
+    c64 = km.cluster_centers_.numpy().astype(np.float64)
+    d2 = ((queries.astype(np.float64)[:, None, :] - c64[None]) ** 2).sum(-1)
+    best2 = np.sort(d2, axis=1)[:, :2]
+    tie = (best2[:, 1] - best2[:, 0]) <= 1e-4 * best2[:, 1]
+    differ = got != want
+    _check(not np.any(differ & ~tie), "served answers differ from predict")
+    return dict(
+        requests=len(payloads), rows=int(queries.shape[0]),
+        tie_rows_differing=int(differ.sum()),
+        warmup_programs=int(warm["programs"]),
+        warmup_seconds=round(float(warm["seconds"]), 2),
+    )
+
+
+# -- driver -------------------------------------------------------------------
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument(
+        "--rehearse-cpu", action="store_true",
+        help="tiny sizes on the CPU with the Pallas interpreter: checks the "
+             "script, never the chip; every line is marked a rehearsal",
+    )
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    on_tpu = devices[0].platform == "tpu"
+    if not on_tpu and not args.rehearse_cpu:
+        print(
+            f"chip_smoke: no TPU: JAX's default backend is "
+            f"{devices[0].platform!r} ({devices[0].device_kind}). This "
+            "script proves the chip; it does not fall back.",
+            file=sys.stderr,
+        )
+        return 2
+    if on_tpu and args.rehearse_cpu:
+        print("chip_smoke: --rehearse-cpu on a TPU host", file=sys.stderr)
+        return 2
+
+    import heat_tpu as ht
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+
+    cache_dir = program_cache.enable_persistent_cache()
+
+    def cache_entries():
+        return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+    where = dict(
+        platform=devices[0].platform, device_kind=devices[0].device_kind,
+        device_count=len(devices),
+    )
+    if args.rehearse_cpu:
+        where["rehearsal"] = True
+    cfg = TINY if args.rehearse_cpu else FULL
+    failed = []
+
+    def emit(stage, ok, seconds, **fields):
+        print(json.dumps(dict(
+            stage=stage, ok=ok, **where, seconds=round(seconds, 2), **fields
+        )), flush=True)
+        if not ok:
+            failed.append(stage)
+
+    t0 = time.perf_counter()
+    try:
+        if on_tpu:
+            peaks = ht.chip_peaks(devices[0].device_kind)._asdict()
+        else:
+            peaks = None
+        _check(
+            ht.get_comm().size == len(devices),
+            "the default communicator does not span every device",
+        )
+        emit("device", True, time.perf_counter() - t0, peaks=peaks,
+             cache_dir=cache_dir, cache_entries=cache_entries())
+    except Exception as e:  # noqa: BLE001 — reported, then fatal
+        traceback.print_exc()
+        emit("device", False, time.perf_counter() - t0, error=repr(e))
+        return 1
+
+    stages = {
+        "train": lambda: stage_train(ht, cfg, devices, on_tpu),
+        "array": lambda: stage_array(ht, cfg, devices, on_tpu),
+        "kernels": lambda: stage_kernels(cfg, on_tpu),
+        "serve": lambda: stage_serve(ht, cfg),
+    }
+    for name, stage in stages.items():
+        t0 = time.perf_counter()
+        try:
+            with telemetry.CompileWatcher() as cw:
+                fields = stage()
+            emit(name, True, time.perf_counter() - t0,
+                 compile_seconds=round(cw.seconds, 2), **fields)
+        except Exception as e:  # noqa: BLE001 — every stage reports; rc below
+            traceback.print_exc()
+            emit(name, False, time.perf_counter() - t0, error=repr(e)[:2000])
+        gc.collect()
+
+    result = dict(
+        ok=not failed,
+        device=dict(
+            platform=devices[0].platform, kind=devices[0].device_kind,
+            count=len(devices),
+        ),
+    )
+    if failed:
+        result["failed"] = failed
+    if args.rehearse_cpu:
+        result["rehearsal"] = True
+    print(json.dumps(dict(cache_dir=cache_dir, cache_entries=cache_entries())),
+          flush=True)
+    print(json.dumps(result), flush=True)
+    return 0 if not failed else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

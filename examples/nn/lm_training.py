@@ -62,14 +62,21 @@ def main():
     impl = "flash" if jax.default_backend() == "tpu" else "local"
     print(f"mesh: {comm.size} devices, attention core: {impl}")
 
+    # comm= makes the flash core run on each chip's batch shard
     lm = TransformerLM(vocab_size=VOCAB, d_model=D_MODEL, num_heads=HEADS,
-                       num_layers=LAYERS, max_len=SEQ, attn_impl=impl)
+                       num_layers=LAYERS, max_len=SEQ, attn_impl=impl,
+                       comm=comm)
     train = make_corpus(BATCH * STEPS_PER_EPOCH, seed=1)
     heldout = make_corpus(256, seed=2)
 
-    params = lm.init(jax.random.PRNGKey(0), train[:2])
+    # parameters and optimizer state replicated over the mesh from the
+    # start: step 2 then sees the placement step 1 returned, and the step
+    # program compiles once
     opt = optax.adamw(1e-2)
-    opt_state = opt.init(params)
+    params = jax.device_put(
+        lm.init(jax.random.PRNGKey(0), train[:comm.size]), comm.replicated()
+    )
+    opt_state = jax.device_put(opt.init(params), comm.replicated())
 
     def loss_fn(p, toks):
         logits = lm.apply(p, toks[:, :-1])

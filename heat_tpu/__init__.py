@@ -14,12 +14,6 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
-# older-runtime API shims (jax.shard_map / lax.pcast / pltpu.CompilerParams)
-# — must install before any kernel module loads (see _jax_compat.py)
-from . import _jax_compat as _compat
-
-_compat.install()
-
 # telemetry first: it is import-light (no core dependency) and the core
 # modules' instrumentation hooks reference it; HEAT_TPU_TELEMETRY=1 in the
 # environment turns recording on here (docs/OBSERVABILITY.md)

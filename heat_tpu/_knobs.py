@@ -179,12 +179,6 @@ _register(
     "(core/program_cache.py); LRU eviction beyond it.",
 )
 _register(
-    "HEAT_TPU_COMPILE_CACHE", "str", None,
-    "Directory for the persistent on-disk XLA compilation cache; read at "
-    "`import heat_tpu`. A second process deserializes instead of "
-    "recompiling (docs/TUNING_RUNBOOK.md).",
-)
-_register(
     "HEAT_TPU_FUSION", "bool", True,
     "Elementwise defer-and-fuse dispatch (core/fusion.py). `0` restores "
     "pure-eager dispatch bit-for-bit.",
@@ -444,7 +438,7 @@ _register(
     "HEAT_TPU_SERVE_NET_REPLICAS", "int", 2,
     "Default replica-process count of serve.net.ReplicaPool (each "
     "replica restores the endpoint checkpoint and warms from the shared "
-    "HEAT_TPU_COMPILE_CACHE / HEAT_TPU_TUNE_DB).",
+    "JAX compilation cache / HEAT_TPU_TUNE_DB).",
 )
 _register(
     "HEAT_TPU_SERVE_NET_POLL_MS", "float", 25.0,
@@ -615,8 +609,8 @@ _register(
     "Directory of the persistent tuning DB (atomic-swap JSON records "
     "keyed by program signature + mesh topology + backend). A second "
     "process pointed at a populated DB starts *tuned* with zero measured "
-    "trials, the same way HEAT_TPU_COMPILE_CACHE makes it start "
-    "*compiled*.",
+    "trials, the same way JAX's persistent compilation cache makes it "
+    "start *compiled*.",
 )
 _register(
     "HEAT_TPU_AUTOTUNE_TRIALS", "int", 5,
@@ -635,12 +629,6 @@ _register(
 _register(
     "HEAT_TPU_SWEEP_ATTN", "bool", False,
     "bench.py: sweep ring/ulysses attention variants in the headline run.",
-    scope="bench",
-)
-_register(
-    "HEAT_TPU_BENCH_COOLDOWN", "float", 60.0,
-    "bench.py: seconds to sleep between heavyweight rows (thermal "
-    "settling on shared hosts).",
     scope="bench",
 )
 _register(
@@ -684,8 +672,6 @@ for _name, _doc in (
      "chunks of test files (bounds accumulated XLA state)."),
     ("HEAT_TPU_CI_ALLOW_MISSING_IO", "Skip the loud optional-I/O backend "
      "presence check."),
-    ("HEAT_TPU_CI_NO_COMPILE_CACHE", "Disable the sweep-wide persistent "
-     "XLA compile cache (measure true cold compiles)."),
     ("HEAT_TPU_CI_SKIP_AUDIT", "Skip the HLO collective-audit step."),
     ("HEAT_TPU_CI_SKIP_WARMCACHE", "Skip the warm-compile-cache reuse "
      "check."),

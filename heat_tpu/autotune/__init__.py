@@ -32,8 +32,8 @@ loop — the observability stack becomes a control system:
    signature + mesh topology + backend). A second process consults the
    DB at ``program_cache`` miss / ``serve.Server`` construction time —
    behind one ``HEAT_TPU_AUTOTUNE`` flag check — and starts *tuned*
-   with zero measured trials, the same way ``HEAT_TPU_COMPILE_CACHE``
-   makes it start *compiled*.
+   with zero measured trials, the same way the persistent compile cache
+   (``program_cache.enable_persistent_cache``) makes it start *compiled*.
 
 Adoption model: a winning config is installed into the knob **overlay**
 (:func:`heat_tpu._knobs.set_override`), the layer every registered knob

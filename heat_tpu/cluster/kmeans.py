@@ -201,35 +201,21 @@ class KMeans(_KCluster):
             pallas_lloyd_applicable,
         )
 
-        done = False
-        if pallas_lloyd_applicable(
+        tol = jnp.asarray(self.tol, xb.dtype)
+        if not pallas_lloyd_applicable(
             x.comm.size, x.split, x.shape[1], self.n_clusters, xb.dtype
         ):
-            # fused single-pass-over-X Lloyd update (see pallas_lloyd);
-            # Mosaic failure degrades to the XLA fit rather than erroring
-            try:
-                if x.comm.size > 1:
-                    p_out = lloyd_fit_pallas_sharded(
-                        x.comm, xb, centers, x.shape[0], self.max_iter,
-                        jnp.asarray(self.tol, xb.dtype),
-                    )
-                else:
-                    p_out = lloyd_fit_pallas(
-                        xb, centers, x.shape[0], self.max_iter,
-                        jnp.asarray(self.tol, xb.dtype),
-                    )
-                # materialize INSIDE the try — async TPU runtime faults
-                # surface lazily and must trigger the fallback here
-                jax.block_until_ready(p_out)
-                centers, labels, inertia, n_iter = p_out
-                done = True
-            except Exception as e:  # pragma: no cover — TPU-runtime only
-                import warnings
-
-                warnings.warn(f"pallas kmeans fell back to XLA: {e!r}")
-        if not done:
             centers, labels, inertia, n_iter = _lloyd_fit(
-                xb, w, centers, self.max_iter, jnp.asarray(self.tol, xb.dtype)
+                xb, w, centers, self.max_iter, tol
+            )
+        # fused single-pass-over-X Lloyd update (see pallas_lloyd)
+        elif x.comm.size > 1:
+            centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
+                x.comm, xb, centers, x.shape[0], self.max_iter, tol
+            )
+        else:
+            centers, labels, inertia, n_iter = lloyd_fit_pallas(
+                xb, centers, x.shape[0], self.max_iter, tol
             )
         n_iter = int(n_iter)
 

@@ -53,8 +53,8 @@ def _round_up(v: int, m: int) -> int:
 
 
 def _lloyd_kernel(
-    lim_ref, x_ref, c_ref, sums_ref, counts_ref, sums_s, counts_s, *, bm, k,
-    precision,
+    lim_ref, x_ref, c_ref, c2_ref, sums_ref, counts_ref, sums_s, counts_s,
+    *, bm, k, precision,
 ):
     """Grid = (num_row_blocks,), sequential. Scratch (sums, counts)
     accumulates across blocks; written out at the last block. ``lim_ref``
@@ -75,11 +75,20 @@ def _lloyd_kernel(
     # on-chip by scripts/tpu_tune.py (Mosaic lowering cost per strategy
     # is not uniform; see pallas_util.dot_f32)
     dot = dot_f32(xb, c, (((1,), (1,)), ((), ())), precision)  # (bm, kp)
-    c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, kp)
-    score = c2 - jnp.float32(2.0) * dot  # argmin-equivalent to d2
+    # ||c||^2 arrives as a lane-major (8, kp) input: reducing c*c over
+    # lanes in here leaves a sublane vector, and Mosaic's relayout of it
+    # to the (1, kp) row the broadcast needs costs ~64 KB of scoped VMEM
+    # per block row (32 MB at bm=512, over the 16 MiB limit)
+    score = c2_ref[0:1, :] - jnp.float32(2.0) * dot  # argmin-equiv. to d2
     jidx = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
     score = jnp.where(jidx < k, score, jnp.float32(3.4e38))  # mask center pads
-    labels = jnp.argmin(score, axis=1)[:, None]  # (bm, 1)
+    # first-minimum index as two lane reductions: jnp.argmin yields i64
+    # under jax_enable_x64, which Mosaic refuses
+    smin = jnp.min(score, axis=1, keepdims=True)
+    labels = jnp.min(
+        jnp.where(score == smin, jidx, jnp.int32(score.shape[1])),
+        axis=1, keepdims=True,
+    )  # (bm, 1)
     row = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     valid = row < lim_ref[0]
     onehot = jnp.where(
@@ -111,6 +120,9 @@ def _lloyd_update(x, centers_pad, n, k, bm, interpret, lim=None,
     kp = centers_pad.shape[0]
     if lim is None:
         lim = jnp.full((1,), n, jnp.int32)
+    c2 = jnp.broadcast_to(
+        jnp.sum(centers_pad * centers_pad, axis=1)[None, :], (8, kp)
+    )
     return pl.pallas_call(
         functools.partial(_lloyd_kernel, bm=bm, k=k, precision=precision),
         grid=(mp // bm,),
@@ -121,6 +133,7 @@ def _lloyd_update(x, centers_pad, n, k, bm, interpret, lim=None,
             pl.BlockSpec((1,), lambda i: (_I0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, dp), lambda i: (i, _I0), memory_space=pltpu.VMEM),
             pl.BlockSpec((kp, dp), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((8, kp), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((kp, dp), lambda i: (_I0, _I0), memory_space=pltpu.VMEM),
@@ -138,7 +151,7 @@ def _lloyd_update(x, centers_pad, n, k, bm, interpret, lim=None,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(lim.astype(jnp.int32), x, centers_pad)
+    )(lim.astype(jnp.int32), x, centers_pad, c2)
 
 
 @functools.partial(

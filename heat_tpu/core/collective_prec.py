@@ -452,6 +452,29 @@ def reduce_scatter(x, axis_name: str, nproc: int, mode_: str,
     return red.astype(x.dtype)
 
 
+def _exact_psum(x, axis_name: str, groups):
+    """``lax.psum``, scoped to ``groups`` when given. jax 0.9 has no
+    grouped psum under shard_map's varying-axis checker (the result is
+    invariant only within a group, which its types cannot say), so the
+    grouped form is the textbook decomposition — reduce-scatter then
+    all-gather over the same groups, the same wire bytes."""
+    if groups is None:
+        return jax.lax.psum(x, axis_name)
+    n = x.size
+    flat = x.reshape(-1)
+    pad = -n % len(groups[0])
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    s = jax.lax.psum_scatter(
+        flat, axis_name, scatter_dimension=0, axis_index_groups=groups,
+        tiled=True,
+    )
+    out = jax.lax.all_gather(
+        s, axis_name, axis_index_groups=groups, tiled=True
+    )
+    return out[:n].reshape(x.shape)
+
+
 def psum(x, axis_name: str, nproc: int, mode_: str,
          block: Optional[int] = None, groups=None):
     """Compressed ``lax.psum`` — the EQuARX two-phase quantized
@@ -464,12 +487,10 @@ def psum(x, axis_name: str, nproc: int, mode_: str,
     ``groups`` scopes every collective to ``axis_index_groups`` (the
     ISSUE 15 cross-node tier); ``nproc`` is then the group size."""
     if mode_ == "off" or not compressible(x.dtype):
-        return jax.lax.psum(x, axis_name, axis_index_groups=groups)
+        return _exact_psum(x, axis_name, groups)
     if mode_ == "bf16":
         w = x if x.dtype == jnp.bfloat16 else x.astype(jnp.bfloat16)
-        return jax.lax.psum(
-            w, axis_name, axis_index_groups=groups
-        ).astype(x.dtype)
+        return _exact_psum(w, axis_name, groups).astype(x.dtype)
     block = block or block_size()
     n = x.size
     red, chunk = _quant_scatter_phase(

@@ -46,8 +46,20 @@ __all__ = [
     "init_distributed",
     "sanitize_comm",
     "use_comm",
+    "to_varying",
     "CommunicationError",
 ]
+
+
+def to_varying(x, axis: str):
+    """Type ``x`` as device-varying over mesh axis ``axis`` for
+    ``shard_map``'s varying-axis checker (fresh accumulators are
+    replicated; a loop carry that mixes with sharded values must vary).
+    A value that already varies passes through: ``pcast`` refuses a
+    varying→varying cast."""
+    if axis in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 class CommunicationError(RuntimeError):

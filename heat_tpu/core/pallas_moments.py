@@ -34,7 +34,12 @@ __all__ = [
 ]
 
 _I0 = np.int32(0)
-_MAX_D = 4096  # (bm, dp) f32 block + 4 (8, dp) accumulators must fit VMEM
+_MAX_D = 4096
+# f32 bytes of one (bm, dp) row block. The kernel holds the block double-
+# buffered plus ~4 block-sized temporaries (masked copy, centered copy,
+# squares), all inside v5e's 16 MiB scoped-VMEM limit: 1024 x 4096 blocks
+# asked Mosaic for 32 MiB.
+_BLOCK_BYTES = 1 << 20
 
 
 def _round_up(v: int, m: int) -> int:
@@ -77,9 +82,12 @@ def _moments_kernel(lim_ref, x_ref, mean_ref, m2_ref, mean_s, m2_s, cnt_s, *, bm
     # LOCAL valid-row count (inside shard_map each shard passes its own
     # limit; block round-up pads past it drop out)
     valid = (row < lim_ref[0]).astype(jnp.float32)  # (bm, 1)
-    nv = jnp.sum(valid)  # block count (scalar f32)
+    # this block's valid rows, in scalar arithmetic: the predicate and the
+    # SMEM count below must not come out of a vector reduction
+    nv_i = jnp.minimum(jnp.maximum(lim_ref[0] - i * bm, 0), bm)
+    nv = nv_i.astype(jnp.float32)
 
-    @pl.when(nv > 0)
+    @pl.when(nv_i > 0)
     def _combine():
         xv = xb * valid
         bsum = jnp.sum(xv, axis=0, keepdims=True)  # (1, dp)
@@ -127,7 +135,9 @@ def column_moments(
         x = pre_map(x)
     m, d = x.shape
     dp = _round_up(d, 64)  # 64-lane granularity: d=64 stays unpadded
-    bm = min(block_m, _round_up(m, 8))
+    bm = min(
+        block_m, _round_up(m, 8), max(8, _BLOCK_BYTES // (dp * 4) // 8 * 8)
+    )
     mp = _round_up(m, bm)
     if (mp, dp) != (m, d):
         x = jnp.pad(x.astype(jnp.float32), ((0, mp - m), (0, dp - d)))

@@ -7,7 +7,7 @@ hi(a)·lo(b) + lo(a)·hi(b)`` with ``hi(x) = bf16(x)`` and
 ``lo(x) = bf16(x − hi(x))`` — which is numerically the classical bf16x3
 compensation (the same error class as ``Precision.HIGH``) but built from
 three DEFAULT-tier dots that Mosaic provably lowers onto the MXU. The
-round-5 on-chip capture (artifacts/bench_tpu_session_r5a.json) measured
+round-5 builder-side on-chip capture (figures in ROADMAP.md) measured
 the HIGH-tier in-kernel dot at ~36× below the cdist write roofline —
 consistent with an off-MXU (VPU-loop) lowering — so guaranteed-MXU
 multi-pass form matters independently of the enum tiers.

@@ -44,13 +44,14 @@ recompiled an identical program. Now:
 Persistent (cross-process) compilation cache
 --------------------------------------------
 Orthogonal to the in-process registry, :func:`enable_persistent_cache`
-wires JAX's on-disk XLA compilation cache: with
-``HEAT_TPU_COMPILE_CACHE=<dir>`` in the environment (read at import, the
-same activation pattern as ``HEAT_TPU_TELEMETRY``), repeated CI shards and
-benchmark sweep processes skip backend compiles entirely — the measured
-dominant cost of the tier-1 suite. ``scripts/run_ci.sh`` and
-``benchmarks/_harness.py`` enable it by default; see
-docs/TUNING_RUNBOOK.md for the knob semantics.
+turns on JAX's on-disk XLA compilation cache under ONE placement rule:
+where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses that
+directory and the code sets no other; where it is not, the cache lives at
+``<checkout>/.jax_cache`` (git-ignored). The path is part of a cache
+entry's key, so it never comes from a temporary directory. The entry
+points call it (``chip_smoke.py``, ``bench.py``, ``benchmarks/_harness.py``,
+``serve/net/replica.py``, ``tests/conftest.py``); child processes inherit
+the environment, so replicas share their parent's cache.
 """
 
 from __future__ import annotations
@@ -59,7 +60,7 @@ import os
 import threading
 import warnings
 from collections import OrderedDict
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, Sequence, Tuple
 
 import jax
 
@@ -75,7 +76,6 @@ __all__ = [
     "reset",
     "clear",
     "enable_persistent_cache",
-    "persistent_cache_dir",
     "DEFAULT_MAXSIZE",
 ]
 
@@ -300,39 +300,25 @@ clear = reset
 
 # -- persistent (cross-process) XLA compilation cache -------------------------
 
-_PERSISTENT_DIR: Optional[str] = None
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_cache(path: str) -> str:
-    """Point JAX's on-disk compilation cache at ``path`` (created if
-    missing) and drop the min-compile-time threshold to 0 so every
-    executable is eligible — the tier-1 suite and the bench sweeps are
-    dominated by many *small* compiles, exactly the entries the default
-    1-second threshold skips. Returns the path. Idempotent."""
-    global _PERSISTENT_DIR
-    path = os.fspath(path)
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+def enable_persistent_cache() -> str:
+    """Turn on JAX's on-disk compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
+    function sets no directory. Unset: ``<checkout>/.jax_cache``. Either
+    way the two entry thresholds drop to 0 so every executable is
+    eligible — the suites and the smoke are dominated by many *small*
+    compiles, exactly the entries the default 1-second threshold skips.
+    Idempotent."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _CHECKOUT_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    _PERSISTENT_DIR = path
     return path
-
-
-def persistent_cache_dir() -> Optional[str]:
-    """The active on-disk compilation cache directory, or None."""
-    return _PERSISTENT_DIR
-
-
-# Environment activation (mirrors HEAT_TPU_TELEMETRY): HEAT_TPU_COMPILE_CACHE
-# names the cache directory; `import heat_tpu` is enough to enable it.
-_env_dir = knobs.raw("HEAT_TPU_COMPILE_CACHE", "").strip()
-if _env_dir:
-    try:
-        enable_persistent_cache(_env_dir)
-    except Exception as _e:  # pragma: no cover — bad path must not kill import
-        warnings.warn(
-            f"heat_tpu.program_cache: cannot enable persistent compile "
-            f"cache at {_env_dir!r} ({_e}); continuing without it"
-        )
-del _env_dir

@@ -617,26 +617,18 @@ def mean(x: DNDarray, axis=None, keepdims_internal: bool = False, keepdims: bool
             x.comm.size, x.split, x.ndim, 0, x.shape[1],
             x.dtype.jnp_type(),  # metadata, so a pending chain stays pending
         ):
-            try:
-                mu = _pallas_moments_fused(x, "mean")
-                if mu is None:
-                    if x.comm.size > 1:
-                        mu, _m2 = sharded_column_moments(
-                            x.comm, x._masked(0), x.shape[0]
-                        )
-                    else:
-                        mu, _m2 = column_moments(x.larray, x.shape[0])
-                import jax
-
-                jax.block_until_ready(mu)  # surface Mosaic faults HERE
-                return DNDarray.from_logical(
-                    mu, None, x.device, x.comm,
-                    types.canonical_heat_type(mu.dtype),
-                )
-            except Exception as e:  # pragma: no cover — TPU-runtime only
-                import warnings
-
-                warnings.warn(f"pallas mean fell back to sum/count: {e!r}")
+            mu = _pallas_moments_fused(x, "mean")
+            if mu is None:
+                if x.comm.size > 1:
+                    mu, _m2 = sharded_column_moments(
+                        x.comm, x._masked(0), x.shape[0]
+                    )
+                else:
+                    mu, _m2 = column_moments(x.larray, x.shape[0])
+            return DNDarray.from_logical(
+                mu, None, x.device, x.comm,
+                types.canonical_heat_type(mu.dtype),
+            )
 
     keep = keepdims or keepdims_internal
     s = arithmetics.sum(x, axis, keepdims=keep)
@@ -913,27 +905,19 @@ def var(x: DNDarray, axis=None, ddof: int = 0, keepdims: bool = False) -> DNDarr
             x.comm.size, x.split, x.ndim, 0, x.shape[1],
             x.dtype.jnp_type(),  # metadata, so a pending chain stays pending
         ):
-            try:
-                out = _pallas_moments_fused(x, "var", ddof=ddof)
-                if out is None:
-                    if x.comm.size > 1:
-                        _mu, m2 = sharded_column_moments(
-                            x.comm, x._masked(0), x.shape[0]
-                        )
-                    else:
-                        _mu, m2 = column_moments(x.larray, x.shape[0])
-                    out = m2 / (x.shape[0] - ddof)
-                import jax
-
-                jax.block_until_ready(out)  # surface Mosaic faults HERE
-                return DNDarray.from_logical(
-                    out, None, x.device, x.comm,
-                    types.canonical_heat_type(out.dtype),
-                )
-            except Exception as e:  # pragma: no cover — TPU-runtime only
-                import warnings
-
-                warnings.warn(f"pallas var fell back to two-pass: {e!r}")
+            out = _pallas_moments_fused(x, "var", ddof=ddof)
+            if out is None:
+                if x.comm.size > 1:
+                    _mu, m2 = sharded_column_moments(
+                        x.comm, x._masked(0), x.shape[0]
+                    )
+                else:
+                    _mu, m2 = column_moments(x.larray, x.shape[0])
+                out = m2 / (x.shape[0] - ddof)
+            return DNDarray.from_logical(
+                out, None, x.device, x.comm,
+                types.canonical_heat_type(out.dtype),
+            )
 
     mu = mean(x, axis, keepdims_internal=True)
     d = arithmetics.sub(x, mu)

@@ -283,18 +283,10 @@ def hier_psum(x, axis_name: str, topo: Topology,
         flat, axis_name, scatter_dimension=0,
         axis_index_groups=topo.node_groups(), tiled=True,
     )
-    if cross_wire == "off" or not collective_prec.compressible(x.dtype):
-        s = jax.lax.psum(s, axis_name, axis_index_groups=topo.cross_groups())
-    elif cross_wire == "bf16":
-        w = s if s.dtype == jnp.bfloat16 else s.astype(jnp.bfloat16)
-        s = jax.lax.psum(
-            w, axis_name, axis_index_groups=topo.cross_groups()
-        ).astype(x.dtype)
-    else:
-        s = collective_prec.psum(
-            s, axis_name, topo.node, cross_wire, block,
-            groups=topo.cross_groups(),
-        )
+    s = collective_prec.psum(
+        s, axis_name, topo.node, cross_wire, block,
+        groups=topo.cross_groups(),
+    )
     out = jax.lax.all_gather(
         s, axis_name, axis_index_groups=topo.node_groups(), tiled=True,
     )

@@ -239,6 +239,15 @@ class DataParallel:
                 return jax.lax.pmean(g, axis)
             return collective_prec.pmean(g, axis, p, wire, block)
 
+        # check_vma=False below: the compressed mean ends in an
+        # all-gather, so every position holds the same gradients, but the
+        # varying-axis checker cannot infer that replication. The kernel
+        # also relies on the unchecked mode for its arithmetic: checked,
+        # the gradient of a P() parameter comes back already summed over
+        # the axis and grad_mean would count it p times. Both are pinned
+        # in tests/test_collective_prec.py (the compressed trajectory
+        # tracks the exact step; every position returns the same bits).
+
         def kernel_body(params, opt_state, batch):
             # local grads of the local-batch mean loss; the global mean
             # over equal shards is the pmean of the local means (the
@@ -262,7 +271,7 @@ class DataParallel:
                 in_specs = (P(), P()) + (P(axis),) * len(batch)
                 return jax.shard_map(
                     kernel, mesh=comm.mesh, in_specs=in_specs,
-                    out_specs=(P(), P(), P()),
+                    out_specs=(P(), P(), P()), check_vma=False,
                 )(params, opt_state, *batch)
 
         else:
@@ -289,7 +298,7 @@ class DataParallel:
                 in_specs = (P(), P(), P()) + (P(axis),) * len(batch)
                 return jax.shard_map(
                     kernel, mesh=comm.mesh, in_specs=in_specs,
-                    out_specs=(P(), P(), P(), P()),
+                    out_specs=(P(), P(), P(), P()), check_vma=False,
                 )(params, opt_state, pending_grads, *batch)
 
         return step

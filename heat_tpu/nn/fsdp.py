@@ -70,6 +70,8 @@ def _tie(tree: Any, token):
     if not leaves:
         return tree
     tok = jax.lax.stop_gradient(token)
+    # the token's zero cotangent must carry the primal's varying axes
+    tok_vma = tuple(jax.typeof(tok).vma)
 
     def impl(args):
         out = jax.lax.optimization_barrier(tuple(args))
@@ -83,7 +85,10 @@ def _tie(tree: Any, token):
         return impl(args), None
 
     def bwd(_, ct):
-        return tuple(ct) + (jnp.zeros(tok.shape, tok.dtype),)
+        zero = jnp.zeros(tok.shape, tok.dtype)
+        if tok_vma:
+            zero = jax.lax.pcast(zero, tok_vma, to="varying")
+        return tuple(ct) + (zero,)
 
     barrier.defvjp(fwd, bwd)
     out = barrier(*(tuple(leaves) + (tok,)))

@@ -15,7 +15,8 @@ Parallelism is selected by ``attn_impl``:
 
 - ``"local"`` — single-shard XLA blockwise attention.
 - ``"flash"`` — the hand-tiled Pallas kernel
-  (:func:`heat_tpu.parallel.flash_attention`, 2.7× the XLA path on v5e).
+  (:func:`heat_tpu.parallel.flash_attention`); with ``comm=`` it runs per
+  batch shard (data parallel over the mesh).
 - ``"ring"`` / ``"ulysses"`` — sequence-parallel over a mesh axis, for
   sequences sharded with :class:`heat_tpu.MeshCommunication` (pass
   ``comm=``). Ring keeps K/V moving over ICI; ulysses swaps sequence↔heads
@@ -28,6 +29,7 @@ model; the dryrun (`__graft_entry__.py`) exercises a dp×sp layout.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -44,14 +46,23 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
     )
 
     if impl == "flash":
-        if block_size is None:
-            return flash_attention(  # tuned tiles
-                q, k, v, causal=causal, bwd_impl=flash_bwd_impl
-            )
-        return flash_attention(
-            q, k, v, causal=causal, block_q=block_size, block_k=block_size,
-            bwd_impl=flash_bwd_impl,
+        # block_size None = the kernel's tuned tiles
+        blocks = {} if block_size is None else {
+            "block_q": block_size, "block_k": block_size,
+        }
+        attend = functools.partial(
+            flash_attention, causal=causal, bwd_impl=flash_bwd_impl, **blocks
         )
+        if comm is not None and comm.size > 1:
+            # data parallel: the kernel runs on each chip's batch shard. A
+            # bare pallas_call is opaque to the SPMD partitioner, which
+            # would gather the sharded batch onto every chip to call it.
+            spec = comm.spec(0, 4)
+            return jax.shard_map(
+                attend, mesh=comm.mesh, in_specs=(spec, spec, spec),
+                out_specs=spec,
+            )(q, k, v)
+        return attend(q, k, v)
     if impl == "ring":
         # the ring processes one mesh chunk per hop; there is no block knob
         return ring_attention(q, k, v, comm=comm, causal=causal)

@@ -215,6 +215,10 @@ class ZeroOptimizer(DataParallelOptimizer):
                     kernel, mesh=comm.mesh,
                     in_specs=(P(), specs_s, P()),
                     out_specs=(P(), specs_s),
+                    # the parameter all-gather leaves every position
+                    # with the same values; the varying-axis checker
+                    # cannot infer that replication
+                    check_vma=False,
                 )(params, opt_state, grads)
 
             return step_fn
@@ -247,6 +251,9 @@ class ZeroOptimizer(DataParallelOptimizer):
 
         def build():
             def kernel(params, opt_state, *batch):
+                # local gradients: under check_vma=False (below) the
+                # gradient of a P() parameter is not summed over the axis
+                # behind our back, so the reduce-scatter is the only sum
                 loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
                 loss = comm.psum(loss, precision="off") / p
 
@@ -278,6 +285,10 @@ class ZeroOptimizer(DataParallelOptimizer):
                     kernel, mesh=comm.mesh,
                     in_specs=in_specs,
                     out_specs=(P(), specs_s, P()),
+                    # as in step(): gathered params. The P() outputs
+                    # being one value on every position is pinned in
+                    # tests/test_zero_optimizer.py
+                    check_vma=False,
                 )(params, opt_state, *batch)
 
             return step_outer

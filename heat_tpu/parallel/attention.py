@@ -217,11 +217,6 @@ def ulysses_attention(
     if h % p != 0:
         raise ValueError(f"heads ({h}) must divide over mesh size ({p})")
     seq_len = t_pad if seq_len is None else seq_len
-    # resolve interpreter mode from the mesh's devices here, outside
-    # shard_map — inside the kernel the inputs are tracers and the global
-    # default backend misleads in mixed-platform processes
-    pallas_interpret = any(d.platform != "tpu" for d in comm.devices)
-
     def kernel(qb, kb, vb):
         # (B, T/p, H, D) -> (B, T, H/p, D): gather seq, scatter heads.
         # Wrapper-routed (ISSUE 15): the exchanges are priced by
@@ -237,7 +232,6 @@ def ulysses_attention(
 
             oh = flash_attention(
                 qh, kh, vh, causal=causal, scale=scale, kv_valid=seq_len,
-                interpret=pallas_interpret,
             )
         else:
             oh = local_attention(

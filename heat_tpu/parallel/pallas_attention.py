@@ -701,25 +701,6 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd_dispatch)
 
 
-def _resolve_interpret(x) -> bool:
-    """True when the kernel must run in the Pallas interpreter.
-
-    Resolved from where the computation actually runs, not the global
-    default backend: a concrete input's device platform wins, because in a
-    mixed-platform process (a forced virtual CPU mesh alongside a live TPU
-    backend, e.g. the multichip dryrun after a real-chip compile check)
-    ``jax.default_backend()`` says "tpu" while the arrays live on CPU.
-    Tracers carry no placement, so they fall back to the default backend.
-    """
-    try:
-        platforms = {d.platform for d in x.devices()}
-        if platforms:
-            return platforms != {"tpu"}
-    except Exception:
-        pass
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -737,11 +718,10 @@ def flash_attention(
 
     Same contract as :func:`heat_tpu.parallel.attention.local_attention`:
     ``(B, T, H, D)`` layout, f32 online softmax, K/V positions >= ``kv_valid``
-    masked as padding. Default (512, 1024) blocks won the v5e block sweep;
-    the jit-chained benchmark at B4·T4096·H8·D128 bf16 measures 68.2 TFLOP/s
-    (README table), 2.7× the XLA online-softmax path. Blocks are clamped for
-    short sequences. ``interpret`` defaults to True off-TPU so the same
-    tests run on the CPU mesh via the Pallas interpreter.
+    masked as padding. Blocks are clamped for short sequences.
+    ``interpret`` defaults to the Pallas interpreter when the default
+    backend is not a TPU, so the same tests run on the CPU mesh;
+    ``chip_smoke.py`` asserts the chip took the compiled side.
 
     ``bwd_impl`` selects the backward strategy: ``"two_pass"`` (the r4
     dq + dk/dv kernels, the measured default), ``"fused"`` (single-pass
@@ -753,7 +733,7 @@ def flash_attention(
     if q.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
     if interpret is None:
-        interpret = _resolve_interpret(q)
+        interpret = jax.default_backend() != "tpu"
     d = q.shape[-1]
     t_k = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

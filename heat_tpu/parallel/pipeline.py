@@ -44,6 +44,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..core import program_cache
+from ..core.communication import to_varying
 from ..telemetry import collectives as _coll
 from . import schedule as _schedule
 
@@ -536,7 +537,7 @@ def pipeline_step_program(
             mb_numel = 1
             for d in mb_shape[1:]:
                 mb_numel *= int(d)
-            varying = lambda v: jax.lax.pcast(v, (axis,), to="varying")
+            varying = lambda v: to_varying(v, axis)
             micro_x = varying(micro_x)
             if train:
                 micro_y = varying(micro_y)
@@ -604,7 +605,7 @@ def pipeline_step_program(
                                 )
 
                             lval, vjp = jax.vjp(fl, ws, x_in)
-                            dws, dx = vjp(jnp.ones((), lval.dtype))
+                            dws, dx = vjp(varying(jnp.ones((), lval.dtype)))
                             return dws, dx, lval.astype(jnp.float32)
 
                         def mid(ws, x_in, g_in):
@@ -708,6 +709,11 @@ def pipeline_step_program(
         p_specs = jax.tree_util.tree_unflatten(
             layout.treedef, [P(axis)] * n_leaves
         )
+        from ..core import topology as _topo
+
+        # a tiered psum ends in an in-node all-gather, whose replication
+        # the varying-axis checker cannot infer for the P() outputs
+        check_vma = _topo.active(p) is None
 
         if train:
             def step(params, opt_state, micro_x, micro_y):
@@ -724,6 +730,7 @@ def pipeline_step_program(
                     mesh=comm.mesh,
                     in_specs=(p_specs, s_specs, P(), P()),
                     out_specs=(p_specs, s_specs, P()),
+                    check_vma=check_vma,
                 )(params, opt_state, micro_x, micro_y)
 
             return step
@@ -734,6 +741,7 @@ def pipeline_step_program(
                 mesh=comm.mesh,
                 in_specs=(p_specs, P()),
                 out_specs=P(),
+                check_vma=check_vma,
             )(params, micro_x)
 
         return fwd

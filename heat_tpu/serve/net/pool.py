@@ -3,11 +3,15 @@
 :class:`ReplicaPool` turns one endpoint checkpoint into N serving
 processes (ISSUE 12): each replica runs
 ``python -m heat_tpu.serve.net.replica`` against the SAME checkpoint,
-and — when the parent exports them — the SAME persistent
-``HEAT_TPU_COMPILE_CACHE`` and ``HEAT_TPU_TUNE_DB`` directories, so
-replica 2..N reach the zero-compile, pre-tuned steady state without
-retracing (the PR 3 / PR 11 "second process starts warm" property, now
-the thing that makes horizontal scale-out cheap). The pool:
+the SAME persistent JAX compilation cache (every replica applies
+``program_cache.enable_persistent_cache``'s rule to the environment it
+inherits) and — when the parent exports it — the SAME
+``HEAT_TPU_TUNE_DB`` directory, so replica 2..N reach the zero-compile,
+pre-tuned steady state without retracing (the PR 3 / PR 11 "second
+process starts warm" property, now the thing that makes horizontal
+scale-out cheap). Replicas are virtual-CPU-mesh processes (``mesh=N``):
+a chip belongs to one process, so a pool on the attached TPU is refused
+(in-process one-chip replicas are ROADMAP R7). The pool:
 
 * **spawns** replicas as detached subprocesses, parses each one's ready
   line (bound ephemeral port, warm-up report), and tails stderr into a
@@ -138,6 +142,19 @@ class ReplicaHandle:
         return self.proc.poll() is None
 
 
+def _replicas_would_take_tpu(env_overrides: Dict[str, str]) -> bool:
+    """Whether a replica started with this environment and no ``--mesh``
+    would initialise the TPU backend."""
+    platforms = env_overrides.get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+    )
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 class ReplicaPool:
     """Spawn + manage ``replicas`` serving processes over one endpoint
     checkpoint (module docstring has the lifecycle)."""
@@ -165,6 +182,15 @@ class ReplicaPool:
         self.mesh = int(mesh)
         self.host = host
         self.env_overrides = dict(env or {})
+        if self.mesh == 0 and _replicas_would_take_tpu(self.env_overrides):
+            raise RuntimeError(
+                "ReplicaPool(mesh=0) starts every replica process on the "
+                "attached platform, and here that is a TPU. A chip belongs "
+                "to one process at a time: a replica started while another "
+                "process holds it (this one, once it has touched JAX, or a "
+                "sibling) only waits out ready_timeout. Pass mesh=N for "
+                "virtual-CPU-mesh replicas."
+            )
         self.python = python or sys.executable
         self.ready_timeout = float(ready_timeout)
         self.log_dir = log_dir or tempfile.mkdtemp(prefix="heat_tpu_pool_")

@@ -10,12 +10,14 @@ N times. Lifecycle:
    crash-recovery path: a replica is rebuilt from the CRC-verified
    resilience checkpoint, never refit — restored answers are
    bit-identical);
-3. ``warmup()`` the whole batch ladder. With the parent exporting a
-   shared ``HEAT_TPU_COMPILE_CACHE`` dir this deserializes instead of
-   compiling, and a shared ``HEAT_TPU_TUNE_DB`` warm-starts the knob
-   overlay with zero measured trials (PR 3 / PR 11 — "a second process
-   starts compiled *and* tuned", now load-bearing for horizontal
-   scale);
+3. ``warmup()`` the whole batch ladder. Replicas share JAX's persistent
+   compilation cache (``program_cache.enable_persistent_cache``: the
+   inherited ``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``),
+   so this deserializes what a sibling or an earlier run compiled
+   instead of compiling, and a shared ``HEAT_TPU_TUNE_DB`` warm-starts
+   the knob overlay with zero measured trials (PR 3 / PR 11 — "a second
+   process starts compiled *and* tuned", now load-bearing for
+   horizontal scale);
 4. start the :class:`~.transport.HttpFront` (which arms the
    steady-state CompileWatcher ``/stats`` exposes) and print ONE
    machine-readable **ready line** on stdout::
@@ -76,10 +78,12 @@ def main(argv=None) -> int:
     # imported here, after the mesh decision — backend init is lazy, and
     # restore() below is the first device touch
     from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
     from heat_tpu.serve import Server
 
     from .transport import HttpFront
 
+    program_cache.enable_persistent_cache()
     server = Server.restore(args.checkpoint)
     warm = server.warmup()
     front = HttpFront(

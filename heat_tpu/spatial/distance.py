@@ -18,7 +18,6 @@ On TPU two paths replace it:
 
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Callable, Optional
 
@@ -322,26 +321,16 @@ def _dist(
         layout_ok = x.comm.size == 1 or x.split == 0
         if layout_ok and pallas_cdist_applicable(x.shape[1], promoted.jnp_type()):
             epi = "rbf" if rbf_gamma is not None else "dist"
-            try:
-                out = _pallas_local(
-                    x.comm,
-                    x.larray.astype(promoted.jnp_type()),
-                    yb.astype(promoted.jnp_type()),
-                    epi,
-                    0.0 if rbf_gamma is None else float(rbf_gamma),
-                )
-                # force materialization INSIDE the try: Mosaic/TPU runtime
-                # faults surface lazily and must trigger the fallback here,
-                # not at the caller's first read
-                jax.block_until_ready(out)
-            except Exception as e:  # pragma: no cover — TPU-runtime only
-                # Mosaic lowering/runtime failure must degrade to the XLA
-                # form, not kill the workload
-                warnings.warn(f"pallas cdist fell back to XLA: {e!r}")
-            else:
-                return DNDarray(
-                    out, (m, n), promoted, out_split, x.device, x.comm, True
-                )
+            out = _pallas_local(
+                x.comm,
+                x.larray.astype(promoted.jnp_type()),
+                yb.astype(promoted.jnp_type()),
+                epi,
+                0.0 if rbf_gamma is None else float(rbf_gamma),
+            )
+            return DNDarray(
+                out, (m, n), promoted, out_split, x.device, x.comm, True
+            )
 
     out = _local_dist(block_fn, x.larray, yb, promoted.jnp_type())
     return _finish(out)

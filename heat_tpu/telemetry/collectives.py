@@ -523,8 +523,10 @@ def hierarchical_allreduce_cost(
     2. **cross-node all-reduce** (DCN) of the ``1/local``-sized shard —
        each device's cross payload is ``B_pad/local``, exactly the shard
        factor the acceptance oracle pins; ``local`` cross groups of
-       ``node`` participants. ``cross_precision`` compresses THIS stage
-       only (bf16 payload, or the EQuARX two-phase form per group);
+       ``node`` participants, emitted as a grouped reduce-scatter +
+       all-gather pair (``collective_prec._exact_psum``: the same wire
+       bytes as one all-reduce). ``cross_precision`` compresses THIS
+       stage only (bf16 payload, or the EQuARX two-phase form per group);
     3. **in-node all-gather** (ICI, exact) of the reduced shard —
        ``B_pad · (local-1) · node``.
 
@@ -551,8 +553,9 @@ def hierarchical_allreduce_cost(
         wire = itemsize
         if cross_precision == "bf16" and itemsize > 2:
             wire = 2
-        cross = 2 * chunk * wire * (node - 1) * local
-        kind = "reduce-scatter+all-reduce+all-gather"
+        chunk_pad = -(-chunk // node) * node  # the pair scatters evenly
+        cross = 2 * chunk_pad * wire * (node - 1) * local
+        kind = "reduce-scatter+all-gather"
     return CollectiveCost(
         kind, tier_ici * 2 + cross, dcn_bytes=cross
     )

@@ -114,6 +114,18 @@ _TENSOR_RE = re.compile(
     r"|c64|c128)\[([0-9,]*)\]"
 )
 
+# Any instruction definition, `[ROOT] %name = <type> opcode(`: the
+# installed XLA prints a collective's operands as bare names
+# (`all-to-all(%wrapped_slice, %wrapped_slice.1)`), so operand bytes come
+# from the defining instruction's result type. Instruction names are
+# unique across an HLO module.
+_DEF_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%(?P<name>[^\s=]+)\s*=\s*(?P<rtype>\([^)]*\)|\S+)\s+"
+    r"[\w\-]+\(",
+    re.MULTILINE,
+)
+_OPERAND_NAME_RE = re.compile(r"%([^\s,()]+)")
+
 _GROUPS_LITERAL_RE = re.compile(r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
@@ -238,11 +250,21 @@ def parse_hlo(
     carries no replica_groups attribute (flat single-group default).
     """
     out: List[EmittedCollective] = []
+    defs: Optional[Dict[str, str]] = None
     for m in _INSTR_RE.finditer(text):
         if m.group("variant") == "-done":
             continue
         op = m.group("op")
         operands, attrs = _split_operands_attrs(m.group("rest"))
+        if not _TENSOR_RE.search(operands):
+            if defs is None:
+                defs = {
+                    d.group("name"): d.group("rtype")
+                    for d in _DEF_RE.finditer(text)
+                }
+            operands = " ".join(
+                defs.get(n, "") for n in _OPERAND_NAME_RE.findall(operands)
+            )
         in_bytes, in_dtype, _ = _tensor_bytes(operands)
         out_bytes, out_dtype, shapes = _tensor_bytes(m.group("rtype"))
         if m.group("variant") == "-start" and in_bytes <= out_bytes:

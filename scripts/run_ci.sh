@@ -75,19 +75,13 @@ fi
 # Persistent XLA compile cache shared across the whole sweep (ISSUE 3): the
 # suite is compile-bound, and retried chunks / repeated sizes / the per-
 # module jax.clear_caches() in conftest all recompile programs a previous
-# process already built. One on-disk cache makes those backend compiles a
-# deserialization. HEAT_TPU_CI_NO_COMPILE_CACHE=1 opts out (e.g. to measure
-# true cold-compile time).
-if [ -z "${HEAT_TPU_CI_NO_COMPILE_CACHE:-}" ]; then
-    if [ -z "${HEAT_TPU_COMPILE_CACHE:-}" ]; then
-        # we created it, we clean it up — a caller-provided cache dir is
-        # theirs to keep (that is the cross-run reuse case)
-        export HEAT_TPU_COMPILE_CACHE=$(mktemp -d -t heat_tpu_cc.XXXXXX)
-        OWN_COMPILE_CACHE=$HEAT_TPU_COMPILE_CACHE
-        trap '[ -n "${OWN_COMPILE_CACHE:-}" ] && rm -rf "$OWN_COMPILE_CACHE"' EXIT
-    fi
-    echo "=== persistent compile cache: ${HEAT_TPU_COMPILE_CACHE} ==="
-fi
+# process already built. tests/conftest.py and the benchmark harness turn
+# JAX's on-disk cache on under the one placement rule
+# (program_cache.enable_persistent_cache): $JAX_COMPILATION_CACHE_DIR where
+# set, else <checkout>/.jax_cache. To measure true cold-compile time, point
+# JAX_COMPILATION_CACHE_DIR at an empty directory.
+COMPILE_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$(pwd)/.jax_cache}"
+echo "=== persistent compile cache: ${COMPILE_CACHE_DIR} ==="
 
 have_coverage=0
 if [ -n "$REPORT" ]; then
@@ -120,8 +114,8 @@ log_resilience() {
 # entries in the persistent compile cache (each "-cache" file is one XLA
 # executable some process had to backend-compile)
 cc_count() {
-    if [ -n "${HEAT_TPU_COMPILE_CACHE:-}" ] && [ -d "${HEAT_TPU_COMPILE_CACHE}" ]; then
-        ls "${HEAT_TPU_COMPILE_CACHE}" 2>/dev/null | grep -c -- '-cache$' || true
+    if [ -d "${COMPILE_CACHE_DIR}" ]; then
+        ls "${COMPILE_CACHE_DIR}" 2>/dev/null | grep -c -- '-cache$' || true
     else
         echo 0
     fi
@@ -184,10 +178,8 @@ for n in $SIZES; do
         echo "=== suite @ ${n} devices ran NO tests — failing the size ==="
         rc=2
     fi
-    if [ -n "${HEAT_TPU_COMPILE_CACHE:-}" ]; then
-        cc_after=$(cc_count)
-        echo "=== compile-count @ ${n} devices: $((cc_after - cc_before)) new XLA executables (cache total ${cc_after}) ==="
-    fi
+    cc_after=$(cc_count)
+    echo "=== compile-count @ ${n} devices: $((cc_after - cc_before)) new XLA executables (cache total ${cc_after}) ==="
     if [ "$rc" != 0 ]; then
         echo "=== suite @ ${n} devices FAILED (rc=$rc) — continuing sweep ==="
         FAILED_SIZES="$FAILED_SIZES $n"
@@ -256,9 +248,9 @@ if [ -z "${HEAT_TPU_CI_SKIP_WARMCACHE:-}" ]; then
     warm_dir=$(mktemp -d -t heat_tpu_warm.XXXXXX)
     warm_rc=0
     cold_out=$(mktemp); warm_out=$(mktemp)
-    if HEAT_TPU_COMPILE_CACHE="$warm_dir" python benchmarks/resplit/heat_tpu.py \
+    if JAX_COMPILATION_CACHE_DIR="$warm_dir" python benchmarks/resplit/heat_tpu.py \
             --n 2048 --features 32 --trials 1 --mesh 4 > "$cold_out" \
-       && HEAT_TPU_COMPILE_CACHE="$warm_dir" python benchmarks/resplit/heat_tpu.py \
+       && JAX_COMPILATION_CACHE_DIR="$warm_dir" python benchmarks/resplit/heat_tpu.py \
             --n 2048 --features 32 --trials 1 --mesh 4 > "$warm_out"; then
         python - "$cold_out" "$warm_out" <<'EOF' || warm_rc=$?
 import json, sys
@@ -309,9 +301,10 @@ if [ -z "${HEAT_TPU_CI_SKIP_FUSION:-}" ]; then
     echo "=== fusion dispatch check (elementwise microbenchmark, 4-device mesh) ==="
     fusion_out=$(mktemp)
     fusion_rc=0
-    # a fresh compile-cache-free run: the program-count comparison must see
-    # real backend compiles, not deserializations from the sweep's cache
-    if env -u HEAT_TPU_COMPILE_CACHE python benchmarks/elementwise/heat_tpu.py \
+    # an empty compile cache: the program-count comparison must see real
+    # backend compiles, not deserializations from the sweep's cache
+    fusion_cc=$(mktemp -d -t heat_tpu_cold.XXXXXX)
+    if JAX_COMPILATION_CACHE_DIR="$fusion_cc" python benchmarks/elementwise/heat_tpu.py \
             --n 100000 --features 64 --trials 2 --mesh 4 > "$fusion_out"; then
         python - "$fusion_out" <<'EOF' || fusion_rc=$?
 import json, sys
@@ -356,7 +349,7 @@ EOF
     if [ -n "$REPORT" ]; then
         cp "$fusion_out" "${REPORT}/fusion_elementwise.jsonl" || true
     fi
-    rm -f "$fusion_out"
+    rm -f "$fusion_out"; rm -rf "$fusion_cc"
     if [ "$fusion_rc" != 0 ]; then
         echo "=== fusion dispatch check FAILED (rc=$fusion_rc) ==="
         FAILED_SIZES="$FAILED_SIZES fusion"
@@ -385,9 +378,10 @@ if [ -z "${HEAT_TPU_CI_SKIP_FUSION_REDUCE:-}" ]; then
     echo "=== fusion-reduce dispatch check (reduction microbenchmark, 4-device mesh) ==="
     fr_out=$(mktemp)
     fr_rc=0
-    # compile-cache-free: the program-count comparison must see real
+    # an empty compile cache: the program-count comparison must see real
     # backend compiles, not deserializations from the sweep's cache
-    if env -u HEAT_TPU_COMPILE_CACHE python benchmarks/reduction/heat_tpu.py \
+    fr_cc=$(mktemp -d -t heat_tpu_cold.XXXXXX)
+    if JAX_COMPILATION_CACHE_DIR="$fr_cc" python benchmarks/reduction/heat_tpu.py \
             --n 100000 --features 64 --trials 2 --mesh 4 > "$fr_out"; then
         python - "$fr_out" <<'EOF' || fr_rc=$?
 import json, sys
@@ -458,7 +452,7 @@ EOF
     if [ -n "$REPORT" ]; then
         cp "$fr_out" "${REPORT}/fusion_reduction.jsonl" || true
     fi
-    rm -f "$fr_out"
+    rm -f "$fr_out"; rm -rf "$fr_cc"
     if [ "$fr_rc" != 0 ]; then
         echo "=== fusion-reduce dispatch check FAILED (rc=$fr_rc) ==="
         FAILED_SIZES="$FAILED_SIZES fusion-reduce"

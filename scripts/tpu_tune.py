@@ -3,7 +3,8 @@
 Run on the real TPU to (a) verify the new Pallas cdist/Lloyd kernels beat
 the XLA forms, (b) find the matmul steady-state MFU config, (c) measure the
 moments pass against the HBM roofline. Each experiment is isolated — a
-failure prints an error line and the sweep continues. Usage:
+failure prints an error line and the sweep continues — and the exit code
+is non-zero when any experiment failed or the host has no TPU. Usage:
 
     python scripts/tpu_tune.py [--only cdist,kmeans,matmul,moments,rbf,lm,attn_bwd]
 
@@ -19,7 +20,9 @@ import numpy as np
 
 
 def _sync(arr):
-    return float(arr[(0,) * arr.ndim])
+    import jax
+
+    return jax.block_until_ready(arr)
 
 
 def _time(fn, repeats=2):
@@ -35,18 +38,19 @@ def emit(**kw):
     print(json.dumps(kw), flush=True)
 
 
-def run_guarded(name, fn):
-    try:
-        fn()
-    except Exception as e:  # noqa: BLE001
-        emit(exp=name, error=repr(e))
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    failed = []
+
+    def run_guarded(name, fn):
+        try:
+            fn()
+        except Exception as e:  # noqa: BLE001 — isolate; the rc says it
+            emit(exp=name, error=repr(e))
+            failed.append(name)
 
     def want(name):
         return only is None or name in only
@@ -56,7 +60,13 @@ def main():
 
     import heat_tpu as ht
 
-    emit(device=jax.devices()[0].device_kind, n=len(jax.devices()))
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"tpu_tune: no TPU (default backend is {dev.platform!r})",
+              file=sys.stderr)
+        return 2
+    peak_gflops = ht.chip_peaks(dev.device_kind).bf16_flops / 1e9
+    emit(device=dev.device_kind, n=len(jax.devices()))
 
     # ---------------- cdist: pallas kernel vs XLA form -------------------
     m, k, reps = 16384, 128, 10
@@ -219,7 +229,7 @@ def main():
                 t = _time(run)
                 gf = reps_ * 2.0 * n_ ** 3 / t / 1e9
                 emit(exp=f"matmul_bf16_n{n_}_r{reps_}", gflops=round(gf, 1),
-                     mfu_v5e=round(gf / 197e3, 3), seconds=round(t, 3))
+                     mfu=round(gf / peak_gflops, 3), seconds=round(t, 3))
 
             run_guarded(f"matmul_{n_}_{reps_}", do)
 
@@ -276,7 +286,7 @@ def main():
                 tm = _time(run)
                 gf = lreps * 6.0 * n_params * b * t / tm / 1e9
                 emit(exp=f"lm_step_remat_{pol or 'full'}_bwd_{bwd}",
-                     gflops=round(gf, 1), mfu_v5e=round(gf / 197e3, 3))
+                     gflops=round(gf, 1), mfu=round(gf / peak_gflops, 3))
 
             run_guarded(f"lm_{pol}_{bwd}", do)
 
@@ -320,7 +330,7 @@ def main():
                 tm = _time(run)
                 gf = areps * 9.0 * b * h * t * t * d / tm / 1e9
                 emit(exp=f"attn_bwd_{impl}_bq{bq}_bk{bk}", gflops=round(gf, 1),
-                     mfu_v5e=round(gf / 197e3, 3))
+                     mfu=round(gf / peak_gflops, 3))
 
             run_guarded(f"attn_bwd_{impl}_{bq}_{bk}", do_ab)
 
@@ -353,6 +363,8 @@ def main():
 
         run_guarded("moments", do_m)
 
+    return 1 if failed else 0
+
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -30,6 +30,14 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
+# The suite is compile-bound and clears JAX's in-memory caches after every
+# module; the on-disk cache (at $JAX_COMPILATION_CACHE_DIR, else
+# <checkout>/.jax_cache) turns every recompile of a program some module or
+# an earlier run already built into a read.
+from heat_tpu.core import program_cache as _program_cache
+
+_program_cache.enable_persistent_cache()
+
 
 import pytest
 

@@ -316,8 +316,11 @@ class TestAuditZeroDrift:
 )
 class TestWrapperCollectives:
     def _smap(self, comm, fn, in_spec, out_spec):
+        # check_vma=False: a gathered payload is the same on every
+        # position, which the varying-axis checker cannot infer
         return jax.shard_map(
-            fn, mesh=comm.mesh, in_specs=in_spec, out_specs=out_spec
+            fn, mesh=comm.mesh, in_specs=in_spec, out_specs=out_spec,
+            check_vma=False,
         )
 
     def test_psum_error_bound(self, comm):
@@ -463,6 +466,26 @@ class TestDataParallelPrecision:
         for mode in LOSSY:
             # ten compressed steps stay close to the exact trajectory
             assert np.abs(finals[mode] - finals["off"]).max() < 5e-2
+
+    @pytest.mark.parametrize("blocking", [True, False])
+    @pytest.mark.parametrize("mode", LOSSY)
+    def test_replicated_outputs_same_bits_on_every_device(
+        self, comm, mode, blocking
+    ):
+        """The compressed step runs with check_vma=False, so nothing
+        checks its P() outputs: parameters, optimizer state, pending
+        gradients and loss must be one value on every position."""
+        step, params, opt_state, batch = self._setup(mode, blocking=blocking)
+        carry = (params, opt_state)
+        if not blocking:
+            carry += (ht.nn.DataParallel.init_pending(params),)
+        for _ in range(3):
+            out = step(*carry, *batch)
+            carry = out[:-1]
+        for leaf in jax.tree.leaves(out):
+            shards = [np.asarray(s.data) for s in leaf.addressable_shards]
+            assert len(shards) == comm.size
+            assert all(s.tobytes() == shards[0].tobytes() for s in shards)
 
     def test_nonblocking_signature_survives(self, comm):
         step, params, opt_state, batch = self._setup("int8", blocking=False)

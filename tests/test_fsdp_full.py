@@ -19,6 +19,7 @@ import heat_tpu as ht
 from heat_tpu import resilience
 from heat_tpu import telemetry as tm
 from heat_tpu.core import program_cache
+from heat_tpu.core.communication import to_varying
 from heat_tpu.nn.fsdp import FSDP
 from heat_tpu.optim import ZeroOptimizer
 from heat_tpu.parallel import fsdp as F
@@ -290,7 +291,8 @@ class TestAuditZeroDrift:
 
         def kernel(c):
             _, vjp = jax.vjp(lambda cc: F.fsdp_gather(cc, leaf, comm), c)
-            (ct,) = vjp(jnp.ones(leaf.shape, jnp.float32))
+            # the gathered primal is typed device-varying; so is its ct
+            (ct,) = vjp(to_varying(jnp.ones(leaf.shape, jnp.float32), axis))
             return ct
 
         fn = jax.jit(

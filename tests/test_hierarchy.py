@@ -266,21 +266,24 @@ class TestTieredAudit:
         )
         aud = self._audit(comm4, lambda v: comm4.psum(v), x)
         topo = comm4.topology()
-        ops = aud.counts()
-        assert ops == {"reduce-scatter": 1, "all-reduce": 1, "all-gather": 1}
-        by_op = {c.op: c for c in aud.collectives}
+        # the cross-node all-reduce is a grouped reduce-scatter +
+        # all-gather pair (collective_prec._exact_psum)
+        assert aud.counts() == {"reduce-scatter": 2, "all-gather": 2}
         # the emitted replica groups ARE the tier ground truth
-        assert [list(g) for g in by_op["reduce-scatter"].groups] == \
-            topo.node_groups()
-        assert [list(g) for g in by_op["all-reduce"].groups] == \
-            topo.cross_groups()
-        assert [list(g) for g in by_op["all-gather"].groups] == \
-            topo.node_groups()
+        tiers = {"node": topo.node_groups(), "cross": topo.cross_groups()}
+        by_tier = {
+            name: [c for c in aud.collectives
+                   if [list(g) for g in c.groups] == groups]
+            for name, groups in tiers.items()
+        }
+        for name in tiers:
+            assert sorted(c.op for c in by_tier[name]) == \
+                ["all-gather", "reduce-scatter"], name
         pred = model.hierarchical_allreduce_cost(n, 4, topo.node, topo.local)
         rep = hlo.compare(aud, pred)
         assert rep.ok, rep.summary()
-        # DCN accounting: the cross-node op's bytes are the dcn_bytes
-        assert by_op["all-reduce"].wire_bytes == pred.dcn_bytes
+        # DCN accounting: the cross-node pair's bytes are the dcn_bytes
+        assert sum(c.wire_bytes for c in by_tier["cross"]) == pred.dcn_bytes
 
     def test_cross_node_payload_is_the_local_shard(self, comm4, monkeypatch):
         """Acceptance oracle: the cross-node all-reduce moves exactly the
@@ -296,11 +299,16 @@ class TestTieredAudit:
         monkeypatch.setenv("HEAT_TPU_HIERARCHICAL", "1")
         hier = self._audit(comm4, lambda v: comm4.psum(v), x)
         flat_ar = [c for c in flat.collectives if c.op == "all-reduce"]
-        cross = [c for c in hier.collectives if c.op == "all-reduce"]
-        assert len(flat_ar) == 1 and len(cross) == 1
         topo = comm4.topology()
-        assert flat_ar[0].in_bytes == cross[0].in_bytes * topo.local
-        reduction = flat_ar[0].wire_bytes / cross[0].wire_bytes
+        # the cross-node all-reduce is the reduce-scatter + all-gather
+        # pair over the cross groups
+        cross = [c for c in hier.collectives
+                 if [list(g) for g in c.groups] == topo.cross_groups()]
+        assert len(flat_ar) == 1
+        assert sorted(c.op for c in cross) == ["all-gather", "reduce-scatter"]
+        cross_rs = next(c for c in cross if c.op == "reduce-scatter")
+        assert flat_ar[0].in_bytes == cross_rs.in_bytes * topo.local
+        reduction = flat_ar[0].wire_bytes / sum(c.wire_bytes for c in cross)
         assert reduction >= topo.local
 
     @pytest.mark.parametrize("mode", ["int8", "blockwise"])

@@ -101,7 +101,49 @@ GOLDEN_ASYNC_PAIR = (
 )
 
 
+# jax 0.9.0's XLA prints operands as bare names (no types): recorded from
+# `jit(shard_map(...)).lower(x).compile().as_text()` on the 8-device CPU
+# mesh. Operand bytes must come from the defining instructions' result
+# types — parsed as written, every collective would read in_bytes == 0.
+GOLDEN_UNTYPED_MODULE = """\
+ENTRY %main.6_spmd (param.1: f32[8,32]) -> f32[1,256] {
+  %param.1 = f32[8,32]{1,0} parameter(0), sharding={devices=[8,1]<=[8]}
+  %wrapped_slice = f32[1,32]{1,0} fusion(%param.1), kind=kLoop, calls=%wrapped_slice_computation
+  %wrapped_slice.1 = f32[1,32]{1,0} fusion(%param.1), kind=kLoop, calls=%wrapped_slice_computation.1
+  %all-to-all = (f32[1,32]{1,0}, f32[1,32]{1,0}) all-to-all(%wrapped_slice, /*index=1*/%wrapped_slice.1), channel_id=1, replica_groups={{0,1},{2,3},{4,5},{6,7}}, metadata={op_name="jit(f)/shard_map/all_to_all" stack_frame_id=3}
+  %get-tuple-element = f32[1,32]{1,0} get-tuple-element(%all-to-all), index=0
+  %multiply_bitcast_fusion = f32[1,256]{1,0} fusion(%get-tuple-element), kind=kLoop, calls=%fused_computation
+  %psum_invariant.7 = f32[1,256]{1,0} all-reduce(%multiply_bitcast_fusion), channel_id=2, replica_groups={{0,1,2,3,4,5,6,7}}, use_global_device_ids=true, to_apply=%region_0.0
+  %all_gather.3 = f32[8,256]{1,0} all-gather(%psum_invariant.7), channel_id=3, replica_groups={{0,1,2,3,4,5,6,7}}, dimensions={0}, use_global_device_ids=true
+  %ppermute.3 = f32[8,256]{1,0} collective-permute(%all_gather.3), channel_id=4, source_target_pairs={{0,1},{1,2},{2,3},{3,4},{4,5},{5,6},{6,7},{7,0}}
+  ROOT %reduce_scatter.7 = f32[1,256]{1,0} reduce-scatter(%ppermute.3), channel_id=5, replica_groups={{0,1,2,3,4,5,6,7}}, use_global_device_ids=true, dimensions={0}, to_apply=%region_1.0
+}
+"""
+
+
 class TestParserGoldens:
+    def test_untyped_operands_resolve_through_their_definitions(self):
+        recs = {c.op: c for c in hlo.parse_hlo(GOLDEN_UNTYPED_MODULE)}
+        assert set(recs) == {
+            "all-to-all", "all-reduce", "all-gather", "collective-permute",
+            "reduce-scatter",
+        }
+        f32 = 4
+        a2a = recs["all-to-all"]
+        assert a2a.in_bytes == 2 * 32 * f32          # two (1, 32) operands
+        assert a2a.wire_bytes == a2a.in_bytes * 1 * 8 // 2
+        ar = recs["all-reduce"]
+        assert ar.in_bytes == 256 * f32
+        assert ar.wire_bytes == 2 * 256 * f32 * 7
+        assert recs["all-gather"].in_bytes == 256 * f32
+        assert recs["all-gather"].wire_bytes == 8 * 256 * f32 * 7
+        cp = recs["collective-permute"]
+        assert cp.in_bytes == 8 * 256 * f32
+        assert cp.wire_bytes == 8 * cp.in_bytes      # one payload per pair
+        rs = recs["reduce-scatter"]
+        assert rs.in_bytes == 8 * 256 * f32
+        assert rs.wire_bytes == rs.in_bytes * 7
+
     def test_all_gather_iota_groups(self):
         (c,) = hlo.parse_hlo(GOLDEN_ALL_GATHER)
         assert c.op == "all-gather"

@@ -558,8 +558,11 @@ class TestActivationWatermark:
         layout = pl.plan_pipeline(layers, mapping)
         rows = pl.shard_pipeline_params(layers, layout, comm)
         st = opt.init(rows)
-        x, y = _data(2 * M, din, seed=31)
-        mx, my = x.reshape(M, 2, din), y.reshape(M, 2, din)
+        # 256-row microbatches (8 KB each): the stash must dominate the
+        # compiler's own scratch, or the comparison reads allocator noise
+        mb = 256
+        x, y = _data(mb * M, din, seed=31)
+        mx, my = x.reshape(M, mb, din), y.reshape(M, mb, din)
 
         def temp_bytes(name):
             table = sch.build_schedule(S, M, name)

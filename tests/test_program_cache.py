@@ -271,43 +271,59 @@ class TestRegistry:
 
 
 class TestPersistentCompileCache:
-    def test_enable_persistent_cache_configures_jax(self, tmp_path):
-        prev = jax.config.jax_compilation_cache_dir
-        prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        try:
-            d = pc.enable_persistent_cache(str(tmp_path / "cc"))
-            assert os.path.isdir(d)
-            assert jax.config.jax_compilation_cache_dir == d
-            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
-            assert pc.persistent_cache_dir() == d
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", prev_min
-            )
+    """The one placement rule (program_cache.enable_persistent_cache):
+    JAX_COMPILATION_CACHE_DIR set -> JAX uses it and the code sets no
+    directory; unset -> <checkout>/.jax_cache. Run in subprocesses: the
+    rule reads the environment JAX was imported under."""
 
-    def test_env_var_activates_and_populates(self, tmp_path):
-        """HEAT_TPU_COMPILE_CACHE=<dir> + `import heat_tpu` is enough: the
-        process writes XLA executables into the directory."""
-        cache = tmp_path / "cc"
+    _SCRIPT = (
+        "import jax, numpy as np\n"
+        "import heat_tpu as ht\n"
+        "seen = []\n"
+        "real = jax.config.update\n"
+        "def spy(name, val):\n"
+        "    seen.append(name)\n"
+        "    return real(name, val)\n"
+        "jax.config.update = spy\n"
+        "d = ht.program_cache.enable_persistent_cache()\n"
+        "jax.config.update = real\n"
+        "x = ht.array(np.arange(10, dtype=np.float32), split=0)\n"
+        "float(x.resplit(None).larray[3])\n"
+        "print('DIR', d)\n"
+        "print('CFG', jax.config.jax_compilation_cache_dir)\n"
+        "print('SET_DIR', 'jax_compilation_cache_dir' in seen)\n"
+        "print('MIN', jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+    )
+
+    def _run(self, cwd, **env_extra):
         env = dict(os.environ)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         env.update(
-            HEAT_TPU_COMPILE_CACHE=str(cache),
             JAX_PLATFORMS="cpu",
             XLA_FLAGS="--xla_force_host_platform_device_count=2",
             PYTHONPATH=REPO + os.pathsep + env.get("PYTHONPATH", ""),
-        )
-        script = (
-            "import jax, numpy as np\n"
-            "import heat_tpu as ht\n"
-            "assert jax.config.jax_compilation_cache_dir, 'cache not wired'\n"
-            "x = ht.array(np.arange(10, dtype=np.float32), split=0)\n"
-            "print(float(x.resplit(None).larray[3]))\n"
+            **env_extra,
         )
         r = subprocess.run(
-            [sys.executable, "-c", script], env=env, cwd=REPO,
+            [sys.executable, "-c", self._SCRIPT], env=env, cwd=cwd,
             capture_output=True, text=True, timeout=300,
         )
         assert r.returncode == 0, r.stdout + r.stderr
-        entries = os.listdir(cache)
-        assert entries, "persistent cache directory stayed empty"
+        return dict(
+            line.split(" ", 1) for line in r.stdout.splitlines()
+            if line.split(" ", 1)[0] in ("DIR", "CFG", "SET_DIR", "MIN")
+        )
+
+    def test_variable_set_code_sets_no_directory(self, tmp_path):
+        cache = tmp_path / "cc"
+        out = self._run(str(tmp_path), JAX_COMPILATION_CACHE_DIR=str(cache))
+        assert out["DIR"] == out["CFG"] == str(cache)
+        assert out["SET_DIR"] == "False"
+        assert float(out["MIN"]) == 0.0
+        assert os.listdir(cache), "persistent cache directory stayed empty"
+
+    def test_variable_unset_uses_checkout_cache(self, tmp_path):
+        # from a foreign cwd: the path hangs off the checkout, not the cwd
+        out = self._run(str(tmp_path))
+        assert out["DIR"] == out["CFG"] == os.path.join(REPO, ".jax_cache")
+        assert out["SET_DIR"] == "True"

@@ -635,7 +635,7 @@ class TestReplicaPoolSubprocess:
         direct.close()
 
         env = {
-            "HEAT_TPU_COMPILE_CACHE": cache,
+            "JAX_COMPILATION_CACHE_DIR": cache,
             "HEAT_TPU_TUNE_DB": tune_db,
             "HEAT_TPU_AUTOTUNE": "1",
             "HEAT_TPU_TELEMETRY": "1",

@@ -207,9 +207,9 @@ class TestHistogramGrid(TestCase):
             x = ht.array(a, split=split)
             for bins, rng_ in [(10, None), (5, (-2.0, 2.0)), (16, (-4.0, 4.0))]:
                 hist, edges = ht.histogram(x, bins=bins, range=rng_)
-                whist, wedges = np.histogram(a, bins=bins, range=rng_)
+                whist, w_edges = np.histogram(a, bins=bins, range=rng_)
                 np.testing.assert_array_equal(np.asarray(hist.numpy()), whist)
-                np.testing.assert_allclose(np.asarray(edges.numpy()), wedges, rtol=1e-5)
+                np.testing.assert_allclose(np.asarray(edges.numpy()), w_edges, rtol=1e-5)
 
     def test_histc_torch_semantics(self):
         a = np.asarray([0.5, 1.5, 2.5, 2.5, 3.5], dtype=np.float32)

@@ -180,6 +180,25 @@ class TestTrainStep:
                 )
         assert float(zloss) == pytest.approx(float(dloss), rel=1e-6)
 
+    @pytest.mark.parametrize("wire", ["off", "bf16", "int8", "blockwise"])
+    def test_replicated_outputs_same_bits_on_every_device(self, comm, wire):
+        """Both steps run with check_vma=False, so nothing checks their
+        P() outputs: the gathered parameters and the loss must be one
+        value on every position."""
+        P0 = {"w2": jnp.zeros((16, 1), jnp.float32)}
+        bx, by = self._data(comm)
+        zo = ZeroOptimizer(optax.adam(5e-2), precision=wire)
+        step = zo.make_train_step(self._loss)
+        p, s = P0, zo.init(P0)
+        for _ in range(3):
+            p, s, loss = step(p, s, bx, by)
+        grads = jax.grad(self._loss)(p, bx, by)
+        p2, _ = zo.step(p, s, grads)
+        for leaf in jax.tree.leaves((p, loss, p2)):
+            shards = [np.asarray(sh.data) for sh in leaf.addressable_shards]
+            assert len(shards) == comm.size
+            assert all(sh.tobytes() == shards[0].tobytes() for sh in shards)
+
     def test_loss_decreases(self, comm):
         P0 = {"w2": jnp.zeros((16, 1), jnp.float32)}
         bx, by = self._data(comm)
