@@ -16,6 +16,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..core import types
 from ..core.dndarray import DNDarray
 from ._kcluster import _KCluster, _d2
@@ -170,20 +171,55 @@ class KMeans(_KCluster):
 
     def fit(self, x: DNDarray) -> "KMeans":
         """Run Lloyd iterations to convergence (reference kmeans.py:102)."""
+        with telemetry.span("heat_tpu.kmeans.fit"):
+            return self._fit(x)
+
+    def _fit(self, x: DNDarray) -> "KMeans":
+        """:meth:`fit` under its span; the four phase spans tile it."""
         if not isinstance(x, DNDarray):
             raise TypeError(f"input needs to be a DNDarray, but was {type(x)}")
         if x.ndim != 2:
             raise ValueError("input needs to be 2D")
+        from .pallas_lloyd import (
+            lloyd_fit_pallas,
+            lloyd_fit_pallas_sharded,
+            pallas_lloyd_applicable,
+        )
 
-        dt, xb, w, centers = self._fit_buffers(x)
+        with telemetry.span("heat_tpu.kmeans.fit.prepare"):
+            dt, xb, w, centers = self._fit_buffers(x)
+            tol = jnp.asarray(self.tol, xb.dtype)
 
-        if self.checkpoint_every is not None:
-            # checkpointed fit: exact iteration windows (the pallas path is
-            # a whole-fit program with no resumable carry, so the windowed
-            # XLA driver serves this mode on every backend)
-            centers, labels, inertia, n_iter = self._fit_checkpointed(
-                xb, w, centers
-            )
+        with telemetry.span("heat_tpu.kmeans.fit.launch"):
+            if self.checkpoint_every is not None:
+                # checkpointed fit: exact iteration windows (the pallas path
+                # is a whole-fit program with no resumable carry, so the
+                # windowed XLA driver serves this mode on every backend)
+                centers, labels, inertia, n_iter = self._fit_checkpointed(
+                    xb, w, centers
+                )
+            elif not pallas_lloyd_applicable(
+                x.comm.size, x.split, x.shape[1], self.n_clusters, xb.dtype
+            ):
+                centers, labels, inertia, n_iter = _lloyd_fit(
+                    xb, w, centers, self.max_iter, tol
+                )
+            # fused single-pass-over-X Lloyd update (see pallas_lloyd)
+            elif x.comm.size > 1:
+                centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
+                    x.comm, xb, centers, x.shape[0], self.max_iter, tol
+                )
+            else:
+                centers, labels, inertia, n_iter = lloyd_fit_pallas(
+                    xb, centers, x.shape[0], self.max_iter, tol
+                )
+
+        with telemetry.span("heat_tpu.kmeans.fit.readback"):
+            # the host waits for the device here
+            self._n_iter = int(n_iter)
+            self._inertia = float(inertia)
+
+        with telemetry.span("heat_tpu.kmeans.fit.wrap"):
             self._cluster_centers = DNDarray.from_logical(
                 centers, None, x.device, x.comm, dt
             )
@@ -191,40 +227,6 @@ class KMeans(_KCluster):
                 labels.astype(jnp.int64), (x.shape[0],), types.int64,
                 x.split, x.device, x.comm, True,
             )
-            self._inertia = float(inertia)
-            self._n_iter = n_iter
-            return self
-
-        from .pallas_lloyd import (
-            lloyd_fit_pallas,
-            lloyd_fit_pallas_sharded,
-            pallas_lloyd_applicable,
-        )
-
-        tol = jnp.asarray(self.tol, xb.dtype)
-        if not pallas_lloyd_applicable(
-            x.comm.size, x.split, x.shape[1], self.n_clusters, xb.dtype
-        ):
-            centers, labels, inertia, n_iter = _lloyd_fit(
-                xb, w, centers, self.max_iter, tol
-            )
-        # fused single-pass-over-X Lloyd update (see pallas_lloyd)
-        elif x.comm.size > 1:
-            centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
-                x.comm, xb, centers, x.shape[0], self.max_iter, tol
-            )
-        else:
-            centers, labels, inertia, n_iter = lloyd_fit_pallas(
-                xb, centers, x.shape[0], self.max_iter, tol
-            )
-        n_iter = int(n_iter)
-
-        self._cluster_centers = DNDarray.from_logical(centers, None, x.device, x.comm, dt)
-        self._labels = DNDarray(
-            labels.astype(jnp.int64), (x.shape[0],), types.int64, x.split, x.device, x.comm, True
-        )
-        self._inertia = float(inertia)
-        self._n_iter = n_iter
         return self
 
     def _fit_checkpointed(self, xb, w, centers):

@@ -151,6 +151,7 @@ def _lloyd_update(x, centers_pad, n, k, bm, interpret, lim=None,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="lloyd_update",
     )(lim.astype(jnp.int32), x, centers_pad, c2)
 
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from . import program_cache, types
 from .communication import MeshCommunication, sanitize_comm
 from .devices import Device, sanitize_device
@@ -97,6 +98,12 @@ def array(
     factories.py:386-429; under a single controller every position holds the
     same block list, so the global shape is locally computable).
     """
+    with telemetry.span("heat_tpu.array.prepare"):
+        return _array(obj, dtype, copy, ndmin, order, split, is_split, device, comm)
+
+
+def _array(obj, dtype, copy, ndmin, order, split, is_split, device, comm) -> DNDarray:
+    """:func:`array` under its span: host data to a placed, laid-out DNDarray."""
     if split is not None and is_split is not None:
         raise ValueError(f"split and is_split are mutually exclusive parameters")
     device = sanitize_device(device)

@@ -241,99 +241,108 @@ def _dist(
     (n_x, n_y) distributed along the rows of x. ``rbf_gamma`` composes the
     Gaussian-kernel epilogue — fused into the Pallas tile when that path
     runs, one extra compiled exp pass otherwise."""
-    if not isinstance(x, DNDarray):
-        raise TypeError(f"x must be a DNDarray, but was {type(x)}")
-    if x.ndim != 2:
-        raise NotImplementedError(f"x has {x.ndim} dimensions, expecting 2")
-    if y is None:
-        y = x
-    if not isinstance(y, DNDarray):
-        raise TypeError(f"y must be a DNDarray, but was {type(y)}")
-    if y.ndim != 2:
-        raise NotImplementedError(f"y has {y.ndim} dimensions, expecting 2")
-    if x.shape[1] != y.shape[1]:
-        raise ValueError(
-            f"inputs must have the same number of features, got {x.shape[1]} and {y.shape[1]}"
-        )
-    if x.split is not None and x.split != 0:
-        raise NotImplementedError("cdist requires x.split in (None, 0)")
+    with telemetry.span("heat_tpu.cdist"):
+        return _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit)
 
-    promoted = types.promote_types(types.promote_types(x.dtype, y.dtype), types.float32)
-    out_split = 0 if x.split == 0 else None
-    m, n = x.shape[0], y.shape[0]
 
-    use_ring = (
-        ring
-        and ring_ok
-        and x.split == 0
-        and y.split == 0
-        and x.comm.size > 1
-    )
-    def _finish(out):
-        if rbf_gamma is not None:
-            out = _rbf_from_dist(out, jnp.asarray(rbf_gamma, out.dtype))
-        return DNDarray(out, (m, n), promoted, out_split, x.device, x.comm, True)
-
-    if use_ring:
-        # ring kernel works on the padded buffers; x pad rows land in output
-        # pad rows, y pad columns are sliced off below. The hop count is
-        # schedule-dependent: the double-buffered kernel skips the final
-        # dead hop (p-1 hops), the serial kernel permutes p times.
-        from ..core import relayout_planner
-
-        p_ring = x.comm.size
-        hops = p_ring - 1 if relayout_planner.ring_overlap() else p_ring
-        from ..core import collective_prec
-
-        ring_wire = collective_prec.effective(promoted.jnp_type())
-        cost, fields, do_audit = telemetry.op_cost(
-            telemetry.collectives.ring_cdist_cost, n, x.shape[1],
-            promoted.byte_size(), x.comm.size, hops, ring_wire,
-            collective_prec.block_size(), audit=audit,
-        )
-        with telemetry.span(
-            "ring_cdist", gshape=[m, n], mesh=x.comm.size,
-            overlap=hops < p_ring, **fields
-        ) as sp:
-            xm = x._masked(0).astype(promoted.jnp_type())
-            ym = y._masked(0).astype(promoted.jnp_type())
-            xw = DNDarray(xm, x.shape, promoted, 0, x.device, x.comm, True)
-            yw = DNDarray(ym, y.shape, promoted, 0, y.device, y.comm, True)
-            out = sp.output(
-                _ring_dist(
-                    xw, yw, block_fn,
-                    audit_cost=cost if do_audit else None,
-                )
+def _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit) -> DNDarray:
+    """:func:`_dist` under its span; prepare, launch and wrap tile it."""
+    with telemetry.span("heat_tpu.cdist.prepare"):
+        if not isinstance(x, DNDarray):
+            raise TypeError(f"x must be a DNDarray, but was {type(x)}")
+        if x.ndim != 2:
+            raise NotImplementedError(f"x has {x.ndim} dimensions, expecting 2")
+        if y is None:
+            y = x
+        if not isinstance(y, DNDarray):
+            raise TypeError(f"y must be a DNDarray, but was {type(y)}")
+        if y.ndim != 2:
+            raise NotImplementedError(f"y has {y.ndim} dimensions, expecting 2")
+        if x.shape[1] != y.shape[1]:
+            raise ValueError(
+                f"inputs must have the same number of features, got {x.shape[1]} and {y.shape[1]}"
             )
-        out = out[:, :n]
-        return _finish(out)
+        if x.split is not None and x.split != 0:
+            raise NotImplementedError("cdist requires x.split in (None, 0)")
 
-    # y's logical rows become output COLUMNS, whole on every row-shard (the
-    # replicated-centers pattern): replicate via the compiled relayout when
-    # y is split — multi-host safe, unlike the host-logical view
-    yb = y._relayout(None) if y.split is not None else y.larray
+        promoted = types.promote_types(types.promote_types(x.dtype, y.dtype), types.float32)
+        jt = promoted.jnp_type()
+        out_split = 0 if x.split == 0 else None
+        m, n = x.shape[0], y.shape[0]
 
-    if block_fn is _quadratic_euclidean:
-        from .pallas_cdist import pallas_cdist_applicable
+        use_ring = (
+            ring
+            and ring_ok
+            and x.split == 0
+            and y.split == 0
+            and x.comm.size > 1
+        )
+        use_pallas = False
+        if use_ring:
+            # ring kernel works on the padded buffers; x pad rows land in
+            # output pad rows, y pad columns are sliced off below. The hop
+            # count is schedule-dependent: the double-buffered kernel skips
+            # the final dead hop (p-1 hops), the serial kernel permutes p
+            # times.
+            from ..core import collective_prec, relayout_planner
 
-        # multi-device needs x row-SHARDED (the shard_map decomposition);
-        # a replicated x on a >1-device mesh keeps the XLA path
-        layout_ok = x.comm.size == 1 or x.split == 0
-        if layout_ok and pallas_cdist_applicable(x.shape[1], promoted.jnp_type()):
-            epi = "rbf" if rbf_gamma is not None else "dist"
+            p_ring = x.comm.size
+            hops = p_ring - 1 if relayout_planner.ring_overlap() else p_ring
+            ring_wire = collective_prec.effective(jt)
+            cost, fields, do_audit = telemetry.op_cost(
+                telemetry.collectives.ring_cdist_cost, n, x.shape[1],
+                promoted.byte_size(), x.comm.size, hops, ring_wire,
+                collective_prec.block_size(), audit=audit,
+            )
+        else:
+            # y's logical rows become output COLUMNS, whole on every
+            # row-shard (the replicated-centers pattern): replicate via the
+            # compiled relayout when y is split — multi-host safe, unlike
+            # the host-logical view
+            xa = x.larray
+            yb = y._relayout(None) if y.split is not None else y.larray
+            if block_fn is _quadratic_euclidean:
+                from .pallas_cdist import pallas_cdist_applicable
+
+                # multi-device needs x row-SHARDED (the shard_map
+                # decomposition); a replicated x on a >1-device mesh keeps
+                # the XLA path
+                layout_ok = x.comm.size == 1 or x.split == 0
+                use_pallas = layout_ok and pallas_cdist_applicable(x.shape[1], jt)
+            if use_pallas:
+                xa, yb = xa.astype(jt), yb.astype(jt)
+
+    with telemetry.span("heat_tpu.cdist.launch"):
+        if use_ring:
+            with telemetry.span(
+                "ring_cdist", gshape=[m, n], mesh=x.comm.size,
+                overlap=hops < p_ring, **fields
+            ) as sp:
+                xm = x._masked(0).astype(jt)
+                ym = y._masked(0).astype(jt)
+                xw = DNDarray(xm, x.shape, promoted, 0, x.device, x.comm, True)
+                yw = DNDarray(ym, y.shape, promoted, 0, y.device, y.comm, True)
+                out = sp.output(
+                    _ring_dist(
+                        xw, yw, block_fn,
+                        audit_cost=cost if do_audit else None,
+                    )
+                )
+        elif use_pallas:
             out = _pallas_local(
-                x.comm,
-                x.larray.astype(promoted.jnp_type()),
-                yb.astype(promoted.jnp_type()),
-                epi,
+                x.comm, xa, yb,
+                "rbf" if rbf_gamma is not None else "dist",
                 0.0 if rbf_gamma is None else float(rbf_gamma),
             )
-            return DNDarray(
-                out, (m, n), promoted, out_split, x.device, x.comm, True
-            )
+        else:
+            out = _local_dist(block_fn, xa, yb, jt)
 
-    out = _local_dist(block_fn, x.larray, yb, promoted.jnp_type())
-    return _finish(out)
+    with telemetry.span("heat_tpu.cdist.wrap"):
+        if use_ring:
+            out = out[:, :n]
+        if rbf_gamma is not None and not use_pallas:  # the kernel fuses its own
+            out = _rbf_from_dist(out, jnp.asarray(rbf_gamma, out.dtype))
+        return DNDarray(out, (m, n), promoted, out_split, x.device, x.comm, True)
 
 
 def cdist(X: DNDarray, Y: Optional[DNDarray] = None, quadratic_expansion: bool = False, ring: bool = False, audit: bool = False) -> DNDarray:

@@ -40,7 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.pallas_util import DotPrecision, dot_f32
 from heat_tpu import _knobs as knobs
-from .. import telemetry
 
 __all__ = ["euclid_pallas", "pallas_cdist_applicable", "cdist_precision"]
 
@@ -118,31 +117,12 @@ def euclid_pallas(
     multiples (zero feature columns contribute nothing to dot or norms;
     pad rows are sliced off the result).
 
-    With telemetry enabled, host-level calls become a ``pallas_cdist``
-    span whose ``bytes`` is the kernel's one obligatory HBM output write
-    (the quantity the fusion exists to minimize — see module docstring);
-    calls from inside a trace (the sharded `shard_map` wrapping in
-    distance.py hands tracers in) bypass instrumentation, since the span
-    would measure trace time, not the kernel.
-
     ``precision=None`` (the default) resolves :func:`cdist_precision` —
     ``"bf16x3"`` unless the ``HEAT_TPU_CDIST_PREC`` env override names a
     ``jax.lax.Precision`` tier.
     """
     if precision is None:
         precision = cdist_precision()
-    if telemetry.enabled() and not isinstance(x, jax.core.Tracer):
-        m, n = int(x.shape[0]), int(y.shape[0])
-        with telemetry.span(
-            "pallas_cdist", bytes=m * n * 4, gshape=[m, n],
-            epilogue=epilogue, hbm_write=True,
-        ) as sp:
-            return sp.output(
-                _euclid_pallas_jit(
-                    x, y, gamma, epilogue=epilogue, block_m=block_m,
-                    block_n=block_n, interpret=interpret, precision=precision,
-                )
-            )
     return _euclid_pallas_jit(
         x, y, gamma, epilogue=epilogue, block_m=block_m, block_n=block_n,
         interpret=interpret, precision=precision,
@@ -191,6 +171,7 @@ def _euclid_pallas_jit(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="euclid_tile",
     )(gamma_arr, x.astype(jnp.float32), y.astype(jnp.float32))
     return out[:m, :n]
 

@@ -9,10 +9,15 @@ measurement substrate that makes them visible again:
 * a process-global :class:`Telemetry` registry — counters plus a JSON-lines
   event sink — enabled via :func:`enable` or ``HEAT_TPU_TELEMETRY=1``
   (sink path via ``HEAT_TPU_TELEMETRY_SINK``);
-* an op/**span** API (``with span("resplit", bytes=...)``) with correct
-  async-dispatch semantics: spans `jax.block_until_ready` their registered
-  outputs before stopping the clock, so a span measures device work, not
-  Python dispatch;
+* an op/**span** API (``with span("resplit", bytes=...)``), on when
+  telemetry is enabled *or* a ``jax.profiler`` session is live. A recorded
+  span enters a ``jax.profiler.TraceAnnotation`` of its name (so it lies in
+  the profile, on the profiler's clock, beside the device lines) and leaves
+  one record in a bounded buffer (:func:`spans`). Enabled explicitly, spans
+  also `jax.block_until_ready` their registered outputs before stopping
+  the clock, so a span measures device work, not Python dispatch; under a
+  profile alone they never block (a profile shows the program as it runs
+  without one);
 * **compile-time accounting** kept separate from execute time:
   :func:`measure_compile` times the AOT ``jit(f).lower(...).compile()``
   path for pure jitted functions, and :class:`CompileWatcher` accumulates
@@ -33,19 +38,22 @@ measurement substrate that makes them visible again:
   Chrome-trace/Perfetto JSON (:func:`export_trace`), plus a
   ``python -m heat_tpu.telemetry.audit`` CLI.
 
-Disabled (the default), every hook compiles down to one module-flag check:
-``span()`` returns a shared no-op context manager, call sites skip field
-construction, and no listener work is done — the overhead budget is "not
-measurable" (<2% on the tier-1 suite, pinned by the acceptance run).
+Off (the default: telemetry disabled and no profiler session), every hook
+compiles down to one module-flag check plus, for ``span()``, one
+``TraceAnnotation.is_enabled()`` call: ``span()`` returns a shared no-op
+context manager, call sites skip field construction, and no listener work
+is done — the overhead budget is "not measurable" (<2% on the tier-1
+suite, pinned by the acceptance run).
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from typing import Any, Dict, IO, Iterable, List, Optional, Union
 
 import jax
@@ -63,6 +71,7 @@ __all__ = [
     "flush",
     "get_registry",
     "span",
+    "spans",
     "trace_event",
     "op_cost",
     "measure_compile",
@@ -100,6 +109,9 @@ class Telemetry:
     Events are dicts with at least ``ts`` (unix seconds), ``kind`` and
     ``name``; spans add ``seconds``, ``depth``, ``parent`` and their user
     fields. The in-memory list and the sink receive identical records.
+    Spans reach this stream only in an explicit session (:func:`enable`);
+    every recorded span, under a profile too, is in the bounded buffer that
+    :func:`spans` reads.
     """
 
     def __init__(self):
@@ -306,7 +318,7 @@ def _atexit_flush() -> None:  # pragma: no cover — exercised via subprocess
 
 
 class _NoopSpan:
-    """Shared do-nothing span returned while telemetry is disabled."""
+    """Shared do-nothing span returned while nothing records."""
 
     __slots__ = ()
 
@@ -326,25 +338,72 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
-class Span:
-    """A timed region with async-correct semantics.
+# Span records: a bounded buffer, oldest dropped first. 32,768 records hold
+# a 20 s profile of a 2.5 ms call at four spans a call; a drop is counted
+# (registry counter ``spans_dropped``), never silent.
+SPAN_BUFFER = 32768
+_SPANS: "deque[dict]" = deque(maxlen=SPAN_BUFFER)
+_SPANS_LOCK = threading.Lock()
+_SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
 
-    Register device outputs with :meth:`output`; on exit the span calls
-    ``jax.block_until_ready`` on them **before** stopping the clock, so the
-    recorded ``seconds`` covers the dispatched device work — without it,
-    JAX's async dispatch would credit the work to whoever reads the result
-    next. Compile time is deliberately NOT separated here (a span times what
-    actually happened); use :func:`measure_compile`/:class:`CompileWatcher`
-    for the compile/execute split.
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+
+
+def _keep(record: dict) -> None:
+    with _SPANS_LOCK:
+        dropped = len(_SPANS) == _SPANS.maxlen
+        _SPANS.append(record)
+    if dropped:
+        get_registry().add("spans_dropped", 1)
+
+
+def spans(clear: bool = False) -> List[dict]:
+    """The recorded spans, oldest first (at most ``SPAN_BUFFER``; the
+    registry counter ``spans_dropped`` says how many older ones went).
+    ``clear=True`` empties the buffer after reading it."""
+    with _SPANS_LOCK:
+        out = list(_SPANS)
+        if clear:
+            _SPANS.clear()
+    return out
+
+
+class Span:
+    """A timed region of host code.
+
+    On entry it enters a ``jax.profiler.TraceAnnotation`` of the same name;
+    on exit it leaves one record in the span buffer (:func:`spans`):
+    ``name``, ``t0_ns``/``t1_ns`` (``time.perf_counter_ns``), ``id``,
+    ``parent_id``, ``root_id`` (the outermost open span of the thread: the
+    spans of one user call share it), ``tid``, the older ``seconds``,
+    ``depth``, ``parent``, ``start_ts`` and the user fields.
+
+    With telemetry enabled explicitly the span also has the older
+    async-correct semantics: register device outputs with :meth:`output`
+    and the span calls ``jax.block_until_ready`` on them **before** stopping
+    the clock, so ``seconds`` covers the dispatched device work; the record
+    also goes to the registry's event stream and counters. A span that
+    records only because a profile is being taken never blocks. Compile
+    time is deliberately NOT separated here (a span times what actually
+    happened); use :func:`measure_compile`/:class:`CompileWatcher` for the
+    compile/execute split.
     """
 
-    __slots__ = ("name", "fields", "_outputs", "_t0", "_wall0")
+    __slots__ = (
+        "name", "fields", "id", "parent_id", "root_id",
+        "_explicit", "_outputs", "_annotation", "_t0_ns", "_wall0",
+    )
 
     def __init__(self, name: str, fields: Dict[str, Any]):
         self.name = name
         self.fields = fields
+        self.id = next(_SPAN_IDS)
+        self.parent_id: Optional[int] = None
+        self.root_id = self.id
+        self._explicit = _ENABLED
         self._outputs: List[Any] = []
-        self._t0 = 0.0
+        self._annotation = jax.profiler.TraceAnnotation(name)
+        self._t0_ns = 0
         self._wall0 = 0.0
 
     def add_fields(self, **fields: Any) -> "Span":
@@ -352,51 +411,68 @@ class Span:
         return self
 
     def output(self, value):
-        """Register a device value to block on at exit; returns it."""
-        self._outputs.append(value)
+        """Register a device value to block on at exit (telemetry enabled
+        explicitly; otherwise nothing is registered); returns it."""
+        if self._explicit:
+            self._outputs.append(value)
         return value
 
     def __enter__(self) -> "Span":
-        _stack().append(self)
+        stack = _stack()
+        if stack:
+            self.parent_id, self.root_id = stack[-1].id, stack[-1].root_id
+        stack.append(self)
         # wall-clock start recorded alongside the perf_counter duration
         # clock: deriving the start as `ts - seconds` would mix the two
         # clocks and break nesting containment in the trace export at
         # µs scale (trace.py anchors slices on start_ts)
         self._wall0 = time.time()
-        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is None and self._outputs:
             jax.block_until_ready(self._outputs)
-        dt = time.perf_counter() - self._t0
+        t1_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        dt = (t1_ns - self._t0_ns) / 1e9
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
         parent = stack[-1].name if stack else None
-        reg = get_registry()
-        if exc_type is not None:
-            reg.emit(
-                "span_error", self.name, seconds=dt, start_ts=self._wall0,
-                error=repr(exc), **self.fields
-            )
-            return False
-        reg.add(f"span.{self.name}.count", 1)
-        reg.add(f"span.{self.name}.seconds", dt)
-        b = self.fields.get("bytes")
-        if b:
-            reg.add(f"span.{self.name}.bytes", b)
-        reg.emit(
-            "span", self.name, seconds=dt, depth=len(stack), parent=parent,
-            start_ts=self._wall0, **self.fields,
-        )
+        old = {"seconds": dt, "start_ts": self._wall0}
+        if exc_type is None:
+            kind = "span"
+            old.update(depth=len(stack), parent=parent)
+        else:
+            kind = "span_error"
+            old["error"] = repr(exc)
+        _keep({
+            "ts": time.time(), "kind": kind, "name": self.name, **old,
+            "id": self.id, "parent_id": self.parent_id, "root_id": self.root_id,
+            "tid": threading.get_ident(), "t0_ns": self._t0_ns, "t1_ns": t1_ns,
+            **self.fields,
+        })
+        if self._explicit:
+            # the event stream and the counters are for the readers of an
+            # explicit session (report, trace export, the JSONL sink)
+            reg = get_registry()
+            if exc_type is None:
+                reg.add(f"span.{self.name}.count", 1)
+                reg.add(f"span.{self.name}.seconds", dt)
+                b = self.fields.get("bytes")
+                if b:
+                    reg.add(f"span.{self.name}.bytes", b)
+            reg.emit(kind, self.name, **old, **self.fields)
         return False
 
 
 def span(name: str, **fields: Any):
-    """Open a telemetry span (context manager). Disabled: returns a shared
-    no-op object — zero allocation, fields ignored."""
-    if not _ENABLED:
+    """Open a span (context manager). Records while telemetry is enabled or
+    a ``jax.profiler`` session is live; otherwise returns a shared no-op
+    object — zero allocation, fields ignored."""
+    if not (_ENABLED or _profiling()):
         return _NOOP_SPAN
     return Span(name, fields)
 
