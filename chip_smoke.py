@@ -216,18 +216,16 @@ def stage_train(ht, cfg, devices, on_tpu):
 def _moments(ht, cfg, devices, took_mosaic):
     import jax
 
-    from heat_tpu.core.pallas_moments import _moments_kernel
-
     n = cfg["moments_rows"]
     x = ht.random.randn(n, FEATURES, dtype=ht.float32, split=0)
     _check(_on_all_devices(x.larray, devices), "split array misses a chip")
     mu = ht.mean(x, axis=0)
-    took_mosaic("mean", _moments_kernel)
+    took_mosaic("mean", "_moments_kernel")
     # var dispatches the program mean has just lowered and would find it in
     # jit's memory: forget it, so that var's own dispatch is lowered too
     jax.clear_caches()
     var = ht.var(x, axis=0)
-    took_mosaic("var", _moments_kernel)
+    took_mosaic("var", "_moments_kernel")
     jax.block_until_ready((mu.larray, var.larray))
     xh = x.numpy()
     mu64 = xh.mean(axis=0, dtype=np.float64)
@@ -264,16 +262,14 @@ def _matmul(ht, cfg, rows):
 def _cdist(ht, cfg, rows, took_mosaic):
     import jax
 
-    from heat_tpu.spatial.pallas_cdist import _kernel
-
     m, k = cfg["cdist_rows"], cfg["cdist_k"]
     x = ht.random.rand(m, k, dtype=ht.float32, split=0)
     y = ht.random.rand(m, k, dtype=ht.float32, split=0)
     sigma = 4.0
     dist = ht.spatial.cdist(x, y, quadratic_expansion=True)
-    took_mosaic("cdist", _kernel)
+    took_mosaic("cdist", "euclid_tile")
     kern = ht.spatial.rbf(x, y, sigma=sigma, quadratic_expansion=True)
-    took_mosaic("rbf", _kernel)
+    took_mosaic("rbf", "euclid_tile")
     jax.block_until_ready((dist.larray, kern.larray))
     idx = rows(m)
     xs, yh = x.numpy()[idx].astype(np.float64), y.numpy().astype(np.float64)
@@ -324,7 +320,7 @@ def _lasso64(x, y, lam, sweeps):
 def _kmeans_lasso(ht, cfg, took_mosaic):
     import jax
 
-    from heat_tpu.cluster.pallas_lloyd import _lloyd_kernel
+    from heat_tpu import telemetry
 
     n, k, iters = cfg["kmeans_rows"], cfg["kmeans_k"], cfg["iters"]
     x = ht.random.randn(n, FEATURES, dtype=ht.float32, split=0)
@@ -334,8 +330,18 @@ def _kmeans_lasso(ht, cfg, took_mosaic):
     km = ht.cluster.KMeans(
         n_clusters=k, init=ht.array(xh[:k]), max_iter=iters, tol=0.0
     )
+    # both orientations of the Lloyd kernel carry one name; the counter
+    # says which the fit took: FEATURES is no lane multiple, so X lies
+    # feature-major on the chip and the blocks follow it
+    form = "kmeans.lloyd.feature_major"
+    counters = telemetry.get_registry().counters
+    before = counters.get(form, 0)
     km.fit(x)
-    took_mosaic("KMeans.fit", _lloyd_kernel)
+    took_mosaic("KMeans.fit", "lloyd_update")
+    _check(
+        jax.default_backend() != "tpu" or counters.get(form, 0) == before + 1,
+        f"KMeans.fit did not count {form}",
+    )
     jax.block_until_ready(km.cluster_centers_.larray)
     _check(km.n_iter_ == iters, f"Lloyd ran {km.n_iter_} of {iters} iterations")
     e_c = _err(km.cluster_centers_.numpy(), _lloyd64(x64, x64[:k], iters))
@@ -375,17 +381,19 @@ def stage_array(ht, cfg, devices, on_tpu):
 
     def took_mosaic(call, kernel):
         """Among the modules lowered since the last look there is a Mosaic
-        custom call of ``kernel``: the call took the Pallas path, compiled.
-        (Off the TPU the library's gates choose the XLA forms.)"""
+        custom call named ``kernel`` (the ``pallas_call``'s ``name=``, or
+        its kernel function's where it gives none): the call took the
+        Pallas path, compiled. (Off the TPU the library's gates choose the
+        XLA forms.)"""
         new = set(os.listdir(ir_dir)) - read
         read.update(new)
         _check(new, f"{call} lowered no module")
         text = "".join(open(os.path.join(ir_dir, f)).read() for f in new)
         _check(
             not on_tpu or re.search(
-                rf'@tpu_custom_call\(.*kernel_name = "{kernel.__name__}"', text
+                rf'@tpu_custom_call\(.*kernel_name = "{kernel}"', text
             ),
-            f"{call}: no Mosaic call of {kernel.__name__} in the "
+            f"{call}: no Mosaic call of {kernel} in the "
             f"{len(new)} modules it lowered",
         )
 

@@ -183,6 +183,7 @@ class KMeans(_KCluster):
         from .pallas_lloyd import (
             lloyd_fit_pallas,
             lloyd_fit_pallas_sharded,
+            lloyd_form,
             pallas_lloyd_applicable,
         )
 
@@ -204,15 +205,20 @@ class KMeans(_KCluster):
                 centers, labels, inertia, n_iter = _lloyd_fit(
                     xb, w, centers, self.max_iter, tol
                 )
-            # fused single-pass-over-X Lloyd update (see pallas_lloyd)
-            elif x.comm.size > 1:
-                centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
-                    x.comm, xb, centers, x.shape[0], self.max_iter, tol
-                )
             else:
-                centers, labels, inertia, n_iter = lloyd_fit_pallas(
-                    xb, centers, x.shape[0], self.max_iter, tol
+                # fused single-pass-over-X Lloyd update, its blocks in the
+                # orientation X has on the chip (see pallas_lloyd)
+                telemetry.get_registry().add(
+                    f"kmeans.lloyd.{lloyd_form(x.shape[1])}"
                 )
+                if x.comm.size > 1:
+                    centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
+                        x.comm, xb, centers, x.shape[0], self.max_iter, tol
+                    )
+                else:
+                    centers, labels, inertia, n_iter = lloyd_fit_pallas(
+                        xb, centers, x.shape[0], self.max_iter, tol
+                    )
 
         with telemetry.span("heat_tpu.kmeans.fit.readback"):
             # the host waits for the device here

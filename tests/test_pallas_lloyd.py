@@ -2,12 +2,20 @@
 the full pallas fit must agree with the XLA `_lloyd_fit` (same centers,
 labels, inertia) from the same start — they implement the same math."""
 
+import functools
+
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
 from heat_tpu.cluster.kmeans import _lloyd_fit
-from heat_tpu.cluster.pallas_lloyd import lloyd_fit_pallas
+from heat_tpu.cluster.pallas_lloyd import (
+    _lloyd_operands,
+    lloyd_fit_pallas,
+    lloyd_fit_pallas_sharded,
+    lloyd_form,
+)
 
 
 def _blobs(n, d, k, seed):
@@ -143,3 +151,174 @@ class TestPallasLloydInterpret:
             np.testing.assert_allclose(
                 np.asarray(got_c), np.asarray(ref_c), rtol=2e-4, atol=2e-3
             )
+
+
+class TestLloydForms:
+    """The two orientations of the block walk: feature-major where
+    ``d % 128 != 0`` (clusters on sublanes, ``k`` rounded to 8), the
+    row-major kernel where X arrives row-major."""
+
+    @pytest.mark.parametrize(
+        "n,d,k,block,form",
+        [
+            (4096, 64, 8, 512, "feature_major"),  # the benchmark's shape
+            (300, 5, 7, 128, "feature_major"),  # ragged: 300 rows, blocks of 128
+            (1000, 100, 3, 256, "feature_major"),  # d over 64, not a tile
+            (2048, 64, 130, 512, "feature_major"),  # k over 128: 17 sublane tiles
+            (257, 18, 9, None, "feature_major"),  # the form's own block, SUSY's width
+            (512, 128, 8, 128, "row_major"),
+            (512, 256, 8, 64, "row_major"),
+            (200, 128, 130, None, "row_major"),  # two lane tiles of clusters
+        ],
+    )
+    def test_agrees_with_the_xla_fit(self, n, d, k, block, form):
+        assert lloyd_form(d) == form
+        x, protos = _blobs(n, d, k, seed=n + d + k)
+        c0 = (protos + 0.25).astype(np.float32)  # one start in each blob
+        want_c, want_l, want_i, want_it = _lloyd_fit(
+            jnp.asarray(x), jnp.ones((n,), jnp.float32), jnp.asarray(c0),
+            6, jnp.float32(-1.0),
+        )
+        got_c, got_l, got_i, got_it = lloyd_fit_pallas(
+            jnp.asarray(x), jnp.asarray(c0), n, 6, jnp.float32(-1.0),
+            block_m=block, interpret=True,
+        )
+        assert int(got_it) == int(want_it) == 6
+        np.testing.assert_allclose(np.asarray(got_c), np.asarray(want_c),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+        np.testing.assert_allclose(float(got_i), float(want_i), rtol=1e-3)
+
+    @pytest.mark.parametrize("d", [5, 8, 18, 64, 96, 100, 127, 129, 192])
+    def test_narrow_and_odd_widths_go_feature_major(self, d):
+        x = jnp.zeros((300, d), jnp.float32)
+        xk, c0, block, feature_major = _lloyd_operands(x, x[:9], None)
+        assert lloyd_form(d) == "feature_major" and feature_major
+        # X.T, rows padded to whole lane tiles, no feature padded; k to 8
+        assert xk.shape == (d, 384) and block == 384 and c0.shape == (16, d)
+
+    @pytest.mark.parametrize("d", [128, 256, 512])
+    def test_lane_multiples_stay_row_major(self, d):
+        x = jnp.zeros((300, d), jnp.float32)
+        xk, c0, block, feature_major = _lloyd_operands(x, x[:9], None)
+        assert lloyd_form(d) == "row_major" and not feature_major
+        assert xk.shape == (304, d) and block == 304 and c0.shape == (128, d)
+
+    def test_block_m_overrides_the_rows_of_either_form(self):
+        for d, want in ((64, 256), (128, 200)):
+            x = jnp.zeros((1000, d), jnp.float32)
+            xk, _, block, fm = _lloyd_operands(x, x[:3], 200)
+            assert block == want  # lanes come in tiles of 128
+            assert xk.shape[1 if fm else 0] % block == 0
+
+    def test_empty_cluster_keeps_center_feature_major(self):
+        # d=64 with a centre that captures nothing, across several blocks
+        rng = np.random.default_rng(3)
+        x = np.vstack([
+            rng.standard_normal((200, 64)).astype(np.float32) * 0.1,
+            rng.standard_normal((200, 64)).astype(np.float32) * 0.1 + 2.0,
+        ])
+        c0 = np.stack([x[0], x[-1], np.full(64, 100.0, np.float32)])
+        got_c, got_l, _, _ = lloyd_fit_pallas(
+            jnp.asarray(x), jnp.asarray(c0), 400, 5, jnp.float32(0.0),
+            block_m=128, interpret=True,
+        )
+        want_c, want_l, _, _ = _lloyd_fit(
+            jnp.asarray(x), jnp.ones((400,), jnp.float32), jnp.asarray(c0),
+            5, jnp.float32(0.0),
+        )
+        np.testing.assert_array_equal(np.asarray(got_c)[2], c0[2])
+        np.testing.assert_allclose(np.asarray(got_c), np.asarray(want_c),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_rows_past_lim_drop_out(self, d):
+        # rows at or past n hold anything (here: far-off values inside a
+        # block and past it); the centres are those of the first n rows
+        n, pad, k = 333, 51, 4
+        x, protos = _blobs(n, d, k, seed=11)
+        xp = np.vstack([x, np.full((pad, d), 1e3, np.float32)])
+        c0 = (protos + 0.25).astype(np.float32)
+        got_c, got_l, got_i, _ = lloyd_fit_pallas(
+            jnp.asarray(xp), jnp.asarray(c0), n, 4, jnp.float32(-1.0),
+            block_m=128, interpret=True,
+        )
+        want_c, want_l, want_i, _ = _lloyd_fit(
+            jnp.asarray(x), jnp.ones((n,), jnp.float32), jnp.asarray(c0),
+            4, jnp.float32(-1.0),
+        )
+        np.testing.assert_allclose(np.asarray(got_c), np.asarray(want_c),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(np.asarray(got_l)[:n], np.asarray(want_l))
+        np.testing.assert_allclose(float(got_i), float(want_i), rtol=1e-3)
+
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_sharded_fit_on_mesh_both_forms(self, d):
+        # shard_map + one psum an iteration around either kernel; the last
+        # shards hold tail pad, which lim masks
+        import heat_tpu as ht
+
+        comm = ht.get_comm()
+        n, k = 40 * comm.size + 3, 5
+        x, protos = _blobs(n, d, k, seed=d)
+        xb = ht.array(x, split=0)._masked(0)
+        m = xb.shape[0]
+        c0 = (protos + 0.25).astype(np.float32)
+        want_c, want_l, want_i, _ = _lloyd_fit(
+            jnp.asarray(np.pad(x, ((0, m - n), (0, 0)))),
+            jnp.asarray((np.arange(m) < n).astype(np.float32)),
+            jnp.asarray(c0), 5, jnp.float32(-1.0),
+        )
+        got_c, got_l, got_i, got_it = lloyd_fit_pallas_sharded(
+            comm, xb, jnp.asarray(c0), n, 5, jnp.float32(-1.0),
+            block_m=16, interpret=True,
+        )
+        assert int(got_it) == 5
+        np.testing.assert_allclose(np.asarray(got_c), np.asarray(want_c),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(
+            np.asarray(got_l)[:n], np.asarray(want_l)[:n]
+        )
+        np.testing.assert_allclose(float(got_i), float(want_i), rtol=1e-3)
+
+    @pytest.mark.parametrize(
+        "d,counter",
+        [(64, "kmeans.lloyd.feature_major"), (128, "kmeans.lloyd.row_major")],
+    )
+    def test_fit_counts_the_form_it_took(self, monkeypatch, d, counter):
+        # KMeans.fit as on a TPU: the gate open, the kernels interpreted
+        import heat_tpu as ht
+        from heat_tpu import telemetry
+        from heat_tpu.cluster import pallas_lloyd
+
+        monkeypatch.setattr(pallas_lloyd, "pallas_lloyd_applicable", lambda *a: True)
+        for name in ("lloyd_fit_pallas", "lloyd_fit_pallas_sharded"):
+            monkeypatch.setattr(
+                pallas_lloyd, name,
+                functools.partial(getattr(pallas_lloyd, name), interpret=True),
+            )
+        x, protos = _blobs(160, d, 3, seed=2)
+        counters = telemetry.get_registry().counters
+        before = {c: counters.get(c, 0) for c in (
+            "kmeans.lloyd.feature_major", "kmeans.lloyd.row_major")}
+        km = ht.cluster.KMeans(
+            n_clusters=3, init=ht.array(protos + 0.25), max_iter=3, tol=-1.0
+        ).fit(ht.array(x, split=0))
+        assert km.n_iter_ == 3
+        after = {c: counters.get(c, 0) for c in before}
+        assert after[counter] == before[counter] + 1  # once a fit
+        assert sum(after.values()) == sum(before.values()) + 1
+
+    def test_xla_fit_counts_no_form(self):
+        # off the TPU the gate is shut: the XLA fit, neither counter
+        import heat_tpu as ht
+        from heat_tpu import telemetry
+
+        x, protos = _blobs(160, 8, 3, seed=4)
+        counters = telemetry.get_registry().counters
+        names = ("kmeans.lloyd.feature_major", "kmeans.lloyd.row_major")
+        before = [counters.get(c, 0) for c in names]
+        ht.cluster.KMeans(n_clusters=3, init=ht.array(protos), max_iter=2).fit(
+            ht.array(x, split=0))
+        assert [counters.get(c, 0) for c in names] == before
