@@ -24,6 +24,11 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          after step one; every Mosaic call takes one chip's share of the
          batch; on several chips, batch, parameters and optimizer state have
          shards on every chip and per-chip peak memory is of one size.
+         Then ``olmoe_1b_7b(num_layers=1)`` at its published widths (625.6 M
+         parameters, 4 x 4,096 tokens) on the first chip through
+         ``DataParallel.make_train_step``: five steps, loss lower at the
+         end, ``moe.dropped`` still 0, and Mosaic calls named ``flash_fwd``
+         and ``flash_bwd_*`` in the step.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
          each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
          bf16 matmul; cdist and rbf of 16384 x 128 (GEMM form); five Lloyd
@@ -62,6 +67,7 @@ import numpy as np
 
 FULL = dict(
     lm=dict(vocab=32768, d_model=1024, heads=16, layers=12, batch=8, seq=1024),
+    olmoe=dict(fields={}, batch=4, seq=4096),  # the published widths
     steps=5,
     moments_rows=8_000_000,
     matmul_n=8192,
@@ -75,6 +81,11 @@ FULL = dict(
 # clamping) and nothing of the size
 TINY = dict(
     lm=dict(vocab=256, d_model=64, heads=4, layers=2, batch=8, seq=128),
+    olmoe=dict(
+        fields=dict(vocab_size=256, d_model=64, num_heads=4, d_ff=32,
+                    num_experts=8, experts_per_token=2, max_len=64),
+        batch=2, seq=64,
+    ),
     steps=5,
     moments_rows=4096,
     matmul_n=256,
@@ -202,11 +213,89 @@ def stage_train(ht, cfg, devices, on_tpu):
             max(peaks) - min(peaks) <= 0.1 * max(peaks),
             f"per-chip peak memory is not of one size: {peaks}",
         )
+    del params, opt_state, batch, leaves
+    gc.collect()
     return dict(
         params=n_params, losses=[round(v, 4) for v in losses],
         first_step_seconds=round(first_step, 2),
         later_steps_seconds=round(later_steps, 2),
         peak_bytes_in_use=peaks,
+        olmoe=_olmoe_steps(cfg, devices, on_tpu),
+    )
+
+
+def _olmoe_steps(cfg, devices, on_tpu):
+    """Five AdamW steps of ``olmoe_1b_7b(num_layers=1)`` on the first chip,
+    through ``DataParallel.make_train_step`` (state donated): the loss falls,
+    no assignment is dropped, and the attention kernels go to Mosaic under
+    the names the benchmark's trace readers look for."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+    from heat_tpu.core.communication import MeshCommunication
+    from heat_tpu.nn import DataParallel, causal_lm_loss, olmoe_1b_7b, read_routing
+
+    c = cfg["olmoe"]
+    comm = MeshCommunication(devices=devices[:1])  # the expert layer runs on one chip
+    model = olmoe_1b_7b(num_layers=1, comm=comm, **c["fields"])
+    opt = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    step = DataParallel(
+        model, comm=comm, optimizer=opt, blocking_parameter_updates=True
+    ).make_train_step(
+        causal_lm_loss(model, load_balance_coef=0.01, router_z_coef=0.001),
+        has_aux=True,
+    )
+    key = tuple(sorted(c["fields"].items()))
+    replicated = comm.replicated()
+    params = program_cache.cached_program(
+        "smoke.olmoe_init", key,
+        lambda: lambda k: {"params": model.clone(attn_impl="local").init(
+            k, jnp.zeros((1, 8), jnp.int32))["params"]},
+        comm=comm, out_shardings=replicated,
+    )(jax.random.PRNGKey(0))
+    opt_state = program_cache.cached_program(
+        "smoke.olmoe_opt_init", key, lambda: opt.init, comm=comm,
+        out_shardings=replicated,
+    )(params)
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    toks = np.random.default_rng(0).integers(
+        0, model.vocab_size, (c["batch"], c["seq"]), dtype=np.int32
+    )
+    kernels = sorted(set(re.findall(
+        r'@tpu_custom_call\(.*kernel_name = "(\w+)"',
+        step.lower(params, opt_state, jnp.asarray(toks)).as_text(),
+    )))
+    if on_tpu:
+        _check(
+            "flash_fwd" in kernels
+            and any(k.startswith("flash_bwd_") for k in kernels),
+            f"no Mosaic call named flash_fwd / flash_bwd_* in the step: {kernels}",
+        )
+    counters = telemetry.get_registry().counters
+    dropped0, assigned0 = counters["moe.dropped"], counters["moe.assignments"]
+    losses = []
+    for _ in range(cfg["steps"]):
+        params, opt_state, loss, aux = step(params, opt_state, toks)
+        loss, aux = read_routing(loss, aux)  # to the host, and into the moe.* counters
+        losses.append(float(loss))
+    _check(all(np.isfinite(losses)), f"olmoe loss not finite: {losses}")
+    _check(losses[-1] < losses[0], f"olmoe loss did not fall: {losses}")
+    due = cfg["steps"] * c["batch"] * c["seq"] * model.experts_per_token
+    _check(
+        counters["moe.dropped"] == dropped0
+        and counters["moe.assignments"] - assigned0 == due,
+        f"routing dropped {counters['moe.dropped'] - dropped0} assignments, "
+        f"counted {counters['moe.assignments'] - assigned0} of {due}",
+    )
+    load = float(aux["expert_counts"].max() / aux["expert_counts"].mean())
+    del params, opt_state
+    gc.collect()
+    return dict(
+        params=n_params, losses=[round(v, 4) for v in losses],
+        mosaic_kernels=kernels, load_max_over_mean=round(load, 3),
     )
 
 

@@ -11,13 +11,20 @@ from . import functional
 from .data_parallel import DataParallel, DataParallelMultiGPU
 from .fsdp import FSDP
 from .pipeline import Pipeline
-from .transformer import MultiHeadAttention, TransformerBlock, TransformerLM
-from .moe import MoEMLP
+from .transformer import (
+    MultiHeadAttention,
+    TransformerBlock,
+    TransformerLM,
+    causal_lm_loss,
+    olmoe_1b_7b,
+)
+from .moe import DroplessMoE, MoEMLP, read_routing
 from .quant_dense import QuantDense
 
 __all__ = [
     "DataParallel",
     "DataParallelMultiGPU",
+    "DroplessMoE",
     "FSDP",
     "functional",
     "MoEMLP",
@@ -26,6 +33,9 @@ __all__ = [
     "QuantDense",
     "TransformerBlock",
     "TransformerLM",
+    "causal_lm_loss",
+    "olmoe_1b_7b",
+    "read_routing",
 ]
 
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
 
+from .. import telemetry
 from ..core import program_cache
 from ..core.communication import MeshCommunication, sanitize_comm
 from ..core.dndarray import DNDarray
@@ -132,11 +133,21 @@ class DataParallel:
 
     def make_train_step(
         self, loss_fn: Callable, optimizer=None,
-        precision: Optional[str] = None,
+        precision: Optional[str] = None, has_aux: bool = False,
     ) -> Callable:
         """Build the compiled DP train step.
 
         ``loss_fn(params, *batch) -> scalar`` closes over :attr:`apply_fn`.
+        With ``has_aux=True`` it returns ``(scalar, aux)`` and the step
+        returns ``aux`` after the loss. Either way everything the step
+        returns is on the device and the call does not wait for it.
+
+        The step **donates** ``params`` and ``opt_state`` (and
+        ``pending_grads``): the arrays passed in are consumed, the returned
+        ones take their memory. Keep a copy of what must outlive the call.
+        Batch arrays still on the host are placed by :meth:`shard_batch`
+        inside the call. Spans: ``heat_tpu.train.step`` with ``.prepare``
+        and ``.launch`` (``nn.moe.read_routing`` records ``.readback``).
         With the batch axis sharded and params replicated, XLA emits exactly
         one gradient psum per step (the reference's per-parameter Allreduce
         hooks, fused). Call with batch arrays sharded via
@@ -175,14 +186,17 @@ class DataParallel:
         wire = collective_prec.resolve(precision)
 
         if wire != "off":
+            if has_aux:
+                raise NotImplementedError("has_aux with a compressed gradient wire")
             step = self._make_compressed_step(loss_fn, optimizer, wire)
         elif self.blocking_parameter_updates:
 
             def step(params, opt_state, *batch):
-                loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-                updates, opt_state = optimizer.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-                return params, opt_state, loss
+                out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(params, *batch)
+                with jax.named_scope("train.optimizer"):
+                    updates, opt_state = optimizer.update(grads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+                return (params, opt_state, *out) if has_aux else (params, opt_state, out)
 
         else:
 
@@ -200,26 +214,44 @@ class DataParallel:
                         "params), or construct with "
                         "blocking_parameter_updates=True for the 3-tuple step"
                     )
-                loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
+                out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(params, *batch)
                 # apply the PREVIOUS step's averaged grads; this step's psum
                 # only feeds the program output — off the critical path
-                updates, opt_state = optimizer.update(
-                    pending_grads, opt_state, params
-                )
-                params = optax.apply_updates(params, updates)
-                return params, opt_state, grads, loss
+                with jax.named_scope("train.optimizer"):
+                    updates, opt_state = optimizer.update(
+                        pending_grads, opt_state, params
+                    )
+                    params = optax.apply_updates(params, updates)
+                if has_aux:
+                    return (params, opt_state, grads, *out)
+                return params, opt_state, grads, out
 
         # (loss_fn, optimizer, mode, wire) is the static config: two
         # wrappers building the same train step share one compiled program
         raw_step = step
+        raw_step.__name__ = "dp_train_step"  # the program's name in a device trace
+        n_state = 2 if self.blocking_parameter_updates else 3
         compiled = program_cache.cached_program(
             "dp_train_step",
-            (loss_fn, optimizer, self.blocking_parameter_updates, wire),
+            (loss_fn, optimizer, self.blocking_parameter_updates, wire, has_aux),
             lambda: raw_step,
             comm=self.comm,
+            donate=range(n_state),
         )
         self._train_step = compiled
-        return compiled
+
+        def train_step(*args):
+            with telemetry.span("heat_tpu.train.step"):
+                with telemetry.span("heat_tpu.train.step.prepare"):
+                    batch = tuple(
+                        a if isinstance(a, jax.Array) else self.shard_batch(a)[0]
+                        for a in args[n_state:]
+                    )
+                with telemetry.span("heat_tpu.train.step.launch"):
+                    return compiled(*args[:n_state], *batch)
+
+        train_step.lower = compiled.lower
+        return train_step
 
     def _make_compressed_step(self, loss_fn, optimizer, wire: str):
         """The shard_map form of the train step whose gradient collective

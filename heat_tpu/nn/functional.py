@@ -8,6 +8,7 @@ long-context attention front-end over :mod:`heat_tpu.parallel`.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Union
 
 import jax
@@ -16,7 +17,7 @@ import jax.numpy as jnp
 from ..core.dndarray import DNDarray
 from ..parallel import local_attention, ring_attention, ulysses_attention
 
-__all__ = ["dense", "scaled_dot_product_attention"]
+__all__ = ["blocked_cross_entropy", "dense", "scaled_dot_product_attention"]
 
 
 def dense(x, w, bias=None, activation=None):
@@ -115,6 +116,80 @@ def scaled_dot_product_attention(
         return DNDarray.from_logical(out, q.split, q.device, q.comm)
 
     return local_attention(q, k, v, causal=causal, scale=scale)
+
+
+def _position_blocks(block, *arrays):
+    """Each array's leading axis padded with zeros to a multiple of ``block``
+    and split into ``(blocks, block, ...)``."""
+    pad = -arrays[0].shape[0] % block
+    return tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(-1, block, *a.shape[1:])
+        for a in arrays
+    )
+
+
+def _operand(x, dtype):
+    return x if dtype is None else x.astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked_ce(hidden, kernel, targets, block, dtype):
+    return _blocked_ce_fwd(hidden, kernel, targets, block, dtype)[0]
+
+
+def _blocked_ce_fwd(hidden, kernel, targets, block, dtype):
+    n = hidden.shape[0]
+    w = _operand(kernel, dtype)
+
+    def one_block(hy):
+        h, y = hy
+        logits = jnp.dot(_operand(h, dtype), w, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0], lse
+
+    ce, lse = jax.lax.map(one_block, _position_blocks(block, hidden, targets))
+    return ce.reshape(-1)[:n], (hidden, kernel, targets, lse)
+
+
+def _blocked_ce_bwd(block, dtype, res, g):
+    """A block's logits once more, ``softmax - onehot`` scaled by the
+    cotangent, and its two products: the hidden states' gradient block by
+    block, the kernel's summed over the blocks in a float32 carry."""
+    hidden, kernel, targets, lse = res
+    n, d = hidden.shape
+    w = _operand(kernel, dtype)
+
+    def one_block(dw, args):
+        h, y, l, gg = args
+        h = _operand(h, dtype)
+        logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        onehot = y[:, None] == jnp.arange(w.shape[1], dtype=y.dtype)[None, :]
+        dlogits = _operand((jnp.exp(logits - l[:, None]) - onehot) * gg[:, None], dtype)
+        dh = jnp.dot(dlogits, w.T, preferred_element_type=jnp.float32)
+        return dw + jnp.dot(h.T, dlogits, preferred_element_type=jnp.float32), dh
+
+    hb, yb, gb = _position_blocks(block, hidden, targets, g)
+    dw, dh = jax.lax.scan(one_block, jnp.zeros(kernel.shape, jnp.float32), (hb, yb, lse, gb))
+    return dh.reshape(-1, d)[:n].astype(hidden.dtype), dw.astype(kernel.dtype), None
+
+
+_blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
+
+
+CE_BLOCK = 2048  # positions a block: 2048 x 50,304 float32 logits are 0.4 GB
+
+
+def blocked_cross_entropy(hidden, kernel, targets, *, dtype=None):
+    """Cross-entropy of ``hidden @ kernel`` against integer ``targets``, one
+    float32 value a position, without ever holding the ``(positions, vocab)``
+    logits: the positions go through in blocks of ``CE_BLOCK`` (the last one
+    padded), each block's logits are taken, reduced and dropped, and the
+    backward pass computes them again. ``hidden`` is ``(N, D)``, ``kernel``
+    ``(D, V)``, ``targets`` ``(N,)``; the products take ``dtype`` operands
+    (None: as they are) and accumulate in float32, as do the log-sum-exp, the
+    loss, and in the backward pass the kernel's gradient over the blocks;
+    both gradients come back in their argument's dtype."""
+    return _blocked_ce(hidden, kernel, targets, min(CE_BLOCK, hidden.shape[0]), dtype)
 
 
 def __getattr__(name):

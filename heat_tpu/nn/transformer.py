@@ -22,6 +22,14 @@ Parallelism is selected by ``attn_impl``:
   ``comm=``). Ring keeps K/V moving over ICI; ulysses swaps sequence↔heads
   with two all_to_alls.
 
+The architecture is a set of fields, not a model file: ``norm``
+(``"layernorm"`` | ``"rmsnorm"``), ``positions`` (``"learned"`` |
+``"rope"``), ``qk_norm``, ``ffn`` (``"swiglu"`` | ``"moe"``, the dropless
+top-k expert layer of :mod:`heat_tpu.nn.moe`) and ``accum_dtype``. The
+defaults are the pre-LN, learned-position, SwiGLU model this module began
+with, parameter tree and numerics unchanged. :func:`olmoe_1b_7b` names the
+one published configuration; :func:`causal_lm_loss` is its training loss.
+
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
 (tp: column/row-split the Dense kernels; dp: replicate) exactly as any flax
 model; the dryrun (`__graft_entry__.py`) exercises a dp×sp layout.
@@ -35,6 +43,38 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from .functional import blocked_cross_entropy
+from .moe import DroplessMoE
+
+
+def _dot_general(accum_dtype):
+    """``lax.dot_general`` giving its result in ``accum_dtype`` (None: as
+    flax does, the operands' dtype)."""
+    if accum_dtype is None:
+        return jax.lax.dot_general
+    return functools.partial(jax.lax.dot_general, preferred_element_type=accum_dtype)
+
+
+def _norm(kind, eps, dtype, name, **kw):
+    if kind == "layernorm":
+        return nn.LayerNorm(dtype=dtype, name=name, **({} if eps is None else {"epsilon": eps}))
+    if kind == "rmsnorm":
+        return nn.RMSNorm(dtype=dtype, name=name, epsilon=1e-6 if eps is None else eps, **kw)
+    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {kind!r}")
+
+
+def rotary(x, theta):
+    """Rotary positions on ``(B, T, H, D)`` in float32, the rotate-half form:
+    ``x cos + (-x2, x1) sin`` with angles ``t * theta^(-2i/D)`` repeated over
+    both halves."""
+    t, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
 
 
 def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
@@ -93,6 +133,11 @@ class MultiHeadAttention(nn.Module):
     dtype: Any = jnp.float32
     # flash backward strategy (pallas_attention.flash_attention bwd_impl)
     flash_bwd_impl: str = "two_pass"
+    # RMSNorm over all of d_model on the query and key projections, before
+    # the split into heads; None = no such norm, else its epsilon
+    qk_norm_eps: Optional[float] = None
+    rope_theta: Optional[float] = None  # rotary positions on q and k
+    accum_dtype: Optional[Any] = None  # dtype of matmul results; None = dtype
 
     @nn.compact
     def __call__(self, x):
@@ -102,21 +147,30 @@ class MultiHeadAttention(nn.Module):
         d_head = d_model // self.num_heads
         dense = lambda name: nn.DenseGeneral(  # noqa: E731
             (self.num_heads, d_head), axis=-1, use_bias=False,
-            dtype=self.dtype, name=name,
+            dtype=self.dtype, name=name, dot_general=_dot_general(self.accum_dtype),
         )
         q, k, v = dense("query")(x), dense("key")(x), dense("value")(x)
+        if self.qk_norm_eps is not None:
+            over_heads = dict(reduction_axes=(-2, -1), feature_axes=(-2, -1))
+            q = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "q_norm", **over_heads)(q)
+            k = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "k_norm", **over_heads)(k)
+        if self.rope_theta is not None:
+            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        q, k, v = (a.astype(self.dtype) for a in (q, k, v))
         o = _attend(
             q, k, v, impl=self.attn_impl, causal=self.causal, comm=self.comm,
             flash_bwd_impl=self.flash_bwd_impl,
             block_size=self.block_size,
         )
         return nn.DenseGeneral(
-            d_model, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out"
+            d_model, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out",
+            dot_general=_dot_general(self.accum_dtype),
         )(o)
 
 
 class TransformerBlock(nn.Module):
-    """Pre-LN residual block: x + attn(LN(x)); x + swiglu(LN(x))."""
+    """Pre-norm residual block: x + attn(norm(x)); x + ffn(norm(x)), the
+    feed-forward a SwiGLU MLP or the dropless expert layer."""
 
     num_heads: int
     mlp_ratio: float = 4.0
@@ -126,21 +180,43 @@ class TransformerBlock(nn.Module):
     block_size: Optional[int] = None  # None = each impl's tuned default
     dtype: Any = jnp.float32
     flash_bwd_impl: str = "two_pass"
+    norm: str = "layernorm"
+    norm_eps: Optional[float] = None  # None = flax's default (1e-6)
+    qk_norm: bool = False
+    rope_theta: Optional[float] = None
+    ffn: str = "swiglu"
+    d_ff: Optional[int] = None  # None = d_model * mlp_ratio; one expert's width for "moe"
+    num_experts: int = 0
+    experts_per_token: int = 0
+    accum_dtype: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x):
         d_model = x.shape[-1]
-        h = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
+        stream = self.dtype if self.accum_dtype is None else self.accum_dtype
+        eps = 1e-6 if self.norm_eps is None else self.norm_eps
+        h = _norm(self.norm, self.norm_eps, stream, "ln1")(x)
         x = x + MultiHeadAttention(
             self.num_heads, self.attn_impl, self.causal, self.comm,
-            self.block_size, self.dtype, self.flash_bwd_impl, name="attn",
+            self.block_size, self.dtype, self.flash_bwd_impl,
+            eps if self.qk_norm else None, self.rope_theta, self.accum_dtype,
+            name="attn",
         )(h)
-        h = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
-        d_ff = int(d_model * self.mlp_ratio)
-        gate = nn.Dense(d_ff, use_bias=False, dtype=self.dtype, name="gate")(h)
-        up = nn.Dense(d_ff, use_bias=False, dtype=self.dtype, name="up")(h)
-        h = nn.silu(gate) * up  # SwiGLU: two MXU GEMMs + one VPU fuse
-        return x + nn.Dense(d_model, use_bias=False, dtype=self.dtype, name="down")(h)
+        h = _norm(self.norm, self.norm_eps, stream, "ln2")(x)
+        d_ff = int(d_model * self.mlp_ratio) if self.d_ff is None else self.d_ff
+        if self.ffn == "moe":
+            return x + DroplessMoE(
+                self.num_experts, self.experts_per_token, d_ff,
+                dtype=self.dtype, accum_dtype=self.accum_dtype, name="moe",
+            )(h)
+        if self.ffn != "swiglu":
+            raise ValueError(f"ffn must be 'swiglu' or 'moe', got {self.ffn!r}")
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=self.dtype, name=name,
+            dot_general=_dot_general(self.accum_dtype),
+        )
+        h = nn.silu(dense(d_ff, "gate")(h)) * dense(d_ff, "up")(h)  # SwiGLU
+        return x + dense(d_model, "down")(h)
 
 
 class TransformerLM(nn.Module):
@@ -162,26 +238,44 @@ class TransformerLM(nn.Module):
     remat_policy: Optional[str] = None
     dtype: Any = jnp.float32
     flash_bwd_impl: str = "two_pass"
+    # the architecture (see the module docstring); defaults = the model above
+    norm: str = "layernorm"
+    norm_eps: Optional[float] = None
+    positions: str = "learned"  # or "rope": no position table
+    rope_theta: float = 10000.0
+    qk_norm: bool = False
+    ffn: str = "swiglu"
+    d_ff: Optional[int] = None
+    num_experts: int = 0
+    experts_per_token: int = 0
+    # results of matrix products, the residual stream and the norms keep
+    # this dtype while the products take ``dtype`` operands; None = dtype
+    accum_dtype: Optional[Any] = None
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head: bool = True):
+        """Logits ``(B, T, vocab)``; with ``head=False`` the final norm's
+        output ``(B, T, d_model)``, for a loss that applies ``lm_head``
+        itself (:func:`causal_lm_loss`)."""
+        if self.positions not in ("learned", "rope"):
+            raise ValueError(f"positions must be 'learned' or 'rope', got {self.positions!r}")
+        stream = self.dtype if self.accum_dtype is None else self.accum_dtype
         if tokens.shape[-1] > self.max_len:
             # nn.Embed's gather would silently clamp positions past the
             # table instead of erroring
             raise ValueError(
                 f"sequence length {tokens.shape[-1]} exceeds max_len {self.max_len}"
             )
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")(tokens)
-        pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype, name="pos")(
-            jnp.arange(tokens.shape[-1])
-        )
-        x = x + pos[None]
+        x = nn.Embed(self.vocab_size, self.d_model, dtype=stream, name="embed")(tokens)
+        if self.positions == "learned":
+            pos = nn.Embed(self.max_len, self.d_model, dtype=stream, name="pos")(
+                jnp.arange(tokens.shape[-1])
+            )
+            x = x + pos[None]
         # rematerialization trades backward-pass FLOPs for activation
         # memory — the standard long-context recipe (HBM is the bottleneck)
         if self.remat:
             if self.remat_policy == "dots":
-                import jax
-
                 block_cls = nn.remat(
                     TransformerBlock,
                     policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
@@ -194,7 +288,85 @@ class TransformerLM(nn.Module):
             x = block_cls(
                 self.num_heads, self.mlp_ratio, self.attn_impl, True,
                 self.comm, self.block_size, self.dtype,
-                self.flash_bwd_impl, name=f"block{i}",
+                self.flash_bwd_impl, self.norm, self.norm_eps, self.qk_norm,
+                self.rope_theta if self.positions == "rope" else None,
+                self.ffn, self.d_ff, self.num_experts, self.experts_per_token,
+                self.accum_dtype, name=f"block{i}",
             )(x)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head")(x)
+        x = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
+        lm_head = nn.Dense(
+            self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
+            dot_general=_dot_general(self.accum_dtype),
+        )
+        if head:
+            return lm_head(x)
+        if self.is_initializing():
+            lm_head(x[:, :1])
+        return x
+
+
+def olmoe_1b_7b(num_layers: int = 16, **fields) -> TransformerLM:
+    """OLMoE-1B-7B (allenai, arXiv:2409.02060; config.json of
+    ``OLMoE-1B-7B-0125-Instruct``) at its published widths: hidden 2048,
+    16 heads of 128, 64 experts of width 1024, top-8 not renormalised,
+    RMSNorm (eps 1e-5) before attention, before the experts and on the query
+    and key projections, rotary positions (theta 10000), untied 50,304-row
+    embedding and head, context 4,096. bfloat16 matmul operands, float32
+    everything else. ``num_layers`` is the one size a chip forces down;
+    ``fields`` passes what is not architecture (``attn_impl``, ``comm``,
+    ``remat``, ...)."""
+    arch = dict(
+        vocab_size=50304, d_model=2048, num_heads=16, num_layers=num_layers,
+        max_len=4096, norm="rmsnorm", norm_eps=1e-5, positions="rope",
+        rope_theta=10000.0, qk_norm=True, ffn="moe", d_ff=1024, num_experts=64,
+        experts_per_token=8, dtype=jnp.bfloat16, accum_dtype=jnp.float32,
+        attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
+def causal_lm_loss(
+    model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0,
+):
+    """``loss_fn(params, tokens) -> (loss, aux)`` for ``make_train_step(...,
+    has_aux=True)``: mean next-token cross-entropy over the ``T - 1`` targets
+    of each row of ``tokens (B, T)``, taken over blocks of positions so that
+    no ``(tokens, vocab)`` array is held (:func:`blocked_cross_entropy`), plus
+    ``load_balance_coef`` x the mean over the expert layers of their
+    ``load_balance`` term and ``router_z_coef`` x that of ``router_z``.
+    ``aux`` holds ``ce``, ``load_balance``, ``router_z``, ``expert_counts``
+    (layers x experts), ``assignments_due`` (layers x tokens x top-k) and
+    ``assignments_computed`` (those the grouped products computed with the
+    chosen expert, :func:`heat_tpu.nn.moe.rows_computed`: a dropless routing
+    gives ``assignments_due``); a model without expert layers gives the three
+    scalars only. ``nn.read_routing(loss, aux)`` brings both to the host and
+    counts the routing."""
+
+    def loss_fn(params, tokens):
+        with jax.named_scope("lm.body"):
+            hidden, state = model.apply(params, tokens, head=False, mutable=["aux"])
+        b, t = tokens.shape
+        targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
+        weights = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
+        with jax.named_scope("lm.head_loss"):
+            ce = blocked_cross_entropy(
+                hidden.reshape(b * t, -1), params["params"]["lm_head"]["kernel"],
+                targets, dtype=model.dtype,
+            )
+            ce = jnp.sum(ce * weights) / (b * (t - 1))
+        layers = [
+            state["aux"][f"block{i}"]["moe"]["moe"][0] for i in range(model.num_layers)
+        ] if model.ffn == "moe" else []
+        zero = jnp.zeros((), jnp.float32)
+        aux = {"ce": ce, "load_balance": zero, "router_z": zero}
+        if layers:
+            aux["load_balance"] = jnp.mean(jnp.stack([a["load_balance"] for a in layers]))
+            aux["router_z"] = jnp.mean(jnp.stack([a["router_z"] for a in layers]))
+            aux["expert_counts"] = jnp.stack([a["expert_counts"] for a in layers])
+            aux["assignments_due"] = len(layers) * b * t * model.experts_per_token
+            aux["assignments_computed"] = sum(a["computed"] for a in layers)
+        loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
+        return loss, aux
+
+    return loss_fn
+

@@ -262,6 +262,7 @@ def _flash_forward(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     if return_lse:
         out, lse = res
@@ -560,6 +561,7 @@ def _flash_bwd_fused(
             ),
         ),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(qp, kp, vp, do_p, lse, dd_p)
 
     return (
@@ -651,6 +653,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, do_p, lse, dd_p)
 
     # dk/dv pass: K blocks on the parallel axis, Q sequential
@@ -689,6 +692,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, do_p, lse, dd_p)
 
     return (

@@ -132,11 +132,12 @@ class TestDataParallelNonBlocking:
         assert dp.blocking_parameter_updates is False  # reference default
         step = dp.make_train_step(mse_loss)
         p0 = jax.device_put(mlp_init(8), comm.replicated())
+        before = {k: np.asarray(v) for k, v in p0.items()}  # the step donates p0
         s = dp.optimizer.init(p0)
         xb, yb = dp.shard_batch(x, y)
         p1, s, pending, loss = step(p0, s, dp.init_pending(p0), xb, yb)
-        for k in p0:  # zero grads applied -> params unchanged
-            np.testing.assert_array_equal(np.asarray(p1[k]), np.asarray(p0[k]))
+        for k in before:  # zero grads applied -> params unchanged
+            np.testing.assert_array_equal(np.asarray(p1[k]), before[k])
         # the emitted pending grads are the true global average
         g_ref = jax.grad(mse_loss)(mlp_init(8), x, y)
         for k in g_ref:
@@ -163,7 +164,8 @@ class TestDataParallelNonBlocking:
     def test_second_step_matches_blocking_first_update(self, comm):
         # nonblocking step 2 applies exactly the grads blocking step 1 applies
         x, y = make_data(seed=5)
-        p0 = mlp_init(8, seed=5)
+        # on the host: each step donates the parameters it is given
+        p0 = jax.tree.map(np.asarray, mlp_init(8, seed=5))
         opt = optax.sgd(0.1)
 
         dpb = DataParallel(
