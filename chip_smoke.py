@@ -39,7 +39,9 @@ array    the reference's workloads at bench.py's sizes on split DNDarrays,
 kernels  each of the six Pallas kernels lowered at its production block
          sizes with ``interpret`` left to the library, the lowering checked
          for a Mosaic custom call (nothing resolved ``interpret=True``),
-         run, and compared with the XLA form it replaces.
+         run, and compared with the XLA form it replaces; the cdist kernel
+         a second time at a shape that is a multiple of neither block, so
+         that the ragged last block of each axis is checked on the chip.
 serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
          (bench.py's serving configuration), warmed up; 32 requests; answers
          equal ``km.predict``; nothing compiled after warm-up.
@@ -71,7 +73,7 @@ FULL = dict(
     steps=5,
     moments_rows=8_000_000,
     matmul_n=8192,
-    cdist_rows=16384, cdist_k=128,
+    cdist_rows=16384, cdist_k=128, cdist_ragged=(1000, 2500, 18),
     kmeans_rows=2_000_000, kmeans_k=64, iters=5,
     attn_fwd=(4, 4096, 8, 128), attn_bwd=(8, 1024, 16, 64),
     kernel_rows=1 << 20, lloyd_rows=1 << 18, int8_n=2048,
@@ -89,7 +91,7 @@ TINY = dict(
     steps=5,
     moments_rows=4096,
     matmul_n=256,
-    cdist_rows=512, cdist_k=32,
+    cdist_rows=512, cdist_k=32, cdist_ragged=(520, 1030, 18),
     kmeans_rows=4096, kmeans_k=8, iters=3,
     attn_fwd=(1, 256, 2, 64), attn_bwd=(1, 256, 2, 64),
     kernel_rows=2048, lloyd_rows=2048, int8_n=256,
@@ -580,23 +582,29 @@ def stage_kernels(cfg, on_tpu):
         )
 
     m, k = cfg["cdist_rows"], cfg["cdist_k"]
-    x = jax.random.uniform(key, (min(m, 2048), k), jnp.float32)
-    y = jax.random.uniform(jax.random.fold_in(key, 1), (m, k), jnp.float32)
     gamma = 1.0 / 32.0
-    # both sides are HIGH-class (bf16x3) dots; see _cdist for the bound
-    run(
-        "cdist_dist",
-        lambda x, y: euclid_pallas(x, y, **rehearse),
-        _distance._quadratic_euclidean, (x, y), 1e-3,
-    )
-    run(
-        "cdist_rbf",
-        lambda x, y: euclid_pallas(
-            x, y, gamma, epilogue="rbf", **rehearse),
-        lambda x, y: jnp.exp(
-            -gamma * _distance._quadratic_euclidean(x, y) ** 2),
-        (x, y), 1e-4,
-    )
+    # block multiples on both axes, then a shape that is a multiple of
+    # neither block: the kernel writes (m, n) itself, so the second pair is
+    # the chip's check of the ragged last block of each axis
+    for tag, (rows_x, rows_y, kk) in (
+        ("", (min(m, 2048), m, k)), ("_ragged", cfg["cdist_ragged"]),
+    ):
+        x = jax.random.uniform(key, (rows_x, kk), jnp.float32)
+        y = jax.random.uniform(jax.random.fold_in(key, 1), (rows_y, kk), jnp.float32)
+        # both sides are HIGH-class (bf16x3) dots; see _cdist for the bound
+        run(
+            f"cdist_dist{tag}",
+            lambda x, y: euclid_pallas(x, y, **rehearse),
+            _distance._quadratic_euclidean, (x, y), 1e-3,
+        )
+        run(
+            f"cdist_rbf{tag}",
+            lambda x, y: euclid_pallas(
+                x, y, gamma, epilogue="rbf", **rehearse),
+            lambda x, y: jnp.exp(
+                -gamma * _distance._quadratic_euclidean(x, y) ** 2),
+            (x, y), 1e-4,
+        )
 
     # separated blobs, one start in each: no row sits near a Voronoi face,
     # so the two programs assign alike and differ by f32 summation order

@@ -9,6 +9,14 @@ GEMM's output tile while it is still in VMEM: one HBM write total (the
 r4 bench measured 7.2 TF/s counted on the XLA path; the output-bandwidth
 roofline at these shapes permits ~30-50 TF/s).
 
+The kernel writes the (m, n) result at its own shape. The grid is
+``cdiv`` over (block_m, block_n) blocks, so where m or n is no block
+multiple the last block of that axis is ragged: Mosaic drops what it
+writes past the array, and no padded output, slice or other m×n pass
+follows the kernel. Only the inputs are zero-padded to block multiples
+(m×k and n×k, small beside the result), so the discarded lanes of an edge
+block are computed from zeros and never from undefined memory.
+
 Epilogues: ``dist`` (euclidean distance, the cdist result) and ``rbf``
 (``exp(-gamma * d2)`` — the Gaussian kernel matrix directly, saving the
 separate exp pass that :func:`heat_tpu.spatial.rbf` otherwise runs).
@@ -114,8 +122,9 @@ def euclid_pallas(
     ``x`` (m, k) and ``y`` (n, k) f32; returns (m, n) f32 — the distance
     matrix (``epilogue='dist'``) or Gaussian kernel matrix
     (``epilogue='rbf'`` with ``gamma``). Inputs are zero-padded to block
-    multiples (zero feature columns contribute nothing to dot or norms;
-    pad rows are sliced off the result).
+    multiples (zero feature columns contribute nothing to dot or norms);
+    the result is written at (m, n) itself, its edge blocks ragged, so pad
+    rows never reach it.
 
     ``precision=None`` (the default) resolves :func:`cdist_precision` —
     ``"bf16x3"`` unless the ``HEAT_TPU_CDIST_PREC`` env override names a
@@ -155,9 +164,11 @@ def _euclid_pallas_jit(
         y = jnp.pad(y, ((0, np_ - n), (0, kp - k)))
     gamma_arr = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
 
-    out = pl.pallas_call(
+    # out_shape is (m, n) itself: a ragged last block's writes past the
+    # array are dropped, and the padded inputs feed those lanes zeros
+    return pl.pallas_call(
         functools.partial(_kernel, epilogue=epilogue, precision=precision),
-        grid=(mp // bm, np_ // bn),
+        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i, j: (_I0, _I0), memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, kp), lambda i, j: (i, _I0), memory_space=pltpu.VMEM),
@@ -166,14 +177,13 @@ def _euclid_pallas_jit(
         out_specs=pl.BlockSpec(
             (bm, bn), lambda i, j: (i, j), memory_space=pltpu.VMEM
         ),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
         name="euclid_tile",
     )(gamma_arr, x.astype(jnp.float32), y.astype(jnp.float32))
-    return out[:m, :n]
 
 
 def pallas_cdist_applicable(k: int, jnp_dtype) -> bool:

@@ -37,6 +37,42 @@ class TestEuclidPallasInterpret:
         )
         np.testing.assert_allclose(got, _np_cdist(x, y), rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("epilogue", ["dist", "rbf"])
+    @pytest.mark.parametrize(
+        "m,n",
+        [
+            (520, 1030),   # ragged last block on both axes
+            (512, 1030),   # on the columns only
+            (520, 1024),   # on the rows only
+            (1024, 2048),  # on neither: two whole blocks an axis
+        ],
+    )
+    def test_edge_blocks_write_the_result_at_its_own_shape(self, m, n, epilogue):
+        # the kernel's output is (m, n) itself: the last block of an axis
+        # that is no block multiple is ragged, and its valid part (the last
+        # rows, the last lanes) must be as right as the interior
+        k, gamma = 18, 0.05
+        rng = np.random.default_rng(m + n)
+        x = rng.standard_normal((m, k)).astype(np.float32)
+        y = rng.standard_normal((n, k)).astype(np.float32)
+        got = np.asarray(
+            euclid_pallas(
+                jnp.asarray(x), jnp.asarray(y), gamma, epilogue=epilogue,
+                interpret=True,
+            )
+        )
+        assert got.shape == (m, n) and got.dtype == np.float32
+        # float64 GEMM form: no (m, n, k) broadcast temporary at these sizes
+        x64, y64 = x.astype(np.float64), y.astype(np.float64)
+        d2 = (x64**2).sum(1)[:, None] + (y64**2).sum(1)[None, :] - 2.0 * x64 @ y64.T
+        d2 = np.maximum(d2, 0.0)
+        want = np.exp(-gamma * d2) if epilogue == "rbf" else np.sqrt(d2)
+        np.testing.assert_allclose(got[-8:], want[-8:], rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(
+            got[:, -128:], want[:, -128:], rtol=2e-4, atol=2e-4
+        )
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
     def test_self_distance_diagonal_zero(self):
         rng = np.random.default_rng(8)
         x = rng.standard_normal((65, 17)).astype(np.float32)
@@ -75,9 +111,13 @@ class TestEuclidPallasInterpret:
         xn = rng.standard_normal((n_rows, 9)).astype(np.float32)
         yn = rng.standard_normal((13, 9)).astype(np.float32)
         x = ht.array(xn, split=0)
+        xbuf = x._masked(0)
         out = _pallas_local(
-            comm, x._masked(0), jnp.asarray(yn), "dist", 0.0, interpret=True
+            comm, xbuf, jnp.asarray(yn), "dist", 0.0, interpret=True
         )
+        # each chip writes its (rows / p, n) slab at that shape: the columns
+        # are y's 13, not a lane-padded 128
+        assert out.shape == (xbuf.shape[0], 13)
         got = np.asarray(out)[:n_rows]  # physical pad rows sliced off
         np.testing.assert_allclose(got, _np_cdist(xn, yn), rtol=2e-4, atol=2e-4)
 
