@@ -31,24 +31,25 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          and ``flash_bwd_*`` in the step.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
          each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
-         bf16 matmul; cdist and rbf of 16384 x 128 (GEMM form); five Lloyd
+         bf16 matmul; cdist and rbf of 16384 x 128 and of a ragged pair
+         (1000 x 18 against 2500 x 18), GEMM form; five Lloyd
          iterations of KMeans(64) on 2M x 64; five Lasso sweeps. What mean,
          var, cdist, rbf and KMeans.fit dispatched is read back from JAX's
-         own dump of the modules it lowered: each holds a Mosaic call of
-         its Pallas kernel, so no gate sent the call to the XLA form.
-kernels  each of the six Pallas kernels lowered at its production block
+         own dump of the modules it lowered: mean, var and the fit each hold
+         a Mosaic call of their Pallas kernel, so no gate sent the call to
+         the XLA form; cdist and rbf each lowered one program,
+         ``_local_dist``, with no Mosaic call.
+kernels  each of the five Pallas kernels lowered at its production block
          sizes with ``interpret`` left to the library, the lowering checked
          for a Mosaic custom call (nothing resolved ``interpret=True``),
-         run, and compared with the XLA form it replaces; the cdist kernel
-         a second time at a shape that is a multiple of neither block, so
-         that the ragged last block of each axis is checked on the chip.
+         run, and compared with the XLA form it replaces.
 serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
          (bench.py's serving configuration), warmed up; 32 requests; answers
          equal ``km.predict``; nothing compiled after warm-up.
 
 Tolerances. TPU matmuls at default precision round their operands to
-bfloat16 (relative 2^-9); the K-family distance GEMMs and the Pallas cdist /
-Lloyd kernels use the three-pass bf16 split product (about 2^-16 of
+bfloat16 (relative 2^-9); the K-family distance GEMMs, cdist among them, and
+the Pallas Lloyd kernel use the three-pass bf16 split product (about 2^-16 of
 |x||y|). Each check below states the bound it uses and prints the error it
 saw. Oracles for matmul and cdist take a row subsample (rows are
 independent); moments, KMeans and Lasso are statistics of every row, so
@@ -350,27 +351,33 @@ def _matmul(ht, cfg, rows):
     return dict(matmul_err=err, matmul_bound=bound)
 
 
-def _cdist(ht, cfg, rows, took_mosaic):
+def _cdist(ht, cfg, rows, one_program):
     import jax
 
     m, k = cfg["cdist_rows"], cfg["cdist_k"]
-    x = ht.random.rand(m, k, dtype=ht.float32, split=0)
-    y = ht.random.rand(m, k, dtype=ht.float32, split=0)
     sigma = 4.0
-    dist = ht.spatial.cdist(x, y, quadratic_expansion=True)
-    took_mosaic("cdist", "euclid_tile")
-    kern = ht.spatial.rbf(x, y, sigma=sigma, quadratic_expansion=True)
-    took_mosaic("rbf", "euclid_tile")
-    jax.block_until_ready((dist.larray, kern.larray))
-    idx = rows(m)
-    xs, yh = x.numpy()[idx].astype(np.float64), y.numpy().astype(np.float64)
-    d2 = (xs * xs).sum(1)[:, None] + (yh * yh).sum(1)[None, :] - 2.0 * xs @ yh.T
-    e_d = _err(np.asarray(dist.larray[idx]), np.sqrt(d2))
-    e_k = _err(np.asarray(kern.larray[idx]), np.exp(-d2 / (2 * sigma * sigma)))
-    # bf16x3 dot: ~2^-16 of |x||y| (~43 at k=128) on d2, halved again by
-    # the square root at d ~ 4.6
-    _check(e_d <= 1e-3 and e_k <= 1e-4, f"cdist off: {e_d}, rbf {e_k}")
-    return dict(cdist_err=e_d, rbf_err=e_k)
+    out = {}
+    # the bench shape, then a pair that no tile divides, x and y of two lengths
+    for tag, (rows_x, rows_y, kk) in (
+        ("", (m, m, k)), ("_ragged", cfg["cdist_ragged"]),
+    ):
+        x = ht.random.rand(rows_x, kk, dtype=ht.float32, split=0)
+        y = ht.random.rand(rows_y, kk, dtype=ht.float32, split=0)
+        dist = one_program("cdist", "_local_dist", lambda: ht.spatial.cdist(
+            x, y, quadratic_expansion=True))
+        kern = one_program("rbf", "_local_dist", lambda: ht.spatial.rbf(
+            x, y, sigma=sigma, quadratic_expansion=True))
+        jax.block_until_ready((dist.larray, kern.larray))
+        idx = rows(rows_x)
+        xs, yh = x.numpy()[idx].astype(np.float64), y.numpy().astype(np.float64)
+        d2 = (xs * xs).sum(1)[:, None] + (yh * yh).sum(1)[None, :] - 2.0 * xs @ yh.T
+        e_d = _err(np.asarray(dist.larray[idx]), np.sqrt(d2))
+        e_k = _err(np.asarray(kern.larray[idx]), np.exp(-d2 / (2 * sigma * sigma)))
+        # bf16x3 dot: ~2^-16 of |x||y| (~43 at k=128) on d2, halved again by
+        # the square root at d ~ 4.6
+        _check(e_d <= 1e-3 and e_k <= 1e-4, f"cdist{tag} off: {e_d}, rbf {e_k}")
+        out.update({f"cdist_err{tag}": e_d, f"rbf_err{tag}": e_k})
+    return out
 
 
 def _lloyd64(x, centers, iters):
@@ -470,23 +477,46 @@ def stage_array(ht, cfg, devices, on_tpu):
     # user's call dispatched, not of what this script thinks it dispatches
     read = set()
 
+    def lowered(call):
+        """The modules lowered since the last look, text by file name."""
+        new = set(os.listdir(ir_dir)) - read
+        read.update(new)
+        _check(new, f"{call} lowered no module")
+        return {f: open(os.path.join(ir_dir, f)).read() for f in new}
+
     def took_mosaic(call, kernel):
         """Among the modules lowered since the last look there is a Mosaic
         custom call named ``kernel`` (the ``pallas_call``'s ``name=``, or
         its kernel function's where it gives none): the call took the
         Pallas path, compiled. (Off the TPU the library's gates choose the
         XLA forms.)"""
-        new = set(os.listdir(ir_dir)) - read
-        read.update(new)
-        _check(new, f"{call} lowered no module")
-        text = "".join(open(os.path.join(ir_dir, f)).read() for f in new)
+        new = lowered(call)
         _check(
             not on_tpu or re.search(
-                rf'@tpu_custom_call\(.*kernel_name = "{kernel}"', text
+                rf'@tpu_custom_call\(.*kernel_name = "{kernel}"',
+                "".join(new.values()),
             ),
             f"{call}: no Mosaic call of {kernel} in the "
             f"{len(new)} modules it lowered",
         )
+
+    def one_program(call, program, fn):
+        """Run ``fn``: it lowered one module, the jitted ``program``, with
+        no Mosaic call in it: one XLA program on every backend. (Beside it
+        only the relayout that makes a split y whole on every chip.)"""
+        read.update(os.listdir(ir_dir))
+        out = fn()
+        new = lowered(call)
+        progs = [f for f in new if "_relayout_program_" not in f]
+        _check(
+            len(progs) == 1 and f"_jit_{program}_" in progs[0],
+            f"{call}: lowered {sorted(new)}, not {program} alone",
+        )
+        _check(
+            "tpu_custom_call" not in "".join(new.values()),
+            f"{call}: a Mosaic call in {sorted(new)}",
+        )
+        return out
 
     out = {}
     with tempfile.TemporaryDirectory() as ir_dir:
@@ -495,7 +525,7 @@ def stage_array(ht, cfg, devices, on_tpu):
             for part in (
                 lambda: _moments(ht, cfg, devices, took_mosaic),
                 lambda: _matmul(ht, cfg, rows),
-                lambda: _cdist(ht, cfg, rows, took_mosaic),
+                lambda: _cdist(ht, cfg, rows, one_program),
                 lambda: _kmeans_lasso(ht, cfg, took_mosaic),
             ):
                 out.update(part())
@@ -520,8 +550,6 @@ def stage_kernels(cfg, on_tpu):
     from heat_tpu.core.linalg.quant import int8_matmul, quantize_int8
     from heat_tpu.core.pallas_moments import column_moments
     from heat_tpu.parallel import flash_attention, local_attention
-    from heat_tpu.spatial import distance as _distance
-    from heat_tpu.spatial.pallas_cdist import euclid_pallas
 
     # On a TPU nobody names the interpreter: flash attention and the int8
     # GEMM choose by backend, the other kernels compile unless told
@@ -579,31 +607,6 @@ def stage_kernels(cfg, on_tpu):
                 q, k, v, causal=True, bwd_impl=impl)),
             attn_grads(lambda q, k, v: local_attention(q, k, v, causal=True)),
             qkv(cfg["attn_bwd"]), 1e-1,
-        )
-
-    m, k = cfg["cdist_rows"], cfg["cdist_k"]
-    gamma = 1.0 / 32.0
-    # block multiples on both axes, then a shape that is a multiple of
-    # neither block: the kernel writes (m, n) itself, so the second pair is
-    # the chip's check of the ragged last block of each axis
-    for tag, (rows_x, rows_y, kk) in (
-        ("", (min(m, 2048), m, k)), ("_ragged", cfg["cdist_ragged"]),
-    ):
-        x = jax.random.uniform(key, (rows_x, kk), jnp.float32)
-        y = jax.random.uniform(jax.random.fold_in(key, 1), (rows_y, kk), jnp.float32)
-        # both sides are HIGH-class (bf16x3) dots; see _cdist for the bound
-        run(
-            f"cdist_dist{tag}",
-            lambda x, y: euclid_pallas(x, y, **rehearse),
-            _distance._quadratic_euclidean, (x, y), 1e-3,
-        )
-        run(
-            f"cdist_rbf{tag}",
-            lambda x, y: euclid_pallas(
-                x, y, gamma, epilogue="rbf", **rehearse),
-            lambda x, y: jnp.exp(
-                -gamma * _distance._quadratic_euclidean(x, y) ** 2),
-            (x, y), 1e-4,
         )
 
     # separated blobs, one start in each: no row sits near a Voronoi face,

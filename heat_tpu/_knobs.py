@@ -226,17 +226,6 @@ _register(
     tunable=Tunable(("64", "128", "256"), "lossy", exact_value="128"),
 )
 _register(
-    "HEAT_TPU_CDIST_PREC", "enum", "bf16x3",
-    "In-kernel dot strategy of the fused pallas cdist kernel; the "
-    "one-line revert knob while bf16x3 is unmeasured on chip "
-    "(docs/TUNING_RUNBOOK.md).",
-    choices=("bf16x3", "default", "high", "highest"),
-    tunable=Tunable(
-        ("bf16x3", "default", "high", "highest"), "lossy",
-        exact_value="highest",
-    ),
-)
-_register(
     "HEAT_TPU_RETRIES", "int", 0,
     "Transient-failure retry budget of the guarded dispatch sites "
     "(resilience/guard.py); 0 = retries off.",

@@ -4,12 +4,16 @@ Re-design of reference heat/spatial/distance.py:136-494, whose engine
 `_dist` (:209) is the reference's ring-communication showpiece: each rank
 keeps a stationary row block and circulates moving blocks rank→rank+1 with
 Send/Recv (:280-326), exploiting symmetry by shipping computed tiles back.
-On TPU two paths replace it:
+Here a distance matrix has two launches:
 
-* **MXU path (default)**: the quadratic expansion ``‖a−b‖² = ‖a‖² + ‖b‖²
-  − 2 a·bᵀ`` turns the whole distance matrix into one GEMM — this is where
-  the FLOPs belong on TPU and it is the benchmark path.
-* **Ring path** (`ring=True` or metric without a GEMM form): a `shard_map`
+* **Local program (default)**: one jitted XLA program, `_local_dist`, on
+  every backend, mesh, feature count and dtype. x keeps its rows (split 0
+  or whole), y is whole on every chip, and each chip writes its slab of
+  the result with no collective; the `rbf` epilogue is part of the same
+  program. With ``quadratic_expansion`` the block is the GEMM form
+  ``‖a−b‖² = ‖a‖² + ‖b‖² − 2 a·bᵀ``, which is the benchmark path
+  (`cdist-susy-1chip`): one output fusion, bound by the write of the result.
+* **Ring program** (`ring=True`, both operands row-split): a `shard_map`
   kernel with the reference's schedule — stationary local rows, K-side
   blocks circulated with `jax.lax.ppermute` over ICI, `lax.fori_loop` over
   mesh steps. Same schedule as ring attention (SURVEY §5); peak memory per
@@ -23,6 +27,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import program_cache, types
 from ..core.dndarray import DNDarray
@@ -86,14 +91,21 @@ _blocked_manhattan = partial(_blocked_rows, _pairwise_manhattan)
 
 
 @partial(jax.jit, static_argnums=(0, 3))
-def _local_dist(block_fn, xm: jax.Array, ym: jax.Array, dt) -> jax.Array:
-    """Single-dispatch local distance computation: cast + block fn compiled
-    as one XLA program (eager per-op dispatch costs a host round-trip each)."""
-    return block_fn(xm.astype(dt), ym.astype(dt))
+def _local_dist(block_fn, xm: jax.Array, ym: jax.Array, dt, gamma=None) -> jax.Array:
+    """The local distance program: cast, block fn and, where ``gamma`` is
+    given, the Gaussian-kernel epilogue, compiled as one XLA program (the
+    epilogue fuses into the write of the result: no second m×n pass).
+    ``gamma`` is traced, so one program serves every ``sigma``; its absence
+    is static. On a mesh the program partitions by its operands' shardings
+    (x split 0, y whole on every chip): each chip writes its slab and no
+    collective runs."""
+    d = block_fn(xm.astype(dt), ym.astype(dt))
+    return d if gamma is None else jnp.exp(-gamma * d * d)
 
 
 @jax.jit
 def _rbf_from_dist(d: jax.Array, gamma) -> jax.Array:
+    """The Gaussian-kernel epilogue of the ring launch, over its result."""
     return jnp.exp(-gamma * d * d)
 
 
@@ -200,34 +212,6 @@ def _ring_dist(
     return smapped(xm, ym)
 
 
-def _pallas_local(
-    comm, xbuf: jax.Array, yb: jax.Array, epilogue: str, gamma: float,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused Pallas euclidean kernel over the local path's layout: x rows
-    (possibly sharded split=0), y replicated. Single mesh: one call;
-    multi-device: shard_map over the row shards (each computes its
-    (local_rows, n) slab — the same decomposition as `_local_dist`, with
-    the whole epilogue fused into the GEMM output tile). ``interpret``
-    exists so the sharded wiring is testable on the CPU mesh."""
-    from .pallas_cdist import euclid_pallas
-
-    if comm.size == 1:
-        return euclid_pallas(xbuf, yb, gamma, epilogue=epilogue, interpret=interpret)
-    spec = comm.spec(0, 2)
-    return jax.shard_map(
-        lambda xb, yy: euclid_pallas(
-            xb, yy, gamma, epilogue=epilogue, interpret=interpret
-        ),
-        mesh=comm.mesh,
-        in_specs=(spec, comm.spec(None, 2)),
-        out_specs=spec,
-        # pallas_call's ShapeDtypeStruct outputs carry no vma annotation;
-        # the varying-across-mesh check cannot see through the kernel
-        check_vma=False,
-    )(xbuf, yb)
-
-
 def _dist(
     x: DNDarray,
     y: Optional[DNDarray],
@@ -239,8 +223,8 @@ def _dist(
 ) -> DNDarray:
     """Distance engine (reference distance.py:209): result is
     (n_x, n_y) distributed along the rows of x. ``rbf_gamma`` composes the
-    Gaussian-kernel epilogue — fused into the Pallas tile when that path
-    runs, one extra compiled exp pass otherwise."""
+    Gaussian-kernel epilogue: inside the local program, or over the ring
+    launch's result."""
     with telemetry.span("heat_tpu.cdist"):
         return _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit)
 
@@ -269,6 +253,8 @@ def _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit) -> DNDarray:
         jt = promoted.jnp_type()
         out_split = 0 if x.split == 0 else None
         m, n = x.shape[0], y.shape[0]
+        # a host scalar: the launch carries it, no program of its own
+        gamma = None if rbf_gamma is None else np.asarray(rbf_gamma, jt)
 
         use_ring = (
             ring
@@ -277,7 +263,6 @@ def _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit) -> DNDarray:
             and y.split == 0
             and x.comm.size > 1
         )
-        use_pallas = False
         if use_ring:
             # ring kernel works on the padded buffers; x pad rows land in
             # output pad rows, y pad columns are sliced off below. The hop
@@ -301,16 +286,6 @@ def _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit) -> DNDarray:
             # the host-logical view
             xa = x.larray
             yb = y._relayout(None) if y.split is not None else y.larray
-            if block_fn is _quadratic_euclidean:
-                from .pallas_cdist import pallas_cdist_applicable
-
-                # multi-device needs x row-SHARDED (the shard_map
-                # decomposition); a replicated x on a >1-device mesh keeps
-                # the XLA path
-                layout_ok = x.comm.size == 1 or x.split == 0
-                use_pallas = layout_ok and pallas_cdist_applicable(x.shape[1], jt)
-            if use_pallas:
-                xa, yb = xa.astype(jt), yb.astype(jt)
 
     with telemetry.span("heat_tpu.cdist.launch"):
         if use_ring:
@@ -328,20 +303,14 @@ def _dist_phases(x, y, block_fn, ring_ok, ring, rbf_gamma, audit) -> DNDarray:
                         audit_cost=cost if do_audit else None,
                     )
                 )
-        elif use_pallas:
-            out = _pallas_local(
-                x.comm, xa, yb,
-                "rbf" if rbf_gamma is not None else "dist",
-                0.0 if rbf_gamma is None else float(rbf_gamma),
-            )
         else:
-            out = _local_dist(block_fn, xa, yb, jt)
+            out = _local_dist(block_fn, xa, yb, jt, gamma)
 
     with telemetry.span("heat_tpu.cdist.wrap"):
         if use_ring:
             out = out[:, :n]
-        if rbf_gamma is not None and not use_pallas:  # the kernel fuses its own
-            out = _rbf_from_dist(out, jnp.asarray(rbf_gamma, out.dtype))
+            if gamma is not None:
+                out = _rbf_from_dist(out, gamma)
         return DNDarray(out, (m, n), promoted, out_split, x.device, x.comm, True)
 
 
@@ -373,9 +342,9 @@ def rbf(
 ) -> DNDarray:
     """Gaussian kernel matrix exp(−‖x−y‖²/2σ²) (reference distance.py:159).
 
-    On TPU with the GEMM form, the exp epilogue fuses into the Pallas
-    distance tile (no separate m×n exp pass); elsewhere it is one extra
-    compiled pass over the distance matrix."""
+    The exp epilogue is part of the local distance program (`_local_dist`:
+    it fuses into the write of the result, no separate m×n pass); only the
+    ring launch (``ring=True``) applies it as a pass over its result."""
     gamma = 1.0 / (2.0 * sigma * sigma)
     fn = _quadratic_euclidean if quadratic_expansion else _blocked_euclidean
     return _dist(X, Y, fn, ring_ok=True, ring=ring, rbf_gamma=gamma,

@@ -1,10 +1,11 @@
 """On-chip tuning sweep (round 5): one JSON line per experiment.
 
-Run on the real TPU to (a) verify the new Pallas cdist/Lloyd kernels beat
-the XLA forms, (b) find the matmul steady-state MFU config, (c) measure the
-moments pass against the HBM roofline. Each experiment is isolated — a
-failure prints an error line and the sweep continues — and the exit code
-is non-zero when any experiment failed or the host has no TPU. Usage:
+Run on the real TPU to (a) verify the Pallas Lloyd kernel beats its XLA
+form and time the cdist program, (b) find the matmul steady-state MFU
+config, (c) measure the moments pass against the HBM roofline. Each
+experiment is isolated — a failure prints an error line and the sweep
+continues — and the exit code is non-zero when any experiment failed or
+the host has no TPU. Usage:
 
     python scripts/tpu_tune.py [--only cdist,kmeans,matmul,moments,rbf,lm,attn_bwd]
 
@@ -68,24 +69,10 @@ def main():
     peak_gflops = ht.chip_peaks(dev.device_kind).bf16_flops / 1e9
     emit(device=dev.device_kind, n=len(jax.devices()))
 
-    # ---------------- cdist: pallas kernel vs XLA form -------------------
+    # ---------------- cdist: the local XLA program ------------------------
     m, k, reps = 16384, 128, 10
     if want("cdist"):
         x = ht.random.rand(m, k, dtype=ht.float32, split=0)
-
-        def bench_cdist(tag, fn):
-            fn()  # compile
-            t = _time(fn)
-            emit(exp=f"cdist_{tag}", gflops=round(reps * 2.0 * m * m * k / t / 1e9, 1),
-                 seconds=round(t, 3))
-
-        def run_pallas():
-            from heat_tpu.spatial.pallas_cdist import euclid_pallas
-
-            out = None
-            for _ in range(reps):
-                out = euclid_pallas(x.larray, x.larray)
-            return _sync(out)
 
         def run_xla():
             from heat_tpu.spatial.distance import _local_dist, _quadratic_euclidean
@@ -95,45 +82,13 @@ def main():
                 out = _local_dist(_quadratic_euclidean, x.larray, x.larray, jnp.float32)
             return _sync(out)
 
-        run_guarded("cdist_pallas", lambda: bench_cdist("pallas", run_pallas))
-        run_guarded("cdist_xla", lambda: bench_cdist("xla", run_xla))
-        # block-size sweep for the pallas kernel
-        from heat_tpu.spatial.pallas_cdist import euclid_pallas
+        def bench_cdist():
+            run_xla()  # compile
+            t = _time(run_xla)
+            emit(exp="cdist_xla", gflops=round(reps * 2.0 * m * m * k / t / 1e9, 1),
+                 seconds=round(t, 3))
 
-        for bm, bn in ((256, 1024), (512, 512), (512, 1024), (512, 2048), (1024, 1024)):
-            def run_blk(bm=bm, bn=bn):
-                out = None
-                for _ in range(reps):
-                    out = euclid_pallas(x.larray, x.larray, block_m=bm, block_n=bn)
-                _sync(out)
-
-            def do(bm=bm, bn=bn, run_blk=run_blk):
-                run_blk()
-                t = _time(run_blk)
-                emit(exp=f"cdist_pallas_bm{bm}_bn{bn}",
-                     gflops=round(reps * 2.0 * m * m * k / t / 1e9, 1))
-
-            run_guarded(f"cdist_blk_{bm}_{bn}", do)
-
-        # precision-strategy sweep: Mosaic's lowering cost for the
-        # in-kernel dot is not uniform (HIGH may lower off the MXU);
-        # measure each strategy against the XLA quadratic form above
-        for prec in ("DEFAULT", "HIGH", "HIGHEST", "bf16x3"):
-            def run_prec(prec=prec):
-                out = None
-                for _ in range(reps):
-                    out = euclid_pallas(
-                        x.larray, x.larray, precision=prec,
-                    )
-                _sync(out)
-
-            def do_prec(prec=prec, run_prec=run_prec):
-                run_prec()
-                t = _time(run_prec)
-                emit(exp=f"cdist_pallas_prec_{prec}",
-                     gflops=round(reps * 2.0 * m * m * k / t / 1e9, 1))
-
-            run_guarded(f"cdist_prec_{prec}", do_prec)
+        run_guarded("cdist_xla", bench_cdist)
 
     # ---------------- rbf fused epilogue ---------------------------------
     if want("rbf"):

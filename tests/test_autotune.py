@@ -103,8 +103,7 @@ class TestTunableMetadata:
                 assert set(t.values) <= set(k.choices), name
 
     def test_lossy_classes_cover_the_accuracy_frontier_knobs(self):
-        for name in ("HEAT_TPU_COLLECTIVE_PREC", "HEAT_TPU_CDIST_PREC",
-                     "HEAT_TPU_SERVE_EXACT"):
+        for name in ("HEAT_TPU_COLLECTIVE_PREC", "HEAT_TPU_SERVE_EXACT"):
             assert knobs.REGISTRY[name].tunable.kind == "lossy", name
         for name in ("HEAT_TPU_RELAYOUT_PLAN", "HEAT_TPU_FUSION_DEPTH",
                      "HEAT_TPU_RING_OVERLAP"):

@@ -150,13 +150,6 @@ class TestKMeansDeep(TestCase):
 
 
 class TestSpatialDeep(TestCase):
-    def test_cdist_self_distance_zero_diagonal(self):
-        rng = np.random.default_rng(9)
-        x = rng.standard_normal((2 * self.comm.size + 1, 5)).astype(np.float32)
-        d = ht.spatial.cdist(ht.array(x, split=0)).numpy()
-        np.testing.assert_allclose(np.diag(d), 0.0, atol=1e-3)
-        np.testing.assert_allclose(d, d.T, atol=1e-3)
-
     def test_cdist_xy_asymmetric_shapes(self):
         rng = np.random.default_rng(10)
         x = rng.standard_normal((self.comm.size + 2, 4)).astype(np.float32)
