@@ -132,45 +132,55 @@ def _operand(x, dtype):
     return x if dtype is None else x.astype(dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blocked_ce(hidden, kernel, targets, block, dtype):
-    return _blocked_ce_fwd(hidden, kernel, targets, block, dtype)[0]
-
-
-def _blocked_ce_fwd(hidden, kernel, targets, block, dtype):
-    n = hidden.shape[0]
-    w = _operand(kernel, dtype)
-
-    def one_block(hy):
-        h, y = hy
-        logits = jnp.dot(_operand(h, dtype), w, preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        return lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0], lse
-
-    ce, lse = jax.lax.map(one_block, _position_blocks(block, hidden, targets))
-    return ce.reshape(-1)[:n], (hidden, kernel, targets, lse)
-
-
-def _blocked_ce_bwd(block, dtype, res, g):
-    """A block's logits once more, ``softmax - onehot`` scaled by the
-    cotangent, and its two products: the hidden states' gradient block by
-    block, the kernel's summed over the blocks in a float32 carry."""
-    hidden, kernel, targets, lse = res
+def _ce_blocks(hidden, kernel, targets, weights, block, dtype, grads):
+    """One loop over the blocks of positions. A block's logits are taken once
+    and from them the log-sum-exp and the cross-entropy of each position;
+    with ``grads`` also ``(softmax - onehot) * weights`` and its two products,
+    the hidden states' gradient block by block and the kernel's summed over
+    the blocks in a float32 carry. Returns the weighted sum, the
+    cross-entropy a position and the two gradients in float32 (without
+    ``grads``: None)."""
     n, d = hidden.shape
     w = _operand(kernel, dtype)
 
     def one_block(dw, args):
-        h, y, l, gg = args
+        h, y, wt = args
         h = _operand(h, dtype)
         logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ce = lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        if not grads:
+            return None, (ce, None)
         onehot = y[:, None] == jnp.arange(w.shape[1], dtype=y.dtype)[None, :]
-        dlogits = _operand((jnp.exp(logits - l[:, None]) - onehot) * gg[:, None], dtype)
+        dlogits = _operand((jnp.exp(logits - lse[:, None]) - onehot) * wt[:, None], dtype)
         dh = jnp.dot(dlogits, w.T, preferred_element_type=jnp.float32)
-        return dw + jnp.dot(h.T, dlogits, preferred_element_type=jnp.float32), dh
+        return dw + jnp.dot(h.T, dlogits, preferred_element_type=jnp.float32), (ce, dh)
 
-    hb, yb, gb = _position_blocks(block, hidden, targets, g)
-    dw, dh = jax.lax.scan(one_block, jnp.zeros(kernel.shape, jnp.float32), (hb, yb, lse, gb))
-    return dh.reshape(-1, d)[:n].astype(hidden.dtype), dw.astype(kernel.dtype), None
+    dw, (ce, dh) = jax.lax.scan(
+        one_block,
+        jnp.zeros(kernel.shape, jnp.float32) if grads else None,
+        _position_blocks(block, hidden, targets, weights),
+    )
+    ce = ce.reshape(-1)[:n]
+    return jnp.sum(ce * weights), ce, dh.reshape(-1, d)[:n] if grads else None, dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _blocked_ce(hidden, kernel, targets, weights, block, dtype):
+    return _ce_blocks(hidden, kernel, targets, weights, block, dtype, grads=False)[0]
+
+
+def _blocked_ce_fwd(hidden, kernel, targets, weights, block, dtype):
+    loss, ce, dh, dw = _ce_blocks(hidden, kernel, targets, weights, block, dtype, grads=True)
+    return loss, (dh.astype(hidden.dtype), dw.astype(kernel.dtype), ce)
+
+
+def _blocked_ce_bwd(block, dtype, res, g):
+    """The loss is linear in its cotangent and the loop has formed the
+    gradients at 1: what is left is to scale them (under ``value_and_grad``
+    by the constant 1, which folds away)."""
+    dh, dw, ce = res
+    return (dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype), None, ce * g
 
 
 _blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
@@ -179,17 +189,25 @@ _blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
 CE_BLOCK = 2048  # positions a block: 2048 x 50,304 float32 logits are 0.4 GB
 
 
-def blocked_cross_entropy(hidden, kernel, targets, *, dtype=None):
-    """Cross-entropy of ``hidden @ kernel`` against integer ``targets``, one
-    float32 value a position, without ever holding the ``(positions, vocab)``
-    logits: the positions go through in blocks of ``CE_BLOCK`` (the last one
-    padded), each block's logits are taken, reduced and dropped, and the
-    backward pass computes them again. ``hidden`` is ``(N, D)``, ``kernel``
-    ``(D, V)``, ``targets`` ``(N,)``; the products take ``dtype`` operands
-    (None: as they are) and accumulate in float32, as do the log-sum-exp, the
-    loss, and in the backward pass the kernel's gradient over the blocks;
-    both gradients come back in their argument's dtype."""
-    return _blocked_ce(hidden, kernel, targets, min(CE_BLOCK, hidden.shape[0]), dtype)
+def blocked_cross_entropy(hidden, kernel, targets, weights=None, *, dtype=None):
+    """``sum(weights * ce)``, a float32 scalar, where ``ce`` is the
+    cross-entropy of ``hidden @ kernel`` against integer ``targets`` a
+    position and ``weights`` (None: ones) carries the mask and the mean's
+    divisor, without ever holding the ``(positions, vocab)`` logits: the
+    positions go through one loop in blocks of ``CE_BLOCK`` (the last one
+    padded), and each block's logits are taken once, reduced and dropped.
+    Where the call is differentiated, the same pass forms ``softmax - onehot``
+    from those logits and both gradients with it, three products a block and
+    no second loop; the backward pass only scales them by the cotangent. A
+    call that is not differentiated forms no gradient. ``hidden`` is
+    ``(N, D)``, ``kernel`` ``(D, V)``, ``targets`` and ``weights`` ``(N,)``;
+    the products take ``dtype`` operands (None: as they are) and accumulate in
+    float32, as do the log-sum-exp, the loss and the kernel's gradient over
+    the blocks; both gradients come back in their argument's dtype, and the
+    cotangent of ``weights`` is the cross-entropy a position."""
+    n = hidden.shape[0]
+    weights = jnp.ones((n,), jnp.float32) if weights is None else weights.astype(jnp.float32)
+    return _blocked_ce(hidden, kernel, targets, weights, min(CE_BLOCK, n), dtype)
 
 
 def __getattr__(name):

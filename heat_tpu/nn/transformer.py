@@ -331,7 +331,10 @@ def causal_lm_loss(
     """``loss_fn(params, tokens) -> (loss, aux)`` for ``make_train_step(...,
     has_aux=True)``: mean next-token cross-entropy over the ``T - 1`` targets
     of each row of ``tokens (B, T)``, taken over blocks of positions so that
-    no ``(tokens, vocab)`` array is held (:func:`blocked_cross_entropy`), plus
+    no ``(tokens, vocab)`` array is held and, under differentiation, with the
+    head's gradients formed in the pass that forms its logits
+    (:func:`blocked_cross_entropy`, which takes the mask and the divisor as
+    its weights), plus
     ``load_balance_coef`` x the mean over the expert layers of their
     ``load_balance`` term and ``router_z_coef`` x that of ``router_z``.
     ``aux`` holds ``ce``, ``load_balance``, ``router_z``, ``expert_counts``
@@ -347,13 +350,13 @@ def causal_lm_loss(
             hidden, state = model.apply(params, tokens, head=False, mutable=["aux"])
         b, t = tokens.shape
         targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
-        weights = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
+        mask = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
+        weights = mask.astype(jnp.float32) / (b * (t - 1))
         with jax.named_scope("lm.head_loss"):
             ce = blocked_cross_entropy(
                 hidden.reshape(b * t, -1), params["params"]["lm_head"]["kernel"],
-                targets, dtype=model.dtype,
+                targets, weights, dtype=model.dtype,
             )
-            ce = jnp.sum(ce * weights) / (b * (t - 1))
         layers = [
             state["aux"][f"block{i}"]["moe"]["moe"][0] for i in range(model.num_layers)
         ] if model.ffn == "moe" else []

@@ -12,6 +12,7 @@ norms and results cost (the control, which must fail).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -278,40 +279,107 @@ def test_rows_computed_counts_what_the_grouped_products_give_the_chosen_expert()
 # -- the blocked cross-entropy ---------------------------------------------------------
 
 
+def _ce_case(n, seed=None):
+    """Hidden states, a kernel, targets, and weights with zeros among them."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(n if seed is None else seed), 4)
+    h = jax.random.normal(k1, (n, 48), jnp.float32)
+    w = jax.random.normal(k2, (48, 257), jnp.float32) * 0.2
+    y = jax.random.randint(k3, (n,), 0, 257)
+    wt = jax.random.uniform(k4, (n,), jnp.float32).at[::5].set(0.0)
+    return h, w, y, wt
+
+
+def _plain_ce(h, w, y):
+    return optax.softmax_cross_entropy_with_integer_labels(h @ w, y)
+
+
+def loops(text):
+    """The ``while`` instructions of a compiled program, each as its line."""
+    return [line for line in text.splitlines() if " while(" in line]
+
+
+def products_over(text, size):
+    """The products of a compiled program (XLA:TPU writes a ``dot`` as a
+    ``convolution``) with ``size`` among the dimensions of their result or of
+    an operand, each as the list of those shapes. Operands are printed by
+    name, so their shapes are looked up where they are defined."""
+    shape = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])", text, re.M))
+    found = []
+    for result, operands in re.findall(r"= (\w+\[[\d,]*\])\S* (?:dot|convolution)\(([^)]*)\)", text):
+        shapes = [result] + [shape.get(name.strip(), "") for name in operands.split(",")]
+        if any(str(size) in re.findall(r"\d+", s) for s in shapes):
+            found.append(shapes)
+    return found
+
+
 @highest
 @pytest.mark.parametrize("n,block", [(64, 16), (50, 16), (10, 2048)])
 def test_blocked_cross_entropy_against_the_plain_one(monkeypatch, n, block):
     monkeypatch.setattr(F, "CE_BLOCK", block)
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(n), 3)
-    h = jax.random.normal(k1, (n, 48), jnp.float32)
-    w = jax.random.normal(k2, (48, 257), jnp.float32) * 0.2
-    y = jax.random.randint(k3, (n,), 0, 257)
-
-    def plain(h, w):
-        return optax.softmax_cross_entropy_with_integer_labels(h @ w, y)
-
+    h, w, y, _ = _ce_case(n)
     got = blocked_cross_entropy(h, w, y)
-    assert got.shape == (n,) and got.dtype == jnp.float32
-    # the same sums in another order: a few float32 roundings of values near 6
-    np.testing.assert_allclose(got, plain(h, w), rtol=0, atol=5e-6)
-    g_got = jax.grad(lambda h, w: blocked_cross_entropy(h, w, y).sum(), (0, 1))(h, w)
-    g_want = jax.grad(lambda h, w: plain(h, w).sum(), (0, 1))(h, w)
+    assert got.shape == () and got.dtype == jnp.float32
+    # the same sums in another order: a few float32 roundings of n values near 6
+    np.testing.assert_allclose(got, _plain_ce(h, w, y).sum(), rtol=2e-6)
+    g_got = jax.grad(lambda h, w: blocked_cross_entropy(h, w, y), (0, 1))(h, w)
+    g_want = jax.grad(lambda h, w: _plain_ce(h, w, y).sum(), (0, 1))(h, w)
     for a, b in zip(g_got, g_want):
         assert rel(a, b) < F32
 
 
+@highest
+@pytest.mark.parametrize("how", ["cotangent_3", "value_and_grad_has_aux", "undifferentiated"])
+def test_weighted_blocked_cross_entropy_against_the_plain_one(monkeypatch, how):
+    """Random weights with zeros among them and a ragged last block (50
+    positions in blocks of 16): the value whether or not the call is
+    differentiated, and all three gradients under a cotangent that is not 1
+    and as ``value_and_grad(..., has_aux=True)`` takes them."""
+    monkeypatch.setattr(F, "CE_BLOCK", 16)
+    h, w, y, wt = _ce_case(50)
+    scale = 3.0 if how == "cotangent_3" else 1.0
+
+    def loss(ce):
+        def f(h, w, wt):
+            value = scale * ce(h, w, wt)
+            return value, {"ce": value}
+
+        return f
+
+    got = loss(lambda h, w, wt: blocked_cross_entropy(h, w, y, wt))
+    want = loss(lambda h, w, wt: jnp.sum(_plain_ce(h, w, y) * wt))
+    if how == "undifferentiated":
+        np.testing.assert_allclose(got(h, w, wt)[0], want(h, w, wt)[0], rtol=2e-6)
+        return
+    (v_got, aux), g_got = jax.value_and_grad(got, (0, 1, 2), has_aux=True)(h, w, wt)
+    (v_want, _), g_want = jax.value_and_grad(want, (0, 1, 2), has_aux=True)(h, w, wt)
+    np.testing.assert_allclose(v_got, v_want, rtol=2e-6)
+    assert aux["ce"] == v_got
+    for a, b in zip(g_got, g_want):
+        assert a.dtype == b.dtype and rel(a, b) < F32
+
+
+@highest
+def test_the_cotangent_of_the_weights_is_the_cross_entropy_a_position(monkeypatch):
+    """Exact, not a silent zero: the loss is linear in its weights. And no
+    weights are weights of one."""
+    monkeypatch.setattr(F, "CE_BLOCK", 16)
+    h, w, y, wt = _ce_case(50)
+    got = jax.grad(lambda wt: blocked_cross_entropy(h, w, y, wt))(wt)
+    np.testing.assert_allclose(got, _plain_ce(h, w, y), rtol=0, atol=5e-6)
+    assert blocked_cross_entropy(h, w, y) == blocked_cross_entropy(h, w, y, jnp.ones(50))
+
+
 def test_blocked_cross_entropy_sums_its_gradients_in_float32_whatever_the_operands(monkeypatch):
-    """bfloat16 operands, sixteen blocks: the kernel's gradient is the sum
-    over the blocks in a float32 carry and the hidden states' gradient comes
-    back in float32, so one block of 256 positions gives the same numbers to a
-    float32 rounding. (A bfloat16 carry over sixteen blocks is off by 1e-3.)"""
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
-    h = jax.random.normal(k1, (256, 48), jnp.float32)
-    w = jax.random.normal(k2, (48, 257), jnp.float32) * 0.2
-    y = jax.random.randint(k3, (256,), 0, 257)
+    """bfloat16 operands, sixteen blocks through the one fused loop: the
+    kernel's gradient is the sum over the blocks in a float32 carry and the
+    hidden states' gradient comes back in float32, so one block of 256
+    positions gives the same numbers to a float32 rounding. (A bfloat16 carry
+    over sixteen blocks is off by 1e-3.)"""
+    h, w, y, wt = _ce_case(256, seed=9)
+
     def grads(block):
         monkeypatch.setattr(F, "CE_BLOCK", block)
-        return jax.grad(lambda h, w: blocked_cross_entropy(h, w, y, dtype=jnp.bfloat16).sum(), (0, 1))(h, w)
+        return jax.grad(lambda h, w: blocked_cross_entropy(h, w, y, wt, dtype=jnp.bfloat16), (0, 1))(h, w)
 
     (dh, dw), (dh1, dw1) = grads(16), grads(256)
     assert dh.dtype == dw.dtype == jnp.float32
@@ -324,11 +392,51 @@ def test_blocked_cross_entropy_holds_no_full_logits(monkeypatch):
     n, v = 256, 257
     h, w = jnp.zeros((n, 48)), jnp.zeros((48, v))
     y = jnp.zeros((n,), jnp.int32)
-    text = jax.jit(jax.grad(lambda h, w: blocked_cross_entropy(h, w, y).sum(), (0, 1))).lower(
+    text = jax.jit(jax.grad(lambda h, w: blocked_cross_entropy(h, w, y), (0, 1))).lower(
         h, w
     ).compile().as_text()
     assert f"[{n},{v}]" not in text and f"[8,32,{v}]" not in text
     assert f"[32,{v}]" in text
+
+
+@pytest.mark.parametrize("differentiated", [True, False])
+def test_the_gradients_are_formed_in_the_one_loop_and_only_under_differentiation(monkeypatch, differentiated):
+    """From the compiled text. Differentiated: one ``while``, whose carry
+    holds the kernel's float32 gradient, and three products with the
+    vocabulary in them (logits, the hidden states' gradient, the kernel's):
+    the logits are not formed twice. Not differentiated: one loop, the logits
+    alone, no ``(D, V)`` float32 carry."""
+    monkeypatch.setattr(F, "CE_BLOCK", 32)
+    n, d, v = 256, 48, 257
+    h, w = jnp.zeros((n, d), jnp.bfloat16), jnp.zeros((d, v), jnp.bfloat16)
+    y, wt = jnp.zeros((n,), jnp.int32), jnp.ones((n,), jnp.float32)
+    fn = lambda h, w: blocked_cross_entropy(h, w, y, wt)  # noqa: E731
+    if differentiated:
+        fn = jax.value_and_grad(fn, (0, 1))
+    text = jax.jit(fn).lower(h, w).compile().as_text()
+    assert len(loops(text)) == 1, loops(text)
+    assert len(products_over(text, v)) == (3 if differentiated else 1), products_over(text, v)
+    assert (f"f32[{d},{v}]" in loops(text)[0]) == differentiated
+
+
+@highest
+def test_causal_lm_loss_is_the_plain_mean_over_the_models_logits(monkeypatch, weights, tokens):
+    """The tiny model's cross-entropy and gradients through the blocked head
+    (64 positions in blocks of 24, the last one ragged) against ``optax`` over
+    the logits of ``model.apply``: the mean over the T - 1 targets a row, the
+    last position of each row left out."""
+    monkeypatch.setattr(F, "CE_BLOCK", 24)
+    model, params = tiny(), lm_step.to_system(weights, 4)
+
+    def plain(params):
+        logits = model.apply(params, tokens)
+        return jnp.mean(optax.softmax_cross_entropy_with_integer_labels(logits[:, :-1], tokens[:, 1:]))
+
+    (got, aux), g_got = jax.value_and_grad(causal_lm_loss(model), has_aux=True)(params, tokens)
+    want, g_want = jax.value_and_grad(plain)(params)
+    assert got == aux["ce"] and rel(got, want) < F32
+    for (path, a), b in zip(jax.tree.leaves_with_path(g_got), jax.tree.leaves(g_want)):
+        assert rel(a, b) < 5 * F32, jax.tree_util.keystr(path)
 
 
 # -- today's defaults, and the published configuration ------------------------------------
