@@ -28,7 +28,13 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          parameters, 4 x 4,096 tokens) on the first chip through
          ``DataParallel.make_train_step``: five steps, loss lower at the
          end, ``moe.dropped`` still 0, and Mosaic calls named ``flash_fwd``
-         and ``flash_bwd_*`` in the step.
+         and ``flash_bwd_*`` in the step. Then one chip's share of
+         ``qwen3_next_80b_a3b`` (4 layers, 32 of 512 experts, 18,992 rows of
+         the vocabulary, 2 x 8,192 tokens, every block rematerialised): the
+         step holds the chunked delta rule (a loop carrying one sequence's
+         state) and flash kernels that read 2 key-value heads for 16 query
+         heads, the loss falls, ``moe.dropped`` stays 0 against the
+         assignments due on the held experts and ``moe.held_share`` is read.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
          each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
          bf16 matmul; cdist and rbf of 16384 x 128 and of a ragged pair
@@ -42,7 +48,11 @@ array    the reference's workloads at bench.py's sizes on split DNDarrays,
 kernels  each of the five Pallas kernels lowered at its production block
          sizes with ``interpret`` left to the library, the lowering checked
          for a Mosaic custom call (nothing resolved ``interpret=True``),
-         run, and compared with the XLA form it replaces.
+         run, and compared with the XLA form it replaces; the flash
+         kernels also with 16 query heads on 2 key-value heads of 256
+         (forward and both backward forms, against the XLA form on repeated
+         K and V), and the chunked delta rule (XLA's, no Mosaic call) against
+         the recurrence a position at a time.
 serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
          (bench.py's serving configuration), warmed up; 32 requests; answers
          equal ``km.predict``; nothing compiled after warm-up.
@@ -71,12 +81,16 @@ import numpy as np
 FULL = dict(
     lm=dict(vocab=32768, d_model=1024, heads=16, layers=12, batch=8, seq=1024),
     olmoe=dict(fields={}, batch=4, seq=4096),  # the published widths
+    # one chip's share of Qwen3-Next at the published widths: a period of four
+    # layers, 32 of 512 experts, an eighth of the vocabulary
+    qnext=dict(fields=dict(num_layers=4, experts_held=(0, 32), vocab_size=18992), batch=2, seq=8192),
     steps=5,
     moments_rows=8_000_000,
     matmul_n=8192,
     cdist_rows=16384, cdist_k=128, cdist_ragged=(1000, 2500, 18),
     kmeans_rows=2_000_000, kmeans_k=64, iters=5,
     attn_fwd=(4, 4096, 8, 128), attn_bwd=(8, 1024, 16, 64),
+    attn_gqa=(1, 2048, 16, 2, 256), rule=(1, 1024, 8, 128),
     kernel_rows=1 << 20, lloyd_rows=1 << 18, int8_n=2048,
     serve_rows=200_000, serve_k=16, requests=32, request_rows=16,
 )
@@ -89,12 +103,20 @@ TINY = dict(
                     num_experts=8, experts_per_token=2, max_len=64),
         batch=2, seq=64,
     ),
+    qnext=dict(
+        fields=dict(num_layers=4, experts_held=(4, 4), vocab_size=256, d_model=64, num_heads=4,
+                    num_kv_heads=2, head_dim=32, gdn_key_heads=2, gdn_value_heads=4,
+                    gdn_key_dim=16, gdn_value_dim=16, d_ff=32, num_experts=16,
+                    experts_per_token=3, shared_d_ff=32, max_len=256),
+        batch=2, seq=160,
+    ),
     steps=5,
     moments_rows=4096,
     matmul_n=256,
     cdist_rows=512, cdist_k=32, cdist_ragged=(520, 1030, 18),
     kmeans_rows=4096, kmeans_k=8, iters=3,
     attn_fwd=(1, 256, 2, 64), attn_bwd=(1, 256, 2, 64),
+    attn_gqa=(1, 256, 4, 2, 64), rule=(1, 160, 2, 16),
     kernel_rows=2048, lloyd_rows=2048, int8_n=256,
     serve_rows=2048, serve_k=4, requests=8, request_rows=4,
 )
@@ -224,6 +246,7 @@ def stage_train(ht, cfg, devices, on_tpu):
         later_steps_seconds=round(later_steps, 2),
         peak_bytes_in_use=peaks,
         olmoe=_olmoe_steps(cfg, devices, on_tpu),
+        qnext=_qnext_steps(cfg, devices, on_tpu),
     )
 
 
@@ -299,6 +322,86 @@ def _olmoe_steps(cfg, devices, on_tpu):
     return dict(
         params=n_params, losses=[round(v, 4) for v in losses],
         mosaic_kernels=kernels, load_max_over_mean=round(load, 3),
+    )
+
+
+def _qnext_steps(cfg, devices, on_tpu):
+    """AdamW steps of one chip's share of ``qwen3_next_80b_a3b`` on the first
+    chip, every block rematerialised: the chip took the chunked delta rule (a
+    loop whose carry is the state of one sequence's heads) and the flash
+    kernels with the key-value heads read by group (compiled Mosaic calls whose
+    K and V operands have fewer heads than Q), the loss falls, ``moe.dropped``
+    stays 0 against the assignments due on the held experts, and
+    ``moe.held_share`` is read."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+    from heat_tpu.core.communication import MeshCommunication
+    from heat_tpu.nn import DataParallel, causal_lm_loss, qwen3_next_80b_a3b, read_routing
+
+    c = cfg["qnext"]
+    comm = MeshCommunication(devices=devices[:1])
+    model = qwen3_next_80b_a3b(comm=comm, remat=True, **c["fields"])
+    opt = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
+    step = DataParallel(
+        model, comm=comm, optimizer=opt, blocking_parameter_updates=True
+    ).make_train_step(causal_lm_loss(model, load_balance_coef=0.001), has_aux=True)
+    key = tuple(sorted(c["fields"].items()))
+    replicated = comm.replicated()
+    params = program_cache.cached_program(
+        "smoke.qnext_init", key,
+        lambda: lambda k: {"params": model.clone(attn_impl="local", remat=False).init(
+            k, jnp.zeros((1, 8), jnp.int32))["params"]},
+        comm=comm, out_shardings=replicated,
+    )(jax.random.PRNGKey(0))
+    opt_state = program_cache.cached_program(
+        "smoke.qnext_opt_init", key, lambda: opt.init, comm=comm, out_shardings=replicated,
+    )(params)
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    toks = np.random.default_rng(0).integers(
+        0, model.vocab_size, (c["batch"], c["seq"]), dtype=np.int32
+    )
+    text = step.lower(params, opt_state, jnp.asarray(toks)).as_text()
+    kernels = sorted(set(re.findall(r'@tpu_custom_call\(.*kernel_name = "(\w+)"', text)))
+    state = f"tensor<1x{model.gdn_value_heads}x{model.gdn_key_dim}x{model.gdn_value_dim}xf32>"
+    _check(state in text, f"no loop over chunks carrying {state} in the step: the delta rule is not the chunked one")
+    if on_tpu:
+        _check(
+            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= set(kernels),
+            f"no Mosaic calls flash_fwd / flash_bwd_dq / flash_bwd_dkv in the step: {kernels}",
+        )
+        kv = f"tensor<{c['batch']}x{model.num_kv_heads}x{c['seq']}x{model.head_dim}xbf16>"
+        _check(
+            any(kv in line for line in text.splitlines() if "flash_fwd" in line),
+            f"flash_fwd does not read K and V at {kv}: the key-value heads were repeated",
+        )
+    counters = telemetry.get_registry().counters
+    before = {k: counters[k] for k in ("moe.dropped", "moe.assignments", "moe.held_share", "moe.steps")}
+    losses, held = [], 0
+    for _ in range(cfg["steps"]):
+        params, opt_state, loss, aux = step(params, opt_state, toks)
+        loss, aux = read_routing(loss, aux)
+        losses.append(float(loss))
+        held += int(aux["expert_counts"][:, model.experts_held[0]:sum(model.experts_held)].sum())
+    _check(all(np.isfinite(losses)), f"qnext loss not finite: {losses}")
+    _check(losses[-1] < losses[0], f"qnext loss did not fall: {losses}")
+    _check(
+        counters["moe.dropped"] == before["moe.dropped"]
+        and counters["moe.assignments"] - before["moe.assignments"] == held,
+        f"routing dropped {counters['moe.dropped'] - before['moe.dropped']} of the {held} assignments "
+        f"due on the held experts, counted {counters['moe.assignments'] - before['moe.assignments']}",
+    )
+    share = (counters["moe.held_share"] - before["moe.held_share"]) / (counters["moe.steps"] - before["moe.steps"])
+    routed = cfg["steps"] * model.num_layers * c["batch"] * c["seq"] * model.experts_per_token
+    _check(abs(share - held / routed) < 1e-9, f"moe.held_share reads {share}, the counts give {held / routed}")
+    del params, opt_state
+    gc.collect()
+    return dict(
+        params=n_params, losses=[round(v, 4) for v in losses], mosaic_kernels=kernels,
+        held_share=round(share, 5), even_share=model.experts_held[1] / model.num_experts,
     )
 
 
@@ -558,10 +661,11 @@ def stage_kernels(cfg, on_tpu):
     key = jax.random.PRNGKey(2)
     report, wrong = {}, []
 
-    def run(name, fn, ref_fn, args, bound):
+    def run(name, fn, ref_fn, args, bound, relative=False):
         """Lower ``fn``, require the Mosaic custom call, run it, and bound
-        its distance from the XLA form. Every kernel reports; the stage
-        fails at the end if any was wrong."""
+        its distance from the XLA form (``relative``: over the largest value
+        of each result). Every kernel reports; the stage fails at the end if
+        any was wrong."""
         prog = program_cache.cached_program(f"smoke.{name}", (), lambda: fn)
         lowered = prog.lower(*args)
         if on_tpu and "tpu_custom_call" not in lowered.as_text():
@@ -573,7 +677,7 @@ def stage_kernels(cfg, on_tpu):
             )(*args)
         )
         err = max(
-            _err(g, np.asarray(r, np.float64))
+            _err(g, np.asarray(r, np.float64)) / (float(np.max(np.abs(r))) if relative else 1.0)
             for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref))
         )
         if not (np.isfinite(err) and err <= bound):
@@ -608,6 +712,60 @@ def stage_kernels(cfg, on_tpu):
             attn_grads(lambda q, k, v: local_attention(q, k, v, causal=True)),
             qkv(cfg["attn_bwd"]), 1e-1,
         )
+
+    # 16 query heads on 2 key-value heads of 256, read by group in the kernels
+    # and repeated for the XLA form
+    b, t, h, hkv, d = cfg["attn_gqa"]
+    gq, gk, gv = (
+        jax.random.normal(k, (b, t, heads, d), jnp.bfloat16)
+        for k, heads in zip(jax.random.split(jax.random.fold_in(key, 7), 3), (h, hkv, hkv))
+    )
+    repeated = lambda q, k, v: local_attention(  # noqa: E731
+        q, jnp.repeat(k, h // hkv, axis=2), jnp.repeat(v, h // hkv, axis=2), causal=True)
+    run("flash_gqa_fwd", lambda q, k, v: flash_attention(q, k, v, causal=True), repeated, (gq, gk, gv), 3e-2)
+    for impl in ("two_pass", "fused"):
+        run(
+            f"flash_gqa_bwd_{impl}",
+            attn_grads(lambda q, k, v, impl=impl: flash_attention(q, k, v, causal=True, bwd_impl=impl)),
+            # dk and dv are sums over a group's 8 heads and 2,048 queries, delivered
+            # in bfloat16: 2^-8 of values of a few tens (0.25 absolute observed on the chip)
+            attn_grads(repeated), (gq, gk, gv), 2e-2, relative=True,
+        )
+
+    # the chunked delta rule (bfloat16 operands in its products) against the
+    # recurrence a position at a time in float32; heads that remember 8 to
+    # 1,024 positions. No Mosaic call: the rule is XLA's (a scan over chunks)
+    from heat_tpu.nn import gated_delta_rule
+
+    b, t, h, d = cfg["rule"]
+    ks = jax.random.split(jax.random.fold_in(key, 11), 4)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    rq = unit(jax.random.normal(ks[0], (b, t, h, d), jnp.float32)) * d**-0.5
+    rk = unit(jax.random.normal(ks[1], (b, t, h, d), jnp.float32))
+    rv = jax.random.normal(ks[2], (b, t, h, d), jnp.float32)
+    rbeta = jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h), jnp.float32))
+    rg = jnp.broadcast_to(-1.0 / (8.0 * 128.0 ** (jnp.arange(h) / max(h - 1, 1))), (b, t, h)).astype(jnp.float32)
+
+    def recurrence(q, k, v, g, beta):
+        def position(state, x):
+            q, k, v, g, beta = x
+            state = state * jnp.exp(g)[..., None, None]
+            seen = jnp.einsum("bhde,bhd->bhe", state, k, precision="highest")
+            state = state + jnp.einsum("bhd,bhe->bhde", k, (v - seen) * beta[..., None], precision="highest")
+            return state, jnp.einsum("bhde,bhd->bhe", state, q, precision="highest")
+
+        xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+        return jnp.moveaxis(jax.lax.scan(position, jnp.zeros((b, h, d, d), jnp.float32), xs)[1], 0, 1)
+
+    rule = program_cache.cached_program(
+        "smoke.delta_rule", (), lambda: lambda *a: gated_delta_rule(*a, dtype=jnp.bfloat16))
+    want = program_cache.cached_program("smoke.delta_rule_recurrence", (), lambda: recurrence)
+    args = (rq, rk, rv, rg, rbeta)
+    got, ref = np.asarray(rule(*args), np.float64), np.asarray(want(*args), np.float64)
+    err = float(np.sqrt(np.mean((got - ref) ** 2) / np.mean(ref**2)))
+    if not err <= 2e-2:  # bfloat16 operands: 5e-3 observed
+        wrong.append(f"delta_rule: rms {err} > 2e-2")
+    report["delta_rule"] = float(f"{err:.3g}")
 
     # separated blobs, one start in each: no row sits near a Voronoi face,
     # so the two programs assign alike and differ by f32 summation order

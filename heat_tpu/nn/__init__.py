@@ -17,7 +17,9 @@ from .transformer import (
     TransformerLM,
     causal_lm_loss,
     olmoe_1b_7b,
+    qwen3_next_80b_a3b,
 )
+from .deltanet import GatedDeltaNet, gated_delta_rule
 from .moe import DroplessMoE, MoEMLP, read_routing
 from .quant_dense import QuantDense
 
@@ -26,7 +28,9 @@ __all__ = [
     "DataParallelMultiGPU",
     "DroplessMoE",
     "FSDP",
+    "GatedDeltaNet",
     "functional",
+    "gated_delta_rule",
     "MoEMLP",
     "MultiHeadAttention",
     "Pipeline",
@@ -35,6 +39,7 @@ __all__ = [
     "TransformerLM",
     "causal_lm_loss",
     "olmoe_1b_7b",
+    "qwen3_next_80b_a3b",
     "read_routing",
 ]
 

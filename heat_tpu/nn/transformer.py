@@ -23,12 +23,21 @@ Parallelism is selected by ``attn_impl``:
   with two all_to_alls.
 
 The architecture is a set of fields, not a model file: ``norm``
-(``"layernorm"`` | ``"rmsnorm"``), ``positions`` (``"learned"`` |
-``"rope"``), ``qk_norm``, ``ffn`` (``"swiglu"`` | ``"moe"``, the dropless
-top-k expert layer of :mod:`heat_tpu.nn.moe`) and ``accum_dtype``. The
-defaults are the pre-LN, learned-position, SwiGLU model this module began
-with, parameter tree and numerics unchanged. :func:`olmoe_1b_7b` names the
-one published configuration; :func:`causal_lm_loss` is its training loss.
+(``"layernorm"`` | ``"rmsnorm"`` | ``"rmsnorm_zero"``, the zero-centred form
+``x rsqrt(mean x^2 + eps) (1 + w)``), ``positions`` (``"learned"`` |
+``"rope"``) with ``rotary_fraction`` (the leading share of a head that is
+rotated), ``qk_norm`` (over all of hidden, or ``"head"``: over each head),
+``num_kv_heads`` and ``head_dim``, ``attn_gate`` (a sigmoid gate on the
+attention output, projected beside the queries), ``mixers`` (the layer
+pattern: which mixer the blocks of one period take, ``"attention"`` or
+``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields),
+``ffn`` (``"swiglu"`` | ``"moe"``, the dropless top-k expert layer of
+:mod:`heat_tpu.nn.moe`, with ``norm_topk``, ``shared_d_ff`` and
+``experts_held``), ``init_std`` and ``accum_dtype``. The defaults are the
+pre-LN, learned-position, SwiGLU model this module began with, parameter tree
+and numerics unchanged. :func:`olmoe_1b_7b` and :func:`qwen3_next_80b_a3b`
+name the published configurations; :func:`causal_lm_loss` is their training
+loss.
 
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
 (tp: column/row-split the Dense kernels; dp: replicate) exactly as any flax
@@ -38,12 +47,14 @@ model; the dryrun (`__graft_entry__.py`) exercises a dp×sp layout.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+import math
+from typing import Any, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .deltanet import GatedDeltaNet
 from .functional import blocked_cross_entropy
 from .moe import DroplessMoE
 
@@ -56,18 +67,52 @@ def _dot_general(accum_dtype):
     return functools.partial(jax.lax.dot_general, preferred_element_type=accum_dtype)
 
 
+class ZeroCentredRMSNorm(nn.Module):
+    """``x rsqrt(mean x^2 + eps) (1 + w)`` over the last axis in float32, ``w``
+    starting at 0 (Qwen3-Next's norm); the result in ``dtype``."""
+
+    epsilon: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.epsilon)
+        return (y * (1.0 + w)).astype(self.dtype)
+
+
 def _norm(kind, eps, dtype, name, **kw):
     if kind == "layernorm":
         return nn.LayerNorm(dtype=dtype, name=name, **({} if eps is None else {"epsilon": eps}))
     if kind == "rmsnorm":
         return nn.RMSNorm(dtype=dtype, name=name, epsilon=1e-6 if eps is None else eps, **kw)
-    raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got {kind!r}")
+    if kind == "rmsnorm_zero" and not kw:
+        return ZeroCentredRMSNorm(1e-6 if eps is None else eps, dtype, name=name)
+    raise ValueError(f"norm must be 'layernorm', 'rmsnorm' or 'rmsnorm_zero', got {kind!r}")
 
 
-def rotary(x, theta):
+def _matrix_init(std):
+    """normal(0, std) where a model states its matrices' deviation; None
+    where it leaves them to each layer's own default."""
+    return None if std is None else nn.initializers.normal(std)
+
+
+def _given(init, key="kernel_init"):
+    """``{key: init}`` for a flax layer, nothing where ``init`` is None."""
+    return {} if init is None else {key: init}
+
+
+def rotary(x, theta, fraction: float = 1.0):
     """Rotary positions on ``(B, T, H, D)`` in float32, the rotate-half form:
     ``x cos + (-x2, x1) sin`` with angles ``t * theta^(-2i/D)`` repeated over
-    both halves."""
+    both halves. ``fraction`` < 1 rotates the first ``fraction * D`` of each
+    head and leaves the rest as it is."""
+    if fraction < 1.0:
+        part = int(x.shape[-1] * fraction)
+        return jnp.concatenate(
+            [rotary(x[..., :part], theta), x[..., part:].astype(jnp.float32)], axis=-1
+        )
     t, d = x.shape[1], x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -85,6 +130,9 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
         ulysses_attention,
     )
 
+    if impl != "flash" and k.shape[2] != q.shape[2]:
+        # only the flash kernels read a group's key-value head by index
+        k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2) for a in (k, v))
     if impl == "flash":
         # block_size None = the kernel's tuned tiles
         blocks = {} if block_size is None else {
@@ -138,39 +186,67 @@ class MultiHeadAttention(nn.Module):
     qk_norm_eps: Optional[float] = None
     rope_theta: Optional[float] = None  # rotary positions on q and k
     accum_dtype: Optional[Any] = None  # dtype of matmul results; None = dtype
+    num_kv_heads: Optional[int] = None  # None = num_heads; fewer: grouped-query attention
+    head_dim: Optional[int] = None  # None = d_model / num_heads
+    # "head": a norm over each head's features, its gain shared by the heads,
+    # in the form ``norm`` names; None: the norm over all of d_model (above)
+    qk_norm_over: Optional[str] = None
+    norm: str = "rmsnorm"
+    rotary_fraction: float = 1.0
+    gate: bool = False  # out = (attention * sigmoid(g)) W_o, g projected beside q
+    matrix_init: Any = None
+    out_init: Any = None
 
     @nn.compact
     def __call__(self, x):
         d_model = x.shape[-1]
-        if d_model % self.num_heads:
+        if self.head_dim is None and d_model % self.num_heads:
             raise ValueError(f"d_model {d_model} not divisible by {self.num_heads} heads")
-        d_head = d_model // self.num_heads
-        dense = lambda name: nn.DenseGeneral(  # noqa: E731
-            (self.num_heads, d_head), axis=-1, use_bias=False,
-            dtype=self.dtype, name=name, dot_general=_dot_general(self.accum_dtype),
+        d_head = d_model // self.num_heads if self.head_dim is None else self.head_dim
+        kv_heads = self.num_heads if self.num_kv_heads is None else self.num_kv_heads
+        if self.num_heads % kv_heads:
+            raise ValueError(f"{self.num_heads} heads do not divide over {kv_heads} key-value heads")
+        dense = lambda name, heads=self.num_heads, width=d_head: nn.DenseGeneral(  # noqa: E731
+            (heads, width), axis=-1, use_bias=False, dtype=self.dtype, name=name,
+            dot_general=_dot_general(self.accum_dtype), **_given(self.matrix_init),
         )
-        q, k, v = dense("query")(x), dense("key")(x), dense("value")(x)
+        if self.gate:
+            with jax.named_scope("attn.gate"):
+                q, g = jnp.split(dense("query", width=2 * d_head)(x), 2, axis=-1)
+        else:
+            q = dense("query")(x)
+        k, v = dense("key", kv_heads)(x), dense("value", kv_heads)(x)
         if self.qk_norm_eps is not None:
-            over_heads = dict(reduction_axes=(-2, -1), feature_axes=(-2, -1))
-            q = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "q_norm", **over_heads)(q)
-            k = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "k_norm", **over_heads)(k)
+            if self.qk_norm_over == "head":
+                q = _norm(self.norm, self.qk_norm_eps, jnp.float32, "q_norm")(q)
+                k = _norm(self.norm, self.qk_norm_eps, jnp.float32, "k_norm")(k)
+            else:
+                over_heads = dict(reduction_axes=(-2, -1), feature_axes=(-2, -1))
+                q = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "q_norm", **over_heads)(q)
+                k = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "k_norm", **over_heads)(k)
         if self.rope_theta is not None:
-            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+            q = rotary(q, self.rope_theta, self.rotary_fraction)
+            k = rotary(k, self.rope_theta, self.rotary_fraction)
         q, k, v = (a.astype(self.dtype) for a in (q, k, v))
         o = _attend(
             q, k, v, impl=self.attn_impl, causal=self.causal, comm=self.comm,
             flash_bwd_impl=self.flash_bwd_impl,
             block_size=self.block_size,
         )
+        if self.gate:
+            with jax.named_scope("attn.gate"):
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(g.astype(jnp.float32))).astype(self.dtype)
         return nn.DenseGeneral(
             d_model, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out",
             dot_general=_dot_general(self.accum_dtype),
+            **_given(self.matrix_init if self.out_init is None else self.out_init),
         )(o)
 
 
 class TransformerBlock(nn.Module):
-    """Pre-norm residual block: x + attn(norm(x)); x + ffn(norm(x)), the
-    feed-forward a SwiGLU MLP or the dropless expert layer."""
+    """Pre-norm residual block: x + mixer(norm(x)); x + ffn(norm(x)), the
+    mixer attention or the Gated DeltaNet, the feed-forward a SwiGLU MLP or
+    the dropless expert layer."""
 
     num_heads: int
     mlp_ratio: float = 4.0
@@ -182,41 +258,71 @@ class TransformerBlock(nn.Module):
     flash_bwd_impl: str = "two_pass"
     norm: str = "layernorm"
     norm_eps: Optional[float] = None  # None = flax's default (1e-6)
-    qk_norm: bool = False
+    qk_norm: Union[bool, str] = False  # True: over all of hidden; "head": over each head
     rope_theta: Optional[float] = None
     ffn: str = "swiglu"
     d_ff: Optional[int] = None  # None = d_model * mlp_ratio; one expert's width for "moe"
     num_experts: int = 0
     experts_per_token: int = 0
     accum_dtype: Optional[Any] = None
+    mixer: str = "attention"  # or "deltanet"
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rotary_fraction: float = 1.0
+    attn_gate: bool = False
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    norm_topk: bool = False
+    shared_d_ff: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    init_std: Optional[float] = None  # None: each layer's own default
+    out_init_std: Optional[float] = None  # the matrices that write into the residual stream
 
     @nn.compact
     def __call__(self, x):
         d_model = x.shape[-1]
         stream = self.dtype if self.accum_dtype is None else self.accum_dtype
         eps = 1e-6 if self.norm_eps is None else self.norm_eps
+        matrix = _matrix_init(self.init_std)
+        out = _matrix_init(self.init_std if self.out_init_std is None else self.out_init_std)
         h = _norm(self.norm, self.norm_eps, stream, "ln1")(x)
-        x = x + MultiHeadAttention(
-            self.num_heads, self.attn_impl, self.causal, self.comm,
-            self.block_size, self.dtype, self.flash_bwd_impl,
-            eps if self.qk_norm else None, self.rope_theta, self.accum_dtype,
-            name="attn",
-        )(h)
+        if self.mixer == "deltanet":
+            x = x + GatedDeltaNet(
+                self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim, self.gdn_value_dim,
+                self.gdn_conv, eps, self.dtype, self.accum_dtype, matrix_init=matrix, out_init=out, name="gdn",
+            )(h)
+        elif self.mixer == "attention":
+            x = x + MultiHeadAttention(
+                self.num_heads, self.attn_impl, self.causal, self.comm,
+                self.block_size, self.dtype, self.flash_bwd_impl,
+                eps if self.qk_norm else None, self.rope_theta, self.accum_dtype,
+                self.num_kv_heads, self.head_dim,
+                self.qk_norm if isinstance(self.qk_norm, str) else None,
+                self.norm, self.rotary_fraction, self.attn_gate, matrix, out,
+                name="attn",
+            )(h)
+        else:
+            raise ValueError(f"mixer must be 'attention' or 'deltanet', got {self.mixer!r}")
         h = _norm(self.norm, self.norm_eps, stream, "ln2")(x)
         d_ff = int(d_model * self.mlp_ratio) if self.d_ff is None else self.d_ff
         if self.ffn == "moe":
             return x + DroplessMoE(
                 self.num_experts, self.experts_per_token, d_ff,
-                dtype=self.dtype, accum_dtype=self.accum_dtype, name="moe",
+                dtype=self.dtype, accum_dtype=self.accum_dtype,
+                norm_topk=self.norm_topk, shared_d_ff=self.shared_d_ff,
+                experts_held=self.experts_held, matrix_init=matrix, out_init=out, name="moe",
             )(h)
         if self.ffn != "swiglu":
             raise ValueError(f"ffn must be 'swiglu' or 'moe', got {self.ffn!r}")
-        dense = lambda width, name: nn.Dense(  # noqa: E731
+        dense = lambda width, name, init: nn.Dense(  # noqa: E731
             width, use_bias=False, dtype=self.dtype, name=name,
-            dot_general=_dot_general(self.accum_dtype),
+            dot_general=_dot_general(self.accum_dtype), **_given(init),
         )
-        h = nn.silu(dense(d_ff, "gate")(h)) * dense(d_ff, "up")(h)  # SwiGLU
-        return x + dense(d_model, "down")(h)
+        h = nn.silu(dense(d_ff, "gate", matrix)(h)) * dense(d_ff, "up", matrix)(h)  # SwiGLU
+        return x + dense(d_model, "down", out)(h)
 
 
 class TransformerLM(nn.Module):
@@ -251,6 +357,25 @@ class TransformerLM(nn.Module):
     # results of matrix products, the residual stream and the norms keep
     # this dtype while the products take ``dtype`` operands; None = dtype
     accum_dtype: Optional[Any] = None
+    # one period of the layer pattern: block i takes mixers[i % len(mixers)]
+    mixers: Tuple[str, ...] = ("attention",)
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rotary_fraction: float = 1.0
+    attn_gate: bool = False
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    norm_topk: bool = False
+    shared_d_ff: int = 0
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count): this chip's share of every expert layer
+    init_std: Optional[float] = None
+    out_init_std: Optional[float] = None
+
+    def mixer_of(self, i: int) -> str:
+        return self.mixers[i % len(self.mixers)]
 
     @nn.compact
     def __call__(self, tokens, head: bool = True):
@@ -266,7 +391,10 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"sequence length {tokens.shape[-1]} exceeds max_len {self.max_len}"
             )
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=stream, name="embed")(tokens)
+        x = nn.Embed(
+            self.vocab_size, self.d_model, dtype=stream, name="embed",
+            **_given(_matrix_init(self.init_std), "embedding_init"),
+        )(tokens)
         if self.positions == "learned":
             pos = nn.Embed(self.max_len, self.d_model, dtype=stream, name="pos")(
                 jnp.arange(tokens.shape[-1])
@@ -291,12 +419,16 @@ class TransformerLM(nn.Module):
                 self.flash_bwd_impl, self.norm, self.norm_eps, self.qk_norm,
                 self.rope_theta if self.positions == "rope" else None,
                 self.ffn, self.d_ff, self.num_experts, self.experts_per_token,
-                self.accum_dtype, name=f"block{i}",
+                self.accum_dtype, self.mixer_of(i), self.num_kv_heads, self.head_dim,
+                self.rotary_fraction, self.attn_gate, self.gdn_key_heads, self.gdn_value_heads,
+                self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv, self.norm_topk,
+                self.shared_d_ff, self.experts_held, self.init_std, self.out_init_std,
+                name=f"block{i}",
             )(x)
         x = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
         lm_head = nn.Dense(
             self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
-            dot_general=_dot_general(self.accum_dtype),
+            dot_general=_dot_general(self.accum_dtype), **_given(_matrix_init(self.init_std)),
         )
         if head:
             return lm_head(x)
@@ -325,6 +457,43 @@ def olmoe_1b_7b(num_layers: int = 16, **fields) -> TransformerLM:
     return TransformerLM(**{**arch, **fields})
 
 
+def qwen3_next_80b_a3b(
+    num_layers: int = 48, experts_held: Optional[Tuple[int, int]] = None, vocab_size: int = 151936, **fields
+) -> TransformerLM:
+    """Qwen3-Next-80B-A3B (Qwen, 2025-09; ``config.json`` of
+    ``Qwen3-Next-80B-A3B-Instruct``, equations of HF ``modeling_qwen3_next.py``)
+    at its published widths: hidden 2048; blocks in periods of three Gated
+    DeltaNet mixers (16 key and 32 value heads of 128, a causal depthwise
+    convolution of 4 taps) and one gated attention (16 query heads on 2
+    key-value heads of 256, a zero-centred RMSNorm on each query and key head,
+    rotary positions on the first quarter of a head at theta 1e7, a sigmoid
+    gate on the output); every block followed by 512 experts of width 512,
+    the top 10 with their weights normalised, and a shared expert of width 512
+    behind a sigmoid gate; zero-centred RMSNorm (eps 1e-6); untied 151,936-row
+    embedding and head. The release's multi-token-prediction module is not
+    part of ``config.json`` and is left out, as HF's model leaves it out.
+    bfloat16 matmul operands, float32 everything else; matrices drawn at 0.02,
+    those that write into the residual stream at ``0.02 / sqrt(2 * 48)``.
+
+    ``num_layers``, ``experts_held`` (``(first, count)``: the experts of every
+    layer that this chip holds, :class:`heat_tpu.nn.DroplessMoE`) and
+    ``vocab_size`` (a slice of the vocabulary is a smaller vocabulary) are what
+    one chip's share of a deployment sets; ``fields`` passes what is not
+    architecture (``attn_impl``, ``comm``, ``remat``, ...)."""
+    arch = dict(
+        vocab_size=vocab_size, d_model=2048, num_heads=16, num_layers=num_layers,
+        max_len=262144, norm="rmsnorm_zero", norm_eps=1e-6, positions="rope",
+        rope_theta=1e7, rotary_fraction=0.25, qk_norm="head", num_kv_heads=2, head_dim=256,
+        attn_gate=True, mixers=("deltanet", "deltanet", "deltanet", "attention"),
+        gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128, gdn_conv=4,
+        ffn="moe", d_ff=512, num_experts=512, experts_per_token=10, norm_topk=True,
+        shared_d_ff=512, experts_held=experts_held, init_std=0.02,
+        out_init_std=0.02 / math.sqrt(2 * 48),
+        dtype=jnp.bfloat16, accum_dtype=jnp.float32, attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
 def causal_lm_loss(
     model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0,
 ):
@@ -341,8 +510,10 @@ def causal_lm_loss(
     (layers x experts), ``assignments_due`` (layers x tokens x top-k) and
     ``assignments_computed`` (those the grouped products computed with the
     chosen expert, :func:`heat_tpu.nn.moe.rows_computed`: a dropless routing
-    gives ``assignments_due``); a model without expert layers gives the three
-    scalars only. ``nn.read_routing(loss, aux)`` brings both to the host and
+    gives ``assignments_due``); a model that holds a share of its experts
+    (``experts_held``) is due the assignments on those, and gives the layers x
+    tokens x top-k as ``assignments_routed``; a model without expert layers
+    gives the three scalars only. ``nn.read_routing(loss, aux)`` brings both to the host and
     counts the routing."""
 
     def loss_fn(params, tokens):
@@ -367,6 +538,9 @@ def causal_lm_loss(
             aux["router_z"] = jnp.mean(jnp.stack([a["router_z"] for a in layers]))
             aux["expert_counts"] = jnp.stack([a["expert_counts"] for a in layers])
             aux["assignments_due"] = len(layers) * b * t * model.experts_per_token
+            if model.experts_held is not None:  # a share: due are those on the held experts
+                aux["assignments_routed"] = aux["assignments_due"]
+                aux["assignments_due"] = sum(a["held"] for a in layers)
             aux["assignments_computed"] = sum(a["computed"] for a in layers)
         loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
         return loss, aux

@@ -141,6 +141,12 @@ def _flash_kernel(
             lse_ref[0, 0] = lse
 
 
+def _kv_head(hi, group):
+    """The key-value head that serves query head ``hi``: every ``group``
+    consecutive query heads read one (``group`` 1: the head itself)."""
+    return hi if group == 1 else jax.lax.div(hi, np.int32(group))
+
+
 def _out_struct(shape, like, dtype=None):
     """ShapeDtypeStruct matching ``like``'s dtype (or an explicit one) —
     inside a shard_map the output must also declare how it varies over mesh
@@ -195,6 +201,7 @@ def _flash_forward(
 ):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
+    group = h // k.shape[1]
     # zero-pad K/V tails are masked out via kv_valid, Q tail rows sliced off
     q, k, v, block_q, block_k, pq, pk, dp = _pad_blocks(
         q, k, v, t_q, t_k, d, block_q, block_k
@@ -243,11 +250,11 @@ def _flash_forward(
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, hi, ki, _I0),
+                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, hi, ki, _I0),
+                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
                 memory_space=pltpu.VMEM,
             ),
         ],
@@ -365,16 +372,21 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, scale, causal, kv_valid, block_q, block_k,
+    *, scale, causal, kv_valid, block_q, block_k, q_blocks, group,
 ):
-    """dK/dV pass. Grid = (B, H, num_k_blocks, num_q_blocks), last
+    """dK/dV pass. Grid = (B, H_kv, num_k_blocks, group * num_q_blocks), last
     sequential: the transposed-probability form — ``dV += Pᵀ dO`` and
-    ``dK += scale · dSᵀ Q`` accumulate per K block across the Q axis."""
+    ``dK += scale · dSᵀ Q`` accumulate per K block across the Q axis, and
+    across the ``group`` query heads that read this key-value head (their Q
+    blocks follow one another on the sequential axis; ``q_blocks`` is how
+    many one head has)."""
     ik = pl.program_id(2)
-    iq = pl.program_id(3)
+    step = pl.program_id(3)
     nq = pl.num_programs(3)
+    # the q block inside its head; with one head a group the step itself
+    iq = step if group == 1 else jax.lax.rem(step, np.int32(q_blocks))
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -405,7 +417,7 @@ def _bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -520,12 +532,21 @@ def _flash_bwd_fused(
     )
 
     tq_p = t_q + pq
+    h_kv = k.shape[1]
+    group = h // h_kv
     grid = (b, h, (t_k + pk) // block_k, tq_p // block_q)
     qo_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, ki, qi: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
     )
+    # K and V are read by the group's head; dk and dv are written a query
+    # head each (the resident dQ block pins a head to its grid row) and
+    # summed over the group after the kernel
     kv_spec = pl.BlockSpec(
+        (1, 1, block_k, dp), lambda bi, hi, ki, qi: (bi, _kv_head(hi, group), ki, _I0),
+        memory_space=pltpu.VMEM,
+    )
+    dkv_spec = pl.BlockSpec(
         (1, 1, block_k, dp), lambda bi, hi, ki, qi: (bi, hi, ki, _I0),
         memory_space=pltpu.VMEM,
     )
@@ -544,12 +565,12 @@ def _flash_bwd_fused(
         ),
         grid=grid,
         in_specs=[qo_spec, kv_spec, kv_spec, qo_spec, lm_spec, lm_spec],
-        out_specs=[dq_spec, kv_spec, kv_spec],
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
         out_shape=[
             # f32: the output block IS the cross-ki accumulator
             _out_struct((b, h, tq_p, dp), q, dtype=jnp.float32),
-            _out_struct((b, h, t_k + pk, dp), k),
-            _out_struct((b, h, t_k + pk, dp), v),
+            _out_struct((b, h, t_k + pk, dp), k, dtype=k.dtype if group == 1 else jnp.float32),
+            _out_struct((b, h, t_k + pk, dp), v, dtype=v.dtype if group == 1 else jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dp), jnp.float32),
@@ -563,6 +584,8 @@ def _flash_bwd_fused(
         interpret=interpret,
         name="flash_bwd_fused",
     )(qp, kp, vp, do_p, lse, dd_p)
+    if group > 1:
+        dk, dv = (a.reshape(b, h_kv, group, t_k + pk, dp).sum(axis=2) for a in (dk, dv))
 
     return (
         dq[:, :, :t_q, :d].astype(q.dtype),
@@ -626,13 +649,15 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
         res, g, block_q, block_k
     )
 
+    h_kv = k.shape[1]
+    group = h // h_kv
     grid_q = (b, h, (t_q + pq) // block_q, (t_k + pk) // block_k)
     qo_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
     )
     kv_spec_q = pl.BlockSpec(
-        (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, hi, ki, _I0),
+        (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
         memory_space=pltpu.VMEM,
     )
     lm_spec_q = pl.BlockSpec(
@@ -656,24 +681,26 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
         name="flash_bwd_dq",
     )(qp, kp, vp, do_p, lse, dd_p)
 
-    # dk/dv pass: K blocks on the parallel axis, Q sequential
-    grid_k = (b, h, (t_k + pk) // block_k, (t_q + pq) // block_q)
-    qo_spec_k = pl.BlockSpec(
-        (1, 1, block_q, dp), lambda bi, hi, ki, qi: (bi, hi, qi, _I0),
-        memory_space=pltpu.VMEM,
-    )
+    # dk/dv pass: K blocks on the parallel axis; the Q blocks of every query
+    # head of the group sequential, so that dk and dv come out summed over them
+    q_blocks = (t_q + pq) // block_q
+    grid_k = (b, h_kv, (t_k + pk) // block_k, group * q_blocks)
+    if group == 1:
+        of_q = lambda bi, hi, ki, qi: (bi, hi, qi, _I0)  # noqa: E731
+    else:
+        def of_q(bi, hi, ki, step):
+            nq = np.int32(q_blocks)
+            return (bi, hi * np.int32(group) + jax.lax.div(step, nq), jax.lax.rem(step, nq), _I0)
+    qo_spec_k = pl.BlockSpec((1, 1, block_q, dp), of_q, memory_space=pltpu.VMEM)
     kv_spec_k = pl.BlockSpec(
         (1, 1, block_k, dp), lambda bi, hi, ki, qi: (bi, hi, ki, _I0),
         memory_space=pltpu.VMEM,
     )
-    lm_spec_k = pl.BlockSpec(
-        (1, 1, block_q, _LANES), lambda bi, hi, ki, qi: (bi, hi, qi, _I0),
-        memory_space=pltpu.VMEM,
-    )
+    lm_spec_k = pl.BlockSpec((1, 1, block_q, _LANES), of_q, memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, q_blocks=q_blocks, group=group,
         ),
         grid=grid_k,
         in_specs=[
@@ -681,8 +708,8 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
         ],
         out_specs=[kv_spec_k, kv_spec_k],
         out_shape=[
-            _out_struct((b, h, t_k + pk, dp), k),
-            _out_struct((b, h, t_k + pk, dp), v),
+            _out_struct((b, h_kv, t_k + pk, dp), k),
+            _out_struct((b, h_kv, t_k + pk, dp), v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dp), jnp.float32),
@@ -722,7 +749,10 @@ def flash_attention(
 
     Same contract as :func:`heat_tpu.parallel.attention.local_attention`:
     ``(B, T, H, D)`` layout, f32 online softmax, K/V positions >= ``kv_valid``
-    masked as padding. Blocks are clamped for short sequences.
+    masked as padding. Blocks are clamped for short sequences. ``k`` and ``v``
+    may have fewer heads than ``q`` (grouped-query attention): query heads
+    ``i * g .. i * g + g - 1`` read key-value head ``i``, by index and without
+    a repeated copy, and ``dk``, ``dv`` come back summed over each group.
     ``interpret`` defaults to the Pallas interpreter when the default
     backend is not a TPU, so the same tests run on the CPU mesh;
     ``chip_smoke.py`` asserts the chip took the compiled side.
@@ -736,6 +766,10 @@ def flash_attention(
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
+    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads do not divide over {k.shape[2]} key and {v.shape[2]} value heads"
+        )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     d = q.shape[-1]
