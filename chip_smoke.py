@@ -32,7 +32,8 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          ``qwen3_next_80b_a3b`` (4 layers, 32 of 512 experts, 18,992 rows of
          the vocabulary, 2 x 8,192 tokens, every block rematerialised): the
          step holds the chunked delta rule (a loop carrying one sequence's
-         state) and flash kernels that read 2 key-value heads for 16 query
+         state, its body the Mosaic calls ``delta_chunk_fwd`` / ``_bwd``) and
+         flash kernels that read 2 key-value heads for 16 query
          heads, the loss falls, ``moe.dropped`` stays 0 against the
          assignments due on the held experts and ``moe.held_share`` is read.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
@@ -51,8 +52,12 @@ kernels  each of the five Pallas kernels lowered at its production block
          run, and compared with the XLA form it replaces; the flash
          kernels also with 16 query heads on 2 key-value heads of 256
          (forward and both backward forms, against the XLA form on repeated
-         K and V), and the chunked delta rule (XLA's, no Mosaic call) against
-         the recurrence a position at a time.
+         K and V), and the gated delta rule at the Qwen3-Next cell's 16 key
+         and 32 value heads of 128: the form whose chunk step is the Pallas
+         kernel against the recurrence a position at a time, and its five
+         gradients against the XLA form's (``delta_rule``, ``delta_rule_bwd``;
+         the counters ``gdn.rule.kernel`` / ``gdn.rule.xla`` say which form
+         each trace took).
 serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
          (bench.py's serving configuration), warmed up; 32 requests; answers
          equal ``km.predict``; nothing compiled after warm-up.
@@ -90,7 +95,7 @@ FULL = dict(
     cdist_rows=16384, cdist_k=128, cdist_ragged=(1000, 2500, 18),
     kmeans_rows=2_000_000, kmeans_k=64, iters=5,
     attn_fwd=(4, 4096, 8, 128), attn_bwd=(8, 1024, 16, 64),
-    attn_gqa=(1, 2048, 16, 2, 256), rule=(1, 1024, 8, 128),
+    attn_gqa=(1, 2048, 16, 2, 256), rule=(1, 1024, 16, 32, 128),
     kernel_rows=1 << 20, lloyd_rows=1 << 18, int8_n=2048,
     serve_rows=200_000, serve_k=16, requests=32, request_rows=16,
 )
@@ -116,7 +121,7 @@ TINY = dict(
     cdist_rows=512, cdist_k=32, cdist_ragged=(520, 1030, 18),
     kmeans_rows=4096, kmeans_k=8, iters=3,
     attn_fwd=(1, 256, 2, 64), attn_bwd=(1, 256, 2, 64),
-    attn_gqa=(1, 256, 4, 2, 64), rule=(1, 160, 2, 16),
+    attn_gqa=(1, 256, 4, 2, 64), rule=(1, 160, 1, 2, 16),
     kernel_rows=2048, lloyd_rows=2048, int8_n=256,
     serve_rows=2048, serve_k=4, requests=8, request_rows=4,
 )
@@ -328,7 +333,8 @@ def _olmoe_steps(cfg, devices, on_tpu):
 def _qnext_steps(cfg, devices, on_tpu):
     """AdamW steps of one chip's share of ``qwen3_next_80b_a3b`` on the first
     chip, every block rematerialised: the chip took the chunked delta rule (a
-    loop whose carry is the state of one sequence's heads) and the flash
+    loop whose carry is the state of one sequence's heads, the chunk step a
+    Mosaic call forward and backward) and the flash
     kernels with the key-value heads read by group (compiled Mosaic calls whose
     K and V operands have fewer heads than Q), the loss falls, ``moe.dropped``
     stays 0 against the assignments due on the held experts, and
@@ -370,8 +376,9 @@ def _qnext_steps(cfg, devices, on_tpu):
     _check(state in text, f"no loop over chunks carrying {state} in the step: the delta rule is not the chunked one")
     if on_tpu:
         _check(
-            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= set(kernels),
-            f"no Mosaic calls flash_fwd / flash_bwd_dq / flash_bwd_dkv in the step: {kernels}",
+            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "delta_chunk_fwd", "delta_chunk_bwd"} <= set(kernels),
+            f"no Mosaic calls flash_fwd / flash_bwd_dq / flash_bwd_dkv / delta_chunk_fwd / delta_chunk_bwd "
+            f"in the step: {kernels}",
         )
         kv = f"tensor<{c['batch']}x{model.num_kv_heads}x{c['seq']}x{model.head_dim}xbf16>"
         _check(
@@ -732,19 +739,23 @@ def stage_kernels(cfg, on_tpu):
             attn_grads(repeated), (gq, gk, gv), 2e-2, relative=True,
         )
 
-    # the chunked delta rule (bfloat16 operands in its products) against the
-    # recurrence a position at a time in float32; heads that remember 8 to
-    # 1,024 positions. No Mosaic call: the rule is XLA's (a scan over chunks)
-    from heat_tpu.nn import gated_delta_rule
+    # the gated delta rule at the Qwen3-Next cell's heads and sizes (bfloat16
+    # operands in its products; heads that remember 8 to 1,024 positions): the
+    # form gated_delta_rule takes here (on a TPU the chunk step is the Pallas
+    # kernel, a Mosaic call in the scan's body) against the recurrence a position
+    # at a time in float32, and its gradients against the XLA form's
+    from heat_tpu import telemetry
+    from heat_tpu.nn import deltanet, gated_delta_rule
 
-    b, t, h, d = cfg["rule"]
-    ks = jax.random.split(jax.random.fold_in(key, 11), 4)
+    b, t, hk, h, d = cfg["rule"]
+    ks = jax.random.split(jax.random.fold_in(key, 11), 5)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
-    rq = unit(jax.random.normal(ks[0], (b, t, h, d), jnp.float32)) * d**-0.5
-    rk = unit(jax.random.normal(ks[1], (b, t, h, d), jnp.float32))
+    rq = unit(jax.random.normal(ks[0], (b, t, hk, d), jnp.float32)) * d**-0.5
+    rk = unit(jax.random.normal(ks[1], (b, t, hk, d), jnp.float32))
     rv = jax.random.normal(ks[2], (b, t, h, d), jnp.float32)
     rbeta = jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, h), jnp.float32))
     rg = jnp.broadcast_to(-1.0 / (8.0 * 128.0 ** (jnp.arange(h) / max(h - 1, 1))), (b, t, h)).astype(jnp.float32)
+    weights = jax.random.normal(ks[4], (b, t, h, d), jnp.float32)  # of the outputs in the loss the gradients are of
 
     def recurrence(q, k, v, g, beta):
         def position(state, x):
@@ -754,18 +765,47 @@ def stage_kernels(cfg, on_tpu):
             state = state + jnp.einsum("bhd,bhe->bhde", k, (v - seen) * beta[..., None], precision="highest")
             return state, jnp.einsum("bhde,bhd->bhe", state, q, precision="highest")
 
+        q, k = (jnp.repeat(a, h // hk, axis=2) for a in (q, k))
         xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
         return jnp.moveaxis(jax.lax.scan(position, jnp.zeros((b, h, d, d), jnp.float32), xs)[1], 0, 1)
 
-    rule = program_cache.cached_program(
-        "smoke.delta_rule", (), lambda: lambda *a: gated_delta_rule(*a, dtype=jnp.bfloat16))
-    want = program_cache.cached_program("smoke.delta_rule_recurrence", (), lambda: recurrence)
+    def xla_rule(*a):
+        step = lambda *x: deltanet._xla_chunk_step(*x, dtype=jnp.bfloat16)  # noqa: E731
+        return deltanet._chunked_rule(step, *a, deltanet.CHUNK)
+
+    gradients = lambda rule: jax.grad(lambda *a: jnp.sum(rule(*a) * weights), argnums=range(5))  # noqa: E731
+    rms = lambda got, ref: float(  # noqa: E731
+        np.sqrt(np.mean((np.asarray(got, np.float64) - np.asarray(ref, np.float64)) ** 2) / np.mean(np.asarray(ref, np.float64) ** 2))
+    )
+    counters = telemetry.get_registry().counters
+    before = {name: counters.get(name, 0) for name in ("gdn.rule.kernel", "gdn.rule.xla")}
+    rule = lambda *a: gated_delta_rule(*a, dtype=jnp.bfloat16)  # noqa: E731
     args = (rq, rk, rv, rg, rbeta)
-    got, ref = np.asarray(rule(*args), np.float64), np.asarray(want(*args), np.float64)
-    err = float(np.sqrt(np.mean((got - ref) ** 2) / np.mean(ref**2)))
+    programs = {
+        name: program_cache.cached_program(f"smoke.{name}", (), lambda fn=fn: fn).lower(*args)
+        for name, fn in (("delta_rule", rule), ("delta_rule_bwd", gradients(rule)))
+    }
+    took = {name: counters.get(name, 0) - n for name, n in before.items()}
+    report.update(took)
+    form = "gdn.rule.kernel" if on_tpu and d % 128 == 0 else "gdn.rule.xla"
+    if took[form] != 2 or sum(took.values()) != 2:
+        wrong.append(f"delta_rule: two traces of the rule counted {took}, not 2 of {form}")
+    for name, lowered in programs.items():
+        if on_tpu and "tpu_custom_call" not in lowered.as_text():
+            wrong.append(f"{name}: no Mosaic custom call in the lowering")
+    want = program_cache.cached_program("smoke.delta_rule_recurrence", (), lambda: recurrence)
+    err = rms(programs["delta_rule"].compile()(*args), want(*args))
     if not err <= 2e-2:  # bfloat16 operands: 5e-3 observed
         wrong.append(f"delta_rule: rms {err} > 2e-2")
     report["delta_rule"] = float(f"{err:.3g}")
+    want = program_cache.cached_program("smoke.delta_rule_bwd_xla", (), lambda: gradients(xla_rule))(*args)
+    errs = [rms(g, w) for g, w in zip(programs["delta_rule_bwd"].compile()(*args), want)]
+    # the two forms round the same bfloat16 operands: what is left is the order of
+    # float32 sums and a rounding turned here and there (6.2e-4 observed on the chip
+    # at these memories of up to 1,024 positions, 5e-5 at memories of a few)
+    if not max(errs) <= 3e-3:
+        wrong.append(f"delta_rule_bwd: rms of (q, k, v, g, beta) {errs} > 3e-3")
+    report["delta_rule_bwd"] = float(f"{max(errs):.3g}")
 
     # separated blobs, one start in each: no row sits near a Voronoi face,
     # so the two programs assign alike and differ by f32 summation order

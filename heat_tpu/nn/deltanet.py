@@ -9,12 +9,28 @@ at zero, a decay ``alpha_t`` in (0, 1) and a write strength ``beta_t``::
 
 :func:`gated_delta_rule` computes it in chunks of positions (HF's
 ``torch_chunk_gated_delta_rule``): inside a chunk the writes depend on each
-other through a unit lower-triangular system, solved for all chunks at once;
-between chunks one ``lax.scan`` carries ``S``. What the scan does is two small
-products a chunk; everything else (the chunk's own scores, the solve, the
-read of the state by the queries) is batched over the chunks outside it. The
-backward pass is the autodiff of that form: the scan keeps the state once a
-chunk (``T / chunk`` states, not ``T``) and the solve keeps its result alone.
+other through a unit lower-triangular system; between chunks one ``lax.scan``
+carries ``S``. All of a chunk's work is the body of that scan, one *chunk
+step* ``(S, q_i, k_i, v_i, decays_i, beta_i) -> (S', o_i)``
+(:func:`heat_tpu.nn.pallas_delta.chunk_step`): the chunk's decays, scores and
+solve, what the state takes off the writes, the new state and the output. On a
+TPU, at head sizes of whole lanes, the step is one Pallas kernel
+(``delta_chunk_fwd``) that keeps everything of size chunk x chunk and chunk x
+head in VMEM and reads a key head once for the value heads it serves;
+elsewhere it is the same function as XLA's program. Outside the scan stay the
+running sum of the log-decays and the split into chunks, which copies nothing
+for one sequence. The backward pass is the scan's transpose, autodiff's, around
+the step's own: a second kernel (``delta_chunk_bwd``) that takes ``(dS', do_i)``
+and the step's inputs and forms the chunk's quantities again, so the forward
+scan keeps its inputs and the state once a chunk (``T / chunk`` states, not
+``T``) and no array of chunks x heads x chunk x chunk exists in either pass.
+
+Why the scan stayed, with ``S`` through HBM once a chunk: the state a chunk
+is what the backward pass needs anyway, and a loop's device event covers its
+body, so the benchmark's reader goes on finding the rule by the loops that
+carry ``S``. On a v5e a step is 21 us forward and 37 us backward at 32 heads of
+128, nine tenths of it the kernel, which is bound by the solve's float32
+products and not by what it moves.
 
 Float32 whatever ``dtype`` says: the decay and its running sum, ``beta``,
 the l2 norms, the state, the triangular solve, the gated norm. The
@@ -25,12 +41,14 @@ float32.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from .. import telemetry
+from .pallas_delta import chunk_step, kernel_chunk_step, takes_kernel
 
 CHUNK = 64
 
@@ -40,87 +58,60 @@ def l2_normalise(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-_HIGHEST = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
-
-
-@jax.custom_vjp
-def _unit_lower_inverse(a):
-    """``(I + a)^-1`` for strictly lower-triangular ``a (..., C, C)``,
-    float32: ``a`` is nilpotent, so the inverse is the finite sum of
-    ``(-a)^j``, taken as the product of ``I + (-a)^(2^i)``: two products a
-    doubling and no loop over rows. Its transpose needs the inverse alone
-    (``-x^T g x^T``), so none of the powers is kept."""
-    c = a.shape[-1]
-    power = -a
-    inv = jnp.eye(c, dtype=a.dtype) + power
-    for _ in range(max(math.ceil(math.log2(c)) - 1, 0)):
-        power = _HIGHEST(power, power)
-        inv = inv + _HIGHEST(inv, power)
-    return inv
-
-
-def _unit_lower_inverse_fwd(a):
-    inv = _unit_lower_inverse(a)
-    return inv, inv
-
-
-def _unit_lower_inverse_bwd(inv, g):
-    inv_t = jnp.swapaxes(inv, -1, -2)
-    return (-_HIGHEST(_HIGHEST(inv_t, g), inv_t),)
-
-
-_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+def _xla_chunk_step(state, q, k, v, run, beta, dtype):
+    """The chunk step in the kernel's layout (``pallas_delta.kernel_chunk_step``)
+    as XLA's own program: :func:`pallas_delta.chunk_step` mapped over the
+    sequences and the heads."""
+    b, h, dk, dv = state.shape
+    c = run.shape[-1]
+    q, k = (jnp.repeat(a.reshape(b, c, -1, dk), h * dk // a.shape[-1], axis=2) for a in (q, k))
+    over_heads = jax.vmap(functools.partial(chunk_step, dtype=dtype), in_axes=(0, 1, 1, 1, 0, 0), out_axes=(0, 1))
+    after, o = jax.vmap(over_heads)(state, q, k, v.reshape(b, c, h, dv), run[:, :, None], beta[:, :, None])
+    return after, o.reshape(b, c, h * dv)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, dtype: Any = jnp.float32):
-    """The gated delta rule over ``q, k (B, T, H, Dk)``, ``v (B, T, H, Dv)``,
+    """The gated delta rule over ``q, k (B, T, Hk, Dk)``, ``v (B, T, H, Dv)``,
     ``g`` = ``log(alpha)`` and ``beta`` ``(B, T, H)``; returns ``o (B, T, H,
-    Dv)`` in float32. ``q`` and ``k`` arrive normalised and scaled. A length
-    that is no multiple of ``chunk`` is padded with positions that neither
-    decay nor write (``g = 0``, ``beta = 0``)."""
+    Dv)`` in float32. ``q`` and ``k`` arrive normalised and scaled; key head
+    ``i`` serves value heads ``i r .. i r + r - 1`` (``r = H / Hk``), with no
+    repeated copy where the kernel runs. A length that is no multiple of
+    ``chunk`` is padded with positions that neither decay nor write (``g = 0``,
+    ``beta = 0``). The chunk step is the Pallas kernel where the shapes and the
+    backend let it (``pallas_delta.takes_kernel``) and XLA's program otherwise;
+    the counters ``gdn.rule.kernel`` and ``gdn.rule.xla`` say which a trace took."""
+    if takes_kernel(q.shape, v.shape, chunk):
+        telemetry.get_registry().add("gdn.rule.kernel")
+        step = functools.partial(kernel_chunk_step, dtype=dtype, interpret=False)
+    else:
+        telemetry.get_registry().add("gdn.rule.xla")
+        # as the kernel's backward does, the XLA form keeps a step's inputs alone
+        step = jax.checkpoint(functools.partial(_xla_chunk_step, dtype=dtype))
+    return _chunked_rule(step, q, k, v, g, beta, chunk)
+
+
+def _chunked_rule(step, q, k, v, g, beta, chunk):
+    """``step`` (either form of the chunk step) over the chunks in turn, the
+    state ``(B, H, Dk, Dv)`` carried from zero by one ``lax.scan``."""
     f32 = jnp.float32
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    b, t, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    if h % hk:
+        raise ValueError(f"{h} value heads do not divide over {hk} key heads")
     pad = -t % chunk
     if pad:
         widen = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
         q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
     n = (t + pad) // chunk
 
-    def chunks(a):  # (B, T, H, ...) -> (N, B, H, C, ...)
-        a = a.reshape((b, n, chunk, h) + a.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 1), 2, 0)
+    def chunks(a):  # (B, T, ...) -> (N, B, C, the rest as one axis): no copy for one sequence
+        return jnp.moveaxis(a.astype(f32).reshape(b, n, chunk, -1), 1, 0)
 
-    def mm(spec, x, y):
-        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype), preferred_element_type=f32)
-
-    q, k, v = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32))
-    g, beta = chunks(g.astype(f32)), chunks(beta.astype(f32))  # (N, B, H, C)
-    run = jnp.cumsum(g, axis=-1)  # the log of the decay since the chunk began
-    rows = jnp.arange(chunk)
-    at_or_below = rows[:, None] >= rows[None, :]
-    gap = run[..., :, None] - run[..., None, :]
-    decay = jnp.where(at_or_below, jnp.exp(jnp.where(at_or_below, gap, 0.0)), 0.0)  # (N, B, H, C, C)
-
-    k_beta = k * beta[..., None]
-    inside = jnp.where(rows[:, None] > rows[None, :], mm("...id,...jd->...ij", k_beta, k) * decay, 0.0)
-    solve = _unit_lower_inverse(inside)
-    writes = mm("...ij,...jd->...id", solve, v * beta[..., None])  # at a zero state
-    reads = mm("...ij,...jd->...id", solve, k_beta * jnp.exp(run)[..., None])  # what the state takes off them
-    to_end = k * jnp.exp(run[..., -1:] - run)[..., None]
-    keep = jnp.exp(run[..., -1])  # (N, B, H): the chunk's whole decay
-
-    def step(state, xs):
-        reads_i, writes_i, to_end_i, keep_i = xs
-        new = writes_i - mm("bhcd,bhde->bhce", reads_i, state)
-        after = state * keep_i[..., None, None] + mm("bhcd,bhce->bhde", to_end_i, new)
-        return after, (state, new)
-
-    _, (states, new) = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32), (reads, writes, to_end, keep))
-    scores = mm("...id,...jd->...ij", q, k) * decay
-    o = mm("...id,...de->...ie", q * jnp.exp(run)[..., None], states) + mm("...ij,...je->...ie", scores, new)
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2), 1, 3)  # (B, N, C, H, Dv)
-    return o.reshape(b, t + pad, h, dv)[:, :t]
+    # the log of the decay since the chunk began, and beta: (N, B, H, C)
+    run = jnp.swapaxes(jnp.cumsum(chunks(g), axis=2), 2, 3)
+    xs = (chunks(q), chunks(k), chunks(v), run, jnp.swapaxes(chunks(beta), 2, 3))
+    _, o = jax.lax.scan(lambda state, x: step(state, *x), jnp.zeros((b, h, dk, dv), f32), xs)
+    return jnp.moveaxis(o, 0, 1).reshape(b, t + pad, h, dv)[:, :t]
 
 
 def causal_depthwise_conv(x, w):
@@ -233,7 +224,6 @@ class GatedDeltaNet(nn.Module):
                 q = l2_normalise(qkv[..., :key_dim].reshape(n, t, hk, dk)) * dk**-0.5
                 k = l2_normalise(qkv[..., key_dim: 2 * key_dim].reshape(n, t, hk, dk))
                 v = qkv[..., 2 * key_dim:].reshape(n, t, hv, dv)
-                q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
             with jax.named_scope("gdn.scan"):
                 beta = jax.nn.sigmoid(ba[..., :hv])
                 g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
