@@ -36,6 +36,14 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          flash kernels that read 2 key-value heads for 16 query
          heads, the loss falls, ``moe.dropped`` stays 0 against the
          assignments due on the held experts and ``moe.held_share`` is read.
+         Then one chip's share of ``trinity_mini`` at the benchmark cell's
+         widths (8 layers, 8 of 128 experts, 25,024 rows of the vocabulary,
+         1 x 16,384 tokens, every block rematerialised) through
+         ``make_train_step(state_rule=balance_bias_rule(0.001))``: the step
+         holds the window kernels (``swa_fwd``, ``swa_bwd_dq``,
+         ``swa_bwd_dkv``) beside the full form's, the loss falls,
+         ``moe.dropped`` stays 0 and every selection bias has moved by the
+         rule's steps.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
          each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
          bf16 matmul; cdist and rbf of 16384 x 128 and of a ragged pair
@@ -57,7 +65,14 @@ kernels  each of the five Pallas kernels lowered at its production block
          kernel against the recurrence a position at a time, and its five
          gradients against the XLA form's (``delta_rule``, ``delta_rule_bwd``;
          the counters ``gdn.rule.kernel`` / ``gdn.rule.xla`` say which form
-         each trace took).
+         each trace took). ``flash_window``: the sliding-window kernels at the
+         Trinity-Mini cell's 32 query heads on 4 of 128, 16,384 positions,
+         window 2,048, forward and dq, dk, dv through the model's own
+         attention core against the masked XLA form in float32, on normal
+         inputs and on the edge probe (the keys exactly 2,047 and 2,048 before
+         a query carry its largest scores); the counters
+         ``attn.window.kernel`` / ``attn.window.xla`` and
+         ``attn.window.blocks_visited`` / ``.blocks_live`` are printed.
 serve    an in-process ``ht.serve.Server`` with ``kmeans_predict``
          (bench.py's serving configuration), warmed up; 32 requests; answers
          equal ``km.predict``; nothing compiled after warm-up.
@@ -89,6 +104,9 @@ FULL = dict(
     # one chip's share of Qwen3-Next at the published widths: a period of four
     # layers, 32 of 512 experts, an eighth of the vocabulary
     qnext=dict(fields=dict(num_layers=4, experts_held=(0, 32), vocab_size=18992), batch=2, seq=8192),
+    # one chip's share of Trinity-Mini as the benchmark's cell cuts it: two periods of
+    # the attention pattern, 8 of 128 experts, an eighth of the vocabulary
+    trinity=dict(fields=dict(num_layers=8, experts_held=(0, 8), vocab_size=25024), batch=1, seq=16384),
     steps=5,
     moments_rows=8_000_000,
     matmul_n=8192,
@@ -96,6 +114,7 @@ FULL = dict(
     kmeans_rows=2_000_000, kmeans_k=64, iters=5,
     attn_fwd=(4, 4096, 8, 128), attn_bwd=(8, 1024, 16, 64),
     attn_gqa=(1, 2048, 16, 2, 256), rule=(1, 1024, 16, 32, 128),
+    attn_window=(16384, 32, 4, 128, 2048),
     kernel_rows=1 << 20, lloyd_rows=1 << 18, int8_n=2048,
     serve_rows=200_000, serve_k=16, requests=32, request_rows=16,
 )
@@ -115,6 +134,12 @@ TINY = dict(
                     experts_per_token=3, shared_d_ff=32, max_len=256),
         batch=2, seq=160,
     ),
+    trinity=dict(
+        fields=dict(num_layers=8, experts_held=(4, 4), vocab_size=256, d_model=64, embed_scale=8.0, num_heads=4,
+                    num_kv_heads=2, head_dim=32, windows=(48, 48, 48, None), dense_d_ff=96, d_ff=32,
+                    num_experts=16, experts_per_token=3, shared_d_ff=32, max_len=256),
+        batch=1, seq=160,
+    ),
     steps=5,
     moments_rows=4096,
     matmul_n=256,
@@ -122,6 +147,7 @@ TINY = dict(
     kmeans_rows=4096, kmeans_k=8, iters=3,
     attn_fwd=(1, 256, 2, 64), attn_bwd=(1, 256, 2, 64),
     attn_gqa=(1, 256, 4, 2, 64), rule=(1, 160, 1, 2, 16),
+    attn_window=(200, 4, 2, 32, 48),
     kernel_rows=2048, lloyd_rows=2048, int8_n=256,
     serve_rows=2048, serve_k=4, requests=8, request_rows=4,
 )
@@ -252,126 +278,123 @@ def stage_train(ht, cfg, devices, on_tpu):
         peak_bytes_in_use=peaks,
         olmoe=_olmoe_steps(cfg, devices, on_tpu),
         qnext=_qnext_steps(cfg, devices, on_tpu),
+        trinity=_trinity_steps(cfg, devices, on_tpu),
     )
 
 
-def _olmoe_steps(cfg, devices, on_tpu):
-    """Five AdamW steps of ``olmoe_1b_7b(num_layers=1)`` on the first chip,
-    through ``DataParallel.make_train_step`` (state donated): the loss falls,
-    no assignment is dropped, and the attention kernels go to Mosaic under
-    the names the benchmark's trace readers look for."""
+def _lm_steps(name, model, loss_fn, c, steps, rule=None):
+    """``steps`` AdamW steps of ``model`` on its communicator's chip through
+    ``DataParallel.make_train_step`` (state donated; ``rule``: its
+    ``state_rule``), each step's loss and routing read with ``read_routing``:
+    the loss is finite and falls, and ``moe.dropped`` stays 0 against the
+    assignments due here (all of them, or those on the experts the model
+    holds). Returns what the three models' own checks read: the step's lowered
+    text and the names of its Mosaic calls, the losses, the last step's
+    routing, the assignments due, what the ``moe.*`` counters gained, and the
+    state the last step returned."""
     import jax
     import jax.numpy as jnp
     import optax
 
     from heat_tpu import telemetry
     from heat_tpu.core import program_cache
-    from heat_tpu.core.communication import MeshCommunication
-    from heat_tpu.nn import DataParallel, causal_lm_loss, olmoe_1b_7b, read_routing
+    from heat_tpu.nn import DataParallel, read_routing
 
-    c = cfg["olmoe"]
-    comm = MeshCommunication(devices=devices[:1])  # the expert layer runs on one chip
-    model = olmoe_1b_7b(num_layers=1, comm=comm, **c["fields"])
+    comm = model.comm
     opt = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
     step = DataParallel(
         model, comm=comm, optimizer=opt, blocking_parameter_updates=True
-    ).make_train_step(
-        causal_lm_loss(model, load_balance_coef=0.01, router_z_coef=0.001),
-        has_aux=True,
-    )
+    ).make_train_step(loss_fn, has_aux=True, state_rule=rule)
     key = tuple(sorted(c["fields"].items()))
-    replicated = comm.replicated()
+
+    def init(k):
+        drawn = model.clone(attn_impl="local", remat=False).init(k, jnp.zeros((1, 8), jnp.int32))
+        return {name: drawn[name] for name in ("params", "route_bias") if name in drawn}
+
     params = program_cache.cached_program(
-        "smoke.olmoe_init", key,
-        lambda: lambda k: {"params": model.clone(attn_impl="local").init(
-            k, jnp.zeros((1, 8), jnp.int32))["params"]},
-        comm=comm, out_shardings=replicated,
+        f"smoke.{name}_init", key, lambda: init, comm=comm, out_shardings=comm.replicated(),
     )(jax.random.PRNGKey(0))
     opt_state = program_cache.cached_program(
-        "smoke.olmoe_opt_init", key, lambda: opt.init, comm=comm,
-        out_shardings=replicated,
+        f"smoke.{name}_opt_init", key, lambda: lambda p: opt.init({"params": p["params"]}),
+        comm=comm, out_shardings=comm.replicated(),
     )(params)
-    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params["params"]))
     toks = np.random.default_rng(0).integers(
         0, model.vocab_size, (c["batch"], c["seq"]), dtype=np.int32
     )
-    kernels = sorted(set(re.findall(
-        r'@tpu_custom_call\(.*kernel_name = "(\w+)"',
-        step.lower(params, opt_state, jnp.asarray(toks)).as_text(),
-    )))
+    text = step.lower(params, opt_state, jnp.asarray(toks)).as_text()
+    kernels = sorted(set(re.findall(r'@tpu_custom_call\(.*kernel_name = "(\w+)"', text)))
+    counters = telemetry.get_registry().counters
+    names = ("moe.dropped", "moe.assignments", "moe.held_share", "moe.steps")
+    before = {k: counters[k] for k in names}
+    first, count = model.experts_held or (0, model.num_experts)
+    losses, due = [], 0
+    for _ in range(steps):
+        params, opt_state, loss, aux = step(params, opt_state, toks)
+        loss, aux = read_routing(loss, aux)  # to the host, and into the moe.* counters
+        losses.append(float(loss))
+        due += int(aux["expert_counts"][:, first:first + count].sum())
+    gained = {k: counters[k] - before[k] for k in names}
+    _check(all(np.isfinite(losses)), f"{name} loss not finite: {losses}")
+    _check(losses[-1] < losses[0], f"{name} loss did not fall: {losses}")
+    _check(
+        gained["moe.dropped"] == 0 and gained["moe.assignments"] == due,
+        f"{name}: routing dropped {gained['moe.dropped']} of the {due} assignments due here, "
+        f"counted {gained['moe.assignments']}",
+    )
+    del opt_state
+    gc.collect()
+    return dict(
+        params=n_params, losses=[round(v, 4) for v in losses], kernels=kernels, text=text, aux=aux, due=due,
+        gained=gained, state=params,
+    )
+
+
+def _olmoe_steps(cfg, devices, on_tpu):
+    """``olmoe_1b_7b(num_layers=1)`` on the first chip (:func:`_lm_steps`): all
+    ``steps x batch x seq x top-k`` assignments are due, and the attention
+    kernels go to Mosaic under the names the benchmark's trace readers look
+    for."""
+    from heat_tpu.core.communication import MeshCommunication
+    from heat_tpu.nn import causal_lm_loss, olmoe_1b_7b
+
+    c = cfg["olmoe"]
+    model = olmoe_1b_7b(num_layers=1, comm=MeshCommunication(devices=devices[:1]), **c["fields"])  # one chip
+    got = _lm_steps(
+        "olmoe", model, causal_lm_loss(model, load_balance_coef=0.01, router_z_coef=0.001), c, cfg["steps"]
+    )
+    kernels = got["kernels"]
     if on_tpu:
         _check(
             "flash_fwd" in kernels
             and any(k.startswith("flash_bwd_") for k in kernels),
             f"no Mosaic call named flash_fwd / flash_bwd_* in the step: {kernels}",
         )
-    counters = telemetry.get_registry().counters
-    dropped0, assigned0 = counters["moe.dropped"], counters["moe.assignments"]
-    losses = []
-    for _ in range(cfg["steps"]):
-        params, opt_state, loss, aux = step(params, opt_state, toks)
-        loss, aux = read_routing(loss, aux)  # to the host, and into the moe.* counters
-        losses.append(float(loss))
-    _check(all(np.isfinite(losses)), f"olmoe loss not finite: {losses}")
-    _check(losses[-1] < losses[0], f"olmoe loss did not fall: {losses}")
-    due = cfg["steps"] * c["batch"] * c["seq"] * model.experts_per_token
     _check(
-        counters["moe.dropped"] == dropped0
-        and counters["moe.assignments"] - assigned0 == due,
-        f"routing dropped {counters['moe.dropped'] - dropped0} assignments, "
-        f"counted {counters['moe.assignments'] - assigned0} of {due}",
+        got["due"] == cfg["steps"] * c["batch"] * c["seq"] * model.experts_per_token,
+        f"{got['due']} assignments due: not every token's top-k",
     )
-    load = float(aux["expert_counts"].max() / aux["expert_counts"].mean())
-    del params, opt_state
-    gc.collect()
+    counts = got["aux"]["expert_counts"]
     return dict(
-        params=n_params, losses=[round(v, 4) for v in losses],
-        mosaic_kernels=kernels, load_max_over_mean=round(load, 3),
+        params=got["params"], losses=got["losses"],
+        mosaic_kernels=kernels, load_max_over_mean=round(float(counts.max() / counts.mean()), 3),
     )
 
 
 def _qnext_steps(cfg, devices, on_tpu):
-    """AdamW steps of one chip's share of ``qwen3_next_80b_a3b`` on the first
-    chip, every block rematerialised: the chip took the chunked delta rule (a
-    loop whose carry is the state of one sequence's heads, the chunk step a
-    Mosaic call forward and backward) and the flash
-    kernels with the key-value heads read by group (compiled Mosaic calls whose
-    K and V operands have fewer heads than Q), the loss falls, ``moe.dropped``
-    stays 0 against the assignments due on the held experts, and
-    ``moe.held_share`` is read."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from heat_tpu import telemetry
-    from heat_tpu.core import program_cache
+    """One chip's share of ``qwen3_next_80b_a3b`` on the first chip, every
+    block rematerialised (:func:`_lm_steps`): the chip took the chunked delta
+    rule (a loop whose carry is the state of one sequence's heads, the chunk
+    step a Mosaic call forward and backward) and the flash kernels with the
+    key-value heads read by group (compiled Mosaic calls whose K and V operands
+    have fewer heads than Q), and ``moe.held_share`` is what the counts give."""
     from heat_tpu.core.communication import MeshCommunication
-    from heat_tpu.nn import DataParallel, causal_lm_loss, qwen3_next_80b_a3b, read_routing
+    from heat_tpu.nn import causal_lm_loss, qwen3_next_80b_a3b
 
     c = cfg["qnext"]
-    comm = MeshCommunication(devices=devices[:1])
-    model = qwen3_next_80b_a3b(comm=comm, remat=True, **c["fields"])
-    opt = optax.adamw(4e-4, b1=0.9, b2=0.95, weight_decay=0.1)
-    step = DataParallel(
-        model, comm=comm, optimizer=opt, blocking_parameter_updates=True
-    ).make_train_step(causal_lm_loss(model, load_balance_coef=0.001), has_aux=True)
-    key = tuple(sorted(c["fields"].items()))
-    replicated = comm.replicated()
-    params = program_cache.cached_program(
-        "smoke.qnext_init", key,
-        lambda: lambda k: {"params": model.clone(attn_impl="local", remat=False).init(
-            k, jnp.zeros((1, 8), jnp.int32))["params"]},
-        comm=comm, out_shardings=replicated,
-    )(jax.random.PRNGKey(0))
-    opt_state = program_cache.cached_program(
-        "smoke.qnext_opt_init", key, lambda: opt.init, comm=comm, out_shardings=replicated,
-    )(params)
-    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
-    toks = np.random.default_rng(0).integers(
-        0, model.vocab_size, (c["batch"], c["seq"]), dtype=np.int32
-    )
-    text = step.lower(params, opt_state, jnp.asarray(toks)).as_text()
-    kernels = sorted(set(re.findall(r'@tpu_custom_call\(.*kernel_name = "(\w+)"', text)))
+    model = qwen3_next_80b_a3b(comm=MeshCommunication(devices=devices[:1]), remat=True, **c["fields"])
+    got = _lm_steps("qnext", model, causal_lm_loss(model, load_balance_coef=0.001), c, cfg["steps"])
+    text, kernels = got["text"], got["kernels"]
     state = f"tensor<1x{model.gdn_value_heads}x{model.gdn_key_dim}x{model.gdn_value_dim}xf32>"
     _check(state in text, f"no loop over chunks carrying {state} in the step: the delta rule is not the chunked one")
     if on_tpu:
@@ -380,35 +403,55 @@ def _qnext_steps(cfg, devices, on_tpu):
             f"no Mosaic calls flash_fwd / flash_bwd_dq / flash_bwd_dkv / delta_chunk_fwd / delta_chunk_bwd "
             f"in the step: {kernels}",
         )
-        kv = f"tensor<{c['batch']}x{model.num_kv_heads}x{c['seq']}x{model.head_dim}xbf16>"
-        _check(
-            any(kv in line for line in text.splitlines() if "flash_fwd" in line),
-            f"flash_fwd does not read K and V at {kv}: the key-value heads were repeated",
-        )
-    counters = telemetry.get_registry().counters
-    before = {k: counters[k] for k in ("moe.dropped", "moe.assignments", "moe.held_share", "moe.steps")}
-    losses, held = [], 0
-    for _ in range(cfg["steps"]):
-        params, opt_state, loss, aux = step(params, opt_state, toks)
-        loss, aux = read_routing(loss, aux)
-        losses.append(float(loss))
-        held += int(aux["expert_counts"][:, model.experts_held[0]:sum(model.experts_held)].sum())
-    _check(all(np.isfinite(losses)), f"qnext loss not finite: {losses}")
-    _check(losses[-1] < losses[0], f"qnext loss did not fall: {losses}")
-    _check(
-        counters["moe.dropped"] == before["moe.dropped"]
-        and counters["moe.assignments"] - before["moe.assignments"] == held,
-        f"routing dropped {counters['moe.dropped'] - before['moe.dropped']} of the {held} assignments "
-        f"due on the held experts, counted {counters['moe.assignments'] - before['moe.assignments']}",
-    )
-    share = (counters["moe.held_share"] - before["moe.held_share"]) / (counters["moe.steps"] - before["moe.steps"])
+        _kv_read_by_group(text, "flash_fwd", model, c)
+    share = got["gained"]["moe.held_share"] / got["gained"]["moe.steps"]
     routed = cfg["steps"] * model.num_layers * c["batch"] * c["seq"] * model.experts_per_token
-    _check(abs(share - held / routed) < 1e-9, f"moe.held_share reads {share}, the counts give {held / routed}")
-    del params, opt_state
-    gc.collect()
+    _check(abs(share - got["due"] / routed) < 1e-9, f"moe.held_share reads {share}, the counts give {got['due'] / routed}")
     return dict(
-        params=n_params, losses=[round(v, 4) for v in losses], mosaic_kernels=kernels,
+        params=got["params"], losses=got["losses"], mosaic_kernels=kernels,
         held_share=round(share, 5), even_share=model.experts_held[1] / model.num_experts,
+    )
+
+
+def _kv_read_by_group(text, kernel, model, c):
+    kv = f"tensor<{c['batch']}x{model.num_kv_heads}x{c['seq']}x{model.head_dim}xbf16>"
+    _check(
+        any(kv in line for line in text.splitlines() if kernel in line),
+        f"{kernel} does not read K and V at {kv}: the key-value heads were repeated",
+    )
+
+
+def _trinity_steps(cfg, devices, on_tpu):
+    """One chip's share of ``trinity_mini`` on the first chip, every block
+    rematerialised, the routers' selection biases carried and moved by
+    ``balance_bias_rule`` in the same program (:func:`_lm_steps`): the chip
+    took the window kernels on the sliding layers and the full form on the
+    others (compiled Mosaic calls of both names), and after ``steps`` steps
+    every bias is a whole number of the rule's steps from 0 and at least one
+    has moved."""
+    import jax
+
+    from heat_tpu.core.communication import MeshCommunication
+    from heat_tpu.nn import balance_bias_rule, causal_lm_loss, trinity_mini
+
+    c, rate = cfg["trinity"], 0.001
+    model = trinity_mini(comm=MeshCommunication(devices=devices[:1]), remat=True, **c["fields"])
+    got = _lm_steps("trinity", model, causal_lm_loss(model), c, cfg["steps"], rule=balance_bias_rule(rate))
+    kernels = got["kernels"]
+    if on_tpu:
+        wanted = {"swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+        _check(wanted <= set(kernels), f"no Mosaic calls {sorted(wanted - set(kernels))} in the step: {kernels}")
+        _kv_read_by_group(got["text"], "swa_fwd", model, c)
+    biases = np.stack([np.asarray(b) for b in jax.tree.leaves(jax.device_get(got["state"]["route_bias"]))])
+    in_steps = biases / rate
+    _check(
+        biases.shape == (len(model.expert_layers()), model.num_experts)
+        and np.abs(in_steps - np.round(in_steps)).max() < 1e-3 and 0 < np.abs(in_steps).max() <= cfg["steps"],
+        f"the selection biases are not whole steps of the rule: largest {np.abs(in_steps).max()} steps",
+    )
+    return dict(
+        params=got["params"], losses=got["losses"], mosaic_kernels=kernels,
+        largest_bias_in_steps=float(np.abs(in_steps).max()),
     )
 
 
@@ -739,6 +782,8 @@ def stage_kernels(cfg, on_tpu):
             attn_grads(repeated), (gq, gk, gv), 2e-2, relative=True,
         )
 
+    report.update(_flash_window(cfg, on_tpu, wrong))
+
     # the gated delta rule at the Qwen3-Next cell's heads and sizes (bfloat16
     # operands in its products; heads that remember 8 to 1,024 positions): the
     # form gated_delta_rule takes here (on a TPU the chunk step is the Pallas
@@ -851,6 +896,112 @@ def stage_kernels(cfg, on_tpu):
         (qa, sa, qb, sb), 1e-3,  # exact i32 products; one f32 rescale each
     )
     _check(not wrong, f"{wrong}; all kernels: {report}")
+    return report
+
+
+def _masked_attention(q, k, v, window, block=2048):
+    """The masked XLA form in float32: ``softmax(q k^T / sqrt(D))`` under the
+    mask ``t - window < j <= t``, a head and ``block`` queries at a time
+    against all keys (a full score matrix under the mask, in blocks of queries)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, heads, d = q.shape
+    k, v = (jnp.repeat(a, heads // a.shape[2], axis=2) for a in (k, v))
+    block = block if t % block == 0 else t
+
+    def one_head(qkv):
+        qh, kh, vh = qkv
+
+        @jax.checkpoint
+        def one_block(args):
+            first, qb = args
+            q_pos, k_pos = first + jnp.arange(block)[:, None], jnp.arange(t)[None, :]
+            mask = (k_pos <= q_pos) & (k_pos > q_pos - window)
+            s = jnp.where(mask, jnp.matmul(qb, kh.T, precision="highest") / np.sqrt(d), -jnp.inf)
+            return jnp.matmul(jax.nn.softmax(s, axis=-1), vh, precision="highest")
+
+        return jax.lax.map(one_block, (jnp.arange(0, t, block), qh.reshape(t // block, block, d))).reshape(t, d)
+
+    by_head = lambda a: a.transpose(0, 2, 1, 3).reshape(b * heads, t, d)  # noqa: E731
+    o = jax.lax.map(one_head, (by_head(q), by_head(k), by_head(v)))
+    return o.reshape(b, heads, t, d).transpose(0, 2, 1, 3)
+
+
+def _flash_window(cfg, on_tpu, wrong):
+    """The sliding-window flash kernels at the Trinity-Mini cell's shapes,
+    through the model's own attention core (``nn.transformer._attend``, which
+    counts what it dispatched): outputs and dq, dk, dv against the masked XLA
+    form in float32 on the same bfloat16-rounded inputs, by root-mean-square
+    gap. Limits: the kernels round the probabilities, dS and the output to
+    bfloat16 (2^-9 each): 2.1e-3 to 2.9e-3 on the normal inputs and, on the
+    edge probe, 1.96e-2 (dq, where dP - D nearly cancels on the one key that
+    carries a query's mass; the same on every seed; my chip runs, PR 32, calls
+    2 and 3); a mask one short or one long reads 0.5 and more on the edge
+    probe, a skipped block 0.1 and more on the normal inputs: 6e-2 lies
+    between."""
+    import jax
+    import jax.numpy as jnp
+
+    from heat_tpu import telemetry
+    from heat_tpu.core import program_cache
+    from heat_tpu.nn.transformer import _attend
+
+    t, h, hkv, d, window = cfg["attn_window"]
+    keys = jax.random.split(jax.random.PRNGKey(32), 6)
+    rounded = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    normal = lambda key, heads: rounded(jax.random.normal(key, (1, t, heads, d), jnp.float32))  # noqa: E731
+    # the edge probe: every key a vector of +-1, a query twice the sum of the keys window - 1 and window before it
+    pk = jnp.where(jax.random.bernoulli(keys[3], 0.5, (1, t, hkv, d)), 1.0, -1.0).astype(jnp.float32)
+    at = jnp.arange(t)
+    pq = 2.0 * jnp.repeat(pk[:, (at - (window - 1)) % t] + pk[:, (at - window) % t], h // hkv, axis=2)
+    sets = {
+        "normal": (normal(keys[0], h), normal(keys[1], hkv), normal(keys[2], hkv)),
+        "edge_probe": (pq, pk, normal(keys[4], hkv)),
+    }
+    weights = jax.random.normal(keys[5], (1, t, h, d), jnp.float32)
+
+    def with_gradients(attend):
+        def f(q, k, v):
+            out = attend(q, k, v).astype(jnp.float32)
+            return jnp.sum(out * weights), out
+
+        def run(q, k, v):
+            (_, out), grads = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (out,) + grads
+
+        return run
+
+    kernel = with_gradients(lambda q, k, v: _attend(
+        q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), impl="flash", causal=True,
+        comm=None, block_size=None, flash_bwd_impl="two_pass", window=window,
+    ))
+    counters = telemetry.get_registry().counters
+    names = ("attn.window.kernel", "attn.window.xla", "attn.window.blocks_visited", "attn.window.blocks_live")
+    before = {name: counters.get(name, 0) for name in names}
+    lowered = program_cache.cached_program("smoke.flash_window", (), lambda: kernel).lower(*sets["normal"])
+    took = {name: counters.get(name, 0) - n for name, n in before.items()}
+    if took["attn.window.kernel"] != 1 or took["attn.window.xla"] != 0:
+        wrong.append(f"flash_window: one trace of the windowed core counted {took}, not kernel 1 / xla 0")
+    text = lowered.as_text()
+    if on_tpu and not all(f'kernel_name = "{name}"' in text for name in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")):
+        wrong.append("flash_window: no Mosaic calls swa_fwd / swa_bwd_dq / swa_bwd_dkv in the lowering")
+    program = lowered.compile()
+    masked = program_cache.cached_program(
+        "smoke.flash_window_xla", (), lambda: with_gradients(lambda q, k, v: _masked_attention(q, k, v, window))
+    )
+    rms = lambda got, ref: float(  # noqa: E731
+        np.sqrt(np.mean((np.asarray(got, np.float64) - np.asarray(ref, np.float64)) ** 2) / np.mean(np.asarray(ref, np.float64) ** 2))
+    )
+    report = {
+        "flash_window_blocks_visited_over_live": took["attn.window.blocks_visited"] / max(took["attn.window.blocks_live"], 1),
+        **{f"flash_window.{name}": took[name] for name in names[:2]},
+    }
+    for name, qkv in sets.items():
+        errs = [rms(g, w) for g, w in zip(program(*qkv), masked(*qkv))]
+        if not max(errs) <= 6e-2:
+            wrong.append(f"flash_window {name}: rms of (out, dq, dk, dv) {errs} > 6e-2")
+        report[f"flash_window_{name}"] = float(f"{max(errs):.3g}")
     return report
 
 

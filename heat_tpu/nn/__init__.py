@@ -18,9 +18,10 @@ from .transformer import (
     causal_lm_loss,
     olmoe_1b_7b,
     qwen3_next_80b_a3b,
+    trinity_mini,
 )
 from .deltanet import GatedDeltaNet, gated_delta_rule
-from .moe import DroplessMoE, MoEMLP, read_routing
+from .moe import DroplessMoE, MoEMLP, balance_bias_rule, read_routing
 from .quant_dense import QuantDense
 
 __all__ = [
@@ -34,6 +35,7 @@ __all__ = [
     "MoEMLP",
     "MultiHeadAttention",
     "Pipeline",
+    "balance_bias_rule",
     "QuantDense",
     "TransformerBlock",
     "TransformerLM",
@@ -41,6 +43,7 @@ __all__ = [
     "olmoe_1b_7b",
     "qwen3_next_80b_a3b",
     "read_routing",
+    "trinity_mini",
 ]
 
 

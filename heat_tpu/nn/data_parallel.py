@@ -134,6 +134,7 @@ class DataParallel:
     def make_train_step(
         self, loss_fn: Callable, optimizer=None,
         precision: Optional[str] = None, has_aux: bool = False,
+        state_rule: Optional[Callable] = None,
     ) -> Callable:
         """Build the compiled DP train step.
 
@@ -155,6 +156,17 @@ class DataParallel:
 
         Blocking mode returns ``step(params, opt_state, *batch) ->
         (params, opt_state, loss)``.
+
+        ``state_rule(state, aux) -> state`` (blocking mode, ``has_aux``): the
+        step also carries state that a rule of its own updates, in the same
+        compiled program. ``params`` is then a dict of collections, as
+        ``module.init`` gives it: ``params["params"]`` is what the loss is
+        differentiated by and all the optimizer ever sees (its state is
+        ``optimizer.init({"params": params["params"]})``: no moment, no decay
+        and no share of a clip's norm for anything else); the other
+        collections are ``state``, handed to the loss with the parameters, then
+        to the rule with the step's ``aux``, and returned (and donated) inside
+        ``params``. ``nn.balance_bias_rule`` is such a rule.
 
         Non-blocking (double-buffered) mode returns ``step(params,
         opt_state, pending_grads, *batch) -> (params, opt_state,
@@ -186,9 +198,28 @@ class DataParallel:
         wire = collective_prec.resolve(precision)
 
         if wire != "off":
-            if has_aux:
-                raise NotImplementedError("has_aux with a compressed gradient wire")
+            if has_aux or state_rule is not None:
+                raise NotImplementedError("has_aux or state_rule with a compressed gradient wire")
             step = self._make_compressed_step(loss_fn, optimizer, wire)
+        elif self.blocking_parameter_updates and state_rule is not None:
+            if not has_aux:
+                raise ValueError("state_rule reads the loss's aux: pass has_aux=True")
+
+            def step(params, opt_state, *batch):
+                trained = {"params": params["params"]}
+                state = {k: v for k, v in params.items() if k != "params"}
+                out, grads = jax.value_and_grad(
+                    lambda p: loss_fn({**state, **p}, *batch), has_aux=True
+                )(trained)
+                with jax.named_scope("train.optimizer"):
+                    updates, opt_state = optimizer.update(grads, opt_state, trained)
+                    trained = optax.apply_updates(trained, updates)
+                with jax.named_scope("train.state_rule"):
+                    state = state_rule(state, out[1])
+                return ({**state, **trained}, opt_state, *out)
+
+        elif state_rule is not None:
+            raise NotImplementedError("state_rule with double-buffered parameter updates")
         elif self.blocking_parameter_updates:
 
             def step(params, opt_state, *batch):
@@ -233,7 +264,7 @@ class DataParallel:
         n_state = 2 if self.blocking_parameter_updates else 3
         compiled = program_cache.cached_program(
             "dp_train_step",
-            (loss_fn, optimizer, self.blocking_parameter_updates, wire, has_aux),
+            (loss_fn, optimizer, self.blocking_parameter_updates, wire, has_aux, state_rule),
             lambda: raw_step,
             comm=self.comm,
             donate=range(n_state),

@@ -172,7 +172,20 @@ class DroplessMoE(nn.Module):
     over the k chosen.
 
     ``shared_d_ff`` > 0 adds an expert of that width that every token takes,
-    behind a sigmoid gate of its own: ``+ sigmoid(h w_s) * E_shared(h)``.
+    behind a sigmoid gate of its own: ``+ sigmoid(h w_s) * E_shared(h)``, or,
+    with ``shared_gate`` false, as it is: ``+ E_shared(h)``.
+
+    ``score="sigmoid"`` scores each expert on its own: ``s = sigmoid(r)`` in
+    float32 in place of the softmax; the weights are the chosen ``s`` (with
+    ``norm_topk`` over their sum + 1e-20), times ``route_scale``. The auxiliary
+    terms stay defined: ``P_e`` is then the mean of ``s`` normalised to sum 1 a
+    token, ``router_z`` as before over the logits. ``select_bias`` adds a bias
+    ``b`` of one float32 an expert that only the *choice* sees: the top-k is
+    taken of ``score + b``, the weights of the score without it, and no
+    gradient reaches ``b``. It is no parameter: it lives in the collection
+    ``route_bias`` beside ``params`` (zeros at ``init``), the optimizer never
+    holds it, and a rule of its own moves it after a step
+    (:func:`balance_bias_rule`, ``DataParallel.make_train_step(state_rule=)``).
 
     ``experts_held = (first, count)`` makes the layer one share of an
     expert-parallel layout: the router, its softmax, the top-k and its
@@ -182,11 +195,12 @@ class DroplessMoE(nn.Module):
     them are computed; the others add nothing. The shares of all ranks, with
     the shared expert counted once, add up to the whole layer. The work
     around the experts goes with the rows that land here: they are gathered,
-    multiplied and summed back into their tokens in windows of ``2 x`` an
-    even share of the ``N * k`` assignments; one window holds them all unless
-    the routing is far from even, and then further windows run, behind a
-    branch, until every held assignment is computed (none is dropped).
-    ``held`` (the assignments due here) is sown beside ``computed``.
+    multiplied and summed back into their tokens in windows: the first, ``2 x``
+    an even share of the ``N * k`` assignments long, holds them all unless the
+    routing is far from even, and then further windows of one even share run,
+    behind a branch, as many as hold a row, until every held assignment is
+    computed (none is dropped). ``held`` (the assignments due here) is sown
+    beside ``computed``.
     """
 
     n_experts: int
@@ -199,6 +213,10 @@ class DroplessMoE(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None
     matrix_init: Any = None  # None: lecun_normal, as every expert matrix was drawn before
     out_init: Any = None  # the matrices that write into the residual stream
+    score: str = "softmax"  # or "sigmoid"
+    route_scale: float = 1.0
+    shared_gate: bool = True
+    select_bias: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -220,10 +238,25 @@ class DroplessMoE(nn.Module):
                 xt.astype(jnp.float32), w_router,
                 precision=jax.lax.Precision.HIGHEST,
             )
-            probs = jax.nn.softmax(logits, axis=-1)
-            weights, chosen = jax.lax.top_k(probs, k)  # (n, k), float32
+            if self.score == "softmax":
+                probs = scores = jax.nn.softmax(logits, axis=-1)
+            elif self.score == "sigmoid":
+                telemetry.get_registry().add("moe.route.sigmoid")
+                scores = jax.nn.sigmoid(logits)
+                probs = scores / jnp.sum(scores, axis=-1, keepdims=True)  # for the auxiliary terms
+            else:
+                raise ValueError(f"score must be 'softmax' or 'sigmoid', got {self.score!r}")
+            if self.select_bias:
+                bias = self.variable("route_bias", "bias", jnp.zeros, (e,), jnp.float32).value
+                _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+                weights = jnp.take_along_axis(scores, chosen, axis=-1)
+            else:
+                weights, chosen = jax.lax.top_k(scores, k)  # (n, k), float32
             if self.norm_topk:
-                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+                total = jnp.sum(weights, axis=-1, keepdims=True)
+                weights = weights / (total if self.score == "softmax" else total + 1e-20)
+            if self.route_scale != 1.0:
+                weights = weights * self.route_scale
             flat = chosen.reshape(n * k).astype(jnp.int32)
             counts = jnp.sum(
                 flat[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
@@ -265,11 +298,14 @@ class DroplessMoE(nn.Module):
                     self.shared_d_ff, "shared_up", matrix
                 )(xt)
                 y = dense(d, "shared_down", matrix if self.out_init is None else self.out_init)(hidden)
-                w_shared = self.param("shared_router", matrix, (d, 1), jnp.float32)
-                gate = jax.nn.sigmoid(jnp.dot(
-                    xt.astype(jnp.float32), w_shared, precision=jax.lax.Precision.HIGHEST,
-                ))
-                out = out + gate * y.astype(jnp.float32)
+                if self.shared_gate:
+                    w_shared = self.param("shared_router", matrix, (d, 1), jnp.float32)
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        xt.astype(jnp.float32), w_shared, precision=jax.lax.Precision.HIGHEST,
+                    ))
+                    out = out + gate * y.astype(jnp.float32)
+                else:
+                    out = out + y.astype(jnp.float32)
 
         f = counts.astype(jnp.float32) / (n * k)
         self.sow("aux", "moe", {
@@ -311,16 +347,15 @@ def _swiglu(rows, sizes, experts, out_dtype):
     return grouped(hidden, experts[2])
 
 
-def _window(static, diff, ints, i):
-    """Window ``i`` of the sorted held rows: gather ``bound`` rows, the grouped
-    products over the part of every expert's group that lies in the window,
-    and the weighted sum back into the rows' tokens. ``(part (n, d) float32,
-    rows computed)``."""
+def _window(static, diff, ints, lo):
+    """The window of ``bound`` sorted held rows from row ``lo``: gather them,
+    the grouped products over the part of every expert's group that lies in
+    the window, and the weighted sum back into the rows' tokens. ``(part (n, d)
+    float32, rows computed)``."""
     bound, k, out_dtype = static
     xt, experts, sorted_weights = diff
     order, by_expert, starts, ends = ints
     n = xt.shape[0]
-    lo = i * bound
     with jax.named_scope("moe.route"):
         tokens = jax.lax.dynamic_slice_in_dim(order, lo, bound) // k
         live = lo + jnp.arange(bound, dtype=jnp.int32) < ends[-1]
@@ -337,42 +372,46 @@ def _window(static, diff, ints, i):
     return part, rows_computed(jax.lax.dynamic_slice_in_dim(by_expert, lo, bound), sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _further_windows(static, windows, diff, ints):
-    """The sum of windows ``1 .. windows - 1``, each behind a branch that
-    skips it when no held row is left for it. The backward pass goes over the
-    windows again and keeps nothing a window: a scan's own transpose would
-    keep every window's operands, the expert weights among them."""
+def _live_further(static, ints):
+    """How many further windows hold a held row."""
+    first, bound = static[:2]
+    return -(-jnp.maximum(ints[3][-1] - first, 0) // bound)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _further_windows(static, diff, ints):
+    """The sum of the windows past the first (``static``: the first's length,
+    theirs, ``k``, the products' dtype): a loop over as many of them as hold a
+    held row, so that what a step pays for an uneven routing goes with the
+    rows that overflow, a window of one even share at a time. The backward pass
+    goes over the same windows again and keeps nothing a window: a loop's own
+    transpose would keep every window's operands, the expert weights among
+    them."""
+    first, bound = static[:2]
     n, d = diff[0].shape
 
-    def body(carry, i):
-        part, done = jax.lax.cond(
-            i * static[0] < ints[3][-1], lambda: _window(static, diff, ints, i),
-            lambda: (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32)),
-        )
-        return (carry[0] + part, carry[1] + done), None
+    def body(j, carry):
+        part, done = _window(static[1:], diff, ints, first + j * bound)
+        return carry[0] + part, carry[1] + done
 
     start = (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32))
-    return jax.lax.scan(body, start, jnp.arange(1, windows, dtype=jnp.int32))[0]
+    return jax.lax.fori_loop(jnp.zeros((), jnp.int32), _live_further(static, ints), body, start)
 
 
-def _further_windows_fwd(static, windows, diff, ints):
-    return _further_windows(static, windows, diff, ints), (diff, ints)
+def _further_windows_fwd(static, diff, ints):
+    return _further_windows(static, diff, ints), (diff, ints)
 
 
-def _further_windows_bwd(static, windows, res, g):
+def _further_windows_bwd(static, res, g):
+    first, bound = static[:2]
     diff, ints = res
-    wide = lambda tree: jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), tree)  # noqa: E731
 
-    def body(acc, i):
-        def live():
-            _, transpose = jax.vjp(lambda operands: _window(static, operands, ints, i)[0], diff)
-            return jax.tree.map(lambda a: a.astype(jnp.float32), transpose(g[0])[0])
+    def body(j, acc):
+        _, transpose = jax.vjp(lambda operands: _window(static[1:], operands, ints, first + j * bound)[0], diff)
+        return jax.tree.map(lambda a, got: a + got.astype(jnp.float32), acc, transpose(g[0])[0])
 
-        got = jax.lax.cond(i * static[0] < ints[3][-1], live, lambda: wide(diff))
-        return jax.tree.map(jnp.add, acc, got), None
-
-    acc = jax.lax.scan(body, wide(diff), jnp.arange(1, windows, dtype=jnp.int32))[0]
+    acc = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), diff)
+    acc = jax.lax.fori_loop(jnp.zeros((), jnp.int32), _live_further(static, ints), body, acc)
     return jax.tree.map(lambda a, like: a.astype(like.dtype), acc, diff), None
 
 
@@ -383,14 +422,22 @@ def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtyp
     """The part of an expert layer that its held experts (``experts``: their
     three weights, ``first`` the id of the first) give: ``(out (n, d) float32,
     computed)``. The ``n * k`` assignments are sorted with the held ones
-    first, by expert and then by token (int32 keys); then windows of ``bound``
-    sorted rows (2 x an even share) are gathered, multiplied and summed back
-    into their tokens. The first window always runs; the others only where
-    held rows are left, behind one ``lax.cond``."""
+    first, by expert and then by token (int32 keys); then windows of sorted
+    rows are gathered, multiplied and summed back into their tokens. The first
+    window, ``2 x`` an even share of the assignments, always runs, and costs its
+    whole length whatever lies in it; the rows past it, if the routing leaves
+    any, go a window of one even share at a time, behind one ``lax.cond``, as
+    many windows as hold a row (:func:`_further_windows`). The first window is
+    no longer than that because every step pays for it (at 4 x, 46 ms of a 1,100
+    ms step of 8 of 128 experts: TPU v5e, my chip runs, PR 32, call 8), and the
+    further ones go by the row because a share of few experts under a routing
+    far from even passes 2 x in many steps (a layer's share read 0.16 to 2.96 x
+    even over ten seeds there, and a further window ~10 ms: call 10)."""
     n = xt.shape[0]
     held = held_counts.shape[0]
-    bound = min(n * k, -(-2 * (-(-n * k * held // e)) // 8) * 8)
-    windows = -(-n * k // bound)
+    even = -(-n * k * held // e)
+    bound = min(n * k, -(-2 * even // 8) * 8)
+    more = min(n * k - bound, -(-even // 8) * 8)  # a further window's rows
     with jax.named_scope("moe.route"):
         local = flat - first
         mine = (local >= 0) & (local < held)
@@ -402,12 +449,20 @@ def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtyp
         ends = jnp.cumsum(held_counts)
     static, diff, ints = (bound, k, out_dtype), (xt, experts, sorted_weights), (order, by_expert, ends - held_counts, ends)
     out, computed = _window(static, diff, ints, 0)
-    if windows > 1:
-        more, done = jax.lax.cond(
-            ends[-1] > bound, lambda: _further_windows(static, windows, diff, ints),
-            lambda: (jnp.zeros_like(out), jnp.zeros((), jnp.int32)),
+    if more:
+        def further():
+            # whole windows to the end of the sorted rows: what is added holds no held row
+            pad = -(n * k - bound) % more
+            padded = lambda a, fill: jnp.pad(a, (0, pad), constant_values=fill)  # noqa: E731
+            return _further_windows(
+                (bound, more, k, out_dtype), (xt, experts, padded(sorted_weights, 0.0)),
+                (padded(order, 0), padded(by_expert, held + 1), ints[2], ends),
+            )
+
+        more_out, done = jax.lax.cond(
+            ends[-1] > bound, further, lambda: (jnp.zeros_like(out), jnp.zeros((), jnp.int32))
         )
-        out, computed = out + more, computed + done
+        out, computed = out + more_out, computed + done
     return out, computed
 
 
@@ -425,6 +480,32 @@ def rows_computed(by_expert, group_sizes):
     return jnp.sum(group == by_expert, dtype=jnp.int32)
 
 
+def balance_bias_rule(rate: float):
+    """``rule(state, aux) -> state`` for ``make_train_step(state_rule=)``: the
+    balance without an auxiliary loss. After a step, in every expert layer,
+    ``b_e += rate * sign(mean_e'(c_e') - c_e)`` with ``c`` the step's own
+    assignment counts over all experts (``aux["expert_counts"]``, layers x
+    experts, in the order of the blocks): an expert that took more than the
+    mean is chosen a little less readily at the next step. ``state`` is what
+    the step carries beside ``params``; its ``route_bias`` collection holds one
+    ``bias`` an expert layer (``DroplessMoE(select_bias=True)``)."""
+
+    def rule(state, aux):
+        counts = aux["expert_counts"].astype(jnp.float32)
+        blocks = sorted(state["route_bias"], key=lambda name: int(name[len("block"):]))
+        if len(blocks) != counts.shape[0]:
+            raise ValueError(f"{len(blocks)} biases for {counts.shape[0]} expert layers")
+        moved = {
+            name: {"moe": {"bias": state["route_bias"][name]["moe"]["bias"] + rate * jnp.sign(
+                jnp.mean(counts[j]) - counts[j]
+            )}}
+            for j, name in enumerate(blocks)
+        }
+        return {**state, "route_bias": moved}
+
+    return rule
+
+
 def record_routing(aux) -> None:
     """Count one step's routing in the telemetry registry from the host copy
     of a loss's auxiliary outputs (:func:`heat_tpu.nn.causal_lm_loss`):
@@ -434,7 +515,9 @@ def record_routing(aux) -> None:
     expert: ``assignments_due - assignments_computed``, 0 for the dropless
     layer), ``moe.steps``, and ``moe.load_max_over_mean`` summed over the
     steps (the busiest expert's count over the mean count, worst layer, over
-    all experts). Where the layers hold a share of their experts, also
+    all experts), and where the loss gives it ``moe.route_bias_max_abs`` (a
+    high-water mark: the largest magnitude a selection bias has reached).
+    Where the layers hold a share of their experts, also
     ``moe.held_assignments`` (the step's assignments on held experts) and
     ``moe.held_share`` (that over tokens x top-k x layers, summed over the
     steps: 1/16 a step for an even routing over sixteen shares). Aux without
@@ -447,6 +530,8 @@ def record_routing(aux) -> None:
     reg.add("moe.assignments", float(aux["assignments_due"]))
     reg.add("moe.dropped", float(aux["assignments_due"]) - float(aux["assignments_computed"]))
     reg.add("moe.load_max_over_mean", float((counts.max(axis=-1) / counts.mean(axis=-1)).max()))
+    if "route_bias_max_abs" in aux:
+        reg.high_water("moe.route_bias_max_abs", float(aux["route_bias_max_abs"]))
     if "assignments_routed" in aux:
         reg.add("moe.held_assignments", float(aux["assignments_due"]))
         reg.add("moe.held_share", float(aux["assignments_due"]) / float(aux["assignments_routed"]))

@@ -33,10 +33,17 @@ pattern: which mixer the blocks of one period take, ``"attention"`` or
 ``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields),
 ``ffn`` (``"swiglu"`` | ``"moe"``, the dropless top-k expert layer of
 :mod:`heat_tpu.nn.moe`, with ``norm_topk``, ``shared_d_ff`` and
-``experts_held``), ``init_std`` and ``accum_dtype``. The defaults are the
+``experts_held``; ``router_score``, ``route_scale``, ``router_bias`` and
+``shared_gate`` for a sigmoid router whose choice a bias corrects and an
+ungated shared expert), ``windows`` and ``rotary`` (one period each, beside
+``mixers``: the sliding window of a block's attention, ``None`` = full, and
+whether it rotates its queries and keys), ``sandwich_norm`` (four norms a
+block: one more on each branch's output), ``embed_scale``, ``dense_layers``
+and ``dense_d_ff`` (leading blocks with a SwiGLU of that width before the
+expert blocks), ``init_std`` and ``accum_dtype``. The defaults are the
 pre-LN, learned-position, SwiGLU model this module began with, parameter tree
-and numerics unchanged. :func:`olmoe_1b_7b` and :func:`qwen3_next_80b_a3b`
-name the published configurations; :func:`causal_lm_loss` is their training
+and numerics unchanged. :func:`olmoe_1b_7b`, :func:`qwen3_next_80b_a3b` and
+:func:`trinity_mini` name the published configurations; :func:`causal_lm_loss` is their training
 loss.
 
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
@@ -54,6 +61,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from .deltanet import GatedDeltaNet
 from .functional import blocked_cross_entropy
 from .moe import DroplessMoE
@@ -122,14 +130,33 @@ def rotary(x, theta, fraction: float = 1.0):
     return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
 
 
-def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
+def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window=None):
+    """The attention core under the scope ``attn.full`` or, with a ``window``,
+    ``attn.window``; the counters ``attn.full.kernel``, ``attn.window.kernel``
+    (the Pallas kernels) and ``attn.window.xla`` (the masked XLA form) say once
+    a trace which ran, ``attn.window.blocks_visited`` / ``.blocks_live`` what
+    the windowed grid covers (:func:`heat_tpu.parallel.pallas_attention.window_grid`)."""
+    with jax.named_scope("attn.full" if window is None else "attn.window"):
+        return _attend_scoped(
+            q, k, v, impl=impl, causal=causal, comm=comm, block_size=block_size,
+            flash_bwd_impl=flash_bwd_impl, window=window,
+        )
+
+
+def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window):
     from ..parallel import (
         flash_attention,
         local_attention,
         ring_attention,
         ulysses_attention,
     )
+    from ..parallel.pallas_attention import window_grid
 
+    count = telemetry.get_registry().add
+    if impl in ("ring", "ulysses") and window is not None:
+        raise ValueError(
+            f"attn_impl {impl!r} has no sliding window: a windowed layer takes 'flash' or 'local'"
+        )
     if impl != "flash" and k.shape[2] != q.shape[2]:
         # only the flash kernels read a group's key-value head by index
         k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2) for a in (k, v))
@@ -139,8 +166,15 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
             "block_q": block_size, "block_k": block_size,
         }
         attend = functools.partial(
-            flash_attention, causal=causal, bwd_impl=flash_bwd_impl, **blocks
+            flash_attention, causal=causal, bwd_impl=flash_bwd_impl, window=window, **blocks
         )
+        if window is None:
+            count("attn.full.kernel")
+        else:
+            count("attn.window.kernel")
+            visited, live = window_grid(q.shape[1], k.shape[1], window, block_size, block_size)
+            count("attn.window.blocks_visited", visited)
+            count("attn.window.blocks_live", live)
         if comm is not None and comm.size > 1:
             # data parallel: the kernel runs on each chip's batch shard. A
             # bare pallas_call is opaque to the SPMD partitioner, which
@@ -159,8 +193,10 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl):
             q, k, v, comm=comm, causal=causal,
             block_size=512 if block_size is None else block_size,
         )
+    if window is not None:
+        count("attn.window.xla")
     return local_attention(
-        q, k, v, causal=causal,
+        q, k, v, causal=causal, window=window,
         block_size=512 if block_size is None else block_size,
     )
 
@@ -196,6 +232,7 @@ class MultiHeadAttention(nn.Module):
     gate: bool = False  # out = (attention * sigmoid(g)) W_o, g projected beside q
     matrix_init: Any = None
     out_init: Any = None
+    window: Optional[int] = None  # position t sees t - window < j <= t; None: every j <= t
 
     @nn.compact
     def __call__(self, x):
@@ -231,7 +268,7 @@ class MultiHeadAttention(nn.Module):
         o = _attend(
             q, k, v, impl=self.attn_impl, causal=self.causal, comm=self.comm,
             flash_bwd_impl=self.flash_bwd_impl,
-            block_size=self.block_size,
+            block_size=self.block_size, window=self.window,
         )
         if self.gate:
             with jax.named_scope("attn.gate"):
@@ -280,6 +317,12 @@ class TransformerBlock(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None
     init_std: Optional[float] = None  # None: each layer's own default
     out_init_std: Optional[float] = None  # the matrices that write into the residual stream
+    window: Optional[int] = None
+    sandwich_norm: bool = False  # x + norm(mixer(norm(x))); x + norm(ffn(norm(x)))
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    router_bias: bool = False
+    shared_gate: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -288,33 +331,38 @@ class TransformerBlock(nn.Module):
         eps = 1e-6 if self.norm_eps is None else self.norm_eps
         matrix = _matrix_init(self.init_std)
         out = _matrix_init(self.init_std if self.out_init_std is None else self.out_init_std)
+        # the norm on a branch's output, where the block has four
+        after = lambda name, y: _norm(self.norm, self.norm_eps, stream, name)(y) if self.sandwich_norm else y  # noqa: E731
         h = _norm(self.norm, self.norm_eps, stream, "ln1")(x)
         if self.mixer == "deltanet":
-            x = x + GatedDeltaNet(
+            x = x + after("ln1_post", GatedDeltaNet(
                 self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim, self.gdn_value_dim,
                 self.gdn_conv, eps, self.dtype, self.accum_dtype, matrix_init=matrix, out_init=out, name="gdn",
-            )(h)
+            )(h))
         elif self.mixer == "attention":
-            x = x + MultiHeadAttention(
+            x = x + after("ln1_post", MultiHeadAttention(
                 self.num_heads, self.attn_impl, self.causal, self.comm,
                 self.block_size, self.dtype, self.flash_bwd_impl,
                 eps if self.qk_norm else None, self.rope_theta, self.accum_dtype,
                 self.num_kv_heads, self.head_dim,
                 self.qk_norm if isinstance(self.qk_norm, str) else None,
-                self.norm, self.rotary_fraction, self.attn_gate, matrix, out,
+                self.norm, self.rotary_fraction, self.attn_gate, matrix, out, self.window,
                 name="attn",
-            )(h)
+            )(h))
         else:
             raise ValueError(f"mixer must be 'attention' or 'deltanet', got {self.mixer!r}")
         h = _norm(self.norm, self.norm_eps, stream, "ln2")(x)
         d_ff = int(d_model * self.mlp_ratio) if self.d_ff is None else self.d_ff
         if self.ffn == "moe":
-            return x + DroplessMoE(
+            return x + after("ln2_post", DroplessMoE(
                 self.num_experts, self.experts_per_token, d_ff,
                 dtype=self.dtype, accum_dtype=self.accum_dtype,
                 norm_topk=self.norm_topk, shared_d_ff=self.shared_d_ff,
-                experts_held=self.experts_held, matrix_init=matrix, out_init=out, name="moe",
-            )(h)
+                experts_held=self.experts_held, matrix_init=matrix, out_init=out,
+                score=self.router_score, route_scale=self.route_scale,
+                shared_gate=self.shared_gate, select_bias=self.router_bias,
+                name="moe",
+            )(h))
         if self.ffn != "swiglu":
             raise ValueError(f"ffn must be 'swiglu' or 'moe', got {self.ffn!r}")
         dense = lambda width, name, init: nn.Dense(  # noqa: E731
@@ -322,7 +370,7 @@ class TransformerBlock(nn.Module):
             dot_general=_dot_general(self.accum_dtype), **_given(init),
         )
         h = nn.silu(dense(d_ff, "gate", matrix)(h)) * dense(d_ff, "up", matrix)(h)  # SwiGLU
-        return x + dense(d_model, "down", out)(h)
+        return x + after("ln2_post", dense(d_model, "down", out)(h))
 
 
 class TransformerLM(nn.Module):
@@ -373,9 +421,31 @@ class TransformerLM(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None  # (first, count): this chip's share of every expert layer
     init_std: Optional[float] = None
     out_init_std: Optional[float] = None
+    # beside ``mixers``, one period each: the sliding window of block i's
+    # attention (None: full) and whether it rotates q and k (positions="rope")
+    windows: Tuple[Optional[int], ...] = (None,)
+    rotary: Tuple[bool, ...] = (True,)
+    sandwich_norm: bool = False
+    embed_scale: Optional[float] = None  # h_0 = E[token] * embed_scale
+    dense_layers: int = 0  # leading blocks with a SwiGLU of width dense_d_ff in place of ``ffn``
+    dense_d_ff: Optional[int] = None
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    router_bias: bool = False
+    shared_gate: bool = True
 
     def mixer_of(self, i: int) -> str:
         return self.mixers[i % len(self.mixers)]
+
+    def window_of(self, i: int) -> Optional[int]:
+        return self.windows[i % len(self.windows)]
+
+    def rotates(self, i: int) -> bool:
+        return self.positions == "rope" and self.rotary[i % len(self.rotary)]
+
+    def expert_layers(self) -> Tuple[int, ...]:
+        """The blocks whose feed-forward is the expert layer."""
+        return tuple(range(self.dense_layers, self.num_layers)) if self.ffn == "moe" else ()
 
     @nn.compact
     def __call__(self, tokens, head: bool = True):
@@ -395,6 +465,8 @@ class TransformerLM(nn.Module):
             self.vocab_size, self.d_model, dtype=stream, name="embed",
             **_given(_matrix_init(self.init_std), "embedding_init"),
         )(tokens)
+        if self.embed_scale is not None:
+            x = x * jnp.asarray(self.embed_scale, x.dtype)
         if self.positions == "learned":
             pos = nn.Embed(self.max_len, self.d_model, dtype=stream, name="pos")(
                 jnp.arange(tokens.shape[-1])
@@ -413,16 +485,20 @@ class TransformerLM(nn.Module):
         else:
             block_cls = TransformerBlock
         for i in range(self.num_layers):
+            dense = i < self.dense_layers
             x = block_cls(
                 self.num_heads, self.mlp_ratio, self.attn_impl, True,
                 self.comm, self.block_size, self.dtype,
                 self.flash_bwd_impl, self.norm, self.norm_eps, self.qk_norm,
-                self.rope_theta if self.positions == "rope" else None,
-                self.ffn, self.d_ff, self.num_experts, self.experts_per_token,
+                self.rope_theta if self.rotates(i) else None,
+                "swiglu" if dense else self.ffn, self.dense_d_ff if dense else self.d_ff,
+                self.num_experts, self.experts_per_token,
                 self.accum_dtype, self.mixer_of(i), self.num_kv_heads, self.head_dim,
                 self.rotary_fraction, self.attn_gate, self.gdn_key_heads, self.gdn_value_heads,
                 self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv, self.norm_topk,
                 self.shared_d_ff, self.experts_held, self.init_std, self.out_init_std,
+                self.window_of(i), self.sandwich_norm, self.router_score, self.route_scale,
+                self.router_bias, self.shared_gate,
                 name=f"block{i}",
             )(x)
         x = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
@@ -494,6 +570,45 @@ def qwen3_next_80b_a3b(
     return TransformerLM(**{**arch, **fields})
 
 
+def trinity_mini(
+    num_layers: int = 32, experts_held: Optional[Tuple[int, int]] = None, vocab_size: int = 200192, **fields
+) -> TransformerLM:
+    """Trinity-Mini (Arcee, 2025-12, 26B-A3B; ``config.json`` of
+    ``arcee-ai/Trinity-Mini``, ``model_type`` ``afmoe``, equations of HF
+    ``modeling_afmoe.py``) at its published widths: hidden 2048, the embedding
+    scaled by ``sqrt(2048)``; 32 query heads on 4 key-value heads of 128, a
+    plain RMSNorm (eps 1e-5) on each query and key head and a sigmoid gate on
+    the output; blocks in periods of three **sliding-window** layers (window
+    2,048: a position sees itself and the 2,047 before it; rotary positions,
+    theta 10,000) and one **full** layer without positions; four norms a block
+    (before and after each branch); blocks 0 and 1 a SwiGLU of width 6,144,
+    every later block 128 experts of width 1,024 chosen by ``sigmoid(x W_r) +
+    b``, the top 8 weighted by their sigmoid over the eight's sum times 2.826,
+    and an ungated shared expert of width 1,024; untied 200,192-row embedding
+    and head. ``b`` is no parameter: the collection ``route_bias`` beside
+    ``params``, moved after every step by :func:`heat_tpu.nn.balance_bias_rule`
+    (rate 0.001) through ``make_train_step(state_rule=)``. bfloat16 matmul
+    operands, float32 everything else; matrices drawn at 0.02, those that write
+    into the residual stream at ``0.02 / sqrt(2 * 32)``.
+
+    ``num_layers``, ``experts_held`` and ``vocab_size`` are what one chip's
+    share of a deployment sets (as for :func:`qwen3_next_80b_a3b`); ``fields``
+    passes what is not architecture (``attn_impl``, ``comm``, ``remat``, ...)."""
+    arch = dict(
+        vocab_size=vocab_size, d_model=2048, num_heads=32, num_layers=num_layers,
+        max_len=131072, norm="rmsnorm", norm_eps=1e-5, positions="rope", rope_theta=10000.0,
+        qk_norm="head", num_kv_heads=4, head_dim=128, attn_gate=True,
+        windows=(2048, 2048, 2048, None), rotary=(True, True, True, False),
+        sandwich_norm=True, embed_scale=math.sqrt(2048), dense_layers=2, dense_d_ff=6144,
+        ffn="moe", d_ff=1024, num_experts=128, experts_per_token=8, norm_topk=True,
+        router_score="sigmoid", route_scale=2.826, router_bias=True,
+        shared_d_ff=1024, shared_gate=False, experts_held=experts_held,
+        init_std=0.02, out_init_std=0.02 / math.sqrt(2 * 32),
+        dtype=jnp.bfloat16, accum_dtype=jnp.float32, attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
 def causal_lm_loss(
     model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0,
 ):
@@ -513,7 +628,9 @@ def causal_lm_loss(
     gives ``assignments_due``); a model that holds a share of its experts
     (``experts_held``) is due the assignments on those, and gives the layers x
     tokens x top-k as ``assignments_routed``; a model without expert layers
-    gives the three scalars only. ``nn.read_routing(loss, aux)`` brings both to the host and
+    gives the three scalars only; a model whose routers carry a selection bias
+    (``router_bias``: ``params`` then holds the collection ``route_bias``)
+    also gives ``route_bias_max_abs``. ``nn.read_routing(loss, aux)`` brings both to the host and
     counts the routing."""
 
     def loss_fn(params, tokens):
@@ -528,9 +645,7 @@ def causal_lm_loss(
                 hidden.reshape(b * t, -1), params["params"]["lm_head"]["kernel"],
                 targets, weights, dtype=model.dtype,
             )
-        layers = [
-            state["aux"][f"block{i}"]["moe"]["moe"][0] for i in range(model.num_layers)
-        ] if model.ffn == "moe" else []
+        layers = [state["aux"][f"block{i}"]["moe"]["moe"][0] for i in model.expert_layers()]
         zero = jnp.zeros((), jnp.float32)
         aux = {"ce": ce, "load_balance": zero, "router_z": zero}
         if layers:
@@ -542,6 +657,8 @@ def causal_lm_loss(
                 aux["assignments_routed"] = aux["assignments_due"]
                 aux["assignments_due"] = sum(a["held"] for a in layers)
             aux["assignments_computed"] = sum(a["computed"] for a in layers)
+            if "route_bias" in params:  # the selection biases a rule moves: how far they have gone
+                aux["route_bias_max_abs"] = jnp.max(jnp.abs(jnp.stack(jax.tree.leaves(params["route_bias"]))))
         loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
         return loss, aux
 

@@ -33,12 +33,13 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _block_attn(q, k, v, m, l, o, q_start, k_start, scale, causal, kv_len_valid):
+def _block_attn(q, k, v, m, l, o, q_start, k_start, scale, causal, kv_len_valid, window=None):
     """One flash-attention accumulation step on local blocks.
 
     q: (B, Tq, H, D); k, v: (B, Tk, H, D); m, l: (B, H, Tq); o like q.
     ``q_start``/``k_start`` are the blocks' global sequence offsets (traced
-    scalars) used for causal masking; ``kv_len_valid`` masks K tail padding.
+    scalars) used for causal masking; ``kv_len_valid`` masks K tail padding;
+    with a ``window`` a query sees the ``window`` keys that end at itself.
     """
     # MXU dots run in the INPUT dtype with f32 accumulation — an up-front
     # astype(f32) would force true-f32 MXU passes at ~1/4 throughput (the
@@ -52,6 +53,8 @@ def _block_attn(q, k, v, m, l, o, q_start, k_start, scale, causal, kv_len_valid)
     if causal:
         q_pos = q_start + jnp.arange(q.shape[1])
         mask = mask & (k_pos[None, :] <= q_pos[:, None])
+        if window is not None:
+            mask = mask & (k_pos[None, :] > q_pos[:, None] - window)
     s = jnp.where(mask[None, None, :, :], s, NEG_INF)
 
     m_new = jnp.maximum(m, s.max(axis=-1))
@@ -86,14 +89,19 @@ def local_attention(
     scale: Optional[float] = None,
     block_size: int = 512,
     kv_valid: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise (flash) attention on one device. ``(B, T, H, D)`` layout.
 
     K/V are processed in ``block_size`` chunks with online softmax — the same
     accumulator the distributed variants carry around the ring, so numerics
     are identical across all three entry points. K/V positions ``>= kv_valid``
-    are treated as padding and masked out.
+    are treated as padding and masked out. ``window`` (with ``causal``):
+    position ``t`` sees the keys ``t - window < j <= t`` (a mask here: every
+    key block is visited; the Pallas kernels skip the blocks outside the band).
     """
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"a window (got {window!r}) is a whole number of positions >= 1 and needs causal=True")
     b, tq, h, d = q.shape
     tk = k.shape[1]
     kv_valid = tk if kv_valid is None else kv_valid
@@ -121,7 +129,7 @@ def local_attention(
         # inputs keep their dtype: the MXU dots inside _block_attn accumulate
         # in f32 via preferred_element_type (bf16 inputs run full-rate)
         return _block_attn(
-            q, kb, vb, m, l, o, 0, k_start, scale, causal, kv_valid,
+            q, kb, vb, m, l, o, 0, k_start, scale, causal, kv_valid, window,
         )
 
     m, l, o = jax.lax.fori_loop(0, nblk, body, (m, l, o))

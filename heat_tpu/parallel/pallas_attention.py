@@ -41,26 +41,121 @@ _LANES = 128  # TPU lane width: scratch rows are broadcast across it
 # heat_tpu enables jax_enable_x64; a Python-int 0 in an index map then traces
 # as an i64 constant, which Mosaic cannot legalize — pin index literals to i32
 _I0 = np.int32(0)
+# tiles of the windowed form (``flash_attention(window=)`` with no blocks given). TPU v5e, 32 query heads
+# on 4 key-value heads of 128, 16,384 positions, window 2,048, bfloat16; forward / forward + dq + dk, dv in ms
+# (my chip run, PR 32, call 2): 1024 x 1024 **9.30 / 27.64**; 512 x 1024 10.01 / 28.71; 256 x 1024 11.11 / 31.23;
+# 512 x 512 13.44 / 29.69; 1024 x 512 14.18 / 34.49; 256 x 512 14.72 / 35.13; 2048 x 512 15.86 / 40.23;
+# 512 x 256 24.34 / 47.65; 256 x 256 24.25 / 53.82; 1024 x 256 25.67 / 48.74 (the full causal form at its own
+# 512 x 1024: 26.91 / 88.99). Wide key blocks win although a band of three of them (3,072 keys for the 2,048 a
+# query sees) wastes more of the edge blocks than five of 512 do: a grid step's fixed cost outweighs it.
+_WINDOW_BLOCK_Q = 1024
+_WINDOW_BLOCK_K = 1024
+
+
+class _Band:
+    """The static geometry of a windowed grid: position ``t`` sees keys ``t -
+    window < j <= t``, so a block of one axis meets a band of blocks of the
+    other, and the grid's sequential axis runs over that band alone (``steps``
+    blocks at most), its block index a function of the other axis's.
+
+    ``keys``: for query block ``i`` the key blocks ``lo(i) .. hi(i)``;
+    otherwise for key block ``i`` the query blocks ``lo(i) .. hi(i)``. ``rows``
+    and ``cols`` are the block sizes of the axis given and of the band's axis,
+    ``n`` how many blocks the band's axis has. ``lo`` and ``hi`` take int32
+    tracers (index maps and kernel bodies: literals pinned to int32); a step
+    past ``hi`` reads block ``hi`` again (no new DMA) and computes nothing.
+    ``visited`` and ``live`` count, over the rows, the grid's steps and those
+    of them whose block holds a visible pair."""
+
+    def __init__(self, window, rows, cols, n, n_rows, keys):
+        self.window, self.rows, self.cols, self.n, self.keys = window, rows, cols, n, keys
+        spans = [self._span(i) for i in range(n_rows)]
+        self.steps = max(1, max(hi - lo + 1 for lo, hi in spans))
+        self.live = sum(max(0, hi - lo + 1) for lo, hi in spans)
+        self.visited = self.steps * n_rows
+
+    def _span(self, i):  # Python ints
+        if self.keys:
+            return max(i * self.rows - (self.window - 1), 0) // self.cols, min((i * self.rows + self.rows - 1) // self.cols, self.n - 1)
+        return min(i * self.rows // self.cols, self.n - 1), min((i * self.rows + self.rows + self.window - 2) // self.cols, self.n - 1)
+
+    def lo(self, i):
+        rows, cols = np.int32(self.rows), np.int32(self.cols)
+        if self.keys:
+            return jax.lax.div(jnp.maximum(i * rows - np.int32(self.window - 1), _I0), cols)
+        return jnp.minimum(jax.lax.div(i * rows, cols), np.int32(self.n - 1))
+
+    def hi(self, i):
+        rows, cols = np.int32(self.rows), np.int32(self.cols)
+        reach = self.rows - 1 if self.keys else self.rows + self.window - 2
+        return jnp.minimum(jax.lax.div(i * rows + np.int32(reach), cols), np.int32(self.n - 1))
+
+    def block(self, i, step):
+        """The band's block that grid step ``step`` of row-block ``i`` reads."""
+        return jnp.minimum(self.lo(i) + step, self.hi(i))
+
+
+def _visible(iq, ik, *, causal, kv_valid, block_q, block_k, window):
+    """The (bq, bk) mask of the pairs a score block may keep: keys before
+    ``kv_valid``, not after the query (``causal``) and, with a ``window``,
+    fewer than ``window`` positions before it."""
+    k_pos = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+    mask = k_pos < kv_valid
+    if causal:
+        q_pos = iq * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0
+        )
+        mask = mask & (k_pos <= q_pos)
+        if window is not None:
+            mask = mask & (k_pos > q_pos - np.int32(window))
+    return mask
+
+
+def _inside_band(iq, ik, *, kv_valid, block_q, block_k, window):
+    """Whether every pair of score block (iq, ik) is visible: such a block
+    takes no mask."""
+    first_q, first_k = iq * block_q, ik * block_k
+    return (
+        (first_k + (block_k - 1) <= first_q)
+        & (first_k > first_q + (block_q - 1 - window))
+        & (first_k + block_k <= kv_valid)
+    )
+
+
+def _when_live(live, iq, ik, accumulate, *, kv_valid, block_q, block_k, window):
+    """Run ``accumulate(masked)`` where the block is live: with a window, the
+    blocks wholly inside the band without their mask."""
+    if window is None:
+        pl.when(live)(functools.partial(accumulate, True))
+        return
+    inside = _inside_band(
+        iq, ik, kv_valid=kv_valid, block_q=block_q, block_k=block_k, window=window
+    )
+    pl.when(live & inside)(functools.partial(accumulate, False))
+    pl.when(live & jnp.logical_not(inside))(functools.partial(accumulate, True))
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
-    *, scale, causal, kv_valid, block_q, block_k,
+    *, scale, causal, kv_valid, block_q, block_k, window=None, band=None,
 ):
     """Grid = (B, H, num_q_blocks, num_k_blocks); last axis is sequential.
 
     Refs arrive as (1, 1, block, D) VMEM tiles. The (m, l, acc) scratch
     persists across the K axis — initialised at ik == 0, finalised into
-    ``o_ref`` at the last K block.
+    ``o_ref`` at the last K block. With a ``window`` the last axis runs over
+    the steps of ``band`` (:class:`_Band`) and the key block follows from it.
     """
     iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    ik = step = pl.program_id(3)
     nk = pl.num_programs(3)
     # Mosaic legalizes only f32 float constants — keep every scalar f32
     neg_inf = jnp.float32(NEG_INF)
     half_neg = jnp.float32(NEG_INF / 2)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_s[:] = jnp.full_like(m_s, neg_inf)
         l_s[:] = jnp.zeros_like(l_s)
@@ -69,13 +164,15 @@ def _flash_kernel(
     # causal skip: a K block strictly above the diagonal band contributes
     # nothing — skip its MXU work entirely (DMA still streams it; the win is
     # ~2× compute on long causal sequences)
-    if causal:
+    if window is not None:
+        ik = band.lo(iq) + step
+        live = ik <= band.hi(iq)
+    elif causal:
         live = ik * block_k <= iq * block_q + (block_q - 1)
     else:
         live = ik >= 0  # always true, keeps one code path
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         # MXU dots run in the INPUT dtype with f32 accumulation
         # (preferred_element_type): bf16 inputs hit the full-rate bf16 MXU
         # (an up-front astype(f32) would force true-f32 passes at ~1/4 the
@@ -88,16 +185,12 @@ def _flash_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * jnp.float32(scale)  # (bq, bk), f32
 
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_pos < kv_valid
-        if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+        if masked:
+            mask = _visible(
+                iq, ik, causal=causal, kv_valid=kv_valid, block_q=block_q,
+                block_k=block_k, window=window,
             )
-            mask = mask & (k_pos <= q_pos)
-        s = jnp.where(mask, s, neg_inf)
+            s = jnp.where(mask, s, neg_inf)
 
         m_prev = m_s[:, 0:1]  # (bq, 1), lanes hold copies
         l_prev = l_s[:, 0:1]
@@ -105,7 +198,7 @@ def _flash_kernel(
         m_new = jnp.maximum(m_prev, m_cur)
         zero = jnp.float32(0.0)
         m_safe = jnp.where(m_new <= half_neg, zero, m_new)
-        p = jnp.where(mask, jnp.exp(s - m_safe), zero)
+        p = jnp.where(mask, jnp.exp(s - m_safe), zero) if masked else jnp.exp(s - m_safe)
         alpha = jnp.where(m_prev <= half_neg, zero, jnp.exp(m_prev - m_safe))
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         # PV in v's dtype (standard flash practice): for bf16 v the f32
@@ -120,7 +213,12 @@ def _flash_kernel(
         l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
         acc_s[:] = acc_s[:] * alpha + pv
 
-    @pl.when(ik == nk - 1)
+    _when_live(
+        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        block_k=block_k, window=window,
+    )
+
+    @pl.when(step == nk - 1)
     def _finalize():
         l_fin = l_s[:, 0:1]
         denom = jnp.where(l_fin == jnp.float32(0.0), jnp.float32(1.0), l_fin)
@@ -195,9 +293,16 @@ def _pad_blocks(q, k, v, t_q, t_k, d, block_q, block_k):
     return q, k, v, block_q, block_k, pq, pk, d + pd
 
 
+def _key_band(window, t_q, t_k, block_q, block_k):
+    """The band of key blocks a query block meets (None without a window)."""
+    if window is None:
+        return None
+    return _Band(window, block_q, block_k, t_k // block_k, t_q // block_q, keys=True)
+
+
 def _flash_forward(
     q, k, v, scale, causal, kv_valid, block_q, block_k, interpret,
-    return_lse=False,
+    return_lse=False, window=None,
 ):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
@@ -208,11 +313,17 @@ def _flash_forward(
     )
 
     grid = (b, h, (t_q + pq) // block_q, (t_k + pk) // block_k)
+    of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0)  # noqa: E731
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, kv_valid=kv_valid,
         block_q=block_q, block_k=block_k,
     )
+    if window is not None:
+        band = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
+        grid = grid[:3] + (band.steps,)
+        of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), band.block(qi, ki), _I0)  # noqa: E731
+        kernel = functools.partial(kernel, window=window, band=band)
     o_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
@@ -249,14 +360,8 @@ def _flash_forward(
                 (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(
-                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
-                memory_space=pltpu.VMEM,
-            ),
+            pl.BlockSpec((1, 1, block_k, dp), of_k, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_k, dp), of_k, memory_space=pltpu.VMEM),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -269,7 +374,7 @@ def _flash_forward(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "swa_fwd",
     )(q, k, v)
     if return_lse:
         out, lse = res
@@ -278,30 +383,30 @@ def _flash_forward(
     return res[:, :, :t_q, :d]
 
 
-def _rebuild_probs(q, k, lse, iq, ik, *, scale, causal, kv_valid, block_q, block_k):
+def _rebuild_probs(
+    q, k, lse, iq, ik, *, scale, causal, kv_valid, block_q, block_k, window=None, masked=True
+):
     """Shared backward-pass probability reconstruction: the (bq, bk) score
-    block, kv_valid + causal masking, and ``p = exp(s − lse)`` — one
-    definition so the dq and dk/dv kernels can never desynchronize."""
+    block, kv_valid + causal (+ window) masking, and ``p = exp(s − lse)`` — one
+    definition so the dq and dk/dv kernels can never desynchronize. A block
+    wholly inside a window's band (``masked`` false) takes no mask."""
     neg_inf = jnp.float32(NEG_INF)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * jnp.float32(scale)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
+    if not masked:
+        return jnp.exp(s - lse)
+    mask = _visible(
+        iq, ik, causal=causal, kv_valid=kv_valid, block_q=block_q, block_k=block_k,
+        window=window,
     )
-    mask = k_pos < kv_valid
-    if causal:
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        mask = mask & (k_pos <= q_pos)
     s = jnp.where(mask, s, neg_inf)
     p = jnp.where(mask, jnp.exp(s - lse), jnp.float32(0.0))
     return p
 
 
 def _bwd_block_terms(
-    refs, iq, ik, *, scale, causal, kv_valid, block_q, block_k
+    refs, iq, ik, *, scale, causal, kv_valid, block_q, block_k, window=None, masked=True
 ):
     """Shared backward block math: unpack the (1, 1, blk, D) refs, rebuild
     p, compute ``dP = dO Vᵀ`` and ``dS = P ∘ (dP − D) · scale`` with the
@@ -317,7 +422,7 @@ def _bwd_block_terms(
 
     p = _rebuild_probs(
         q, k, lse, iq, ik, scale=scale, causal=causal, kv_valid=kv_valid,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, window=window, masked=masked,
     )  # (bq, bk)
     p_mx = p if do.dtype == jnp.float32 else p.astype(do.dtype)
     dp = jax.lax.dot_general(
@@ -330,9 +435,10 @@ def _bwd_block_terms(
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, dq_acc,
-    *, scale, causal, kv_valid, block_q, block_k,
+    *, scale, causal, kv_valid, block_q, block_k, window=None, band=None,
 ):
-    """dQ pass. Grid = (B, H, num_q_blocks, num_k_blocks), last sequential.
+    """dQ pass. Grid = (B, H, num_q_blocks, num_k_blocks), last sequential
+    (with a ``window``: the steps of the key ``band``, as in the forward).
 
     p is rebuilt from the saved log-sum-exp (``p = exp(s − lse)``), then
     ``dS = P ∘ (dP − D)`` and ``dQ += scale · dS Kᵀ`` accumulate in VMEM
@@ -340,31 +446,38 @@ def _bwd_dq_kernel(
     dots in the input dtype with f32 accumulation.
     """
     iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    ik = step = pl.program_id(3)
     nk = pl.num_programs(3)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    if causal:
+    if window is not None:
+        ik = band.lo(iq) + step
+        live = ik <= band.hi(iq)
+    elif causal:
         live = ik * block_k <= iq * block_q + (block_q - 1)
     else:
         live = ik >= 0
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         _, k, _, _, _, ds_mx = _bwd_block_terms(
             (q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref), iq, ik,
             scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window, masked=masked,
         )
         dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
             ds_mx, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(ik == nk - 1)
+    _when_live(
+        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        block_k=block_k, window=window,
+    )
+
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -372,14 +485,15 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, scale, causal, kv_valid, block_q, block_k, q_blocks, group,
+    *, scale, causal, kv_valid, block_q, block_k, q_blocks, group, window=None, band=None,
 ):
     """dK/dV pass. Grid = (B, H_kv, num_k_blocks, group * num_q_blocks), last
     sequential: the transposed-probability form — ``dV += Pᵀ dO`` and
     ``dK += scale · dSᵀ Q`` accumulate per K block across the Q axis, and
     across the ``group`` query heads that read this key-value head (their Q
     blocks follow one another on the sequential axis; ``q_blocks`` is how
-    many one head has)."""
+    many one head has). With a ``window`` a head's steps are those of the
+    query ``band`` of this key block (``q_blocks`` = ``band.steps``)."""
     ik = pl.program_id(2)
     step = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -391,20 +505,22 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if causal:
+    if window is not None:
+        iq = band.lo(ik) + iq
+        live = iq <= band.hi(ik)
+    elif causal:
         live = iq * block_q + (block_q - 1) >= ik * block_k
     else:
         live = iq >= 0
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         # same (bq, bk) score orientation as the dq pass — the q-dim
         # contractions below transpose implicitly via dot_general dimension
         # numbers (no Mosaic-side transposes)
         q, _, _, do, p_mx, ds_mx = _bwd_block_terms(
             (q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref), iq, ik,
             scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window, masked=masked,
         )
         # dV += Pᵀ dO: contract the q dim of both operands
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
@@ -416,6 +532,11 @@ def _bwd_dkv_kernel(
             ds_mx, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _when_live(
+        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        block_k=block_k, window=window,
+    )
 
     @pl.when(step == nq - 1)
     def _finalize():
@@ -449,7 +570,7 @@ def _bwd_prologue(res, g, block_q, block_k):
 def _bwd_fused_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
     dk_acc, dv_acc,
-    *, scale, causal, kv_valid, block_q, block_k,
+    *, scale, causal, kv_valid, block_q, block_k, window=None, band=None,
 ):
     """Single-pass backward. Grid = (B, H, num_k_blocks, num_q_blocks),
     the last two sequential.
@@ -464,29 +585,31 @@ def _bwd_fused_kernel(
     block stays resident, which is why the fused path is gated on
     ``_fused_bwd_fits``."""
     ik = pl.program_id(2)
-    iq = pl.program_id(3)
+    iq = step = pl.program_id(3)  # with a window: the step inside the query band
     nq = pl.num_programs(3)
 
-    @pl.when((ik == 0) & (iq == 0))
+    @pl.when((ik == 0) & (step == 0))
     def _init_dq():
         dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init_kv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if causal:
+    if window is not None:
+        iq = band.lo(ik) + step
+        live = iq <= band.hi(ik)
+    elif causal:
         live = iq * block_q + (block_q - 1) >= ik * block_k
     else:
         live = iq >= 0
 
-    @pl.when(live)
-    def _accumulate():
+    def _accumulate(masked):
         q, k, _, do, p_mx, ds_mx = _bwd_block_terms(
             (q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref), iq, ik,
             scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, window=window, masked=masked,
         )
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
             p_mx, do, (((0,), (0,)), ((), ())),
@@ -504,7 +627,12 @@ def _bwd_fused_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(iq == nq - 1)
+    _when_live(
+        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        block_k=block_k, window=window,
+    )
+
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -520,8 +648,15 @@ def _fused_bwd_fits(t_q_padded: int, dp: int) -> bool:
     return t_q_padded * dp * 4 <= _FUSED_BWD_DQ_BYTES
 
 
+def _query_band(window, t_q, t_k, block_q, block_k):
+    """The band of query blocks a key block meets (None without a window)."""
+    if window is None:
+        return None
+    return _Band(window, block_k, block_q, t_q // block_q, t_k // block_k, keys=False)
+
+
 def _flash_bwd_fused(
-    scale, causal, kv_valid, block_q, block_k, interpret, res, g
+    scale, causal, kv_valid, block_q, block_k, interpret, res, g, window=None
 ):
     """Fused-kernel backward; same contract as the two-pass `_flash_bwd`."""
     q, k, v, out, lse = res
@@ -535,10 +670,17 @@ def _flash_bwd_fused(
     h_kv = k.shape[1]
     group = h // h_kv
     grid = (b, h, (t_k + pk) // block_k, tq_p // block_q)
-    qo_spec = pl.BlockSpec(
-        (1, 1, block_q, dp), lambda bi, hi, ki, qi: (bi, hi, qi, _I0),
-        memory_space=pltpu.VMEM,
+    of_q = lambda bi, hi, ki, qi: (bi, hi, qi, _I0)  # noqa: E731
+    kernel = functools.partial(
+        _bwd_fused_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
+        block_q=block_q, block_k=block_k,
     )
+    if window is not None:
+        band = _query_band(window, tq_p, t_k + pk, block_q, block_k)
+        grid = grid[:3] + (band.steps,)
+        of_q = lambda bi, hi, ki, qi: (bi, hi, band.block(ki, qi), _I0)  # noqa: E731
+        kernel = functools.partial(kernel, window=window, band=band)
+    qo_spec = pl.BlockSpec((1, 1, block_q, dp), of_q, memory_space=pltpu.VMEM)
     # K and V are read by the group's head; dk and dv are written a query
     # head each (the resident dQ block pins a head to its grid row) and
     # summed over the group after the kernel
@@ -550,19 +692,13 @@ def _flash_bwd_fused(
         (1, 1, block_k, dp), lambda bi, hi, ki, qi: (bi, hi, ki, _I0),
         memory_space=pltpu.VMEM,
     )
-    lm_spec = pl.BlockSpec(
-        (1, 1, block_q, _LANES), lambda bi, hi, ki, qi: (bi, hi, qi, _I0),
-        memory_space=pltpu.VMEM,
-    )
+    lm_spec = pl.BlockSpec((1, 1, block_q, _LANES), of_q, memory_space=pltpu.VMEM)
     dq_spec = pl.BlockSpec(
         (1, 1, tq_p, dp), lambda bi, hi, ki, qi: (bi, hi, _I0, _I0),
         memory_space=pltpu.VMEM,
     )
     dq, dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_fused_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
-        ),
+        kernel,
         grid=grid,
         in_specs=[qo_spec, kv_spec, kv_spec, qo_spec, lm_spec, lm_spec],
         out_specs=[dq_spec, dkv_spec, dkv_spec],
@@ -582,7 +718,7 @@ def _flash_bwd_fused(
             ),
         ),
         interpret=interpret,
-        name="flash_bwd_fused",
+        name="flash_bwd_fused" if window is None else "swa_bwd_fused",
     )(qp, kp, vp, do_p, lse, dd_p)
     if group > 1:
         dk, dv = (a.reshape(b, h_kv, group, t_k + pk, dp).sum(axis=2) for a in (dk, dv))
@@ -595,28 +731,28 @@ def _flash_bwd_fused(
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
 def _flash(
-    q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl
+    q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window
 ):
     return _flash_forward(
-        q, k, v, scale, causal, kv_valid, block_q, block_k, interpret
+        q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, window=window
     )
 
 
 def _flash_fwd(
-    q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl
+    q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window
 ):
     out, lse = _flash_forward(
         q, k, v, scale, causal, kv_valid, block_q, block_k, interpret,
-        return_lse=True,
+        return_lse=True, window=window,
     )
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_dispatch(
-    scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, res, g
+    scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window, res, g
 ):
     """Pick the backward implementation. ``"auto"`` takes the fused
     single-pass kernel whenever its resident f32 dQ block fits the VMEM
@@ -630,14 +766,14 @@ def _flash_bwd_dispatch(
         )
     if bwd_impl == "fused":
         return _flash_bwd_fused(
-            scale, causal, kv_valid, block_q, block_k, interpret, res, g
+            scale, causal, kv_valid, block_q, block_k, interpret, res, g, window
         )
     return _flash_bwd(
-        scale, causal, kv_valid, block_q, block_k, interpret, res, g
+        scale, causal, kv_valid, block_q, block_k, interpret, res, g, window
     )
 
 
-def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, window=None):
     """Flash backward as two Pallas kernels (dq; dk/dv) using the saved O
     and log-sum-exp — O(T) memory, every MXU dot in the input dtype (the
     r3 XLA-recompute backward ran true-f32 passes; this is the lm_step MFU
@@ -652,23 +788,25 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
     h_kv = k.shape[1]
     group = h // h_kv
     grid_q = (b, h, (t_q + pq) // block_q, (t_k + pk) // block_k)
+    of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0)  # noqa: E731
+    static = dict(scale=scale, causal=causal, kv_valid=kv_valid, block_q=block_q, block_k=block_k)
+    dq_kernel = functools.partial(_bwd_dq_kernel, **static)
+    if window is not None:
+        keys = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
+        grid_q = grid_q[:3] + (keys.steps,)
+        of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), keys.block(qi, ki), _I0)  # noqa: E731
+        dq_kernel = functools.partial(dq_kernel, window=window, band=keys)
     qo_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
     )
-    kv_spec_q = pl.BlockSpec(
-        (1, 1, block_k, dp), lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0),
-        memory_space=pltpu.VMEM,
-    )
+    kv_spec_q = pl.BlockSpec((1, 1, block_k, dp), of_k, memory_space=pltpu.VMEM)
     lm_spec_q = pl.BlockSpec(
         (1, 1, block_q, _LANES), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
     )
     dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k,
-        ),
+        dq_kernel,
         grid=grid_q,
         in_specs=[qo_spec, kv_spec_q, kv_spec_q, qo_spec, lm_spec_q, lm_spec_q],
         out_specs=qo_spec,
@@ -678,30 +816,34 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "swa_bwd_dq",
     )(qp, kp, vp, do_p, lse, dd_p)
 
     # dk/dv pass: K blocks on the parallel axis; the Q blocks of every query
     # head of the group sequential, so that dk and dv come out summed over them
     q_blocks = (t_q + pq) // block_q
+    queries = _query_band(window, t_q + pq, t_k + pk, block_q, block_k)
+    if window is not None:
+        q_blocks = queries.steps  # a head's steps: the band of this key block
+    in_band = (lambda ki, qi: qi) if window is None else queries.block
     grid_k = (b, h_kv, (t_k + pk) // block_k, group * q_blocks)
     if group == 1:
-        of_q = lambda bi, hi, ki, qi: (bi, hi, qi, _I0)  # noqa: E731
+        of_q = lambda bi, hi, ki, qi: (bi, hi, in_band(ki, qi), _I0)  # noqa: E731
     else:
         def of_q(bi, hi, ki, step):
             nq = np.int32(q_blocks)
-            return (bi, hi * np.int32(group) + jax.lax.div(step, nq), jax.lax.rem(step, nq), _I0)
+            return (bi, hi * np.int32(group) + jax.lax.div(step, nq), in_band(ki, jax.lax.rem(step, nq)), _I0)
     qo_spec_k = pl.BlockSpec((1, 1, block_q, dp), of_q, memory_space=pltpu.VMEM)
     kv_spec_k = pl.BlockSpec(
         (1, 1, block_k, dp), lambda bi, hi, ki, qi: (bi, hi, ki, _I0),
         memory_space=pltpu.VMEM,
     )
     lm_spec_k = pl.BlockSpec((1, 1, block_q, _LANES), of_q, memory_space=pltpu.VMEM)
+    dkv_kernel = functools.partial(_bwd_dkv_kernel, **static, q_blocks=q_blocks, group=group)
+    if window is not None:
+        dkv_kernel = functools.partial(dkv_kernel, window=window, band=queries)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
-            block_q=block_q, block_k=block_k, q_blocks=q_blocks, group=group,
-        ),
+        dkv_kernel,
         grid=grid_k,
         in_specs=[
             qo_spec_k, kv_spec_k, kv_spec_k, qo_spec_k, lm_spec_k, lm_spec_k,
@@ -719,7 +861,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g):
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
     )(qp, kp, vp, do_p, lse, dd_p)
 
     return (
@@ -740,16 +882,19 @@ def flash_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     kv_valid: Optional[int] = None,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     bwd_impl: str = "two_pass",
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention as a hand-tiled Pallas TPU kernel.
 
     Same contract as :func:`heat_tpu.parallel.attention.local_attention`:
     ``(B, T, H, D)`` layout, f32 online softmax, K/V positions >= ``kv_valid``
-    masked as padding. Blocks are clamped for short sequences. ``k`` and ``v``
+    masked as padding. Blocks (``None``: the tuned tiles, 512 x 1024, with a
+    window `_WINDOW_BLOCK_Q` x `_WINDOW_BLOCK_K`) are clamped for short
+    sequences. ``k`` and ``v``
     may have fewer heads than ``q`` (grouped-query attention): query heads
     ``i * g .. i * g + g - 1`` read key-value head ``i``, by index and without
     a repeated copy, and ``dk``, ``dv`` come back summed over each group.
@@ -763,6 +908,14 @@ def flash_attention(
     `_bwd_fused_kernel`), or ``"auto"`` (fused whenever the dQ block fits
     the VMEM budget). The fused path stays opt-in until the on-chip sweep
     (scripts/tpu_tune.py attn_bwd) records it winning.
+
+    ``window`` (with ``causal``): position ``t`` sees the keys ``t - window <
+    j <= t``, itself and the ``window - 1`` before it. The grid's key axis (for
+    dk/dv the query axis) then covers the blocks of that band alone, its block
+    index computed from the other axis's, and blocks wholly inside the band
+    take no mask; the kernels are named ``swa_fwd``, ``swa_bwd_dq``,
+    ``swa_bwd_dkv`` (``swa_bwd_fused``). ``window=None`` is the full form, its
+    kernels and grids as they were.
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
@@ -776,6 +929,12 @@ def flash_attention(
     t_k = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     kv_valid = t_k if kv_valid is None else int(kv_valid)
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(f"a window (got {window!r}) is a whole number of positions >= 1 and needs causal=True")
+        window = int(window)
+    block_q = (512 if window is None else _WINDOW_BLOCK_Q) if block_q is None else block_q
+    block_k = (1024 if window is None else _WINDOW_BLOCK_K) if block_k is None else block_k
     # kernel works in (B, H, T, D); public layout is (B, T, H, D)
     if bwd_impl not in ("two_pass", "fused", "auto"):
         raise ValueError(
@@ -784,6 +943,19 @@ def flash_attention(
     out = _flash(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3),
-        scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl,
+        scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window,
     )
     return out.transpose(0, 2, 1, 3)
+
+
+def window_grid(t_q: int, t_k: int, window: int, block_q: Optional[int] = None, block_k: Optional[int] = None):
+    """``(visited, live)``: the key blocks that the windowed forward kernel's
+    grid visits for one head of ``t_q`` queries on ``t_k`` keys, and those of
+    them that hold a pair some query sees (a grid that covers the band and no
+    more: equal)."""
+    block_q, block_k, pq, pk, _ = _block_geometry(
+        t_q, t_k, _LANES, _WINDOW_BLOCK_Q if block_q is None else block_q,
+        _WINDOW_BLOCK_K if block_k is None else block_k,
+    )
+    band = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
+    return band.visited, band.live
