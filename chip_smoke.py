@@ -34,16 +34,19 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          step holds the chunked delta rule (a loop carrying one sequence's
          state, its body the Mosaic calls ``delta_chunk_fwd`` / ``_bwd``) and
          flash kernels that read 2 key-value heads for 16 query
-         heads, the loss falls, ``moe.dropped`` stays 0 against the
-         assignments due on the held experts and ``moe.held_share`` is read.
+         heads, ``flash_fwd`` once (the block's checkpoint keeps the core's
+         output and log-sum-exp: ``attn.kept`` 1), the loss falls,
+         ``moe.dropped`` stays 0 against the assignments due on the held
+         experts and ``moe.held_share`` is read.
          Then one chip's share of ``trinity_mini`` at the benchmark cell's
          widths (8 layers, 8 of 128 experts, 25,024 rows of the vocabulary,
          1 x 16,384 tokens, every block rematerialised) through
          ``make_train_step(state_rule=balance_bias_rule(0.001))``: the step
          holds the window kernels (``swa_fwd``, ``swa_bwd_dq``,
-         ``swa_bwd_dkv``) beside the full form's, the loss falls,
-         ``moe.dropped`` stays 0 and every selection bias has moved by the
-         rule's steps.
+         ``swa_bwd_dkv``) beside the full form's, each forward kernel once a
+         block (``swa_fwd`` x 6, ``flash_fwd`` x 2, ``attn.kept`` 8), the
+         loss falls, ``moe.dropped`` stays 0 and every selection bias has
+         moved by the rule's steps.
 array    the reference's workloads at bench.py's sizes on split DNDarrays,
          each against a float64 NumPy oracle: mean/var of 8M x 64; 8192^2
          bf16 matmul; cdist and rbf of 16384 x 128 and of a ragged pair
@@ -289,9 +292,10 @@ def _lm_steps(name, model, loss_fn, c, steps, rule=None):
     the loss is finite and falls, and ``moe.dropped`` stays 0 against the
     assignments due here (all of them, or those on the experts the model
     holds). Returns what the three models' own checks read: the step's lowered
-    text and the names of its Mosaic calls, the losses, the last step's
-    routing, the assignments due, what the ``moe.*`` counters gained, and the
-    state the last step returned."""
+    text and how many Mosaic calls of each name it holds, the attention cores
+    whose residuals the blocks' checkpoints keep (``attn.kept``, counted as the
+    step is traced), the losses, the last step's routing, the assignments due,
+    what the ``moe.*`` counters gained, and the state the last step returned."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -322,9 +326,12 @@ def _lm_steps(name, model, loss_fn, c, steps, rule=None):
     toks = np.random.default_rng(0).integers(
         0, model.vocab_size, (c["batch"], c["seq"]), dtype=np.int32
     )
-    text = step.lower(params, opt_state, jnp.asarray(toks)).as_text()
-    kernels = sorted(set(re.findall(r'@tpu_custom_call\(.*kernel_name = "(\w+)"', text)))
     counters = telemetry.get_registry().counters
+    kept = counters["attn.kept"]
+    text = step.lower(params, opt_state, jnp.asarray(toks)).as_text()
+    kept = int(counters["attn.kept"] - kept)
+    found = re.findall(r'@tpu_custom_call\(.*kernel_name = "(\w+)"', text)
+    kernels = {k: found.count(k) for k in sorted(set(found))}
     names = ("moe.dropped", "moe.assignments", "moe.held_share", "moe.steps")
     before = {k: counters[k] for k in names}
     first, count = model.experts_held or (0, model.num_experts)
@@ -345,8 +352,8 @@ def _lm_steps(name, model, loss_fn, c, steps, rule=None):
     del opt_state
     gc.collect()
     return dict(
-        params=n_params, losses=[round(v, 4) for v in losses], kernels=kernels, text=text, aux=aux, due=due,
-        gained=gained, state=params,
+        params=n_params, losses=[round(v, 4) for v in losses], kernels=kernels, kept=kept, text=text, aux=aux,
+        due=due, gained=gained, state=params,
     )
 
 
@@ -404,13 +411,25 @@ def _qnext_steps(cfg, devices, on_tpu):
             f"in the step: {kernels}",
         )
         _kv_read_by_group(text, "flash_fwd", model, c)
+    _forward_kernels_run_once(got, {"flash_fwd": 1}, on_tpu)
     share = got["gained"]["moe.held_share"] / got["gained"]["moe.steps"]
     routed = cfg["steps"] * model.num_layers * c["batch"] * c["seq"] * model.experts_per_token
     _check(abs(share - got["due"] / routed) < 1e-9, f"moe.held_share reads {share}, the counts give {got['due'] / routed}")
     return dict(
-        params=got["params"], losses=got["losses"], mosaic_kernels=kernels,
+        params=got["params"], losses=got["losses"], mosaic_kernels=kernels, attn_kept=got["kept"],
         held_share=round(share, 5), even_share=model.experts_held[1] / model.num_experts,
     )
+
+
+def _forward_kernels_run_once(got, forward, on_tpu):
+    """Every attention block of a rematerialised model keeps its core's output
+    and log-sum-exp (``attn.kept`` counts the blocks), so the step holds each
+    flash forward kernel once a block: the backward pass does not run it again."""
+    blocks = sum(forward.values())
+    _check(got["kept"] == blocks, f"attn.kept reads {got['kept']} for {blocks} attention blocks")
+    if on_tpu:
+        held = {k: got["kernels"].get(k, 0) for k in forward}
+        _check(held == forward, f"the step holds the forward kernels {held} times, its blocks are {forward}")
 
 
 def _kv_read_by_group(text, kernel, model, c):
@@ -442,6 +461,8 @@ def _trinity_steps(cfg, devices, on_tpu):
         wanted = {"swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
         _check(wanted <= set(kernels), f"no Mosaic calls {sorted(wanted - set(kernels))} in the step: {kernels}")
         _kv_read_by_group(got["text"], "swa_fwd", model, c)
+    windowed = sum(model.window_of(i) is not None for i in range(model.num_layers))
+    _forward_kernels_run_once(got, {"swa_fwd": windowed, "flash_fwd": model.num_layers - windowed}, on_tpu)
     biases = np.stack([np.asarray(b) for b in jax.tree.leaves(jax.device_get(got["state"]["route_bias"]))])
     in_steps = biases / rate
     _check(
@@ -450,7 +471,7 @@ def _trinity_steps(cfg, devices, on_tpu):
         f"the selection biases are not whole steps of the rule: largest {np.abs(in_steps).max()} steps",
     )
     return dict(
-        params=got["params"], losses=got["losses"], mosaic_kernels=kernels,
+        params=got["params"], losses=got["losses"], mosaic_kernels=kernels, attn_kept=got["kept"],
         largest_bias_in_steps=float(np.abs(in_steps).max()),
     )
 

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from ..parallel.pallas_attention import KEPT_RESIDUALS
 from .deltanet import GatedDeltaNet
 from .functional import blocked_cross_entropy
 from .moe import DroplessMoE
@@ -385,9 +386,16 @@ class TransformerLM(nn.Module):
     attn_impl: str = "local"
     comm: Optional[Any] = None
     block_size: Optional[int] = None  # None = each impl's tuned default
-    remat: bool = False  # checkpoint each block: O(L) -> O(1) activations
-    # None = full recompute; "dots" = save MXU dot outputs and recompute
-    # only the cheap elementwise ops (jax.checkpoint_policies.
+    # checkpoint each block: O(L) -> O(1) activations. A block keeps what its
+    # flash kernels name (`pallas_attention.KEPT_RESIDUALS`: the attention
+    # core's output and its log-sum-exp, one float a row; (2·head_dim + 4)
+    # bytes a position and query head: 136 MB a block at 16,384 positions x 32
+    # heads of 128) and the backward pass recomputes everything else in the
+    # block, so a flash forward kernel runs once a step. The other attention
+    # forms (local, ring, ulysses) name nothing and are recomputed whole
+    remat: bool = False
+    # None = recompute all but the above; "dots" = also save MXU dot outputs
+    # and recompute only the cheap elementwise ops (jax.checkpoint_policies.
     # dots_with_no_batch_dims_saveable) — usually faster when HBM allows
     remat_policy: Optional[str] = None
     dtype: Any = jnp.float32
@@ -473,17 +481,20 @@ class TransformerLM(nn.Module):
             )
             x = x + pos[None]
         # rematerialization trades backward-pass FLOPs for activation
-        # memory — the standard long-context recipe (HBM is the bottleneck)
+        # memory — the standard long-context recipe (HBM is the bottleneck).
+        # A block's backward pass runs the block forward again, all but the
+        # flash forward kernel: its output and log-sum-exp are kept by name
+        block_cls = TransformerBlock
         if self.remat:
+            keep = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
             if self.remat_policy == "dots":
-                block_cls = nn.remat(
-                    TransformerBlock,
-                    policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                keep = jax.checkpoint_policies.save_from_both_policies(
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable, keep
                 )
-            else:
-                block_cls = nn.remat(TransformerBlock)
-        else:
-            block_cls = TransformerBlock
+            block_cls = nn.remat(TransformerBlock, policy=keep)
+            telemetry.get_registry().add("attn.kept", sum(
+                self.attn_impl == "flash" and self.mixer_of(i) == "attention" for i in range(self.num_layers)
+            ))
         for i in range(self.num_layers):
             dense = i < self.dense_layers
             x = block_cls(

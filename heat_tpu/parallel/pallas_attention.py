@@ -33,10 +33,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# the names `_flash_fwd` gives the attention's output and its log-sum-exp (one
+# float a row): what `jax.checkpoint_policies.save_only_these_names` takes to
+# rematerialise everything round the forward kernel and not the kernel
+KEPT_RESIDUALS = ("attn.out", "attn.lse")
 _LANES = 128  # TPU lane width: scratch rows are broadcast across it
 # heat_tpu enables jax_enable_x64; a Python-int 0 in an index map then traces
 # as an i64 constant, which Mosaic cannot legalize — pin index literals to i32
@@ -546,8 +551,9 @@ def _bwd_dkv_kernel(
 
 def _bwd_prologue(res, g, block_q, block_k):
     """Shared backward host-side prep: the D = rowsum(dO ∘ O) residual,
-    block clamping/padding of every operand, and the lane-broadcast dd
-    layout. One definition for the two-pass and fused drivers."""
+    block clamping/padding of every operand, and the lane-broadcast layout
+    of dd and of the log-sum-exp column the forward rule kept. One definition
+    for the two-pass and fused drivers."""
     q, k, v, out, lse = res
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
@@ -561,10 +567,11 @@ def _bwd_prologue(res, g, block_q, block_k):
         do_p = jnp.pad(g, ((0, 0), (0, 0), (0, pq), (0, pd_extra)))
     else:
         do_p = g
-    dd_p = jnp.pad(dd, ((0, 0), (0, 0), (0, pq)))[..., None] * jnp.ones(
-        (_LANES,), jnp.float32
+    dd_p, lse_p = (
+        jnp.pad(a, ((0, 0), (0, 0), (0, pq)))[..., None] * jnp.ones((_LANES,), jnp.float32)
+        for a in (dd, lse)
     )
-    return qp, kp, vp, do_p, dd_p, block_q, block_k, pq, pk, dp
+    return qp, kp, vp, do_p, lse_p, dd_p, block_q, block_k, pq, pk, dp
 
 
 def _bwd_fused_kernel(
@@ -659,10 +666,10 @@ def _flash_bwd_fused(
     scale, causal, kv_valid, block_q, block_k, interpret, res, g, window=None
 ):
     """Fused-kernel backward; same contract as the two-pass `_flash_bwd`."""
-    q, k, v, out, lse = res
+    q, k, v = res[:3]
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    qp, kp, vp, do_p, dd_p, block_q, block_k, pq, pk, dp = _bwd_prologue(
+    qp, kp, vp, do_p, lse_p, dd_p, block_q, block_k, pq, pk, dp = _bwd_prologue(
         res, g, block_q, block_k
     )
 
@@ -719,7 +726,7 @@ def _flash_bwd_fused(
         ),
         interpret=interpret,
         name="flash_bwd_fused" if window is None else "swa_bwd_fused",
-    )(qp, kp, vp, do_p, lse, dd_p)
+    )(qp, kp, vp, do_p, lse_p, dd_p)
     if group > 1:
         dk, dv = (a.reshape(b, h_kv, group, t_k + pk, dp).sum(axis=2) for a in (dk, dv))
 
@@ -748,6 +755,12 @@ def _flash_fwd(
         q, k, v, scale, causal, kv_valid, block_q, block_k, interpret,
         return_lse=True, window=window,
     )
+    # the two residuals only this kernel can produce, named so that a
+    # checkpoint round the caller can keep them (`KEPT_RESIDUALS`) and run the
+    # kernel once; the log-sum-exp as one float a row, not the kernel's
+    # lane-broadcast layout (128 times the bytes: twice the output's)
+    out = checkpoint_name(out, KEPT_RESIDUALS[0])
+    lse = checkpoint_name(lse[:, :, : q.shape[2], 0], KEPT_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
@@ -778,10 +791,10 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
     and log-sum-exp — O(T) memory, every MXU dot in the input dtype (the
     r3 XLA-recompute backward ran true-f32 passes; this is the lm_step MFU
     lever)."""
-    q, k, v, out, lse = res
+    q, k, v = res[:3]
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    qp, kp, vp, do_p, dd_p, block_q, block_k, pq, pk, dp = _bwd_prologue(
+    qp, kp, vp, do_p, lse_p, dd_p, block_q, block_k, pq, pk, dp = _bwd_prologue(
         res, g, block_q, block_k
     )
 
@@ -817,7 +830,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
         ),
         interpret=interpret,
         name="flash_bwd_dq" if window is None else "swa_bwd_dq",
-    )(qp, kp, vp, do_p, lse, dd_p)
+    )(qp, kp, vp, do_p, lse_p, dd_p)
 
     # dk/dv pass: K blocks on the parallel axis; the Q blocks of every query
     # head of the group sequential, so that dk and dv come out summed over them
@@ -862,7 +875,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
         ),
         interpret=interpret,
         name="flash_bwd_dkv" if window is None else "swa_bwd_dkv",
-    )(qp, kp, vp, do_p, lse, dd_p)
+    )(qp, kp, vp, do_p, lse_p, dd_p)
 
     return (
         dq[:, :, :t_q, :d].astype(q.dtype),

@@ -188,21 +188,38 @@ def test_the_grid_covers_the_band_and_little_more():
     assert window_grid(384, 384, 1, 32, 32) == (12, 12)  # a window of one: the diagonal blocks
 
 
-# sha256 of the jaxpr (grids, index maps, kernel bodies) of flash_attention's forward and backward
-# with no window, recorded from the parent commit 58dc9ba (PR 31): the full form is the parent's
+# sha256 of flash_attention's forward and backward with no window. "kernels": the four ``pallas_call`` equations
+# alone (grids, index maps, kernel bodies), recorded from the parent commit 4f3dc3a (PR 32) and the same at
+# 58dc9ba (PR 31): the full form's kernels are the parent's. "whole": the jaxpr round them, re-pinned by PR 34,
+# whose forward rule names ``out`` and the log-sum-exp's column (two ``name`` equations, a slice) and whose
+# backward prologue broadcasts the column back to the kernels' lanes
 PARENT_FORM = {
-    (16, 16, 4096, 128, "bfloat16", "two_pass", True): "b00f36a902865bcffd02e7f2fdd6864a034b0357ec4560485392d46ea5b6285a",
-    (16, 2, 8192, 256, "bfloat16", "two_pass", True): "f1b33934e7056670bfb9b49f20a6b9ae880aa6d1181f4c8d1898148dfc5cd952",
-    (4, 1, 384, 64, "float32", "fused", True): "c64beff282779970346e67f88ac246bf8385515d1b087136cb7b66296aae443c",
-    (4, 2, 300, 128, "float32", "two_pass", False): "76098ce14add973e5b1636b8c99a3c946b7c341b85cc0bbd9540fa876a49267f",
+    (16, 16, 4096, 128, "bfloat16", "two_pass", True): {
+        "kernels": "8e4b2de7ecd4e24fe20668ab08b328ef3416baabfb07f3d03d5aee39f01b72c6",
+        "whole": "6ad6931999a06b1fc38312066de93d870b6fd09dbaac53b3b534bfa08ce2fa74",
+    },
+    (16, 2, 8192, 256, "bfloat16", "two_pass", True): {
+        "kernels": "5858301964828e326d05fec6ca59b785a432fc1674046f879afe1f5e7b694db8",
+        "whole": "17a591df28b7e7896f3f066c84d2a5dd52b27ce367bae7d79f3e521c5c76b7b2",
+    },
+    (4, 1, 384, 64, "float32", "fused", True): {
+        "kernels": "6a2cd251335edbe49414e97fd8a35275cefb030e089797ed69d6c337305f27b2",
+        "whole": "117111f0d78fff625884d283cce9f81053e6dea22140f6af0d43312999528f2f",
+    },
+    (4, 2, 300, 128, "float32", "two_pass", False): {
+        "kernels": "79dc22c92d7e9c01f7b1f6822cd83e3bb4838a07b02036ec0c5f96ce4e9f6a3a",
+        "whole": "150ccbd3d363c0acfe749e9f0aca4c8512d8006b576c0dbab0a36b2fda1d6574",
+    },
 }
 
 
+@pytest.mark.parametrize("part", ["kernels", "whole"])
 @pytest.mark.parametrize("form", sorted(PARENT_FORM), ids=lambda f: f"{f[0]}on{f[1]}x{f[2]}x{f[3]}-{f[5]}")
-def test_without_a_window_the_kernels_lower_to_the_parents_form(form):
+def test_without_a_window_the_kernels_lower_to_the_parents_form(form, part):
     heads, kv_heads, t, d, dtype, bwd, causal = form
-    text = str(_lowered(None, heads, kv_heads, t, d, jnp.dtype(dtype), bwd, causal))
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_FORM[form]
+    lowered = _lowered(None, heads, kv_heads, t, d, jnp.dtype(dtype), bwd, causal)
+    text = str(lowered) if part == "whole" else "\n".join(str(e) for e in _pallas_calls(lowered.jaxpr, []))
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_FORM[form][part]
 
 
 def test_a_window_needs_causal_and_a_whole_number_of_positions():
