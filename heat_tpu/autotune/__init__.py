@@ -357,12 +357,9 @@ def _tune_locked(
             _emit(site, "trial", config_index=idx, sample=i, seconds=dt)
 
         with knobs.overlay(cfg):
-            with telemetry.span(
-                "autotune.measure", site=site, config_index=idx
-            ):
-                samples, out = trials.measure(
-                    workload, k=k, warmup=warmup, on_sample=on_sample
-                )
+            samples, out = trials.measure(
+                workload, k=k, warmup=warmup, on_sample=on_sample
+            )
         trials_run += len(samples)
         return trials.robust_median(samples), out
 

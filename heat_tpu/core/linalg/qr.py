@@ -178,17 +178,11 @@ def _cholqr_split1(a: DNDarray, dt, calc_q: bool, audit: bool = False) -> QR:
         else comm.size
     )
     while passes_left > 0:
-        cost, fields, do_audit = telemetry.op_cost(
+        cost, _, do_audit = telemetry.op_cost(
             telemetry.collectives.gram_ring_cost, m, n, dt.byte_size(),
             comm.size, gram_hops, audit=audit,
         )
-        with telemetry.span(
-            "cholqr_gram_ring", gshape=[m, n],
-            overlap=gram_hops < comm.size, **fields,
-        ) as sp:
-            g = sp.output(
-                _gram_ring(q_buf, comm, audit_cost=cost if do_audit else None)
-            )[:n, :n]
+        g = _gram_ring(q_buf, comm, audit_cost=cost if do_audit else None)[:n, :n]
         ell = jnp.linalg.cholesky(g)
         # breakdown check on the small factor (one n² host fetch): NaNs or a
         # collapsed diagonal mean G is (numerically) singular on THIS pass —

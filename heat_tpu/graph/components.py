@@ -61,25 +61,24 @@ def connected_components(
     limit = n if max_iter is None else int(max_iter)
     rounds = 0
     prev = labels.numpy()
-    with telemetry.span("sparse.components", gshape=[n, n], nnz=A.nnz):
-        for _ in range(max(1, limit)):
-            cand = htsparse.spmv(
-                A, labels, reduce="min", pattern=True, out_split=None
+    for _ in range(max(1, limit)):
+        cand = htsparse.spmv(
+            A, labels, reduce="min", pattern=True, out_split=None
+        )
+        new_log = jnp.minimum(labels.larray, cand.larray)
+        if At is not None:
+            cand_t = htsparse.spmv(
+                At, labels, reduce="min", pattern=True, out_split=None
             )
-            new_log = jnp.minimum(labels.larray, cand.larray)
-            if At is not None:
-                cand_t = htsparse.spmv(
-                    At, labels, reduce="min", pattern=True, out_split=None
-                )
-                new_log = jnp.minimum(new_log, cand_t.larray)
-            rounds += 1
-            cur = np.asarray(new_log)
-            labels = DNDarray(
-                new_log, (n,), types.int64, None, A.device, A.comm, True
-            )
-            if np.array_equal(cur, prev):
-                break
-            prev = cur
+            new_log = jnp.minimum(new_log, cand_t.larray)
+        rounds += 1
+        cur = np.asarray(new_log)
+        labels = DNDarray(
+            new_log, (n,), types.int64, None, A.device, A.comm, True
+        )
+        if np.array_equal(cur, prev):
+            break
+        prev = cur
     if telemetry.enabled():
         reg = telemetry.get_registry()
         reg.add("sparse.components", 1)

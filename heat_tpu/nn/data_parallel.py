@@ -148,7 +148,9 @@ class DataParallel:
         ones take their memory. Keep a copy of what must outlive the call.
         Batch arrays still on the host are placed by :meth:`shard_batch`
         inside the call. Spans: ``heat_tpu.train.step`` with ``.prepare``
-        and ``.launch`` (``nn.moe.read_routing`` records ``.readback``).
+        and ``.launch`` (``nn.moe.read_routing`` records ``.readback``); while
+        they record, ``telemetry.hlo.program_scopes("dp_train_step")`` gives the
+        compiled step's scope map (instruction -> modules, scopes, pass).
         With the batch axis sharded and params replicated, XLA emits exactly
         one gradient psum per step (the reference's per-parameter Allreduce
         hooks, fused). Call with batch arrays sharded via
@@ -278,8 +280,12 @@ class DataParallel:
                         a if isinstance(a, jax.Array) else self.shard_batch(a)[0]
                         for a in args[n_state:]
                     )
-                with telemetry.span("heat_tpu.train.step.launch"):
-                    return compiled(*args[:n_state], *batch)
+                with telemetry.span("heat_tpu.train.step.launch") as launch:
+                    out = compiled(*args[:n_state], *batch)
+                    if launch.recording:  # telemetry on or a profile live: the program can be asked for its scopes
+                        # after the call, which may have traced anew; a donated array still says its shape and placement
+                        telemetry.hlo.note_launch("dp_train_step", compiled, (*args[:n_state], *batch))
+                    return out
 
         train_step.lower = compiled.lower
         return train_step

@@ -648,29 +648,31 @@ def causal_lm_loss(
         with jax.named_scope("lm.body"):
             hidden, state = model.apply(params, tokens, head=False, mutable=["aux"])
         b, t = tokens.shape
-        targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
-        mask = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
-        weights = mask.astype(jnp.float32) / (b * (t - 1))
+        with jax.named_scope("lm.targets"):
+            targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
+            mask = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
+            weights = mask.astype(jnp.float32) / (b * (t - 1))
         with jax.named_scope("lm.head_loss"):
             ce = blocked_cross_entropy(
                 hidden.reshape(b * t, -1), params["params"]["lm_head"]["kernel"],
                 targets, weights, dtype=model.dtype,
             )
         layers = [state["aux"][f"block{i}"]["moe"]["moe"][0] for i in model.expert_layers()]
-        zero = jnp.zeros((), jnp.float32)
-        aux = {"ce": ce, "load_balance": zero, "router_z": zero}
-        if layers:
-            aux["load_balance"] = jnp.mean(jnp.stack([a["load_balance"] for a in layers]))
-            aux["router_z"] = jnp.mean(jnp.stack([a["router_z"] for a in layers]))
-            aux["expert_counts"] = jnp.stack([a["expert_counts"] for a in layers])
-            aux["assignments_due"] = len(layers) * b * t * model.experts_per_token
-            if model.experts_held is not None:  # a share: due are those on the held experts
-                aux["assignments_routed"] = aux["assignments_due"]
-                aux["assignments_due"] = sum(a["held"] for a in layers)
-            aux["assignments_computed"] = sum(a["computed"] for a in layers)
-            if "route_bias" in params:  # the selection biases a rule moves: how far they have gone
-                aux["route_bias_max_abs"] = jnp.max(jnp.abs(jnp.stack(jax.tree.leaves(params["route_bias"]))))
-        loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
+        with jax.named_scope("lm.loss"):  # the terms beside the cross-entropy, their sum
+            zero = jnp.zeros((), jnp.float32)
+            aux = {"ce": ce, "load_balance": zero, "router_z": zero}
+            if layers:
+                aux["load_balance"] = jnp.mean(jnp.stack([a["load_balance"] for a in layers]))
+                aux["router_z"] = jnp.mean(jnp.stack([a["router_z"] for a in layers]))
+                aux["expert_counts"] = jnp.stack([a["expert_counts"] for a in layers])
+                aux["assignments_due"] = len(layers) * b * t * model.experts_per_token
+                if model.experts_held is not None:  # a share: due are those on the held experts
+                    aux["assignments_routed"] = aux["assignments_due"]
+                    aux["assignments_due"] = sum(a["held"] for a in layers)
+                aux["assignments_computed"] = sum(a["computed"] for a in layers)
+                if "route_bias" in params:  # the selection biases a rule moves: how far they have gone
+                    aux["route_bias_max_abs"] = jnp.max(jnp.abs(jnp.stack(jax.tree.leaves(params["route_bias"]))))
+            loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
         return loss, aux
 
     return loss_fn

@@ -567,10 +567,10 @@ def _bwd_prologue(res, g, block_q, block_k):
         do_p = jnp.pad(g, ((0, 0), (0, 0), (0, pq), (0, pd_extra)))
     else:
         do_p = g
-    dd_p, lse_p = (
-        jnp.pad(a, ((0, 0), (0, 0), (0, pq)))[..., None] * jnp.ones((_LANES,), jnp.float32)
-        for a in (dd, lse)
-    )
+    lanes = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, pq)))[..., None] * jnp.ones((_LANES,), jnp.float32)  # noqa: E731
+    dd_p = lanes(dd)
+    with jax.named_scope("attn.lse"):  # the kept column back in the kernels' layout
+        lse_p = lanes(lse)
     return qp, kp, vp, do_p, lse_p, dd_p, block_q, block_k, pq, pk, dp
 
 

@@ -321,6 +321,7 @@ class _NoopSpan:
     """Shared do-nothing span returned while nothing records."""
 
     __slots__ = ()
+    recording = False
 
     def __enter__(self):
         return self
@@ -393,6 +394,7 @@ class Span:
         "name", "fields", "id", "parent_id", "root_id",
         "_explicit", "_outputs", "_annotation", "_t0_ns", "_wall0",
     )
+    recording = True  # the shared no-op span says False
 
     def __init__(self, name: str, fields: Dict[str, Any]):
         self.name = name

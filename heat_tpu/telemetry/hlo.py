@@ -44,6 +44,16 @@ once per distinct program, not per call — and recorded both in this
 module (:func:`last_audit`, :func:`recent`) and, when telemetry is
 recording, as an ``hlo_audit`` event that :func:`..report.summarize`
 aggregates into the ``hlo_collectives`` benchmark section.
+
+The same text carries, on every instruction, ``metadata={op_name="..."}``:
+the flax module path, every ``jax.named_scope`` and the pass that wrote the
+instruction. :func:`scope_rows` reads it into a **scope map** (instruction
+name -> modules, scopes, pass), and :func:`program_scopes` gives the map of
+a program this process ran under a span (``nn.DataParallel``'s train step
+notes its launches with :func:`note_launch`). A device trace names an event by
+the instruction's own line, so the map joined to any profile splits a
+program's device time by scope: ``docs/OBSERVABILITY.md``, "Scopes inside a
+step".
 """
 
 from __future__ import annotations
@@ -76,6 +86,9 @@ __all__ = [
     "recent",
     "clear",
     "DEFAULT_TOLERANCE",
+    "scope_rows",
+    "note_launch",
+    "program_scopes",
 ]
 
 # Byte-drift tolerance: |emitted - predicted| / predicted beyond which a
@@ -554,9 +567,10 @@ def disable_audit() -> None:
 
 
 def clear() -> None:
-    """Drop the memo cache and the recent-audit ring."""
+    """Drop the memo cache, the recent-audit ring and the noted launches."""
     _CACHE.clear()
     _RECENT.clear()
+    _LAUNCHED.clear()
 
 
 def recent() -> List[AuditRecord]:
@@ -633,6 +647,294 @@ def audit_call(
         ev.update(fields or {})
         get_registry().emit("hlo_audit", site, **ev)
     return rec
+
+
+# -- the scope map: instruction name -> module path, named scopes, pass --------
+#
+# An ``op_name`` is the name stack JAX kept for the equation an instruction
+# came from, ``/``-joined, the primitive last. The spellings below are those of
+# the three published-width train steps compiled for a TPU v5e on jax 0.9.0
+# (pinned in ``tests/test_step_scopes_tpu_compile.py``):
+#
+#   jit(dp_train_step)/jvp(lm.body)/TransformerLM/block3/attn/attn.window/slice
+#       forward;
+#   jit(dp_train_step)/transpose(jvp(lm.body))/TransformerLM/block0/ln1/mul
+#       backward, no checkpoint;
+#   .../transpose(jvp(lm.body))/TransformerLM/jvp(lm.body)/TransformerLM/checkpoint/block3/moe/moe.route/gather
+#       backward under ``nn.remat``: the transposed equation's own stack follows
+#       the stack of the place where the transpose ran, from its root again;
+#   .../jvp(lm.body)/TransformerLM/checkpoint/rematted_computation/block3/ln1/mul
+#       the block run again in the backward pass: recomputed;
+#   .../checkpoint/block1/gdn/while/body/closed_call/checkpoint/rematted_computation/gdn.conv/mul
+#       a ``jax.checkpoint`` inside a ``lax.map`` inside a rematerialised block;
+#   .../while/body/transpose(jvp(moe.combine))/mul, .../while/body/jvp(moe.route)/gather
+#       a custom VJP's backward rule that differentiates inside a loop.
+#
+# A transform wraps the stack's elements (``jvp(lm.body)``), a jitted function
+# inside the step reads ``jit(silu)``, and JAX's own frames are plain words.
+# Flax opens a ``jax.named_scope`` of a module's name, so nothing but the
+# spelling tells a module from a scope: a scope of this repo is dotted
+# (``moe.route``), a flax name never is.
+
+_COMPUTATION_RE = re.compile(r"^(?P<entry>ENTRY\s+)?%?(?P<name>[^\s(]+)\s+\(.*->.*\{\s*$")
+_ANY_INSTR_RE = re.compile(r"^\s+(?P<root>ROOT\s+)?%?(?P<name>[^\s=]+)\s*=\s*(?P<rest>.*)$")
+# the opcode: the first `` word(`` behind the closing bracket of the result's type
+_OPCODE_RE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
+_CALLED_RE = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)=%?([^\s,)}]+)"
+)
+_BRANCHES_RE = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_WRAPPED_RE = re.compile(r"^(\w+)\((.*)\)$")
+_IDENTIFIER_RE = re.compile(r"^[A-Za-z_]\w*$")
+_BRANCH_FRAME_RE = re.compile(r"^branch_\d+_fun$")
+# JAX's own frames in a name stack: control flow, closed calls, checkpoints
+_FRAMES = frozenset({"while", "body", "cond", "closed_call", "checkpoint", "rematted_computation"})
+# the instructions whose called computations hold instructions of their own
+# that run (and show in a device trace); a fusion's are folded into its row
+_REACHES = {
+    "while": ("body", "condition"),
+    "call": ("to_apply",),
+    "conditional": ("true_computation", "false_computation", "branches"),
+}
+
+_NO_SCOPE: Dict[str, Any] = {"path": "", "modules": "", "scopes": (), "pass": ""}
+
+
+def _unwrap(component: str) -> Tuple[List[str], str]:
+    """``transpose(jvp(lm.body))`` -> (``["transpose", "jvp"]``, ``"lm.body"``)."""
+    wrappers: List[str] = []
+    m = _WRAPPED_RE.match(component)
+    while m:
+        wrappers.append(m.group(1))
+        component = m.group(2)
+        m = _WRAPPED_RE.match(component)
+    return wrappers, component
+
+
+def split_op_name(op_name: str) -> Dict[str, Any]:
+    """One ``op_name`` path as ``{"path", "modules", "scopes", "pass"}``:
+    ``modules`` the flax path (``TransformerLM/block3/attn/query``), ``scopes``
+    the dotted ``jax.named_scope``s on it, outermost first, transforms taken
+    off (``("lm.body", "attn.window")``), ``pass`` one of ``forward``,
+    ``recomputed`` (a checkpoint's rematerialised computation, which runs in
+    the backward pass) and ``backward`` (under a ``transpose(...)``). What
+    lies outside every transform (the optimizer) reads ``forward``. An empty
+    path gives empty fields."""
+    if not op_name:
+        return dict(_NO_SCOPE)
+    # XLA joins the names of two instructions it folds into one with ";", the second cut to where they part
+    parts = op_name.split(";")[0].split("/")
+    if "rematted_computation" in parts:
+        which = "recomputed"
+    elif any(c.startswith("transpose(") for c in parts):
+        which = "backward"
+    else:
+        which = "forward"
+    # under a checkpoint the transposed equation brings its own stack, from the
+    # root again, behind that of the place where the transpose ran: keep one
+    for i, c in enumerate(parts):
+        if c.startswith("transpose(") and c[len("transpose("):-1] in parts[i + 1:]:
+            parts = parts[:i + 1] + parts[parts.index(c[len("transpose("):-1], i + 1):]
+            break
+    modules: List[str] = []
+    scopes: List[str] = []
+    for c in parts[:-1]:  # the last is the primitive
+        wrappers, name = _unwrap(c)
+        if "jit" in wrappers or name in _FRAMES or _BRANCH_FRAME_RE.match(name):
+            continue
+        if "." in name:
+            if name not in scopes:
+                scopes.append(name)
+        elif _IDENTIFIER_RE.match(name):
+            modules.append(name)
+    return {"path": op_name, "modules": "/".join(modules), "scopes": tuple(scopes), "pass": which}
+
+
+def _computations(text: str):
+    """``(entry, {computation: [instruction]})`` of an optimized module's
+    text, an instruction as ``{"name", "op", "root", "op_name", "called"}``."""
+    entry, comps, current = None, {}, None
+    for line in text.splitlines():
+        if not line:
+            continue
+        if not line[0].isspace():
+            m = _COMPUTATION_RE.match(line)
+            current = None
+            if m:
+                current = comps.setdefault(m.group("name"), [])
+                if m.group("entry"):
+                    entry = m.group("name")
+            continue
+        if current is None:
+            continue
+        m = _ANY_INSTR_RE.match(line)
+        if not m:
+            continue
+        rest = m.group("rest")
+        op = _OPCODE_RE.search(rest)
+        operands = _split_operands_attrs(rest[op.end():])[0] if op else ""
+        called = {k: [v] for k, v in _CALLED_RE.findall(rest)}
+        branches = _BRANCHES_RE.search(rest)
+        if branches:
+            called["branches"] = [b.strip().lstrip("%") for b in branches.group(1).split(",") if b.strip()]
+        name = _OP_NAME_RE.search(rest)
+        current.append({
+            "name": m.group("name"), "op": op.group(1) if op else "", "root": bool(m.group("root")),
+            "op_name": name.group(1) if name else "", "called": called,
+            "operands": _OPERAND_NAME_RE.findall(operands),
+        })
+    return entry, comps
+
+
+def _nearest_named(start: str, named: set, users: dict, operands: dict, hops: int = 4) -> Optional[str]:
+    """The nearest instruction of ``named`` to ``start`` in its computation:
+    through what uses it first (a copy belongs to what it feeds), then through
+    what it reads, a few steps at most."""
+    for nexts in (users, operands):
+        frontier, seen = [start], {start}
+        for _ in range(hops):
+            reached = []
+            for n in frontier:
+                for m in nexts.get(n, ()):
+                    if m in named:
+                        return m
+                    if m not in seen:
+                        seen.add(m)
+                        reached.append(m)
+            frontier = reached
+    return None
+
+
+def scope_rows(text: str) -> Dict[str, Dict[str, Any]]:
+    """The scope map of a compiled module's ``as_text()``: a row for every
+    instruction of the entry computation and of every computation a ``while``,
+    ``call`` or ``conditional`` reaches from it, by the instruction's name (a
+    device event's name is the instruction's line, whose head is this name and
+    is unique in its module).
+
+    A row is :func:`split_op_name` of the instruction's ``op_name`` with its
+    opcode as ``op``. A ``fusion``'s row is its own (XLA gives a fusion its
+    root's metadata; the root's where it has none, and where the root is a
+    bitcast or tuple the compiler added, the last fused instruction's that has
+    any) and lists as ``fused`` the distinct ``(modules, scopes)`` of the fused
+    computation's instructions, so that a reader can tell a fusion that mixes
+    pieces. An instruction without
+    metadata (a copy, bitcast or zero fill the compiler added, a parameter)
+    has empty fields; where an instruction of its computation that has some is
+    near (what uses it, else what it reads, through at most four others without),
+    the row names it as ``via``: a reader may lend the copy that row."""
+    entry, comps = _computations(text)
+    rows: Dict[str, Dict[str, Any]] = {}
+    todo, seen = [entry], {entry}
+    while todo:
+        body = comps.get(todo.pop(), ())
+        for ins in body:
+            op_name, fused = ins["op_name"], ()
+            if ins["op"] == "fusion":
+                inside = [i for c in ins["called"].get("calls", ()) for i in comps.get(c, ())]
+                named = [i["op_name"] for i in inside if i["op_name"]]
+                if not op_name:  # the root's; a root the compiler added (a bitcast, a tuple) has none: the last named one's
+                    op_name = next((i["op_name"] for i in inside if i["root"]), "") or (named[-1] if named else "")
+                found = {}
+                for name in named:
+                    r = split_op_name(name)
+                    found[(r["modules"], r["scopes"])] = None
+                fused = tuple(found)
+            rows[ins["name"]] = {"op": ins["op"], **split_op_name(op_name), "fused": fused}
+            for key in _REACHES.get(ins["op"], ()):
+                for c in ins["called"].get(key, ()):
+                    if c not in seen:
+                        seen.add(c)
+                        todo.append(c)
+        mine = [i["name"] for i in body]
+        named = {n for n in mine if rows[n]["path"]}
+        if named and len(named) < len(mine):
+            operands = {i["name"]: i["operands"] for i in body}
+            users: Dict[str, List[str]] = {}
+            for i in body:
+                for o in i["operands"]:
+                    users.setdefault(o, []).append(i["name"])
+            for n in mine:
+                if n not in named:
+                    via = _nearest_named(n, named, users, operands)
+                    if via is not None:
+                        rows[n]["via"] = via
+    return rows
+
+
+# -- the map of a program this process ran -------------------------------------
+
+
+@dataclass
+class _Launched:
+    lower: Any        # the jitted program's ``lower``
+    variants: Any     # how many signatures it had traced when this one was kept
+    signature: Any    # the call's arguments as ``jax.ShapeDtypeStruct``s: no arrays
+    rows: Optional[Dict[str, Dict[str, Any]]] = None
+
+
+_LAUNCHED: Dict[str, _Launched] = {}
+
+
+def _variants(lower) -> Optional[int]:
+    """Signatures the jitted function behind ``lower`` has traced: a retrace
+    adds one. None where this jax does not say."""
+    size = getattr(getattr(lower, "__self__", None), "_cache_size", None)
+    return size() if callable(size) else None
+
+
+def _abstract(leaf):
+    if not (hasattr(leaf, "shape") and hasattr(leaf, "dtype")):
+        return leaf
+    # an uncommitted array leaves its placement to the program, as the call did
+    sharding = leaf.sharding if getattr(leaf, "committed", False) else None
+    return jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=sharding, weak_type=getattr(leaf, "weak_type", False)
+    )
+
+
+def note_launch(site: str, program, args) -> None:
+    """Keep, once a program, the abstract signature (shape, dtype, sharding of
+    each leaf; no array) of a call of the jitted ``program`` at ``site``, so
+    that :func:`program_scopes` can ask for the text of what ran. A launch
+    wrapper calls this **only while its span records**: with telemetry off and
+    no profile live nothing is kept. A retrace (the jitted function holds one
+    signature more than when the last was kept) drops what was kept, the map
+    with it, and keeps the new call's."""
+    lower = program.lower
+    variants = _variants(lower)
+    kept = _LAUNCHED.get(site)
+    if kept is not None and variants is not None and kept.variants == variants and kept.lower == lower:
+        return
+    _LAUNCHED[site] = _Launched(lower, variants, jax.tree.map(_abstract, args))
+    from . import get_registry
+
+    get_registry().add(f"hlo.launches_noted.{site}", 1)
+
+
+def program_scopes(site: str) -> Optional[Dict[str, Dict[str, Any]]]:
+    """:func:`scope_rows` of the program that ran at ``site`` (the train step:
+    ``"dp_train_step"``), or None where no launch of it was noted. Lowers and
+    compiles from the kept signature on the first request, after the fact: the
+    jitted function answers from what it compiled for the call itself (no
+    backend compile; else JAX's persistent cache, else a compile), and the rows
+    are kept. The text is the executable's: one loaded from the persistent
+    cache carries the names of the source that compiled it there, whose key
+    leaves metadata out. Never raises."""
+    kept = _LAUNCHED.get(site)
+    if kept is None:
+        return None
+    if kept.rows is None:
+        from ..core.program_cache import _DONATION_NOISE
+
+        try:
+            with warnings.catch_warnings():
+                warnings.filterwarnings("ignore", message=_DONATION_NOISE)
+                kept.rows = scope_rows(kept.lower(*kept.signature).compile().as_text())
+        except Exception as e:  # the map observes; it must never take the workload down
+            warnings.warn(f"heat_tpu.telemetry.hlo: no scope map of {site!r} ({e!r})")
+            return None
+    return kept.rows
 
 
 # Environment activation (mirrors HEAT_TPU_TELEMETRY): the benchmark

@@ -1,0 +1,198 @@
+"""The scope map of the three published-width train steps, compiled for a
+described TPU v5e: the spellings ``telemetry.hlo.split_op_name`` reads are the
+compiler's own here (custom VJPs, scans, ``nn.remat`` with a policy, a
+``jax.checkpoint`` inside a ``lax.map`` inside a rematerialised block), every
+kernel falls in its piece of ``chipbench/scope_trace.PIECES``, few of the
+instructions that can be a device event are left without a piece, and the
+programs are the ones the tree compiled before it named anything: PR 35's
+scopes moved metadata alone. A compile is not a run: nothing here is a time.
+
+The steps are built as ``tests/chipbench/test_chipbench_{lm,qnext,trinity}_tpu_compile.py``
+build them (those fixtures also compile each cell's check; here the step alone).
+"""
+
+import hashlib
+import os
+import re
+
+import pytest
+
+from chipbench import manifest, scope_trace
+from heat_tpu.telemetry import hlo
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# ``memory_analysis`` totals and a digest of the instruction list at the parent
+# of PR 35 (2129a44; described v5e:2x2, jax 0.9.0, libtpu 0.0.34): the text's
+# computations with ``metadata={...}`` and the Mosaic kernels' serialized bodies
+# (which carry source paths) taken out, the numbers XLA appends to names
+# dropped (two compiles of the Qwen3-Next step number their instructions
+# differently; the other two are the parent's byte for byte) and the lines
+# sorted. A PR that changes a program on purpose records its own.
+PARENT = {
+    "olmoe-train-4k-1chip": (15_751_272_448, "e0d807ed29622d9cfe144bbea2e8b92ef007b831ec51da502d46ed5189769a9c"),
+    "qwen3next-train-8k-1chip": (13_213_087_744, "220989a4f753aca6f885eeb7abad710b6f33b3073ea05336487c4fc97ef56809"),
+    "trinity-train-16k-1chip": (14_986_435_072, "fdf1ee742afa6fcf36211b218096077b60658bb66697d0bb6f5980974b10656d"),
+}
+
+# what a device trace of these steps shows as an event of its own (my chip runs, PR 35)
+RUNS = {
+    "fusion", "custom-call", "convolution", "dot", "copy", "sort", "scatter", "gather", "reduce", "reduce-window",
+    "select-and-scatter", "dynamic-slice", "dynamic-update-slice", "concatenate", "pad", "transpose", "slice",
+    "broadcast", "iota", "reshape", "convert", "while", "conditional",
+}
+# custom calls that are no kernel: the compiler's own markers, no event
+NO_KERNEL = re.compile(r'custom_call_target="(ConcatBitcast|AssumeGatherIndicesInBound|GatherScatterIndicesBitpacked|X64Combine)"')
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module", params=sorted(PARENT))
+def step(request, topo):
+    import jax
+    import jax.numpy as jnp
+
+    import heat_tpu.nn as nn
+    from heat_tpu.core.communication import MeshCommunication
+
+    parts = manifest.load(REPO)
+    config = parts.config(parts.cell(request.param))
+    kind = parts.module("kinds", config["kind"])
+    comm = MeshCommunication(devices=topo.devices[:1])
+    if config["kind"] == "lm_step":
+        model = nn.olmoe_1b_7b(num_layers=config["num_hidden_layers"], comm=comm)
+    else:
+        model = kind.build_model(config, comm)
+    rule, collections = {}, ("params",)
+    if config["kind"] == "trinity_step":
+        loss_fn = nn.causal_lm_loss(model)
+        rule, collections = {"state_rule": nn.balance_bias_rule(config["bias_rate"])}, ("params", "route_bias")
+    else:
+        loss_fn = nn.causal_lm_loss(
+            model, load_balance_coef=config["loss"]["load_balance"], router_z_coef=config["loss"]["router_z"]
+        )
+    opt = kind.optimizer(config["optimizer"])
+    train_step = nn.DataParallel(model, comm=comm, optimizer=opt, blocking_parameter_updates=True).make_train_step(
+        loss_fn, has_aux=True, **rule
+    )
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    params = {k: shapes[k] for k in collections}
+    placed = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=comm.replicated()), tree
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (config["sequences_per_step"], config["sequence_length"]), jnp.int32, sharding=comm.sharding(0, 2)
+    )
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"  # the flash and delta kernels ask it whether to run in the interpreter
+    try:
+        program = train_step.lower(
+            placed(params), placed(jax.eval_shape(opt.init, {"params": params["params"]})), tokens
+        ).compile()
+    finally:
+        jax.default_backend = backend
+    text = program.as_text()
+    return request.param, program, text, hlo.scope_rows(text)
+
+
+def _instruction_lines(text):
+    return {
+        m.group(1): line for line in text.splitlines()
+        if (m := re.match(r"\s+(?:ROOT\s+)?%?([^\s=]+) = ", line))
+    }
+
+
+def test_every_kernel_and_loop_falls_in_its_piece(step):
+    cell, _, text, rows = step
+    lines = _instruction_lines(text)
+    pieces = {name: scope_trace.piece_of(row) for name, row in rows.items()}
+    # the Mosaic kernels, wherever they stand (XLA fuses the delta rule's into its loop's update of the stacked outputs)
+    kernels = {}
+    for name, line in lines.items():
+        if " custom-call(" in line and "tpu_custom_call" in line and not name.startswith("ragged-dot"):
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            kernels[name] = scope_trace.piece_of(hlo.split_op_name(op_name))
+    flash = {n: p for n, p in kernels.items() if re.match(r"(flash|swa)_", n)}
+    delta = {n: p for n, p in kernels.items() if n.startswith("delta_chunk_")}
+    assert flash and set(flash.values()) == {"attention_core"}
+    assert all(n in rows and pieces[n] == "attention_core" for n in flash)  # events of their own
+    assert len(flash) + len(delta) == len(kernels)  # no kernel the table has not heard of
+    if cell == "qwen3next-train-8k-1chip":
+        assert len(delta) == 12 and set(delta.values()) == {"delta_rule"}  # nine forward, three backward
+        holding = [n for n, r in rows.items() if r["op"] == "fusion" and any("delta_chunk" in m for m, _ in r["fused"])]
+        assert len(holding) == 12 and {pieces[n] for n in holding} == {"delta_rule"}
+    else:
+        assert not delta
+    grouped = {n: p for n, p in pieces.items() if n.startswith("ragged-dot")}  # XLA:TPU's own kernel, metadata and product
+    assert len(grouped) >= 9 and set(grouped.values()) == {"experts"}
+    # the head's loop and every instruction of its body
+    head = [n for n, r in rows.items() if "lm.head_loss" in r["scopes"]]
+    assert sum(rows[n]["op"] == "while" for n in head) == 1
+    assert len(head) > 40 and {pieces[n] for n in head} == {"head_loss"}
+    assert {p for n, p in pieces.items() if "train.optimizer" in rows[n]["scopes"]} == {"optimizer"}
+
+
+def test_the_passes_and_scopes_are_the_models_own(step):
+    cell, _, _, rows = step
+    passes = {r["pass"] for r in rows.values()} - {""}
+    scopes = {s for r in rows.values() for s in r["scopes"]}
+    common = {"lm.body", "lm.head_loss", "lm.targets", "lm.loss", "train.optimizer", "moe.route", "moe.experts",
+              "moe.combine", "attn.full", "attn.lse"}
+    if cell == "olmoe-train-4k-1chip":  # no checkpoint: nothing is run again
+        assert passes == {"forward", "backward"} and scopes == common
+    elif cell == "qwen3next-train-8k-1chip":
+        assert passes == {"forward", "recomputed", "backward"}
+        assert scopes == common | {"attn.gate", "moe.shared", "gdn.project", "gdn.conv", "gdn.scan", "gdn.gate_norm"}
+    else:
+        assert passes == {"forward", "recomputed", "backward"}
+        assert scopes == common | {"attn.gate", "attn.window", "moe.shared", "train.state_rule"}
+    # a recomputed instruction lies in a block (or, hoisted out of a loop by XLA, keeps its scope), and a module path
+    # never repeats its root
+    for r in rows.values():
+        if r["pass"] == "recomputed":
+            assert re.search(r"(^|/)block\d+(/|$)", r["modules"]) or len(r["scopes"]) > 1, r
+        assert r["modules"].count("TransformerLM") <= 1, r
+        assert not set(r["modules"].split("/")) & hlo._FRAMES, r
+
+
+def test_few_instructions_that_can_run_are_left_without_a_piece(step):
+    _, _, text, rows = step
+    lines = _instruction_lines(text)
+    runs = [n for n, r in rows.items() if r["op"] in RUNS and not NO_KERNEL.search(lines[n])]
+    assert len(runs) > 150
+    # with the row a copy or zero fill borrows from the neighbour the map names (``via``), as the reader places it
+    unscoped = [n for n in runs if scope_trace.piece_of(scope_trace.lent(rows, rows[n])) == scope_trace.UNSCOPED]
+    assert len(unscoped) <= 0.05 * len(runs), (len(unscoped), len(runs))
+    # by their own metadata alone: what the compiler added without a name is a sixth of them at most
+    bare = [n for n in runs if not rows[n]["path"]]
+    assert len(bare) <= 0.2 * len(runs) and all("via" in rows[n] for n in bare if n not in unscoped)
+
+
+def test_the_program_is_the_parents_but_for_metadata(step):
+    cell, program, text, _ = step
+    total, digest = PARENT[cell]
+    m = program.memory_analysis()
+    assert m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes == total
+    body = text[text.index("\n%"):]  # the computations, without the header's table of source files
+    body = re.sub(r", metadata=\{[^{}]*\}", "", body)
+    body = re.sub(r'"body":\s*"[^"]*"', "", body)
+    lines = sorted(re.sub(r"\.\d+", "", body).splitlines())
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == digest
