@@ -136,7 +136,9 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window=N
     ``attn.window``; the counters ``attn.full.kernel``, ``attn.window.kernel``
     (the Pallas kernels) and ``attn.window.xla`` (the masked XLA form) say once
     a trace which ran, ``attn.window.blocks_visited`` / ``.blocks_live`` what
-    the windowed grid covers (:func:`heat_tpu.parallel.pallas_attention.window_grid`)."""
+    the windowed grid covers (:func:`heat_tpu.parallel.pallas_attention.window_grid`),
+    ``attn.full.blocks_streamed`` / ``.blocks_live`` what a full causal layer's
+    grid copies (:func:`~heat_tpu.parallel.pallas_attention.causal_grid`)."""
     with jax.named_scope("attn.full" if window is None else "attn.window"):
         return _attend_scoped(
             q, k, v, impl=impl, causal=causal, comm=comm, block_size=block_size,
@@ -151,7 +153,7 @@ def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, w
         ring_attention,
         ulysses_attention,
     )
-    from ..parallel.pallas_attention import window_grid
+    from ..parallel.pallas_attention import causal_grid, window_grid
 
     count = telemetry.get_registry().add
     if impl in ("ring", "ulysses") and window is not None:
@@ -171,6 +173,10 @@ def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, w
         )
         if window is None:
             count("attn.full.kernel")
+            if causal:
+                _, streamed, live = causal_grid(q.shape[1], k.shape[1], block_size, block_size)
+                count("attn.full.blocks_streamed", streamed)
+                count("attn.full.blocks_live", live)
         else:
             count("attn.window.kernel")
             visited, live = window_grid(q.shape[1], k.shape[1], window, block_size, block_size)

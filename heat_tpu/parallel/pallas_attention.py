@@ -57,11 +57,18 @@ _WINDOW_BLOCK_Q = 1024
 _WINDOW_BLOCK_K = 1024
 
 
+def _tiles(window, block_q, block_k):
+    """Block sizes not given: the tuned tiles of the form."""
+    tuned = (512, 1024) if window is None else (_WINDOW_BLOCK_Q, _WINDOW_BLOCK_K)
+    return tuned[0] if block_q is None else block_q, tuned[1] if block_k is None else block_k
+
+
 class _Band:
-    """The static geometry of a windowed grid: position ``t`` sees keys ``t -
+    """The static geometry of a causal grid: position ``t`` sees keys ``t -
     window < j <= t``, so a block of one axis meets a band of blocks of the
     other, and the grid's sequential axis runs over that band alone (``steps``
-    blocks at most), its block index a function of the other axis's.
+    blocks at most), its block index a function of the other axis's. No window
+    is one that sees every key (``t_q + t_k``): the band ends at the diagonal.
 
     ``keys``: for query block ``i`` the key blocks ``lo(i) .. hi(i)``;
     otherwise for key block ``i`` the query blocks ``lo(i) .. hi(i)``. ``rows``
@@ -69,8 +76,9 @@ class _Band:
     ``n`` how many blocks the band's axis has. ``lo`` and ``hi`` take int32
     tracers (index maps and kernel bodies: literals pinned to int32); a step
     past ``hi`` reads block ``hi`` again (no new DMA) and computes nothing.
-    ``visited`` and ``live`` count, over the rows, the grid's steps and those
-    of them whose block holds a visible pair."""
+    ``visited``, ``streamed`` and ``live`` count, over the rows, the grid's
+    steps, those that name another block than the step before (a row's first
+    among them: what is copied) and those whose block holds a visible pair."""
 
     def __init__(self, window, rows, cols, n, n_rows, keys):
         self.window, self.rows, self.cols, self.n, self.keys = window, rows, cols, n, keys
@@ -78,6 +86,7 @@ class _Band:
         self.steps = max(1, max(hi - lo + 1 for lo, hi in spans))
         self.live = sum(max(0, hi - lo + 1) for lo, hi in spans)
         self.visited = self.steps * n_rows
+        self.streamed = sum(len({min(lo + step, hi) for step in range(self.steps)}) for lo, hi in spans)
 
     def _span(self, i):  # Python ints
         if self.keys:
@@ -122,17 +131,16 @@ def _inside_band(iq, ik, *, kv_valid, block_q, block_k, window):
     """Whether every pair of score block (iq, ik) is visible: such a block
     takes no mask."""
     first_q, first_k = iq * block_q, ik * block_k
-    return (
-        (first_k + (block_k - 1) <= first_q)
-        & (first_k > first_q + (block_q - 1 - window))
-        & (first_k + block_k <= kv_valid)
-    )
+    inside = first_k + (block_k - 1) <= first_q
+    if window is not None:
+        inside = inside & (first_k > first_q + (block_q - 1 - window))
+    return inside & (first_k + block_k <= kv_valid)
 
 
-def _when_live(live, iq, ik, accumulate, *, kv_valid, block_q, block_k, window):
-    """Run ``accumulate(masked)`` where the block is live: with a window, the
-    blocks wholly inside the band without their mask."""
-    if window is None:
+def _when_live(live, iq, ik, accumulate, *, causal, kv_valid, block_q, block_k, window):
+    """Run ``accumulate(masked)`` where the block is live: in the causal
+    forms, the blocks wholly inside the band without their mask."""
+    if not causal:
         pl.when(live)(functools.partial(accumulate, True))
         return
     inside = _inside_band(
@@ -150,7 +158,7 @@ def _flash_kernel(
 
     Refs arrive as (1, 1, block, D) VMEM tiles. The (m, l, acc) scratch
     persists across the K axis — initialised at ik == 0, finalised into
-    ``o_ref`` at the last K block. With a ``window`` the last axis runs over
+    ``o_ref`` at the last K block. With ``causal`` the last axis runs over
     the steps of ``band`` (:class:`_Band`) and the key block follows from it.
     """
     iq = pl.program_id(2)
@@ -166,14 +174,12 @@ def _flash_kernel(
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    # causal skip: a K block strictly above the diagonal band contributes
-    # nothing — skip its MXU work entirely (DMA still streams it; the win is
-    # ~2× compute on long causal sequences)
-    if window is not None:
+    # causal: the steps walk the band's key blocks, the diagonal's the last; a
+    # step past it names that block again, so nothing is copied for it, and
+    # computes nothing
+    if causal:
         ik = band.lo(iq) + step
         live = ik <= band.hi(iq)
-    elif causal:
-        live = ik * block_k <= iq * block_q + (block_q - 1)
     else:
         live = ik >= 0  # always true, keeps one code path
 
@@ -219,7 +225,7 @@ def _flash_kernel(
         acc_s[:] = acc_s[:] * alpha + pv
 
     _when_live(
-        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        live, iq, ik, _accumulate, causal=causal, kv_valid=kv_valid, block_q=block_q,
         block_k=block_k, window=window,
     )
 
@@ -298,11 +304,11 @@ def _pad_blocks(q, k, v, t_q, t_k, d, block_q, block_k):
     return q, k, v, block_q, block_k, pq, pk, d + pd
 
 
-def _key_band(window, t_q, t_k, block_q, block_k):
-    """The band of key blocks a query block meets (None without a window)."""
-    if window is None:
+def _key_band(causal, window, t_q, t_k, block_q, block_k):
+    """The band of key blocks a query block meets (None unless ``causal``)."""
+    if not causal:
         return None
-    return _Band(window, block_q, block_k, t_k // block_k, t_q // block_q, keys=True)
+    return _Band(window or t_q + t_k, block_q, block_k, t_k // block_k, t_q // block_q, keys=True)
 
 
 def _flash_forward(
@@ -319,16 +325,15 @@ def _flash_forward(
 
     grid = (b, h, (t_q + pq) // block_q, (t_k + pk) // block_k)
     of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0)  # noqa: E731
+    band = _key_band(causal, window, t_q + pq, t_k + pk, block_q, block_k)
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, kv_valid=kv_valid,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, window=window, band=band,
     )
-    if window is not None:
-        band = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
+    if causal:
         grid = grid[:3] + (band.steps,)
         of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), band.block(qi, ki), _I0)  # noqa: E731
-        kernel = functools.partial(kernel, window=window, band=band)
     o_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
@@ -394,7 +399,7 @@ def _rebuild_probs(
     """Shared backward-pass probability reconstruction: the (bq, bk) score
     block, kv_valid + causal (+ window) masking, and ``p = exp(s − lse)`` — one
     definition so the dq and dk/dv kernels can never desynchronize. A block
-    wholly inside a window's band (``masked`` false) takes no mask."""
+    wholly inside the causal band (``masked`` false) takes no mask."""
     neg_inf = jnp.float32(NEG_INF)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -443,7 +448,7 @@ def _bwd_dq_kernel(
     *, scale, causal, kv_valid, block_q, block_k, window=None, band=None,
 ):
     """dQ pass. Grid = (B, H, num_q_blocks, num_k_blocks), last sequential
-    (with a ``window``: the steps of the key ``band``, as in the forward).
+    (with ``causal``: the steps of the key ``band``, as in the forward).
 
     p is rebuilt from the saved log-sum-exp (``p = exp(s − lse)``), then
     ``dS = P ∘ (dP − D)`` and ``dQ += scale · dS Kᵀ`` accumulate in VMEM
@@ -458,11 +463,9 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    if window is not None:
+    if causal:
         ik = band.lo(iq) + step
         live = ik <= band.hi(iq)
-    elif causal:
-        live = ik * block_k <= iq * block_q + (block_q - 1)
     else:
         live = ik >= 0
 
@@ -478,7 +481,7 @@ def _bwd_dq_kernel(
         )
 
     _when_live(
-        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        live, iq, ik, _accumulate, causal=causal, kv_valid=kv_valid, block_q=block_q,
         block_k=block_k, window=window,
     )
 
@@ -497,7 +500,7 @@ def _bwd_dkv_kernel(
     ``dK += scale · dSᵀ Q`` accumulate per K block across the Q axis, and
     across the ``group`` query heads that read this key-value head (their Q
     blocks follow one another on the sequential axis; ``q_blocks`` is how
-    many one head has). With a ``window`` a head's steps are those of the
+    many one head has). With ``causal`` a head's steps are those of the
     query ``band`` of this key block (``q_blocks`` = ``band.steps``)."""
     ik = pl.program_id(2)
     step = pl.program_id(3)
@@ -510,11 +513,9 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if window is not None:
+    if causal:
         iq = band.lo(ik) + iq
         live = iq <= band.hi(ik)
-    elif causal:
-        live = iq * block_q + (block_q - 1) >= ik * block_k
     else:
         live = iq >= 0
 
@@ -539,7 +540,7 @@ def _bwd_dkv_kernel(
         )
 
     _when_live(
-        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        live, iq, ik, _accumulate, causal=causal, kv_valid=kv_valid, block_q=block_q,
         block_k=block_k, window=window,
     )
 
@@ -592,7 +593,7 @@ def _bwd_fused_kernel(
     block stays resident, which is why the fused path is gated on
     ``_fused_bwd_fits``."""
     ik = pl.program_id(2)
-    iq = step = pl.program_id(3)  # with a window: the step inside the query band
+    iq = step = pl.program_id(3)  # causal: the step inside the query band
     nq = pl.num_programs(3)
 
     @pl.when((ik == 0) & (step == 0))
@@ -604,11 +605,9 @@ def _bwd_fused_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if window is not None:
+    if causal:
         iq = band.lo(ik) + step
         live = iq <= band.hi(ik)
-    elif causal:
-        live = iq * block_q + (block_q - 1) >= ik * block_k
     else:
         live = iq >= 0
 
@@ -635,7 +634,7 @@ def _bwd_fused_kernel(
         )
 
     _when_live(
-        live, iq, ik, _accumulate, kv_valid=kv_valid, block_q=block_q,
+        live, iq, ik, _accumulate, causal=causal, kv_valid=kv_valid, block_q=block_q,
         block_k=block_k, window=window,
     )
 
@@ -655,11 +654,11 @@ def _fused_bwd_fits(t_q_padded: int, dp: int) -> bool:
     return t_q_padded * dp * 4 <= _FUSED_BWD_DQ_BYTES
 
 
-def _query_band(window, t_q, t_k, block_q, block_k):
-    """The band of query blocks a key block meets (None without a window)."""
-    if window is None:
+def _query_band(causal, window, t_q, t_k, block_q, block_k):
+    """The band of query blocks a key block meets (None unless ``causal``)."""
+    if not causal:
         return None
-    return _Band(window, block_k, block_q, t_q // block_q, t_k // block_k, keys=False)
+    return _Band(window or t_q + t_k, block_k, block_q, t_q // block_q, t_k // block_k, keys=False)
 
 
 def _flash_bwd_fused(
@@ -678,15 +677,14 @@ def _flash_bwd_fused(
     group = h // h_kv
     grid = (b, h, (t_k + pk) // block_k, tq_p // block_q)
     of_q = lambda bi, hi, ki, qi: (bi, hi, qi, _I0)  # noqa: E731
+    band = _query_band(causal, window, tq_p, t_k + pk, block_q, block_k)
     kernel = functools.partial(
         _bwd_fused_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, window=window, band=band,
     )
-    if window is not None:
-        band = _query_band(window, tq_p, t_k + pk, block_q, block_k)
+    if causal:
         grid = grid[:3] + (band.steps,)
         of_q = lambda bi, hi, ki, qi: (bi, hi, band.block(ki, qi), _I0)  # noqa: E731
-        kernel = functools.partial(kernel, window=window, band=band)
     qo_spec = pl.BlockSpec((1, 1, block_q, dp), of_q, memory_space=pltpu.VMEM)
     # K and V are read by the group's head; dk and dv are written a query
     # head each (the resident dQ block pins a head to its grid row) and
@@ -802,13 +800,11 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
     group = h // h_kv
     grid_q = (b, h, (t_q + pq) // block_q, (t_k + pk) // block_k)
     of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), ki, _I0)  # noqa: E731
-    static = dict(scale=scale, causal=causal, kv_valid=kv_valid, block_q=block_q, block_k=block_k)
-    dq_kernel = functools.partial(_bwd_dq_kernel, **static)
-    if window is not None:
-        keys = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
+    static = dict(scale=scale, causal=causal, kv_valid=kv_valid, block_q=block_q, block_k=block_k, window=window)
+    keys = _key_band(causal, window, t_q + pq, t_k + pk, block_q, block_k)
+    if causal:
         grid_q = grid_q[:3] + (keys.steps,)
         of_k = lambda bi, hi, qi, ki: (bi, _kv_head(hi, group), keys.block(qi, ki), _I0)  # noqa: E731
-        dq_kernel = functools.partial(dq_kernel, window=window, band=keys)
     qo_spec = pl.BlockSpec(
         (1, 1, block_q, dp), lambda bi, hi, qi, ki: (bi, hi, qi, _I0),
         memory_space=pltpu.VMEM,
@@ -819,7 +815,7 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
         memory_space=pltpu.VMEM,
     )
     dq = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_bwd_dq_kernel, **static, band=keys),
         grid=grid_q,
         in_specs=[qo_spec, kv_spec_q, kv_spec_q, qo_spec, lm_spec_q, lm_spec_q],
         out_specs=qo_spec,
@@ -835,10 +831,10 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
     # dk/dv pass: K blocks on the parallel axis; the Q blocks of every query
     # head of the group sequential, so that dk and dv come out summed over them
     q_blocks = (t_q + pq) // block_q
-    queries = _query_band(window, t_q + pq, t_k + pk, block_q, block_k)
-    if window is not None:
+    queries = _query_band(causal, window, t_q + pq, t_k + pk, block_q, block_k)
+    if causal:
         q_blocks = queries.steps  # a head's steps: the band of this key block
-    in_band = (lambda ki, qi: qi) if window is None else queries.block
+    in_band = queries.block if causal else (lambda ki, qi: qi)
     grid_k = (b, h_kv, (t_k + pk) // block_k, group * q_blocks)
     if group == 1:
         of_q = lambda bi, hi, ki, qi: (bi, hi, in_band(ki, qi), _I0)  # noqa: E731
@@ -852,11 +848,8 @@ def _flash_bwd(scale, causal, kv_valid, block_q, block_k, interpret, res, g, win
         memory_space=pltpu.VMEM,
     )
     lm_spec_k = pl.BlockSpec((1, 1, block_q, _LANES), of_q, memory_space=pltpu.VMEM)
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, **static, q_blocks=q_blocks, group=group)
-    if window is not None:
-        dkv_kernel = functools.partial(dkv_kernel, window=window, band=queries)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_bwd_dkv_kernel, **static, q_blocks=q_blocks, group=group, band=queries),
         grid=grid_k,
         in_specs=[
             qo_spec_k, kv_spec_k, kv_spec_k, qo_spec_k, lm_spec_k, lm_spec_k,
@@ -922,13 +915,16 @@ def flash_attention(
     the VMEM budget). The fused path stays opt-in until the on-chip sweep
     (scripts/tpu_tune.py attn_bwd) records it winning.
 
-    ``window`` (with ``causal``): position ``t`` sees the keys ``t - window <
-    j <= t``, itself and the ``window - 1`` before it. The grid's key axis (for
-    dk/dv the query axis) then covers the blocks of that band alone, its block
-    index computed from the other axis's, and blocks wholly inside the band
-    take no mask; the kernels are named ``swa_fwd``, ``swa_bwd_dq``,
-    ``swa_bwd_dkv`` (``swa_bwd_fused``). ``window=None`` is the full form, its
-    kernels and grids as they were.
+    ``causal``: the grid's key axis (for dk/dv the query axis) covers the
+    blocks up to the diagonal alone, its block index computed from the other
+    axis's (a row's steps past its diagonal name the diagonal's block again and
+    copy nothing), and blocks wholly under the diagonal take no mask. ``window``
+    (with ``causal``): position ``t`` sees the keys ``t - window < j <= t``,
+    itself and the ``window - 1`` before it: the same grid over the band that a
+    lower edge leaves, the kernels named ``swa_fwd``, ``swa_bwd_dq``,
+    ``swa_bwd_dkv`` (``swa_bwd_fused``) where ``window=None`` keeps
+    ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv`` (``flash_bwd_fused``).
+    Without ``causal`` every block is visited.
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
@@ -946,8 +942,7 @@ def flash_attention(
         if not causal or int(window) < 1:
             raise ValueError(f"a window (got {window!r}) is a whole number of positions >= 1 and needs causal=True")
         window = int(window)
-    block_q = (512 if window is None else _WINDOW_BLOCK_Q) if block_q is None else block_q
-    block_k = (1024 if window is None else _WINDOW_BLOCK_K) if block_k is None else block_k
+    block_q, block_k = _tiles(window, block_q, block_k)
     # kernel works in (B, H, T, D); public layout is (B, T, H, D)
     if bwd_impl not in ("two_pass", "fused", "auto"):
         raise ValueError(
@@ -961,14 +956,18 @@ def flash_attention(
     return out.transpose(0, 2, 1, 3)
 
 
+def causal_grid(t_q: int, t_k: int, block_q: Optional[int] = None, block_k: Optional[int] = None, window: Optional[int] = None):
+    """``(visited, streamed, live)`` of the causal forward kernel's grid for
+    one head of ``t_q`` queries on ``t_k`` keys: its steps, those of them that
+    name another key block than the step before (what is copied) and the key
+    blocks that hold a pair some query sees (a grid that copies the band and
+    no more: the last two equal)."""
+    block_q, block_k, pq, pk, _ = _block_geometry(t_q, t_k, _LANES, *_tiles(window, block_q, block_k))
+    band = _key_band(True, window, t_q + pq, t_k + pk, block_q, block_k)
+    return band.visited, band.streamed, band.live
+
+
 def window_grid(t_q: int, t_k: int, window: int, block_q: Optional[int] = None, block_k: Optional[int] = None):
-    """``(visited, live)``: the key blocks that the windowed forward kernel's
-    grid visits for one head of ``t_q`` queries on ``t_k`` keys, and those of
-    them that hold a pair some query sees (a grid that covers the band and no
-    more: equal)."""
-    block_q, block_k, pq, pk, _ = _block_geometry(
-        t_q, t_k, _LANES, _WINDOW_BLOCK_Q if block_q is None else block_q,
-        _WINDOW_BLOCK_K if block_k is None else block_k,
-    )
-    band = _key_band(window, t_q + pq, t_k + pk, block_q, block_k)
-    return band.visited, band.live
+    """``(visited, live)`` of :func:`causal_grid` for the windowed form."""
+    visited, _, live = causal_grid(t_q, t_k, block_q, block_k, window)
+    return visited, live

@@ -189,22 +189,24 @@ def test_the_grid_covers_the_band_and_little_more():
 
 
 # sha256 of flash_attention's forward and backward with no window. "kernels": the four ``pallas_call`` equations
-# alone (grids, index maps, kernel bodies), recorded from the parent commit 4f3dc3a (PR 32) and the same at
-# 58dc9ba (PR 31): the full form's kernels are the parent's. "whole": the jaxpr round them, re-pinned by PR 34,
-# whose forward rule names ``out`` and the log-sum-exp's column (two ``name`` equations, a slice) and whose
-# backward prologue broadcasts the column back to the kernels' lanes
+# alone (grids, index maps, kernel bodies); "whole": the jaxpr round them (PR 34's forward rule names ``out`` and the
+# log-sum-exp's column, its backward prologue broadcasts the column back to the kernels' lanes). The form without
+# ``causal`` is still the one recorded from 4f3dc3a (PR 32; the same at 58dc9ba, PR 31, but for PR 34's "whole"):
+# every block visited, one body. The three causal forms were re-pinned by PR 38, which gave them the band's grid
+# (the key axis ends at a row's diagonal, blocks wholly under it take no mask: ``tests/test_flash_causal_band.py``
+# holds them to the parent's values and walks their index maps); their kernels keep the names ``flash_*``
 PARENT_FORM = {
     (16, 16, 4096, 128, "bfloat16", "two_pass", True): {
-        "kernels": "8e4b2de7ecd4e24fe20668ab08b328ef3416baabfb07f3d03d5aee39f01b72c6",
-        "whole": "6ad6931999a06b1fc38312066de93d870b6fd09dbaac53b3b534bfa08ce2fa74",
+        "kernels": "d4c35e2b70175ac8901d1a8054fca14463308bd2fde9368f886e2b01c1f3dd67",
+        "whole": "7697a068e45e7c2d4e5f07c64619df499149bc10d3a50bd732177dab36755870",
     },
     (16, 2, 8192, 256, "bfloat16", "two_pass", True): {
-        "kernels": "5858301964828e326d05fec6ca59b785a432fc1674046f879afe1f5e7b694db8",
-        "whole": "17a591df28b7e7896f3f066c84d2a5dd52b27ce367bae7d79f3e521c5c76b7b2",
+        "kernels": "1a9fc54602dca67a91391cd54fe6220c628b82569d1071df2dd999b5af4195c9",
+        "whole": "2c7c05b92d1fa87d5718f49acda7a4e807cab5267551d62a248ad5ca86ce0a95",
     },
     (4, 1, 384, 64, "float32", "fused", True): {
-        "kernels": "6a2cd251335edbe49414e97fd8a35275cefb030e089797ed69d6c337305f27b2",
-        "whole": "117111f0d78fff625884d283cce9f81053e6dea22140f6af0d43312999528f2f",
+        "kernels": "5f8c5cb4f836424e78ba2416eca579e463de1edb1acbd61464e2626e8da010ec",
+        "whole": "e4ddcc926588e6dfa605ebe51746e280afbb93fa9da29879dd18ec3d54d47850",
     },
     (4, 2, 300, 128, "float32", "two_pass", False): {
         "kernels": "79dc22c92d7e9c01f7b1f6822cd83e3bb4838a07b02036ec0c5f96ce4e9f6a3a",
@@ -216,6 +218,8 @@ PARENT_FORM = {
 @pytest.mark.parametrize("part", ["kernels", "whole"])
 @pytest.mark.parametrize("form", sorted(PARENT_FORM), ids=lambda f: f"{f[0]}on{f[1]}x{f[2]}x{f[3]}-{f[5]}")
 def test_without_a_window_the_kernels_lower_to_the_parents_form(form, part):
+    """Without ``causal``: the parent's form of PR 32, untouched. With it: the
+    band's grid as PR 38 recorded it; a change of either is made on purpose."""
     heads, kv_heads, t, d, dtype, bwd, causal = form
     lowered = _lowered(None, heads, kv_heads, t, d, jnp.dtype(dtype), bwd, causal)
     text = str(lowered) if part == "whole" else "\n".join(str(e) for e in _pallas_calls(lowered.jaxpr, []))
