@@ -12,10 +12,12 @@ from .data_parallel import DataParallel, DataParallelMultiGPU
 from .fsdp import FSDP
 from .pipeline import Pipeline
 from .transformer import (
+    GatedShortConv,
     MultiHeadAttention,
     TransformerBlock,
     TransformerLM,
     causal_lm_loss,
+    lfm2_24b_a2b,
     olmoe_1b_7b,
     qwen3_next_80b_a3b,
     trinity_mini,
@@ -30,6 +32,7 @@ __all__ = [
     "DroplessMoE",
     "FSDP",
     "GatedDeltaNet",
+    "GatedShortConv",
     "functional",
     "gated_delta_rule",
     "MoEMLP",
@@ -40,6 +43,7 @@ __all__ = [
     "TransformerBlock",
     "TransformerLM",
     "causal_lm_loss",
+    "lfm2_24b_a2b",
     "olmoe_1b_7b",
     "qwen3_next_80b_a3b",
     "read_routing",

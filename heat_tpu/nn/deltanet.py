@@ -124,6 +124,17 @@ def causal_depthwise_conv(x, w):
     return sum(padded[:, j:j + t] * w[:, j] for j in range(taps))
 
 
+def _conv_pullback(g, x, w):
+    """``g``, the cotangent of ``causal_depthwise_conv(x, w)``, pulled back to
+    ``x`` (the same taps reversed) and to ``w`` (a sum over positions a tap)."""
+    taps, t = w.shape[1], x.shape[1]
+    later = jnp.pad(g, ((0, 0), (0, taps - 1), (0, 0)))
+    g_x = sum(later[:, taps - 1 - j: taps - 1 - j + t] * w[:, j] for j in range(taps))
+    earlier = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    g_w = jnp.stack([jnp.sum(g * earlier[:, j:j + t], axis=(0, 1)) for j in range(taps)], axis=1)
+    return g_x, g_w
+
+
 @jax.custom_vjp
 def conv_silu(x, w):
     """``silu(causal_depthwise_conv(x, w))`` in float32. The backward pass
@@ -140,19 +151,43 @@ def _conv_silu_fwd(x, w):
 
 def _conv_silu_bwd(res, g):
     x, w = res
-    taps, t = w.shape[1], x.shape[1]
     x32 = x.astype(jnp.float32)
     pre = causal_depthwise_conv(x32, w)
     gate = jax.nn.sigmoid(pre)
-    g_pre = g * gate * (1.0 + pre * (1.0 - gate))
-    later = jnp.pad(g_pre, ((0, 0), (0, taps - 1), (0, 0)))
-    g_x = sum(later[:, taps - 1 - j: taps - 1 - j + t] * w[:, j] for j in range(taps))
-    earlier = jnp.pad(x32, ((0, 0), (taps - 1, 0), (0, 0)))
-    g_w = jnp.stack([jnp.sum(g_pre * earlier[:, j:j + t], axis=(0, 1)) for j in range(taps)], axis=1)
+    g_x, g_w = _conv_pullback(g * gate * (1.0 + pre * (1.0 - gate)), x32, w)
     return g_x.astype(x.dtype), g_w
 
 
 conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+@jax.custom_vjp
+def gated_short_conv(b, c, x, w):
+    """``c * causal_depthwise_conv(b * x, w)`` in float32: the short
+    convolution of the LFM2 family between its two multiplicative gates, ``b``,
+    ``c`` and ``x`` ``(B, T, C)``, ``w (C, K)``, no bias and no activation. The
+    backward pass keeps ``b``, ``c``, ``x`` and ``w`` alone and is written out
+    (the gated input and the convolution again, the cotangent carried to the
+    input by the same taps reversed, a sum over positions a tap): autodiff
+    would hold a shifted copy of every channel for every tap."""
+    f32 = jnp.float32
+    return c.astype(f32) * causal_depthwise_conv(b.astype(f32) * x.astype(f32), w)
+
+
+def _gated_short_conv_fwd(b, c, x, w):
+    return gated_short_conv(b, c, x, w), (b, c, x, w)
+
+
+def _gated_short_conv_bwd(res, g):
+    b, c, x, w = res
+    b32, c32, x32 = (a.astype(jnp.float32) for a in (b, c, x))
+    z = b32 * x32
+    g_z, g_w = _conv_pullback(g * c32, z, w)
+    g_c = g * causal_depthwise_conv(z, w)
+    return (g_z * x32).astype(b.dtype), g_c.astype(c.dtype), (g_z * b32).astype(x.dtype), g_w
+
+
+gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
 
 
 class GatedDeltaNet(nn.Module):

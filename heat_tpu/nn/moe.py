@@ -177,7 +177,7 @@ class DroplessMoE(nn.Module):
 
     ``score="sigmoid"`` scores each expert on its own: ``s = sigmoid(r)`` in
     float32 in place of the softmax; the weights are the chosen ``s`` (with
-    ``norm_topk`` over their sum + 1e-20), times ``route_scale``. The auxiliary
+    ``norm_topk`` over their sum + ``norm_topk_eps``), times ``route_scale``. The auxiliary
     terms stay defined: ``P_e`` is then the mean of ``s`` normalised to sum 1 a
     token, ``router_z`` as before over the logits. ``select_bias`` adds a bias
     ``b`` of one float32 an expert that only the *choice* sees: the top-k is
@@ -195,12 +195,14 @@ class DroplessMoE(nn.Module):
     them are computed; the others add nothing. The shares of all ranks, with
     the shared expert counted once, add up to the whole layer. The work
     around the experts goes with the rows that land here: they are gathered,
-    multiplied and summed back into their tokens in windows: the first, ``2 x``
-    an even share of the ``N * k`` assignments long, holds them all unless the
-    routing is far from even, and then further windows of one even share run,
-    behind a branch, as many as hold a row, until every held assignment is
-    computed (none is dropped). ``held`` (the assignments due here) is sown
-    beside ``computed``.
+    multiplied and summed back into their tokens in windows: the first,
+    ``held_window`` (2) x an even share of the ``N * k`` assignments long, holds
+    them all unless the routing is far from even, and then further windows of
+    one even share run, behind a branch, as many as hold a row, until every
+    held assignment is computed (none is dropped). A first window as long as
+    the assignments themselves (``held_window >= n_experts / count``) leaves
+    no further one: the layer's time then does not follow the routing.
+    ``held`` (the assignments due here) is sown beside ``computed``.
     """
 
     n_experts: int
@@ -217,6 +219,8 @@ class DroplessMoE(nn.Module):
     route_scale: float = 1.0
     shared_gate: bool = True
     select_bias: bool = False
+    norm_topk_eps: float = 1e-20  # added to the sum a sigmoid router's top-k weights are divided by
+    held_window: float = 2.0  # the held experts' first window, in even shares of the assignments
 
     @nn.compact
     def __call__(self, x):
@@ -254,7 +258,7 @@ class DroplessMoE(nn.Module):
                 weights, chosen = jax.lax.top_k(scores, k)  # (n, k), float32
             if self.norm_topk:
                 total = jnp.sum(weights, axis=-1, keepdims=True)
-                weights = weights / (total if self.score == "softmax" else total + 1e-20)
+                weights = weights / (total if self.score == "softmax" else total + self.norm_topk_eps)
             if self.route_scale != 1.0:
                 weights = weights * self.route_scale
             flat = chosen.reshape(n * k).astype(jnp.int32)
@@ -284,7 +288,7 @@ class DroplessMoE(nn.Module):
         else:
             out, computed = _held_experts(
                 xt.astype(self.dtype), flat, weights.reshape(n * k), counts[first:first + held],
-                experts, first, e, k, out_dtype,
+                experts, first, e, k, out_dtype, self.held_window,
             )
             sown["held"] = jnp.sum(counts[first:first + held])
 
@@ -418,13 +422,13 @@ def _further_windows_bwd(static, res, g):
 _further_windows.defvjp(_further_windows_fwd, _further_windows_bwd)
 
 
-def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtype):
+def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtype, window=2.0):
     """The part of an expert layer that its held experts (``experts``: their
     three weights, ``first`` the id of the first) give: ``(out (n, d) float32,
     computed)``. The ``n * k`` assignments are sorted with the held ones
     first, by expert and then by token (int32 keys); then windows of sorted
     rows are gathered, multiplied and summed back into their tokens. The first
-    window, ``2 x`` an even share of the assignments, always runs, and costs its
+    window, ``window`` (2) x an even share of the assignments, always runs, and costs its
     whole length whatever lies in it; the rows past it, if the routing leaves
     any, go a window of one even share at a time, behind one ``lax.cond``, as
     many windows as hold a row (:func:`_further_windows`). The first window is
@@ -432,11 +436,13 @@ def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtyp
     ms step of 8 of 128 experts: TPU v5e, my chip runs, PR 32, call 8), and the
     further ones go by the row because a share of few experts under a routing
     far from even passes 2 x in many steps (a layer's share read 0.16 to 2.96 x
-    even over ten seeds there, and a further window ~10 ms: call 10)."""
+    even over ten seeds there, and a further window ~10 ms: call 10). A share
+    whose routing drifts past 2 x inside a run's first steps is given a longer
+    one (LFM2-24B-A2B's cell: 5 x, 65 ms of a 556 ms step, PR 39)."""
     n = xt.shape[0]
     held = held_counts.shape[0]
     even = -(-n * k * held // e)
-    bound = min(n * k, -(-2 * even // 8) * 8)
+    bound = min(n * k, math.ceil(window * even / 8) * 8)
     more = min(n * k - bound, -(-even // 8) * 8)  # a further window's rows
     with jax.named_scope("moe.route"):
         local = flat - first

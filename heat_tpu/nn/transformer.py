@@ -29,21 +29,23 @@ The architecture is a set of fields, not a model file: ``norm``
 rotated), ``qk_norm`` (over all of hidden, or ``"head"``: over each head),
 ``num_kv_heads`` and ``head_dim``, ``attn_gate`` (a sigmoid gate on the
 attention output, projected beside the queries), ``mixers`` (the layer
-pattern: which mixer the blocks of one period take, ``"attention"`` or
-``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields),
+pattern: which mixer the blocks of one period take, ``"attention"``,
+``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields, or
+``"shortconv"``, :class:`GatedShortConv` with ``conv_taps`` taps),
 ``ffn`` (``"swiglu"`` | ``"moe"``, the dropless top-k expert layer of
-:mod:`heat_tpu.nn.moe`, with ``norm_topk``, ``shared_d_ff`` and
-``experts_held``; ``router_score``, ``route_scale``, ``router_bias`` and
+:mod:`heat_tpu.nn.moe`, with ``norm_topk``, ``norm_topk_eps``, ``shared_d_ff``,
+``experts_held`` and ``held_window``; ``router_score``, ``route_scale``, ``router_bias`` and
 ``shared_gate`` for a sigmoid router whose choice a bias corrects and an
 ungated shared expert), ``windows`` and ``rotary`` (one period each, beside
 ``mixers``: the sliding window of a block's attention, ``None`` = full, and
 whether it rotates its queries and keys), ``sandwich_norm`` (four norms a
 block: one more on each branch's output), ``embed_scale``, ``dense_layers``
 and ``dense_d_ff`` (leading blocks with a SwiGLU of that width before the
-expert blocks), ``init_std`` and ``accum_dtype``. The defaults are the
+expert blocks), ``tie_embeddings`` (the head is the embedding table: no
+``lm_head``), ``init_std`` and ``accum_dtype``. The defaults are the
 pre-LN, learned-position, SwiGLU model this module began with, parameter tree
-and numerics unchanged. :func:`olmoe_1b_7b`, :func:`qwen3_next_80b_a3b` and
-:func:`trinity_mini` name the published configurations; :func:`causal_lm_loss` is their training
+and numerics unchanged. :func:`olmoe_1b_7b`, :func:`qwen3_next_80b_a3b`,
+:func:`trinity_mini` and :func:`lfm2_24b_a2b` name the published configurations; :func:`causal_lm_loss` is their training
 loss.
 
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
@@ -63,7 +65,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.pallas_attention import KEPT_RESIDUALS
-from .deltanet import GatedDeltaNet
+from .deltanet import GatedDeltaNet, gated_short_conv
 from .functional import blocked_cross_entropy
 from .moe import DroplessMoE
 
@@ -287,10 +289,61 @@ class MultiHeadAttention(nn.Module):
         )(o)
 
 
+class GatedShortConv(nn.Module):
+    """The gated short convolution, the mixer of the LFM2 family (HF
+    ``modeling_lfm2_moe.py``), ``(B, T, D_model)`` in and out::
+
+        [b | c | x] = u W_in                 W_in: D -> 3 D, split in thirds in that order
+        y_t = sum_j w[:, j] * (b * x)_{t - (K - 1) + j}      depthwise, causal, no bias, no activation
+        out = (c * y) W_out
+
+    No state beyond the ``K - 1`` earlier positions, no positions. The two
+    gates and the taps are float32 (:func:`heat_tpu.nn.deltanet.gated_short_conv`,
+    whose backward pass keeps ``b``, ``c``, ``x`` and ``w`` alone); the two
+    projections take ``dtype`` operands and give ``accum_dtype`` results, under
+    the scope ``conv.project``; the gates and the taps run under ``conv.mix``.
+    The counter ``conv.mixers`` counts the mixers a trace builds. The taps are
+    drawn uniform in ``+-1 / sqrt(K)`` (torch's draw for a depthwise ``Conv1d``
+    of ``K`` taps: a convolution that passes its input on at its own size)."""
+
+    taps: int = 3
+    dtype: Any = jnp.float32
+    accum_dtype: Optional[Any] = None
+    matrix_init: Any = None  # None: lecun_normal
+    out_init: Any = None  # None: as matrix_init
+
+    @nn.compact
+    def __call__(self, u):
+        d = u.shape[-1]
+        out_dtype = self.dtype if self.accum_dtype is None else self.accum_dtype
+        init = nn.initializers.lecun_normal() if self.matrix_init is None else self.matrix_init
+        w_in = self.param("in_proj", init, (d, 3 * d), jnp.float32)
+        w = self.param(
+            "conv", nn.initializers.variance_scaling(1 / 3, "fan_in", "uniform", in_axis=1, out_axis=0),
+            (d, self.taps), jnp.float32,
+        )
+        w_out = self.param("out_proj", init if self.out_init is None else self.out_init, (d, d), jnp.float32)
+        telemetry.get_registry().add("conv.mixers")
+
+        def project(a, m):
+            return jnp.dot(a.astype(self.dtype), m.astype(self.dtype), preferred_element_type=out_dtype)
+
+        with jax.named_scope("conv.project"):
+            # one matrix, read in three column ranges: no (tokens, 3 D) result to slice
+            b, c, x = (project(u, w_in[:, i * d:(i + 1) * d]) for i in range(3))
+        with jax.named_scope("conv.mix"):
+            y = gated_short_conv(b, c, x, w)
+        with jax.named_scope("conv.project"):
+            return project(y, w_out)
+
+
+MIXERS = ("attention", "deltanet", "shortconv")
+
+
 class TransformerBlock(nn.Module):
     """Pre-norm residual block: x + mixer(norm(x)); x + ffn(norm(x)), the
-    mixer attention or the Gated DeltaNet, the feed-forward a SwiGLU MLP or
-    the dropless expert layer."""
+    mixer attention, the Gated DeltaNet or the gated short convolution, the
+    feed-forward a SwiGLU MLP or the dropless expert layer."""
 
     num_heads: int
     mlp_ratio: float = 4.0
@@ -309,7 +362,7 @@ class TransformerBlock(nn.Module):
     num_experts: int = 0
     experts_per_token: int = 0
     accum_dtype: Optional[Any] = None
-    mixer: str = "attention"  # or "deltanet"
+    mixer: str = "attention"  # one of MIXERS
     num_kv_heads: Optional[int] = None
     head_dim: Optional[int] = None
     rotary_fraction: float = 1.0
@@ -330,6 +383,9 @@ class TransformerBlock(nn.Module):
     route_scale: float = 1.0
     router_bias: bool = False
     shared_gate: bool = True
+    conv_taps: int = 3
+    norm_topk_eps: float = 1e-20
+    held_window: float = 2.0
 
     @nn.compact
     def __call__(self, x):
@@ -356,8 +412,12 @@ class TransformerBlock(nn.Module):
                 self.norm, self.rotary_fraction, self.attn_gate, matrix, out, self.window,
                 name="attn",
             )(h))
+        elif self.mixer == "shortconv":
+            x = x + after("ln1_post", GatedShortConv(
+                self.conv_taps, self.dtype, self.accum_dtype, matrix, out, name="conv",
+            )(h))
         else:
-            raise ValueError(f"mixer must be 'attention' or 'deltanet', got {self.mixer!r}")
+            raise ValueError(f"mixer must be one of {MIXERS}, got {self.mixer!r}")
         h = _norm(self.norm, self.norm_eps, stream, "ln2")(x)
         d_ff = int(d_model * self.mlp_ratio) if self.d_ff is None else self.d_ff
         if self.ffn == "moe":
@@ -368,7 +428,7 @@ class TransformerBlock(nn.Module):
                 experts_held=self.experts_held, matrix_init=matrix, out_init=out,
                 score=self.router_score, route_scale=self.route_scale,
                 shared_gate=self.shared_gate, select_bias=self.router_bias,
-                name="moe",
+                norm_topk_eps=self.norm_topk_eps, held_window=self.held_window, name="moe",
             )(h))
         if self.ffn != "swiglu":
             raise ValueError(f"ffn must be 'swiglu' or 'moe', got {self.ffn!r}")
@@ -381,7 +441,9 @@ class TransformerBlock(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    """Causal LM: token embedding → blocks → final LN → tied-untied logits."""
+    """Causal LM: token embedding → blocks → final LN → logits, by a head of
+    its own (``lm_head``) or, with ``tie_embeddings``, by the embedding table
+    itself (``params`` then holds no ``lm_head``)."""
 
     vocab_size: int
     d_model: int
@@ -447,6 +509,10 @@ class TransformerLM(nn.Module):
     route_scale: float = 1.0
     router_bias: bool = False
     shared_gate: bool = True
+    conv_taps: int = 3  # the taps of a "shortconv" mixer
+    norm_topk_eps: float = 1e-20  # a sigmoid router's top-k weights are divided by their sum + this
+    tie_embeddings: bool = False  # logits = h E^T: the head is the embedding table
+    held_window: float = 2.0  # with ``experts_held``: the first window of held rows, in even shares (DroplessMoE)
 
     def mixer_of(self, i: int) -> str:
         return self.mixers[i % len(self.mixers)]
@@ -475,10 +541,11 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"sequence length {tokens.shape[-1]} exceeds max_len {self.max_len}"
             )
-        x = nn.Embed(
+        embed = nn.Embed(
             self.vocab_size, self.d_model, dtype=stream, name="embed",
             **_given(_matrix_init(self.init_std), "embedding_init"),
-        )(tokens)
+        )
+        x = embed(tokens)
         if self.embed_scale is not None:
             x = x * jnp.asarray(self.embed_scale, x.dtype)
         if self.positions == "learned":
@@ -515,10 +582,17 @@ class TransformerLM(nn.Module):
                 self.gdn_key_dim, self.gdn_value_dim, self.gdn_conv, self.norm_topk,
                 self.shared_d_ff, self.experts_held, self.init_std, self.out_init_std,
                 self.window_of(i), self.sandwich_norm, self.router_score, self.route_scale,
-                self.router_bias, self.shared_gate,
+                self.router_bias, self.shared_gate, self.conv_taps, self.norm_topk_eps, self.held_window,
                 name=f"block{i}",
             )(x)
         x = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
+        if self.tie_embeddings:
+            if not head:
+                return x
+            with jax.named_scope("lm.tied_head"):
+                return jnp.dot(
+                    x.astype(self.dtype), embed.embedding.astype(self.dtype).T, preferred_element_type=stream,
+                )
         lm_head = nn.Dense(
             self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
             dot_general=_dot_general(self.accum_dtype), **_given(_matrix_init(self.init_std)),
@@ -626,6 +700,47 @@ def trinity_mini(
     return TransformerLM(**{**arch, **fields})
 
 
+def lfm2_24b_a2b(
+    num_layers: int = 40, experts_held: Optional[Tuple[int, int]] = None, vocab_size: int = 65536,
+    first_block: int = 0, **fields
+) -> TransformerLM:
+    """LFM2-24B-A2B (Liquid AI; ``config.json`` of ``LiquidAI/LFM2-24B-A2B``,
+    ``model_type`` ``lfm2_moe``, equations of HF ``modeling_lfm2_moe.py``) at its
+    published widths: hidden 2048; blocks in periods of (conv, conv, attention,
+    conv): the **gated short convolution** (:class:`GatedShortConv`, 3 taps, no
+    bias) and grouped-query attention (32 query heads on 8 key-value heads of
+    64, a plain RMSNorm, eps 1e-5, on each query and key head before rotary at
+    theta 1e6, no gate, no window); two norms a block; blocks 0 and 1 a SwiGLU
+    of width 11,776, every later block 64 experts of width 1,536 chosen by
+    ``sigmoid(x W_r) + b``, the top 4 weighted by their sigmoid over the four's
+    sum + 1e-6, no shared expert; the head tied to the 65,536-row embedding.
+    ``b`` is the collection ``route_bias`` beside ``params``, moved after every
+    step by :func:`heat_tpu.nn.balance_bias_rule` through
+    ``make_train_step(state_rule=)``. bfloat16 matmul operands, float32
+    everything else; matrices drawn at 0.02, those that write into the residual
+    stream at ``0.02 / sqrt(2 * 40)``.
+
+    ``num_layers``, ``experts_held`` and ``vocab_size`` are what one chip's
+    share of a deployment sets (as for :func:`qwen3_next_80b_a3b`), and
+    ``first_block``: the published block that this stage's first one is, which
+    turns the mixers' period to start there and leaves ``2 - first_block``
+    leading dense blocks. ``fields`` passes what is not architecture
+    (``attn_impl``, ``comm``, ``remat``, ...)."""
+    period = ("shortconv", "shortconv", "attention", "shortconv")
+    arch = dict(
+        vocab_size=vocab_size, d_model=2048, num_heads=32, num_layers=num_layers,
+        max_len=128000, norm="rmsnorm", norm_eps=1e-5, positions="rope", rope_theta=1e6,
+        qk_norm="head", num_kv_heads=8, head_dim=64,
+        mixers=tuple(period[(first_block + i) % len(period)] for i in range(len(period))), conv_taps=3,
+        dense_layers=max(0, 2 - first_block), dense_d_ff=11776,
+        ffn="moe", d_ff=1536, num_experts=64, experts_per_token=4, norm_topk=True, norm_topk_eps=1e-6,
+        router_score="sigmoid", router_bias=True, experts_held=experts_held, tie_embeddings=True,
+        init_std=0.02, out_init_std=0.02 / math.sqrt(2 * 40),
+        dtype=jnp.bfloat16, accum_dtype=jnp.float32, attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
 def causal_lm_loss(
     model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0,
 ):
@@ -659,10 +774,16 @@ def causal_lm_loss(
             mask = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
             weights = mask.astype(jnp.float32) / (b * (t - 1))
         with jax.named_scope("lm.head_loss"):
-            ce = blocked_cross_entropy(
-                hidden.reshape(b * t, -1), params["params"]["lm_head"]["kernel"],
-                targets, weights, dtype=model.dtype,
+            head = functools.partial(
+                blocked_cross_entropy, hidden.reshape(b * t, -1), targets=targets, weights=weights, dtype=model.dtype
             )
+            if model.tie_embeddings:
+                # the table is the head: its gradient is the gather's rows plus the head's product
+                telemetry.get_registry().add("lm.head.tied")
+                with jax.named_scope("lm.tied_head"):
+                    ce = head(kernel=params["params"]["embed"]["embedding"].T)
+            else:
+                ce = head(kernel=params["params"]["lm_head"]["kernel"])
         layers = [state["aux"][f"block{i}"]["moe"]["moe"][0] for i in model.expert_layers()]
         with jax.named_scope("lm.loss"):  # the terms beside the cross-entropy, their sum
             zero = jnp.zeros((), jnp.float32)

@@ -37,6 +37,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry
+
 NEG_INF = -1e30
 # the names `_flash_fwd` gives the attention's output and its log-sum-exp (one
 # float a row): what `jax.checkpoint_policies.save_only_these_names` takes to
@@ -292,6 +294,9 @@ def _pad_blocks(q, k, v, t_q, t_k, d, block_q, block_k):
     block_q, block_k, pq, pk, pd = _block_geometry(
         t_q, t_k, d, block_q, block_k
     )
+    # lanes added to the head dimension, a kernel call traced: every product of
+    # the kernel then carries them as zeros (a head of 64 runs at half)
+    telemetry.get_registry().add("attn.lanes_padded", pd)
     if pq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pq), (0, 0)))
     if pk:
