@@ -195,14 +195,28 @@ class DroplessMoE(nn.Module):
     them are computed; the others add nothing. The shares of all ranks, with
     the shared expert counted once, add up to the whole layer. The work
     around the experts goes with the rows that land here: they are gathered,
-    multiplied and summed back into their tokens in windows: the first,
-    ``held_window`` (2) x an even share of the ``N * k`` assignments long, holds
-    them all unless the routing is far from even, and then further windows of
-    one even share run, behind a branch, as many as hold a row, until every
-    held assignment is computed (none is dropped). A first window as long as
-    the assignments themselves (``held_window >= n_experts / count``) leaves
-    no further one: the layer's time then does not follow the routing.
-    ``held`` (the assignments due here) is sown beside ``computed``.
+    multiplied and summed back into their tokens in windows of the sorted
+    assignments: the first, ``held_window`` (2) x an even share of the
+    ``N * k`` assignments long, holds them all unless the routing is far from
+    even, and then further windows of one even share run, behind a branch, as
+    many as hold a row, until every held assignment is computed (none is
+    dropped). Where the first window is longer than 3 even shares, a window is
+    the length of its buffers, not of its work: the gather of its rows, the
+    elementwise pass between the grouped products and the sum back into the
+    tokens go over blocks of a quarter of an even share, as many as hold a
+    held row (a trip count read from the routing), and the grouped products
+    skip what lies past their last group, so a step's time follows the rows
+    that land here and not the window (``held_window`` 5 for one even share
+    of live rows: 555 ms a step with every pass over the window, 514 with
+    the blocks: TPU v5e, my chip runs, PR 40, calls 1 and 2). A shorter
+    window is moved whole, one pass each: a row costs 2 to 3 times as much
+    in a block's gather and scatter-add as in a whole window's, so at 2
+    shares, half of them live, the blocks lose (calls 4 and 5).
+    A first window as long as the assignments themselves (``held_window >=
+    n_experts / count``) leaves no further one and no branch.
+    ``held`` (the assignments due here) and ``moved`` (the rows gone over, by
+    blocks or by whole windows: 1.0 x ``held`` is movement that touches live
+    rows only) are sown beside ``computed``.
     """
 
     n_experts: int
@@ -286,7 +300,7 @@ class DroplessMoE(nn.Module):
                 out = jnp.einsum("nk,nkd->nd", weights, y.astype(jnp.float32))
             computed = rows_computed(by_expert, counts)
         else:
-            out, computed = _held_experts(
+            out, computed, sown["moved"] = _held_experts(
                 xt.astype(self.dtype), flat, weights.reshape(n * k), counts[first:first + held],
                 experts, first, e, k, out_dtype, self.held_window,
             )
@@ -324,56 +338,214 @@ class DroplessMoE(nn.Module):
         return out.astype(x.dtype).reshape(b, t, d)
 
 
+# A first window longer than this many even shares of the assignments is moved
+# in blocks of an even share over LIVE_BLOCKS_A_SHARE (rounded up to 8 rows), as
+# many blocks as hold a held row; a shorter one whole, in one pass. Both from
+# readings on the chip: :func:`_held_experts`
+BLOCKS_FROM_SHARES = 3
+LIVE_BLOCKS_A_SHARE = 4
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _take_rows(xt, tokens, n):
-    """``xt[tokens]``; its transpose sums the rows back into their tokens
-    (``segment_sum``), as :func:`_sum_rows` does forward."""
+def _take_window(xt, tokens, n):
+    """``xt[tokens]``, a window whole; its transpose sums the rows back into
+    their tokens (``segment_sum``)."""
     return xt[tokens]
 
 
-def _take_rows_fwd(xt, tokens, n):
+def _take_window_fwd(xt, tokens, n):
     return xt[tokens], tokens
 
 
-def _take_rows_bwd(n, tokens, g):
+def _take_window_bwd(n, tokens, g):
     return jax.ops.segment_sum(g, tokens, num_segments=n), None
+
+
+_take_window.defvjp(_take_window_fwd, _take_window_bwd)
+
+
+def _live_blocks(block, bound, live, body, start):
+    """``body(first, at, carry)`` over the blocks of ``block`` rows that hold
+    one of a window's ``live`` first rows: a loop whose trip count is read from
+    the routing. A block covers rows ``at .. at + block - 1``; ``at`` is
+    ``first = j * block`` but in the last block of a window that is no whole
+    number of them, which lies back over the one before (to write such a row
+    again writes the same; a sum takes the rows from ``first`` on)."""
+
+    def step(j, carry):
+        return body(j * block, jnp.minimum(j * block, bound - block), carry)
+
+    return jax.lax.fori_loop(jnp.zeros((), jnp.int32), -(-live // block), step, start)
+
+
+def _sum_live(block, start, y, weights, tokens, live):
+    """``start`` ``(n, d)`` float32 with the rows ``i < live`` of ``y`` (times
+    ``weights[i]``, where given) added into its rows ``tokens[i]``, a block at a
+    time in place; no row of ``y`` from ``live`` on is read into the sum (it
+    is selected out, never multiplied by a zero)."""
+    bound = tokens.shape[0]
+
+    def body(first, at, acc):
+        at_rows = at + jnp.arange(block, dtype=jnp.int32)
+        rows = jax.lax.dynamic_slice_in_dim(y, at, block).astype(jnp.float32)
+        if weights is not None:
+            rows = rows * jax.lax.dynamic_slice_in_dim(weights, at, block)[:, None]
+        rows = jnp.where(((at_rows >= first) & (at_rows < live))[:, None], rows, 0)
+        return acc.at[jax.lax.dynamic_slice_in_dim(tokens, at, block)].add(rows)
+
+    return _live_blocks(block, bound, live, body, start)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _take_rows(block, n, xt, tokens, live):
+    """``xt[tokens[i]]`` for the rows ``i < live`` and zeros from there on,
+    gathered a block at a time into a buffer of ``len(tokens)`` rows; its
+    transpose sums the live rows back into their ``n`` tokens, as
+    :func:`_sum_rows` does forward."""
+    bound, d = tokens.shape[0], xt.shape[1]
+
+    def body(first, at, rows):
+        keep = at + jnp.arange(block, dtype=jnp.int32) < live
+        got = jnp.where(keep[:, None], xt[jax.lax.dynamic_slice_in_dim(tokens, at, block)], 0)
+        return jax.lax.dynamic_update_slice_in_dim(rows, got, at, 0)
+
+    return _live_blocks(block, bound, live, body, jnp.zeros((bound, d), xt.dtype))
+
+
+def _take_rows_fwd(block, n, xt, tokens, live):
+    return _take_rows(block, n, xt, tokens, live), (tokens, live)
+
+
+def _take_rows_bwd(block, n, res, g):
+    start = jnp.zeros((n, g.shape[1]), jnp.float32)
+    return _sum_live(block, start, g, None, *res).astype(g.dtype), None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _swiglu(rows, sizes, experts, out_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sum_rows(block, start, y, weights, tokens, live):
+    """``start + sum_i weights[i] * y[i]`` into row ``tokens[i]`` (``(n, d)``
+    float32) over the rows ``i < live`` (:func:`_sum_live`); its transpose
+    gathers the cotangent's rows a block at a time, as :func:`_take_rows` does
+    forward, and forms the weights' gradient (a row dot) in the same loop."""
+    return _sum_live(block, start, y, weights, tokens, live)
+
+
+def _sum_rows_fwd(block, start, y, weights, tokens, live):
+    return _sum_rows(block, start, y, weights, tokens, live), (y, weights, tokens, live)
+
+
+def _sum_rows_bwd(block, res, g):
+    y, weights, tokens, live = res
+
+    def body(first, at, carry):
+        keep = at + jnp.arange(block, dtype=jnp.int32) < live
+        got = g[jax.lax.dynamic_slice_in_dim(tokens, at, block)]
+        rows = jax.lax.dynamic_slice_in_dim(y, at, block).astype(jnp.float32)
+        w = jax.lax.dynamic_slice_in_dim(weights, at, block)
+        dy = jnp.where(keep[:, None], w[:, None] * got, 0).astype(y.dtype)
+        dw = jnp.where(keep, jnp.sum(rows * got, axis=-1), 0).astype(weights.dtype)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0) for whole, part in zip(carry, (dy, dw)))
+
+    dy, dw = _live_blocks(block, y.shape[0], live, body, (jnp.zeros_like(y), jnp.zeros_like(weights)))
+    return g, dy, dw, None, None
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def _gate(gate, up, dtype):
+    return (nn.silu(gate) * up).astype(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _gate_rows(block, dtype, gate, up, live):
+    """``silu(gate) * up`` as ``dtype`` over the blocks that hold one of the
+    ``live`` first rows; zeros in the blocks past them (no grouped product
+    reads a row from ``live`` on, whatever it holds)."""
+
+    def body(first, at, hidden):
+        got = _gate(*(jax.lax.dynamic_slice_in_dim(a, at, block) for a in (gate, up)), dtype)
+        return jax.lax.dynamic_update_slice_in_dim(hidden, got, at, 0)
+
+    return _live_blocks(block, gate.shape[0], live, body, jnp.zeros(gate.shape, dtype))
+
+
+def _gate_rows_fwd(block, dtype, gate, up, live):
+    return _gate_rows(block, dtype, gate, up, live), (gate, up, live)
+
+
+def _gate_rows_bwd(block, dtype, res, g):
+    gate, up, live = res
+
+    def body(first, at, carry):
+        here = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block)  # noqa: E731
+        got = jax.vjp(functools.partial(_gate, dtype=dtype), here(gate), here(up))[1](here(g))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(whole, part, at, 0) for whole, part in zip(carry, got))
+
+    return (*_live_blocks(block, gate.shape[0], live, body, (jnp.zeros_like(gate), jnp.zeros_like(up))), None)
+
+
+_gate_rows.defvjp(_gate_rows_fwd, _gate_rows_bwd)
+
+
+def _swiglu(rows, sizes, experts, out_dtype, live_blocks=None):
     """``(silu(rows Wg) * (rows Wu)) Wd`` as three grouped products over
     ``sizes`` rows an expert; ``experts`` = ``(Wg, Wu, Wd)`` in the operands'
-    dtype."""
+    dtype. ``live_blocks = (block, live)``: the elementwise pass between the
+    products goes over the blocks of the ``live`` first rows alone."""
     grouped = functools.partial(jax.lax.ragged_dot, group_sizes=sizes, preferred_element_type=out_dtype)
-    hidden = (nn.silu(grouped(rows, experts[0])) * grouped(rows, experts[1])).astype(rows.dtype)
+    gate, up = grouped(rows, experts[0]), grouped(rows, experts[1])
+    if live_blocks is None:
+        hidden = (nn.silu(gate) * up).astype(rows.dtype)
+    else:
+        hidden = _gate_rows(live_blocks[0], rows.dtype, gate, up, live_blocks[1])
     return grouped(hidden, experts[2])
 
 
-def _window(static, diff, ints, lo):
+def _window(static, diff, ints, lo, start):
     """The window of ``bound`` sorted held rows from row ``lo``: gather them,
     the grouped products over the part of every expert's group that lies in
-    the window, and the weighted sum back into the rows' tokens. ``(part (n, d)
-    float32, rows computed)``."""
-    bound, k, out_dtype = static
+    the window, and the weighted sum back into the rows' tokens, added to
+    ``start``. With a ``block``, the gather, the pass between the products and
+    the sum go over the blocks of that many rows that hold a held row; without
+    (``None``), over the window in one pass each, whatever lies in it.
+    ``(start + the window's part (n, d) float32, int32 (rows computed, rows moved))``."""
+    bound, block, k, out_dtype = static
     xt, experts, sorted_weights = diff
     order, by_expert, starts, ends = ints
     n = xt.shape[0]
+    # a grouped product leaves the rows past its last group as they lay in
+    # memory (on the chip; the CPU zeroes them), forward and backward: both
+    # ways those rows are selected out or never read, never multiplied by a zero
     with jax.named_scope("moe.route"):
         tokens = jax.lax.dynamic_slice_in_dim(order, lo, bound) // k
-        live = lo + jnp.arange(bound, dtype=jnp.int32) < ends[-1]
         sizes = jnp.clip(ends, lo, lo + bound) - jnp.clip(starts, lo, lo + bound)
-        # a grouped product leaves the rows past its last group as they lay in
-        # memory (on the chip; the CPU zeroes them), forward and backward:
-        # both ways those rows are selected out, never multiplied by a zero
-        rows = jnp.where(live[:, None], _take_rows(xt, tokens, n), 0)
-    with jax.named_scope("moe.experts"):
-        y = jnp.where(live[:, None], _swiglu(rows, sizes, experts, out_dtype), 0)
-    with jax.named_scope("moe.combine"):
-        w = jnp.where(live, jax.lax.dynamic_slice_in_dim(sorted_weights, lo, bound), 0.0)
-        part = jax.ops.segment_sum(w[:, None] * y.astype(jnp.float32), tokens, num_segments=n)
-    return part, rows_computed(jax.lax.dynamic_slice_in_dim(by_expert, lo, bound), sizes)
+    weights = functools.partial(jax.lax.dynamic_slice_in_dim, sorted_weights, lo, bound)
+    if block is None:
+        live = lo + jnp.arange(bound, dtype=jnp.int32) < ends[-1]
+        with jax.named_scope("moe.route"):
+            rows = jnp.where(live[:, None], _take_window(xt, tokens, n), 0)
+        with jax.named_scope("moe.experts"):
+            y = jnp.where(live[:, None], _swiglu(rows, sizes, experts, out_dtype), 0)
+        with jax.named_scope("moe.combine"):
+            weighted = jnp.where(live, weights(), 0.0)[:, None] * y.astype(jnp.float32)
+            out = start + jax.ops.segment_sum(weighted, tokens, num_segments=n)
+        moved = jnp.full((), bound, jnp.int32)
+    else:
+        block = min(block, bound)
+        live = jnp.clip(ends[-1] - lo, 0, bound)
+        with jax.named_scope("moe.route"):
+            rows = _take_rows(block, n, xt, tokens, live)
+        with jax.named_scope("moe.experts"):
+            y = _swiglu(rows, sizes, experts, out_dtype, (block, live))
+        with jax.named_scope("moe.combine"):
+            out = _sum_rows(block, start, y, weights(), tokens, live)
+        moved = -(-live // block) * block
+    computed = rows_computed(jax.lax.dynamic_slice_in_dim(by_expert, lo, bound), sizes)
+    return out, jnp.stack([computed, moved])
 
 
 def _live_further(static, ints):
@@ -385,7 +557,7 @@ def _live_further(static, ints):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _further_windows(static, diff, ints):
     """The sum of the windows past the first (``static``: the first's length,
-    theirs, ``k``, the products' dtype): a loop over as many of them as hold a
+    theirs, a block's, ``k``, the products' dtype): a loop over as many of them as hold a
     held row, so that what a step pays for an uneven routing goes with the
     rows that overflow, a window of one even share at a time. The backward pass
     goes over the same windows again and keeps nothing a window: a loop's own
@@ -395,10 +567,10 @@ def _further_windows(static, diff, ints):
     n, d = diff[0].shape
 
     def body(j, carry):
-        part, done = _window(static[1:], diff, ints, first + j * bound)
-        return carry[0] + part, carry[1] + done
+        out, done = _window(static[1:], diff, ints, first + j * bound, carry[0])
+        return out, carry[1] + done
 
-    start = (jnp.zeros((n, d), jnp.float32), jnp.zeros((), jnp.int32))
+    start = (jnp.zeros((n, d), jnp.float32), jnp.zeros((2,), jnp.int32))
     return jax.lax.fori_loop(jnp.zeros((), jnp.int32), _live_further(static, ints), body, start)
 
 
@@ -411,8 +583,8 @@ def _further_windows_bwd(static, res, g):
     diff, ints = res
 
     def body(j, acc):
-        _, transpose = jax.vjp(lambda operands: _window(static[1:], operands, ints, first + j * bound)[0], diff)
-        return jax.tree.map(lambda a, got: a + got.astype(jnp.float32), acc, transpose(g[0])[0])
+        window = lambda operands: _window(static[1:], operands, ints, first + j * bound, jnp.zeros_like(g[0]))[0]  # noqa: E731
+        return jax.tree.map(lambda a, got: a + got.astype(jnp.float32), acc, jax.vjp(window, diff)[1](g[0])[0])
 
     acc = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), diff)
     acc = jax.lax.fori_loop(jnp.zeros((), jnp.int32), _live_further(static, ints), body, acc)
@@ -425,20 +597,42 @@ _further_windows.defvjp(_further_windows_fwd, _further_windows_bwd)
 def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtype, window=2.0):
     """The part of an expert layer that its held experts (``experts``: their
     three weights, ``first`` the id of the first) give: ``(out (n, d) float32,
-    computed)``. The ``n * k`` assignments are sorted with the held ones
+    computed, moved)``. The ``n * k`` assignments are sorted with the held ones
     first, by expert and then by token (int32 keys); then windows of sorted
-    rows are gathered, multiplied and summed back into their tokens. The first
-    window, ``window`` (2) x an even share of the assignments, always runs, and costs its
-    whole length whatever lies in it; the rows past it, if the routing leaves
-    any, go a window of one even share at a time, behind one ``lax.cond``, as
-    many windows as hold a row (:func:`_further_windows`). The first window is
-    no longer than that because every step pays for it (at 4 x, 46 ms of a 1,100
-    ms step of 8 of 128 experts: TPU v5e, my chip runs, PR 32, call 8), and the
-    further ones go by the row because a share of few experts under a routing
-    far from even passes 2 x in many steps (a layer's share read 0.16 to 2.96 x
-    even over ten seeds there, and a further window ~10 ms: call 10). A share
-    whose routing drifts past 2 x inside a run's first steps is given a longer
-    one (LFM2-24B-A2B's cell: 5 x, 65 ms of a 556 ms step, PR 39)."""
+    rows are gathered, multiplied and summed back into their tokens
+    (:func:`_window`). The first window is ``window`` (2) x an even share of
+    the assignments long; the rows past it, if the routing leaves any, go a
+    window of one even share at a time, behind one ``lax.cond``, as many
+    windows as hold a row (:func:`_further_windows`), and the first window adds
+    its rows onto what they gave. **A first window longer than
+    ``BLOCKS_FROM_SHARES`` (3) even shares goes by blocks**: its length is what
+    its buffers hold and the grouped products are handed, and what is done
+    round the products goes by blocks of ``even / LIVE_BLOCKS_A_SHARE`` rows,
+    as many as hold a held row, so a long window costs its zero fills and
+    little more: with 5 even shares for one of live rows (LFM2-24B-A2B's
+    cell, 40,960 rows for ~8,000) a step took 555.2-556.5 ms with every pass
+    over the window and 512.9-516.2 by blocks; blocks of a half, a quarter and
+    an eighth of a share read 523.6-528.8, 514.8-518.5 and 514.0-517.2 before
+    the sums ran onto one another, and a quarter and an eighth 512.9-516.2
+    and 513.3-516.5 after: a quarter it is (TPU v5e, three seeds of 30 steps,
+    my chip runs, PR 40, calls 1 and 2). What such a window still pays by its
+    length: the zero fills of its buffers (~16 ms a step there) and, in the
+    grouped products' own transposes, the casts and the sum of the row
+    gradients (~11 ms). **A shorter first window is moved whole**, one gather,
+    select and ``segment_sum`` each, whatever lies in it: a row of a block
+    costs ~245 ns in the loop's scatter-add and ~65 in its gather where a
+    whole window's cost ~91 and ~33 (XLA sorts a whole scatter's indices; a
+    sort a block, tried, costs more than it gives), so blocks pay from about
+    3 window rows a live one: at 2 even shares with one live, by blocks,
+    Trinity-Mini's step read +0.0 to +0.6% and Qwen3-Next's +2.5 to +2.9%
+    (calls 4 and 5); at 3, in the LFM2 cell's shapes, 499.0-502.1 ms by
+    blocks for 510.9-511.9 whole, and at 4 by blocks 506.2-508.6 (two seeds
+    of 20 steps, call 9): there the two forms cross near 2.5 shares, in
+    Qwen3-Next's shapes past 3, and no cell runs a window between 2 and 5.
+    ``moved`` counts the rows gone over either way. A further
+    window still runs its three products again backward and carries the
+    experts' float32 gradients (~10 ms for rows worth ~5: PR 32, call 10),
+    which is why the first one is sized to hold the routing a cell sees."""
     n = xt.shape[0]
     held = held_counts.shape[0]
     even = -(-n * k * held // e)
@@ -453,23 +647,22 @@ def _held_experts(xt, flat, weights, held_counts, experts, first, e, k, out_dtyp
         _, inverse = jax.lax.sort_key_val(order, ids)
         sorted_weights = _unsort(weights, inverse, order)  # weights[order]; transposed by a gather too
         ends = jnp.cumsum(held_counts)
-    static, diff, ints = (bound, k, out_dtype), (xt, experts, sorted_weights), (order, by_expert, ends - held_counts, ends)
-    out, computed = _window(static, diff, ints, 0)
+    block = -(-even // (8 * LIVE_BLOCKS_A_SHARE)) * 8 if bound > BLOCKS_FROM_SHARES * even else None
+    static, diff, ints = (bound, block, k, out_dtype), (xt, experts, sorted_weights), (order, by_expert, ends - held_counts, ends)
+    out, counted = jnp.zeros(xt.shape, jnp.float32), jnp.zeros((2,), jnp.int32)
     if more:
         def further():
             # whole windows to the end of the sorted rows: what is added holds no held row
             pad = -(n * k - bound) % more
             padded = lambda a, fill: jnp.pad(a, (0, pad), constant_values=fill)  # noqa: E731
             return _further_windows(
-                (bound, more, k, out_dtype), (xt, experts, padded(sorted_weights, 0.0)),
+                (bound, more, block, k, out_dtype), (xt, experts, padded(sorted_weights, 0.0)),
                 (padded(order, 0), padded(by_expert, held + 1), ints[2], ends),
             )
 
-        more_out, done = jax.lax.cond(
-            ends[-1] > bound, further, lambda: (jnp.zeros_like(out), jnp.zeros((), jnp.int32))
-        )
-        out, computed = out + more_out, computed + done
-    return out, computed
+        out, counted = jax.lax.cond(ends[-1] > bound, further, lambda: (out, counted))
+    out, here = _window(static, diff, ints, 0, out)  # onto what the further windows gave
+    return out, *(counted + here)
 
 
 def rows_computed(by_expert, group_sizes):
@@ -524,9 +717,12 @@ def record_routing(aux) -> None:
     all experts), and where the loss gives it ``moe.route_bias_max_abs`` (a
     high-water mark: the largest magnitude a selection bias has reached).
     Where the layers hold a share of their experts, also
-    ``moe.held_assignments`` (the step's assignments on held experts) and
+    ``moe.held_assignments`` (the step's assignments on held experts),
     ``moe.held_share`` (that over tokens x top-k x layers, summed over the
-    steps: 1/16 a step for an even routing over sixteen shares). Aux without
+    steps: 1/16 a step for an even routing over sixteen shares) and
+    ``moe.held_rows_moved`` (the rows gone over round the held experts: by
+    blocks ``held_assignments`` and what a window's last block holds past its
+    last live row, by whole windows their lengths). Aux without
     ``expert_counts`` (a dense model) counts nothing."""
     if not (isinstance(aux, dict) and "expert_counts" in aux):
         return
@@ -541,6 +737,7 @@ def record_routing(aux) -> None:
     if "assignments_routed" in aux:
         reg.add("moe.held_assignments", float(aux["assignments_due"]))
         reg.add("moe.held_share", float(aux["assignments_due"]) / float(aux["assignments_routed"]))
+        reg.add("moe.held_rows_moved", float(aux["rows_moved"]))
 
 
 def read_routing(loss, aux):

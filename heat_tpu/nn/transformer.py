@@ -759,7 +759,8 @@ def causal_lm_loss(
     chosen expert, :func:`heat_tpu.nn.moe.rows_computed`: a dropless routing
     gives ``assignments_due``); a model that holds a share of its experts
     (``experts_held``) is due the assignments on those, and gives the layers x
-    tokens x top-k as ``assignments_routed``; a model without expert layers
+    tokens x top-k as ``assignments_routed`` and the rows its layers moved round
+    the held experts as ``rows_moved``; a model without expert layers
     gives the three scalars only; a model whose routers carry a selection bias
     (``router_bias``: ``params`` then holds the collection ``route_bias``)
     also gives ``route_bias_max_abs``. ``nn.read_routing(loss, aux)`` brings both to the host and
@@ -796,6 +797,7 @@ def causal_lm_loss(
                 if model.experts_held is not None:  # a share: due are those on the held experts
                     aux["assignments_routed"] = aux["assignments_due"]
                     aux["assignments_due"] = sum(a["held"] for a in layers)
+                    aux["rows_moved"] = sum(a["moved"] for a in layers)
                 aux["assignments_computed"] = sum(a["computed"] for a in layers)
                 if "route_bias" in params:  # the selection biases a rule moves: how far they have gone
                     aux["route_bias_max_abs"] = jnp.max(jnp.abs(jnp.stack(jax.tree.leaves(params["route_bias"]))))

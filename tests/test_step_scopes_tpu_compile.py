@@ -3,9 +3,13 @@ described TPU v5e: the spellings ``telemetry.hlo.split_op_name`` reads are the
 compiler's own here (custom VJPs, scans, ``nn.remat`` with a policy, a
 ``jax.checkpoint`` inside a ``lax.map`` inside a rematerialised block), every
 kernel falls in its piece of ``chipbench/scope_trace.PIECES``, few of the
-instructions that can be a device event are left without a piece, and the
-programs are the ones the tree compiled before it named anything: PR 35's
-scopes moved metadata alone. A compile is not a run: nothing here is a time.
+instructions that can be a device event are left without a piece, the
+programs are the ones recorded below (PR 35's scopes moved metadata alone;
+PR 40 moved the two share steps on purpose and left OLMoE's as it was), and
+the rows round the held experts move whole at a first window of 2 even shares
+and in loops by the live rows at a longer one (one expert layer at the LFM2
+cell's widths, compiled by itself). A compile is not a run: nothing here is a
+time.
 
 The steps are built as ``tests/chipbench/test_chipbench_{lm,qnext,trinity}_tpu_compile.py``
 build them (those fixtures also compile each cell's check; here the step alone).
@@ -22,17 +26,23 @@ from heat_tpu.telemetry import hlo
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# ``memory_analysis`` totals and a digest of the instruction list at the parent
-# of PR 35 (2129a44; described v5e:2x2, jax 0.9.0, libtpu 0.0.34): the text's
-# computations with ``metadata={...}`` and the Mosaic kernels' serialized bodies
-# (which carry source paths) taken out, the numbers XLA appends to names
-# dropped (two compiles of the Qwen3-Next step number their instructions
-# differently; the other two are the parent's byte for byte) and the lines
-# sorted. A PR that changes a program on purpose records its own.
+# ``memory_analysis`` totals and a digest of the instruction list (described
+# v5e:2x2, jax 0.9.0, libtpu 0.0.34): the text's computations with
+# ``metadata={...}`` and the Mosaic kernels' serialized bodies (which carry
+# source paths) taken out, the numbers XLA appends to names dropped (two
+# compiles of the Qwen3-Next step number their instructions differently; the
+# other two are byte for byte) and the lines sorted. A PR that changes a
+# program on purpose records its own. OLMoE's pair is the parent of PR 35's
+# (2129a44) to this day: PR 35's scopes moved metadata alone, and PR 40's
+# blocks round the held experts pass by the layer that holds every expert (that
+# it still compiles to this digest is the bypass). The two share steps are
+# PR 40's: their first windows of 2 even shares are still moved whole, in the
+# parent's passes, but a window's sum starts from the sum before it and the
+# rows gone over are counted (13,213,087,744 and 14,986,435,072 bytes before).
 PARENT = {
     "olmoe-train-4k-1chip": (15_751_272_448, "e0d807ed29622d9cfe144bbea2e8b92ef007b831ec51da502d46ed5189769a9c"),
-    "qwen3next-train-8k-1chip": (13_213_087_744, "220989a4f753aca6f885eeb7abad710b6f33b3073ea05336487c4fc97ef56809"),
-    "trinity-train-16k-1chip": (14_986_435_072, "fdf1ee742afa6fcf36211b218096077b60658bb66697d0bb6f5980974b10656d"),
+    "qwen3next-train-8k-1chip": (13_212_765_696, "d273977b1315e7b0d87daa2076fe7fb80ffd22359ea90a6fb62019334682dd0a"),
+    "trinity-train-16k-1chip": (14_987_403_264, "7cc995a5aa3ec84ad622def3d9c75915c99aa0a2150522d6d3bde00c4601759e"),
 }
 
 # what a device trace of these steps shows as an event of its own (my chip runs, PR 35)
@@ -188,11 +198,106 @@ def test_few_instructions_that_can_run_are_left_without_a_piece(step):
 
 def test_the_program_is_the_parents_but_for_metadata(step):
     cell, program, text, _ = step
-    total, digest = PARENT[cell]
     m = program.memory_analysis()
-    assert m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes == total
+    total = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
     body = text[text.index("\n%"):]  # the computations, without the header's table of source files
     body = re.sub(r", metadata=\{[^{}]*\}", "", body)
     body = re.sub(r'"body":\s*"[^"]*"', "", body)
     lines = sorted(re.sub(r"\.\d+", "", body).splitlines())
-    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == digest
+    assert (total, hashlib.sha256("\n".join(lines).encode()).hexdigest()) == PARENT[cell]
+
+
+def _computations(text):
+    """Computation name -> its instruction lines."""
+    found, lines = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            lines = found.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return found
+
+
+def _op_name(line):
+    m = re.search(r'op_name="([^"]*)"', line)
+    return m.group(1) if m else ""
+
+
+_LIVE_LOOP = re.compile(r"moe\.(route|combine)\)*/while$")
+
+
+def _live_loops(found):
+    return [l for lines in found.values() for l in lines if " while(" in l and _LIVE_LOOP.search(_op_name(l))]
+
+
+def test_a_first_window_of_two_shares_is_moved_whole(step):
+    """``nn/moe.py::_held_experts`` (PR 40): a first window of up to 3 even
+    shares is moved in one pass each way (a row costs more in a block than in
+    a whole window: blocks lose at 2 shares); the three steps compiled here
+    hold no loop over live blocks, and the layer that holds every expert none
+    either."""
+    cell, _, text, _ = step
+    found = _computations(text)
+    assert not _live_loops(found)
+    if cell != "olmoe-train-4k-1chip":  # a window's one scatter-add back into the tokens, in no loop over blocks
+        whole = re.compile(r"moe\.combine\)*/scatter-add$")
+        assert [x for lines in found.values() for x in lines if " scatter(" in x and whole.search(_op_name(x))]
+
+
+@pytest.fixture(scope="module")
+def long_window(topo):
+    """One expert layer at the LFM2 cell's widths and first window (8 of 64
+    experts, 5 even shares: 40,960 rows for an even 8,192), forward and
+    backward, compiled by itself: seconds, where a step takes a minute."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from heat_tpu.nn.moe import DroplessMoE
+
+    layer = DroplessMoE(
+        64, 4, 1536, dtype=jnp.bfloat16, accum_dtype=jnp.float32, norm_topk=True, score="sigmoid", select_bias=True,
+        experts_held=(0, 8), held_window=5.0,
+    )
+    here = SingleDeviceSharding(topo.devices[0])
+    placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=here), tree)  # noqa: E731
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=here)
+    tree = jax.eval_shape(layer.init, jax.random.PRNGKey(0), jnp.zeros((1, 8, 2048), jnp.bfloat16))
+
+    def loss(params, x, bias):
+        return jnp.sum(layer.apply({"params": params, "route_bias": bias}, x).astype(jnp.float32) ** 2)
+
+    program = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(placed(tree["params"]), x, placed(tree["route_bias"])).compile()
+    return program.as_text()
+
+
+def test_the_rows_round_the_held_experts_move_in_loops_by_the_live_rows(long_window):
+    """``nn/moe.py::_window`` (PR 40), a first window longer than 3 even shares:
+    the gather of a window's rows and the sum back into their tokens, and their
+    transposes, are loops over blocks of rows whose trip count is an operand
+    (read from the routing), each block written into the loop's carry in place;
+    no gather or scatter of a whole window's rows stands outside one."""
+    found = _computations(long_window)
+    loops = _live_loops(found)
+    rows_by_hidden = re.compile(r"\[(\d+),2048\]")
+    whole = lambda s: any(int(r) >= 8192 for r in rows_by_hidden.findall(s))  # noqa: E731
+    # a window: the gather and the sum forward, both transposes; the first window and the further ones
+    assert len(loops) >= 4 * 2, len(loops)
+    for line in loops:
+        condition = found[re.search(r"condition=%([\w.\-]+)", line).group(1)]
+        body = found[re.search(r"body=%([\w.\-]+)", line).group(1)]
+        defined = {re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", x).group(1): x for x in condition}
+        root = next(x for x in condition if x.lstrip().startswith("ROOT"))
+        compared = re.search(r" compare\(([^)]*)\)", root).group(1).split(", ")
+        assert len(compared) == 2 and all(" get-tuple-element(" in defined[c.split(" ")[-1]] for c in compared), root
+        assert not [x for x in body if " copy(" in x and whole(x.split(" copy(")[0])], line[:200]
+        assert any(" dynamic-update-slice(" in x or "scatter-add" in _op_name(x) for x in body)
+    moves = re.compile(r"moe\.(route|combine)\)*/(?!while/body/)(.*/)?(gather|scatter-add)$")
+    outside = [
+        x for lines in found.values() for x in lines
+        if moves.search(_op_name(x)) and whole(x.split(", metadata=")[0])
+    ]
+    assert not outside, outside[:3]
