@@ -13,10 +13,13 @@ from .fsdp import FSDP
 from .pipeline import Pipeline
 from .transformer import (
     GatedShortConv,
+    Latent,
+    LatentAttention,
     MultiHeadAttention,
     TransformerBlock,
     TransformerLM,
     causal_lm_loss,
+    glm_4_7_flash,
     lfm2_24b_a2b,
     olmoe_1b_7b,
     qwen3_next_80b_a3b,
@@ -33,6 +36,8 @@ __all__ = [
     "FSDP",
     "GatedDeltaNet",
     "GatedShortConv",
+    "Latent",
+    "LatentAttention",
     "functional",
     "gated_delta_rule",
     "MoEMLP",
@@ -43,6 +48,7 @@ __all__ = [
     "TransformerBlock",
     "TransformerLM",
     "causal_lm_loss",
+    "glm_4_7_flash",
     "lfm2_24b_a2b",
     "olmoe_1b_7b",
     "qwen3_next_80b_a3b",

@@ -30,8 +30,9 @@ rotated), ``qk_norm`` (over all of hidden, or ``"head"``: over each head),
 ``num_kv_heads`` and ``head_dim``, ``attn_gate`` (a sigmoid gate on the
 attention output, projected beside the queries), ``mixers`` (the layer
 pattern: which mixer the blocks of one period take, ``"attention"``,
-``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields, or
-``"shortconv"``, :class:`GatedShortConv` with ``conv_taps`` taps),
+``"deltanet"``, :mod:`heat_tpu.nn.deltanet`, sized by the ``gdn_*`` fields,
+``"shortconv"``, :class:`GatedShortConv` with ``conv_taps`` taps, or
+``"latent"``, :class:`LatentAttention` sized by ``latent``, a :class:`Latent`),
 ``ffn`` (``"swiglu"`` | ``"moe"``, the dropless top-k expert layer of
 :mod:`heat_tpu.nn.moe`, with ``norm_topk``, ``norm_topk_eps``, ``shared_d_ff``,
 ``experts_held`` and ``held_window``; ``router_score``, ``route_scale``, ``router_bias`` and
@@ -42,11 +43,13 @@ whether it rotates its queries and keys), ``sandwich_norm`` (four norms a
 block: one more on each branch's output), ``embed_scale``, ``dense_layers``
 and ``dense_d_ff`` (leading blocks with a SwiGLU of that width before the
 expert blocks), ``tie_embeddings`` (the head is the embedding table: no
-``lm_head``), ``init_std`` and ``accum_dtype``. The defaults are the
+``lm_head``), ``mtp_modules`` (multi-token-prediction modules behind the
+trunk, each a merge with the next token's embedding, one more block and a pass
+through the same head), ``init_std`` and ``accum_dtype``. The defaults are the
 pre-LN, learned-position, SwiGLU model this module began with, parameter tree
 and numerics unchanged. :func:`olmoe_1b_7b`, :func:`qwen3_next_80b_a3b`,
-:func:`trinity_mini` and :func:`lfm2_24b_a2b` name the published configurations; :func:`causal_lm_loss` is their training
-loss.
+:func:`trinity_mini`, :func:`lfm2_24b_a2b` and :func:`glm_4_7_flash` name the published configurations;
+:func:`causal_lm_loss` is their training loss.
 
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
 (tp: column/row-split the Dense kernels; dp: replicate) exactly as any flax
@@ -57,7 +60,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Tuple, Union
+from typing import Any, NamedTuple, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -289,6 +292,101 @@ class MultiHeadAttention(nn.Module):
         )(o)
 
 
+class Latent(NamedTuple):
+    """The five sizes of a latent attention (``mixer="latent"``): the ranks of
+    the queries' and of the keys' and values' latents, a head's part without
+    positions, its rotary part, and a value head's size."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as it is trained (DeepSeek-V2/V3's MLA, HF
+    ``modeling_deepseek_v3.py``; no bias anywhere), ``(B, T, D_model)`` in and
+    out, with ``n`` an RMSNorm over a latent::
+
+        c_q = n(u W_qa);   [q_nope | q_rope] = c_q W_qb          a head: nope | rope
+        [c_kv | k_r] = u W_kva;   c_kv = n(c_kv);   [k_nope | v] = c_kv W_kvb
+        q_rope, k_r = rotary(q_rope), rotary(k_r)                rotate-half over ``rope``; k_r has no head axis
+        q_i = [q_nope,i | q_rope,i];   k_i = [k_nope,i | k_r]    the same k_r for every head i
+        out = [softmax(q_i k_i^T / sqrt(nope + rope)) v_i]_i W_o
+
+    The latent is not absorbed into the queries: the core is plain multi-head
+    attention over the assembled rows, the same :func:`_attend` call as
+    :class:`MultiHeadAttention`'s, so the flash kernels, their kept residuals
+    and the scopes ``attn.*`` are shared. A head's query and key must be as
+    wide as its value (``nope + rope == v``). The two norms and rotary are
+    float32; the five projections take ``dtype`` operands and give
+    ``accum_dtype`` results. Scopes: ``mla.down`` (``W_qa``, ``W_kva``, the two
+    norms), ``mla.up`` (``W_qb``, ``W_kvb``), ``mla.assemble`` (rotary, the
+    broadcast of ``k_r`` over the heads and the joins; backward the splits and
+    the sum over the heads). Counters, once a trace: ``mla.mixers`` and
+    ``mla.key_rows_built`` (the ``B x T x heads x (nope + rope)`` elements the
+    joined keys take: what a kernel that took ``k_r`` as an operand of its own
+    would not write)."""
+
+    num_heads: int
+    latent: Latent
+    attn_impl: str = "local"
+    causal: bool = True
+    comm: Optional[Any] = None
+    block_size: Optional[int] = None
+    dtype: Any = jnp.float32
+    flash_bwd_impl: str = "two_pass"
+    norm_eps: float = 1e-6
+    rope_theta: Optional[float] = None  # None: no positions
+    accum_dtype: Optional[Any] = None
+    matrix_init: Any = None
+    out_init: Any = None
+
+    @nn.compact
+    def __call__(self, u):
+        q_rank, kv_rank, nope, rope, v_dim = self.latent
+        if nope + rope != v_dim:
+            raise ValueError(
+                f"a latent head's query and key ({nope} + {rope}) must be as wide as its value ({v_dim})"
+            )
+        b, t, d_model = u.shape
+        heads = self.num_heads
+        dense = lambda features, name: nn.DenseGeneral(  # noqa: E731
+            features, axis=-1, use_bias=False, dtype=self.dtype, name=name,
+            dot_general=_dot_general(self.accum_dtype), **_given(self.matrix_init),
+        )
+        count = telemetry.get_registry().add
+        count("mla.mixers")
+        count("mla.key_rows_built", b * t * heads * (nope + rope))
+        with jax.named_scope("mla.down"):
+            c_q = _norm("rmsnorm", self.norm_eps, jnp.float32, "q_a_norm")(dense(q_rank, "q_a")(u))
+            c_kv, k_r = jnp.split(dense(kv_rank + rope, "kv_a")(u), [kv_rank], axis=-1)
+            c_kv = _norm("rmsnorm", self.norm_eps, jnp.float32, "kv_a_norm")(c_kv)
+        with jax.named_scope("mla.up"):
+            q = dense((heads, nope + rope), "q_b")(c_q)
+            kv = dense((heads, nope + v_dim), "kv_b")(c_kv)
+        with jax.named_scope("mla.assemble"):
+            q_rope, k_r = q[..., nope:], k_r[:, :, None, :]  # one key of ``rope`` a position
+            if self.rope_theta is not None:
+                q_rope, k_r = rotary(q_rope, self.rope_theta), rotary(k_r, self.rope_theta)
+            q = jnp.concatenate([q[..., :nope].astype(self.dtype), q_rope.astype(self.dtype)], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope].astype(self.dtype), jnp.broadcast_to(k_r.astype(self.dtype), (b, t, heads, rope))],
+                axis=-1,
+            )
+            v = kv[..., nope:].astype(self.dtype)
+        o = _attend(
+            q, k, v, impl=self.attn_impl, causal=self.causal, comm=self.comm,
+            flash_bwd_impl=self.flash_bwd_impl, block_size=self.block_size,
+        )
+        return nn.DenseGeneral(
+            d_model, axis=(-2, -1), use_bias=False, dtype=self.dtype, name="out",
+            dot_general=_dot_general(self.accum_dtype),
+            **_given(self.matrix_init if self.out_init is None else self.out_init),
+        )(o)
+
+
 class GatedShortConv(nn.Module):
     """The gated short convolution, the mixer of the LFM2 family (HF
     ``modeling_lfm2_moe.py``), ``(B, T, D_model)`` in and out::
@@ -337,13 +435,13 @@ class GatedShortConv(nn.Module):
             return project(y, w_out)
 
 
-MIXERS = ("attention", "deltanet", "shortconv")
+MIXERS = ("attention", "deltanet", "shortconv", "latent")
 
 
 class TransformerBlock(nn.Module):
     """Pre-norm residual block: x + mixer(norm(x)); x + ffn(norm(x)), the
-    mixer attention, the Gated DeltaNet or the gated short convolution, the
-    feed-forward a SwiGLU MLP or the dropless expert layer."""
+    mixer attention, the Gated DeltaNet, the gated short convolution or the
+    latent attention, the feed-forward a SwiGLU MLP or the dropless expert layer."""
 
     num_heads: int
     mlp_ratio: float = 4.0
@@ -386,6 +484,7 @@ class TransformerBlock(nn.Module):
     conv_taps: int = 3
     norm_topk_eps: float = 1e-20
     held_window: float = 2.0
+    latent: Optional[Latent] = None  # the sizes of a "latent" mixer
 
     @nn.compact
     def __call__(self, x):
@@ -415,6 +514,11 @@ class TransformerBlock(nn.Module):
         elif self.mixer == "shortconv":
             x = x + after("ln1_post", GatedShortConv(
                 self.conv_taps, self.dtype, self.accum_dtype, matrix, out, name="conv",
+            )(h))
+        elif self.mixer == "latent":
+            x = x + after("ln1_post", LatentAttention(
+                self.num_heads, Latent(*self.latent), self.attn_impl, self.causal, self.comm, self.block_size,
+                self.dtype, self.flash_bwd_impl, eps, self.rope_theta, self.accum_dtype, matrix, out, name="attn",
             )(h))
         else:
             raise ValueError(f"mixer must be one of {MIXERS}, got {self.mixer!r}")
@@ -513,6 +617,11 @@ class TransformerLM(nn.Module):
     norm_topk_eps: float = 1e-20  # a sigmoid router's top-k weights are divided by their sum + this
     tie_embeddings: bool = False  # logits = h E^T: the head is the embedding table
     held_window: float = 2.0  # with ``experts_held``: the first window of held rows, in even shares (DroplessMoE)
+    latent: Optional[Latent] = None  # the sizes of the "latent" mixers (LatentAttention)
+    # multi-token-prediction modules behind the trunk (DeepSeek-V3, section 2.2): module j merges the stream
+    # before it with the embedding of token i + j + 1, runs one more block (``block<num_layers + j>``, the
+    # pattern continued) and reads the trunk's head behind a norm of its own: ``__call__(..., mtp=True)``
+    mtp_modules: int = 0
 
     def mixer_of(self, i: int) -> str:
         return self.mixers[i % len(self.mixers)]
@@ -524,14 +633,20 @@ class TransformerLM(nn.Module):
         return self.positions == "rope" and self.rotary[i % len(self.rotary)]
 
     def expert_layers(self) -> Tuple[int, ...]:
-        """The blocks whose feed-forward is the expert layer."""
-        return tuple(range(self.dense_layers, self.num_layers)) if self.ffn == "moe" else ()
+        """The blocks whose feed-forward is the expert layer, the
+        prediction modules' blocks among them."""
+        return tuple(range(self.dense_layers, self.num_layers + self.mtp_modules)) if self.ffn == "moe" else ()
 
     @nn.compact
-    def __call__(self, tokens, head: bool = True):
+    def __call__(self, tokens, head: bool = True, mtp: bool = False):
         """Logits ``(B, T, vocab)``; with ``head=False`` the final norm's
         output ``(B, T, d_model)``, for a loss that applies ``lm_head``
-        itself (:func:`causal_lm_loss`)."""
+        itself (:func:`causal_lm_loss`). With ``mtp`` a pair: that, and the
+        same of each prediction module, whose position ``i`` stands for token
+        ``i + j + 2`` (module ``j``; the embeddings it merges are rolled, so its
+        last ``j + 1`` positions are fed the sequence's first tokens and are no
+        prediction of anything: a loss weighs them zero, and attention being
+        causal they reach no earlier position)."""
         if self.positions not in ("learned", "rope"):
             raise ValueError(f"positions must be 'learned' or 'rope', got {self.positions!r}")
         stream = self.dtype if self.accum_dtype is None else self.accum_dtype
@@ -566,11 +681,13 @@ class TransformerLM(nn.Module):
                 )
             block_cls = nn.remat(TransformerBlock, policy=keep)
             telemetry.get_registry().add("attn.kept", sum(
-                self.attn_impl == "flash" and self.mixer_of(i) == "attention" for i in range(self.num_layers)
+                self.attn_impl == "flash" and self.mixer_of(i) in ("attention", "latent")
+                for i in range(self.num_layers + self.mtp_modules)
             ))
-        for i in range(self.num_layers):
+
+        def block(i, x):
             dense = i < self.dense_layers
-            x = block_cls(
+            return block_cls(
                 self.num_heads, self.mlp_ratio, self.attn_impl, True,
                 self.comm, self.block_size, self.dtype,
                 self.flash_bwd_impl, self.norm, self.norm_eps, self.qk_norm,
@@ -583,25 +700,46 @@ class TransformerLM(nn.Module):
                 self.shared_d_ff, self.experts_held, self.init_std, self.out_init_std,
                 self.window_of(i), self.sandwich_norm, self.router_score, self.route_scale,
                 self.router_bias, self.shared_gate, self.conv_taps, self.norm_topk_eps, self.held_window,
-                name=f"block{i}",
+                self.latent, name=f"block{i}",
             )(x)
-        x = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
+
+        for i in range(self.num_layers):
+            x = block(i, x)
+        outs = [_norm(self.norm, self.norm_eps, stream, "ln_f")(x)]
+        if self.mtp_modules and (mtp or self.is_initializing()):
+            telemetry.get_registry().add("lm.mtp.modules", self.mtp_modules)
+            out_init = _matrix_init(self.init_std if self.out_init_std is None else self.out_init_std)
+            for j in range(self.mtp_modules):
+                with jax.named_scope("mtp.merge"):
+                    ahead = embed(jnp.roll(tokens, -(j + 1), axis=-1))  # position i: token i + j + 1
+                    if self.embed_scale is not None:
+                        ahead = ahead * jnp.asarray(self.embed_scale, ahead.dtype)
+                    x = nn.Dense(
+                        self.d_model, use_bias=False, dtype=self.dtype, name=f"mtp{j}_eh_proj",
+                        dot_general=_dot_general(self.accum_dtype), **_given(out_init),
+                    )(jnp.concatenate([
+                        _norm(self.norm, self.norm_eps, stream, f"mtp{j}_enorm")(ahead),
+                        _norm(self.norm, self.norm_eps, stream, f"mtp{j}_hnorm")(x),
+                    ], axis=-1))
+                with jax.named_scope("mtp.block"):
+                    x = block(self.num_layers + j, x)
+                outs.append(_norm(self.norm, self.norm_eps, stream, f"mtp{j}_ln_f")(x))
         if self.tie_embeddings:
-            if not head:
-                return x
-            with jax.named_scope("lm.tied_head"):
-                return jnp.dot(
-                    x.astype(self.dtype), embed.embedding.astype(self.dtype).T, preferred_element_type=stream,
-                )
-        lm_head = nn.Dense(
-            self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
-            dot_general=_dot_general(self.accum_dtype), **_given(_matrix_init(self.init_std)),
-        )
+            def project(x):
+                with jax.named_scope("lm.tied_head"):
+                    return jnp.dot(
+                        x.astype(self.dtype), embed.embedding.astype(self.dtype).T, preferred_element_type=stream,
+                    )
+        else:
+            project = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
+                dot_general=_dot_general(self.accum_dtype), **_given(_matrix_init(self.init_std)),
+            )
+            if not head and self.is_initializing():
+                project(outs[0][:, :1])
         if head:
-            return lm_head(x)
-        if self.is_initializing():
-            lm_head(x[:, :1])
-        return x
+            outs = [project(x) for x in outs]
+        return (outs[0], outs[1:]) if mtp else outs[0]
 
 
 def olmoe_1b_7b(num_layers: int = 16, **fields) -> TransformerLM:
@@ -638,7 +776,8 @@ def qwen3_next_80b_a3b(
     the top 10 with their weights normalised, and a shared expert of width 512
     behind a sigmoid gate; zero-centred RMSNorm (eps 1e-6); untied 151,936-row
     embedding and head. The release's multi-token-prediction module is not
-    part of ``config.json`` and is left out, as HF's model leaves it out.
+    part of ``config.json`` and is left out, as HF's model leaves it out
+    (``mtp_modules=1`` among ``fields`` would add one in :func:`glm_4_7_flash`'s form).
     bfloat16 matmul operands, float32 everything else; matrices drawn at 0.02,
     those that write into the residual stream at ``0.02 / sqrt(2 * 48)``.
 
@@ -741,8 +880,51 @@ def lfm2_24b_a2b(
     return TransformerLM(**{**arch, **fields})
 
 
+def glm_4_7_flash(
+    num_layers: int = 47, experts_held: Optional[Tuple[int, int]] = None, vocab_size: int = 154880,
+    mtp_modules: int = 1, **fields
+) -> TransformerLM:
+    """GLM-4.7-Flash (Z.ai, 30B-A3B; ``config.json`` of ``zai-org/GLM-4.7-Flash``,
+    ``model_type`` ``glm4_moe_lite``, equations of HF ``modeling_deepseek_v3.py``,
+    from which it derives) at its published widths: hidden 2048; every mixer a
+    **latent attention** (:class:`LatentAttention`: 20 heads, queries through a
+    latent of 768 and keys and values through one of 512, each with an RMSNorm
+    in it, a head's query and key a part of 192 without positions beside a part
+    of 64 with rotary positions at theta 1e6, **the rotary key one vector a
+    position that all heads share**, values of 256); two norms a block (RMSNorm,
+    eps 1e-5); block 0 a SwiGLU of width 10,240, every later block 64 experts
+    of width 1,536 chosen by ``sigmoid(x W_r) + b``, the top 4 weighted by their
+    sigmoid over the four's sum + 1e-20 times 1.8, and an ungated shared expert
+    of width 1,536; untied 154,880-row embedding and head; and **one
+    multi-token-prediction module** (``mtp_modules``; DeepSeek-V3's report,
+    section 2.2: ``[norm(E[t_{i+1}]) | norm(h_i)] W_eh``, one more whole block,
+    the trunk's head behind a norm of its own), whose loss
+    :func:`causal_lm_loss` adds at ``mtp_coef``. ``b`` is the collection
+    ``route_bias`` beside ``params``, moved after every step by
+    :func:`heat_tpu.nn.balance_bias_rule` through ``make_train_step(state_rule=)``,
+    the module's expert layer's among them. bfloat16 matmul operands, float32
+    everything else; matrices drawn at 0.02, those that write into the residual
+    stream (``W_eh`` among them) at ``0.02 / sqrt(2 * 47)``.
+
+    ``num_layers``, ``experts_held`` and ``vocab_size`` are what one chip's
+    share of a deployment sets (as for :func:`qwen3_next_80b_a3b`); ``fields``
+    passes what is not architecture (``attn_impl``, ``comm``, ``remat``, ...)."""
+    arch = dict(
+        vocab_size=vocab_size, d_model=2048, num_heads=20, num_layers=num_layers,
+        max_len=202752, norm="rmsnorm", norm_eps=1e-5, positions="rope", rope_theta=1e6,
+        mixers=("latent",), latent=Latent(q_rank=768, kv_rank=512, nope=192, rope=64, v=256),
+        dense_layers=1, dense_d_ff=10240,
+        ffn="moe", d_ff=1536, num_experts=64, experts_per_token=4, norm_topk=True,
+        router_score="sigmoid", route_scale=1.8, router_bias=True,
+        shared_d_ff=1536, shared_gate=False, experts_held=experts_held, mtp_modules=mtp_modules,
+        init_std=0.02, out_init_std=0.02 / math.sqrt(2 * 47),
+        dtype=jnp.bfloat16, accum_dtype=jnp.float32, attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
 def causal_lm_loss(
-    model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0,
+    model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0, mtp_coef: float = 0.3,
 ):
     """``loss_fn(params, tokens) -> (loss, aux)`` for ``make_train_step(...,
     has_aux=True)``: mean next-token cross-entropy over the ``T - 1`` targets
@@ -763,18 +945,25 @@ def causal_lm_loss(
     the held experts as ``rows_moved``; a model without expert layers
     gives the three scalars only; a model whose routers carry a selection bias
     (``router_bias``: ``params`` then holds the collection ``route_bias``)
-    also gives ``route_bias_max_abs``. ``nn.read_routing(loss, aux)`` brings both to the host and
+    also gives ``route_bias_max_abs``; a model with prediction modules
+    (``mtp_modules``) adds ``mtp_coef`` x ``ce_mtp`` to the loss and gives
+    ``ce_mtp``: the mean over the modules of module ``j``'s cross-entropy
+    against the token ``j + 2`` ahead, averaged over the ``T - j - 2`` positions
+    that have one, through the same head (whose gradient is the sum of its
+    uses), and its expert layers stand after the trunk's in everything that
+    goes by layer. ``nn.read_routing(loss, aux)`` brings both to the host and
     counts the routing."""
 
-    def loss_fn(params, tokens):
-        with jax.named_scope("lm.body"):
-            hidden, state = model.apply(params, tokens, head=False, mutable=["aux"])
+    def head_loss(params, hidden, tokens, ahead, scope):
+        """The mean cross-entropy of ``hidden (B, T, D)`` through the head
+        against the token ``ahead`` positions on, over the positions that
+        have one; the head's loop under ``scope``."""
         b, t = tokens.shape
         with jax.named_scope("lm.targets"):
-            targets = jnp.roll(tokens, -1, axis=1).reshape(b * t)
-            mask = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t)).reshape(b * t)
-            weights = mask.astype(jnp.float32) / (b * (t - 1))
-        with jax.named_scope("lm.head_loss"):
+            targets = jnp.roll(tokens, -ahead, axis=1).reshape(b * t)
+            mask = jnp.broadcast_to(jnp.arange(t) < t - ahead, (b, t)).reshape(b * t)
+            weights = mask.astype(jnp.float32) / (b * (t - ahead))
+        with jax.named_scope(scope):
             head = functools.partial(
                 blocked_cross_entropy, hidden.reshape(b * t, -1), targets=targets, weights=weights, dtype=model.dtype
             )
@@ -782,13 +971,21 @@ def causal_lm_loss(
                 # the table is the head: its gradient is the gather's rows plus the head's product
                 telemetry.get_registry().add("lm.head.tied")
                 with jax.named_scope("lm.tied_head"):
-                    ce = head(kernel=params["params"]["embed"]["embedding"].T)
-            else:
-                ce = head(kernel=params["params"]["lm_head"]["kernel"])
+                    return head(kernel=params["params"]["embed"]["embedding"].T)
+            return head(kernel=params["params"]["lm_head"]["kernel"])
+
+    def loss_fn(params, tokens):
+        with jax.named_scope("lm.body"):
+            (hidden, ahead), state = model.apply(params, tokens, head=False, mtp=True, mutable=["aux"])
+        b, t = tokens.shape
+        ce = head_loss(params, hidden, tokens, 1, "lm.head_loss")
+        of_modules = [head_loss(params, h, tokens, j + 2, "mtp.head_loss") for j, h in enumerate(ahead)]
         layers = [state["aux"][f"block{i}"]["moe"]["moe"][0] for i in model.expert_layers()]
         with jax.named_scope("lm.loss"):  # the terms beside the cross-entropy, their sum
             zero = jnp.zeros((), jnp.float32)
             aux = {"ce": ce, "load_balance": zero, "router_z": zero}
+            if of_modules:
+                aux["ce_mtp"] = sum(of_modules) / len(of_modules)
             if layers:
                 aux["load_balance"] = jnp.mean(jnp.stack([a["load_balance"] for a in layers]))
                 aux["router_z"] = jnp.mean(jnp.stack([a["router_z"] for a in layers]))
@@ -802,6 +999,8 @@ def causal_lm_loss(
                 if "route_bias" in params:  # the selection biases a rule moves: how far they have gone
                     aux["route_bias_max_abs"] = jnp.max(jnp.abs(jnp.stack(jax.tree.leaves(params["route_bias"]))))
             loss = ce + load_balance_coef * aux["load_balance"] + router_z_coef * aux["router_z"]
+            if of_modules:
+                loss = loss + mtp_coef * aux["ce_mtp"]
         return loss, aux
 
     return loss_fn
