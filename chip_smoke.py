@@ -42,8 +42,8 @@ train    ``TransformerLM`` at the bench width, all 12 layers (vocab 32,768,
          widths (8 layers, 8 of 128 experts, 25,024 rows of the vocabulary,
          1 x 16,384 tokens, every block rematerialised) through
          ``make_train_step(state_rule=balance_bias_rule(0.001))``: the step
-         holds the window kernels (``swa_fwd``, ``swa_bwd_dq``,
-         ``swa_bwd_dkv``) beside the full form's, each forward kernel once a
+         holds the window kernels (``swa_fwd``, ``swa_bwd_fused``)
+         beside the full form's, each forward kernel once a
          block (``swa_fwd`` x 6, ``flash_fwd`` x 2, ``attn.kept`` 8), the
          loss falls, ``moe.dropped`` stays 0 and every selection bias has
          moved by the rule's steps.
@@ -406,9 +406,8 @@ def _qnext_steps(cfg, devices, on_tpu):
     _check(state in text, f"no loop over chunks carrying {state} in the step: the delta rule is not the chunked one")
     if on_tpu:
         _check(
-            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "delta_chunk_fwd", "delta_chunk_bwd"} <= set(kernels),
-            f"no Mosaic calls flash_fwd / flash_bwd_dq / flash_bwd_dkv / delta_chunk_fwd / delta_chunk_bwd "
-            f"in the step: {kernels}",
+            {"flash_fwd", "flash_bwd_fused", "delta_chunk_fwd", "delta_chunk_bwd"} <= set(kernels),
+            f"no Mosaic calls flash_fwd / flash_bwd_fused / delta_chunk_fwd / delta_chunk_bwd in the step: {kernels}",
         )
         _kv_read_by_group(text, "flash_fwd", model, c)
     _forward_kernels_run_once(got, {"flash_fwd": 1}, on_tpu)
@@ -458,7 +457,7 @@ def _trinity_steps(cfg, devices, on_tpu):
     got = _lm_steps("trinity", model, causal_lm_loss(model), c, cfg["steps"], rule=balance_bias_rule(rate))
     kernels = got["kernels"]
     if on_tpu:
-        wanted = {"swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+        wanted = {"swa_fwd", "swa_bwd_fused", "flash_fwd", "flash_bwd_fused"}
         _check(wanted <= set(kernels), f"no Mosaic calls {sorted(wanted - set(kernels))} in the step: {kernels}")
         _kv_read_by_group(got["text"], "swa_fwd", model, c)
     windowed = sum(model.window_of(i) is not None for i in range(model.num_layers))
@@ -995,7 +994,7 @@ def _flash_window(cfg, on_tpu, wrong):
 
     kernel = with_gradients(lambda q, k, v: _attend(
         q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), impl="flash", causal=True,
-        comm=None, block_size=None, flash_bwd_impl="two_pass", window=window,
+        comm=None, block_size=None, flash_bwd_impl="auto", window=window,
     ))
     counters = telemetry.get_registry().counters
     names = ("attn.window.kernel", "attn.window.xla", "attn.window.blocks_visited", "attn.window.blocks_live")
@@ -1005,8 +1004,8 @@ def _flash_window(cfg, on_tpu, wrong):
     if took["attn.window.kernel"] != 1 or took["attn.window.xla"] != 0:
         wrong.append(f"flash_window: one trace of the windowed core counted {took}, not kernel 1 / xla 0")
     text = lowered.as_text()
-    if on_tpu and not all(f'kernel_name = "{name}"' in text for name in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv")):
-        wrong.append("flash_window: no Mosaic calls swa_fwd / swa_bwd_dq / swa_bwd_dkv in the lowering")
+    if on_tpu and not all(f'kernel_name = "{name}"' in text for name in ("swa_fwd", "swa_bwd_fused")):
+        wrong.append("flash_window: no Mosaic calls swa_fwd / swa_bwd_fused in the lowering")
     program = lowered.compile()
     masked = program_cache.cached_program(
         "smoke.flash_window_xla", (), lambda: with_gradients(lambda q, k, v: _masked_attention(q, k, v, window))

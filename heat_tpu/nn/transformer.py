@@ -228,7 +228,7 @@ class MultiHeadAttention(nn.Module):
     block_size: Optional[int] = None  # None = each impl's tuned default
     dtype: Any = jnp.float32
     # flash backward strategy (pallas_attention.flash_attention bwd_impl)
-    flash_bwd_impl: str = "two_pass"
+    flash_bwd_impl: str = "auto"
     # RMSNorm over all of d_model on the query and key projections, before
     # the split into heads; None = no such norm, else its epsilon
     qk_norm_eps: Optional[float] = None
@@ -336,7 +336,7 @@ class LatentAttention(nn.Module):
     comm: Optional[Any] = None
     block_size: Optional[int] = None
     dtype: Any = jnp.float32
-    flash_bwd_impl: str = "two_pass"
+    flash_bwd_impl: str = "auto"
     norm_eps: float = 1e-6
     rope_theta: Optional[float] = None  # None: no positions
     accum_dtype: Optional[Any] = None
@@ -450,7 +450,7 @@ class TransformerBlock(nn.Module):
     comm: Optional[Any] = None
     block_size: Optional[int] = None  # None = each impl's tuned default
     dtype: Any = jnp.float32
-    flash_bwd_impl: str = "two_pass"
+    flash_bwd_impl: str = "auto"
     norm: str = "layernorm"
     norm_eps: Optional[float] = None  # None = flax's default (1e-6)
     qk_norm: Union[bool, str] = False  # True: over all of hidden; "head": over each head
@@ -571,7 +571,7 @@ class TransformerLM(nn.Module):
     # dots_with_no_batch_dims_saveable) — usually faster when HBM allows
     remat_policy: Optional[str] = None
     dtype: Any = jnp.float32
-    flash_bwd_impl: str = "two_pass"
+    flash_bwd_impl: str = "auto"
     # the architecture (see the module docstring); defaults = the model above
     norm: str = "layernorm"
     norm_eps: Optional[float] = None

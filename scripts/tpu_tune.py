@@ -245,49 +245,56 @@ def main():
 
             run_guarded(f"lm_{pol}_{bwd}", do)
 
-    # ---------------- attention backward block sweep ---------------------
+    # ---------------- attention backward: fused against two-pass ----------
+    # the training cells' shapes (batch, positions, query heads, key-value heads,
+    # head size, window) at the tuned tiles, then other tiles at a head of 256;
+    # a line holds the forward alone and the forward with its backward, in ms
     if want("attn_bwd"):
         from heat_tpu.parallel import flash_attention
 
-        (b, t, h, d, areps) = (4, 4096, 8, 128, 10)
-        akey = jax.random.PRNGKey(1)
-        kq, kk, kv = jax.random.split(akey, 3)
-        aq = jax.random.normal(kq, (b, t, h, d), dtype=jnp.bfloat16)
-        ak = jax.random.normal(kk, (b, t, h, d), dtype=jnp.bfloat16)
-        av = jax.random.normal(kv, (b, t, h, d), dtype=jnp.bfloat16)
+        cells = {
+            "glm": (2, 8192, 20, 20, 256, None), "qnext": (2, 8192, 16, 2, 256, None),
+            "trinity_full": (1, 16384, 32, 4, 128, None), "trinity_swa": (1, 16384, 32, 4, 128, 2048),
+            "lfm2": (2, 8192, 32, 8, 64, None), "olmoe": (4, 4096, 16, 16, 128, None),
+        }
+        areps = 10
+        tuned = [(cell, impl, (None, None)) for cell in cells for impl in ("two_pass", "fused")]
+        tiles = [("glm", impl, blks) for impl in ("two_pass", "fused")
+                 for blks in ((1024, 1024), (512, 512), (256, 1024), (1024, 512), (512, 2048))]
+        for cell, impl, (bq, bk) in tuned + tiles:
+            def do_ab(cell=cell, impl=impl, bq=bq, bk=bk):
+                b, t, h, h_kv, d, window = cells[cell]
+                aq, ak, av = (
+                    jax.random.normal(key, (b, t, heads, d), dtype=jnp.bfloat16)
+                    for key, heads in zip(jax.random.split(jax.random.PRNGKey(1), 3), (h, h_kv, h_kv))
+                )
 
-        for impl, (bq, bk) in [
-            (im, blks)
-            for im in ("two_pass", "fused")
-            for blks in ((256, 512), (512, 512), (512, 1024), (1024, 512),
-                         (1024, 1024), (256, 1024), (512, 2048))
-        ]:
-            def do_ab(impl=impl, bq=bq, bk=bk):
-                def loss(q_, k_, v_):
+                def attend(q_, k_, v_):
                     return flash_attention(
-                        q_, k_, v_, causal=True, block_q=bq, block_k=bk,
-                        bwd_impl=impl,
-                    ).astype(jnp.float32).sum()
+                        q_, k_, v_, causal=True, window=window, block_q=bq, block_k=bk, bwd_impl=impl,
+                    )
 
-                @jax.jit
-                def chain(q, k, v):
-                    def body(_, carry):
-                        q_, k_, v_ = carry
-                        dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q_, k_, v_)
-                        return (q_ + dq * jnp.bfloat16(1e-3),
-                                k_ + dk * jnp.bfloat16(1e-3),
-                                v_ + dv * jnp.bfloat16(1e-3))
+                def chain(step):
+                    return jax.jit(lambda q, k, v: jax.lax.fori_loop(0, areps, step, (q, k, v))[0])
 
-                    return jax.lax.fori_loop(0, areps, body, (q, k, v))[0]
+                small = jnp.bfloat16(1e-3)
+                forward = chain(lambda _, c: (c[0] + attend(*c) * small, c[1], c[2]))
+                both = chain(lambda _, c: tuple(
+                    a + g * small for a, g in zip(c, jax.grad(
+                        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(*c))
+                ))
+                ms = {}
+                for name, fn in (("fwd", forward), ("fwd_bwd", both)):
+                    run = lambda: _sync(fn(aq, ak, av).astype(jnp.float32))  # noqa: E731
+                    run()
+                    ms[name] = _time(run, repeats=3) / areps * 1e3
+                pairs = t * (t + 1) / 2 if window is None else window * (t - (window - 1) / 2)
+                gf = 4.0 * b * h * pairs * d * 2 / 1e9  # four products of the head size a pair: the backward as a model counts it
+                bwd = ms["fwd_bwd"] - ms["fwd"]
+                emit(exp=f"attn_bwd_{cell}_{impl}_bq{bq}_bk{bk}", fwd_ms=round(ms["fwd"], 3),
+                     bwd_ms=round(bwd, 3), bwd_mfu=round(gf / bwd * 1e3 / peak_gflops, 3))
 
-                run = lambda: _sync(chain(aq, ak, av).astype(jnp.float32))
-                run()
-                tm = _time(run)
-                gf = areps * 9.0 * b * h * t * t * d / tm / 1e9
-                emit(exp=f"attn_bwd_{impl}_bq{bq}_bk{bk}", gflops=round(gf, 1),
-                     mfu=round(gf / peak_gflops, 3))
-
-            run_guarded(f"attn_bwd_{impl}_{bq}_{bk}", do_ab)
+            run_guarded(f"attn_bwd_{cell}_{impl}_{bq}_{bk}", do_ab)
 
     # ---------------- moments vs HBM roofline ----------------------------
     if want("moments"):

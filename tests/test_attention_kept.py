@@ -46,7 +46,7 @@ def kernels_of(loss, params):
 CASES = {
     "full": dict(),
     "window-and-full": dict(windows=(16, None)),
-    "fused-backward": dict(flash_bwd_impl="fused", windows=(16, None)),
+    "two-pass-backward": dict(flash_bwd_impl="two_pass", windows=(16, None)),
     "with-dots": dict(remat_policy="dots"),
     "ragged-length": dict(windows=(16, None), length=41),  # 40 positions: the last block of queries is half padding
     "batch-over-the-mesh": dict(mesh=True, windows=(16, None)),
@@ -75,7 +75,7 @@ def test_gradients_are_the_whole_rematerialisations_with_one_forward_kernel_a_bl
     forward = lambda found: sorted(n for n in found if n in FORWARD)  # noqa: E731
     a_block = ["flash_fwd", "flash_fwd"] if "windows" not in fields else ["flash_fwd", "swa_fwd"]
     assert forward(names) == a_block and forward(names_whole) == sorted(2 * a_block)
-    # the backward kernels are untouched: one dq and one dk/dv (or one fused) a block, either way
+    # the backward kernels are untouched: one fused (or, forced, one dq and one dk/dv) a block, either way
     assert sorted(n for n in names if n not in FORWARD) == sorted(n for n in names_whole if n not in FORWARD)
     assert len(names) == len(names_whole) - 2
 
@@ -90,7 +90,7 @@ def test_the_kept_log_sum_exp_is_lane_zero_of_the_kernels_one_float_a_row(window
     )
     static = (d**-0.5, True, t, 16, 16, True)  # scale, causal, kv_valid, block_q, block_k, interpret
     out, lanes = pallas_attention._flash_forward(q, k, v, *static, return_lse=True, window=window)
-    primal, (_, _, _, out_kept, lse) = pallas_attention._flash_fwd(q, k, v, *static, "two_pass", window)
+    primal, (_, _, _, out_kept, lse) = pallas_attention._flash_fwd(q, k, v, *static, "auto", window)
     assert lanes.shape == (b, h, -(-t // 16) * 16, 128)  # the kernel's layout: padded rows, a value broadcast over the lanes
     assert lse.shape == (b, h, t) and lse.dtype == jnp.float32
     np.testing.assert_array_equal(lse, lanes[:, :, :t, 0])

@@ -27,10 +27,10 @@ def kernels(text: str) -> dict:
 
 
 def test_the_trinity_step_runs_each_forward_kernel_once(compiled):  # noqa: F811
-    """Six sliding layers and two full ones: a forward, a dq and a dk/dv kernel each."""
+    """Six sliding layers and two full ones: a forward and a fused backward kernel each."""
     _, program, _ = compiled
     assert kernels(program.as_text()) == {
-        "swa_fwd": 6, "swa_bwd_dq": 6, "swa_bwd_dkv": 6, "flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
+        "swa_fwd": 6, "swa_bwd_fused": 6, "flash_fwd": 2, "flash_bwd_fused": 2,
     }
 
 
@@ -54,6 +54,6 @@ def test_the_qwen3_next_step_runs_its_forward_kernel_once_and_fits(qnext_compile
     """One attention block a period of four: one kernel of each kind; the check's
     gradients still fit beside both AdamW moments (8 bytes a parameter)."""
     _, program, grads_program = qnext_compiled
-    assert kernels(program.as_text()) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert kernels(program.as_text()) == {"flash_fwd": 1, "flash_bwd_fused": 1}
     assert total(program.memory_analysis()) < 15 * GIB
     assert total(grads_program.memory_analysis()) + 8 * 625_667_136 < 15 * GIB

@@ -95,14 +95,15 @@ def _calls(t_q, t_k, block_q, block_k, heads, kv_heads, window=None, d=128, dtyp
 
 
 def _walk(call, operand):
-    """One pass over the grid's last two axes in the order the pipeline takes
-    them (first batch, first head of axis 1): the ``(head, block)`` that the
-    operand's index map names at each step, as an array ``(rows, steps, 2)``."""
+    """One pass over the grid's axes past the first two in the order the
+    pipeline takes them (first batch, first head of axis 1): the ``(head,
+    block)`` that the operand's index map names at each step, as an array
+    ``(*axes, 2)`` (rows x steps; for the fused backward group x rows x steps)."""
     mapping = call.params["grid_mapping"]
     index_map = mapping.block_mappings[operand].index_map_jaxpr
-    rows, steps = (a.ravel().astype(np.int32) for a in np.meshgrid(*map(np.arange, mapping.grid[2:]), indexing="ij"))
-    zero = np.zeros_like(rows)
-    index = jax.vmap(lambda *at: jax.core.eval_jaxpr(index_map.jaxpr, index_map.consts, *at))(zero, zero, rows, steps)
+    at = [a.ravel().astype(np.int32) for a in np.meshgrid(*map(np.arange, mapping.grid[2:]), indexing="ij")]
+    zero = np.zeros_like(at[0])
+    index = jax.vmap(lambda *at: jax.core.eval_jaxpr(index_map.jaxpr, index_map.consts, *at))(zero, zero, *at)
     return np.stack([np.asarray(index[1]), np.asarray(index[2])], axis=-1).reshape(*mapping.grid[2:], 2)
 
 
@@ -147,18 +148,37 @@ def test_the_causal_grid_copies_the_live_blocks_and_no_other(name):
         assert causal_grid(t_q, t_k)[1:] == (LIVE[name], LIVE[name])  # the tuned tiles are the default
 
 
-def test_the_fused_backward_takes_the_band_too():
-    calls = _calls(384, 384, 128, 128, 4, 1, d=64, dtype=jnp.float32, bwd="fused")
+@pytest.mark.parametrize("name", sorted(GRIDS))
+def test_the_rule_takes_one_backward_kernel_on_the_key_band(name):
+    """What the shape gives (``bwd_impl="auto"``, the default): the fused
+    kernel, whose grid is a key-value head's query heads in turn, their query
+    blocks, and a query block's key blocks up to its diagonal: K and V are
+    copied as the forward copies them, Q, dO and the two columns once a query
+    block, and dk, dv stay one block a key-value head."""
+    t_q, t_k, block_q, block_k, heads, kv_heads = GRIDS[name]
+    calls = _calls(*GRIDS[name], bwd="auto")
     assert sorted(calls) == ["flash_bwd_fused", "flash_fwd"]
-    copied = _copied(_walk(calls["flash_bwd_fused"], 0))
-    assert [[iq for _, iq in row] for row in copied] == [[0, 1, 2], [1, 2], [2]]
+    fused, group, rows = calls["flash_bwd_fused"], heads // kv_heads, t_q // block_q
+    keys = _key_band(True, None, t_q, t_k, block_q, block_k)
+    assert fused.params["grid_mapping"].grid == (1, kv_heads, group, rows, keys.steps)
+    live = [(iq, ik) for iq in range(rows) for ik in range(t_k // block_k) if ik * block_k <= iq * block_q + block_q - 1]
+    for operand in (1, 2):  # K and V, of the group's one head
+        copied = _copied(_walk(fused, operand).reshape(group * rows, keys.steps, 2))
+        assert [(i % rows, ik) for i, row in enumerate(copied) for _, ik in row] == live * group, operand
+        assert {head for row in copied for head, _ in row} == {0}
+    for operand in (0, 3, 4, 5, 6):  # Q, dO, the log-sum-exp, D and dq: a query head's block, the same at every step
+        walk = _walk(fused, operand)
+        assert [row == [(g, iq)] for g in range(group) for iq in range(rows) for row in [_copied(walk[g])[iq]]] == [True] * (group * rows)
+    for operand in (7, 8):  # dk, dv
+        assert not _walk(fused, operand).any()
 
 
 # sha256 of the windowed form's ``pallas_call`` equations (grids, index maps, bodies) at fbb99b0 (PR 35), before the
-# full form took the band's grid: the windowed kernels are this change's control
+# full form took the band's grid: the windowed kernels are this change's control (the fused form as PR 42 left it,
+# on the key band with dK, dV resident)
 WINDOWED_AT_THE_PARENT = {
     (16384, 16384, 1024, 1024, 32, 4, 2048, 128, "bfloat16", "two_pass"): "249f050263728dda680bbd01a77389bd36c85b27bfb9ba6a24eda846bfe70d74",
-    (384, 384, 64, 32, 8, 1, 100, 16, "float32", "fused"): "1213ec3d3a6ca40d21467fe3c2fac3b0073d679ad4c5f84598861a3d09502bb4",
+    (384, 384, 64, 32, 8, 1, 100, 16, "float32", "fused"): "757d1dea9e5f638cae722666122ff51e51ea80be6ca83e0721d13166b0df459b",
     (300, 300, 32, 64, 4, 2, 24, 16, "float32", "two_pass"): "9ccbb8b31614fd802f4287e9280fd384e9104195f4c5471bd80fd9a232e01353",
 }
 

@@ -172,8 +172,11 @@ def test_a_windowed_grid_visits_the_bands_blocks_only():
         _lowered(None, block_q=32, block_k=32).jaxpr, []
     )}
     assert full == {"flash_fwd": (1, 8, 12, 12), "flash_bwd_dq": (1, 8, 12, 12), "flash_bwd_dkv": (1, 1, 12, 8 * 12)}
-    fused = [e.params["name"] for e in _pallas_calls(_lowered(64, bwd="fused", block_q=32, block_k=32).jaxpr, [])]
-    assert fused == ["swa_fwd", "swa_bwd_fused"]
+    # what the shape gives: one backward kernel, the group's heads in turn, a query block's band of keys
+    fused = {e.params["name"]: e.params["grid_mapping"].grid for e in _pallas_calls(
+        _lowered(64, bwd="auto", block_q=32, block_k=32).jaxpr, []
+    )}
+    assert fused == {"swa_fwd": (1, 8, 12, 3), "swa_bwd_fused": (1, 1, 8, 12, 3)}
     # the band is ceil((block_q + window - 1) / block_k) + 1 blocks at most, whatever the blocks
     for bq, bk, window in [(32, 32, 24), (32, 64, 100), (64, 32, 100), (128, 128, 384)]:
         steps = _pallas_calls(_lowered(window, block_q=bq, block_k=bk).jaxpr, [])[0].params["grid_mapping"].grid[3]
@@ -194,7 +197,8 @@ def test_the_grid_covers_the_band_and_little_more():
 # ``causal`` is still the one recorded from 4f3dc3a (PR 32; the same at 58dc9ba, PR 31, but for PR 34's "whole"):
 # every block visited, one body. The three causal forms were re-pinned by PR 38, which gave them the band's grid
 # (the key axis ends at a row's diagonal, blocks wholly under it take no mask: ``tests/test_flash_causal_band.py``
-# holds them to the parent's values and walks their index maps); their kernels keep the names ``flash_*``
+# holds them to the parent's values and walks their index maps); their kernels keep the names ``flash_*``. The fused
+# backward was re-pinned by PR 42, which gave it the key band and made dK, dV its resident blocks
 PARENT_FORM = {
     (16, 16, 4096, 128, "bfloat16", "two_pass", True): {
         "kernels": "d4c35e2b70175ac8901d1a8054fca14463308bd2fde9368f886e2b01c1f3dd67",
@@ -205,8 +209,8 @@ PARENT_FORM = {
         "whole": "2c7c05b92d1fa87d5718f49acda7a4e807cab5267551d62a248ad5ca86ce0a95",
     },
     (4, 1, 384, 64, "float32", "fused", True): {
-        "kernels": "5f8c5cb4f836424e78ba2416eca579e463de1edb1acbd61464e2626e8da010ec",
-        "whole": "e4ddcc926588e6dfa605ebe51746e280afbb93fa9da29879dd18ec3d54d47850",
+        "kernels": "41267c5765f3f6d1dea654d1ce3c306990acb106ee0e6fcf27e44818ec0b1d8a",
+        "whole": "9e5ed321290bd45c46e4dae00283ab46b5eef2832a870539a8902bbaa63a7e44",
     },
     (4, 2, 300, 128, "float32", "two_pass", False): {
         "kernels": "79dc22c92d7e9c01f7b1f6822cd83e3bb4838a07b02036ec0c5f96ce4e9f6a3a",

@@ -211,7 +211,7 @@ class TestPallasBackwardKernels:
 class TestFusedBackward:
     """The fused single-pass backward must produce the SAME grads as the
     two-pass kernels (shared `_rebuild_probs`; only the accumulation
-    schedule differs — f32 dQ resident vs per-pass scratch)."""
+    schedule differs — f32 dK, dV resident vs per-pass scratch)."""
 
     def _grads(self, fn, q, k, v, g):
         def loss(q_, k_, v_):
@@ -254,15 +254,15 @@ class TestFusedBackward:
     def test_auto_resolves_and_matches(self):
         from heat_tpu.parallel import flash_attention
         from heat_tpu.parallel.pallas_attention import (
+            _bwd_takes_fused,
             _flash_bwd_fused,
-            _fused_bwd_fits,
         )
         import heat_tpu.parallel.pallas_attention as pa
 
         # "auto" must actually take the fused branch at this shape (the
         # grads comparison alone would pass even if dispatch regressed to
         # two_pass — record the fused driver running)
-        assert _fused_bwd_fits(256, 128)
+        assert _bwd_takes_fused(256, 256, 64, 2, 128, 128)
         calls = []
         orig = _flash_bwd_fused
 

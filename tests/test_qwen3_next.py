@@ -191,7 +191,7 @@ def test_a_bfloat16_state_fails_where_the_chunked_rule_in_its_stated_precision_d
 
 
 @highest
-@pytest.mark.parametrize("impl, bwd", [("flash", "two_pass"), ("flash", "fused"), ("local", "two_pass")])
+@pytest.mark.parametrize("impl, bwd", [("flash", "two_pass"), ("flash", "auto"), ("local", "auto")])
 def test_gated_attention_against_the_reference(weights, impl, bwd):
     """The flash kernels (in the interpreter here) read a group's key-value
     head by index and give dk, dv summed over the group; the local path
