@@ -32,6 +32,14 @@ carry ``S``. On a v5e a step is 21 us forward and 37 us backward at 32 heads of
 128, nine tenths of it the kernel, which is bound by the solve's float32
 products and not by what it moves.
 
+Before the rule stand the causal depthwise convolution of the projection's ``q |
+k | v`` channels, SiLU and the l2 norms of ``q`` and ``k``
+(:func:`conv_silu_norm`): on a TPU, at the same head sizes, one more pair of
+kernels (:mod:`heat_tpu.nn.pallas_gdn_conv`) that reads the projection once each
+way; elsewhere :func:`conv_silu` and :func:`l2_normalise`, XLA's passes. All four
+kernels are called through a module-level ``jax.jit``, so a model's mixers, in
+each of their passes, share one trace and one lowering of each.
+
 Float32 whatever ``dtype`` says: the decay and its running sum, ``beta``,
 the l2 norms, the state, the triangular solve, the gated norm. The
 projections and the chunk products take ``dtype`` operands and accumulate in
@@ -48,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from . import pallas_gdn_conv
 from .pallas_delta import chunk_step, kernel_chunk_step, takes_kernel
 
 CHUNK = 64
@@ -190,6 +199,31 @@ def _gated_short_conv_bwd(res, g):
 gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
 
 
+def conv_silu_norm(x, w, hk: int, dk: int, hv: int, dv: int):
+    """The mixer's pass before the rule: ``x (B, T, C)``, the projection's ``[q
+    | k | v]`` channels, through :func:`conv_silu` with taps ``w (C, K)``, then
+    ``q`` and ``k`` l2-normalised over their head and ``q`` scaled by
+    ``dk^-1/2``. Returns ``q, k (B, T, hk, dk)`` and ``v (B, T, hv, dv)`` in
+    float32. One Pallas kernel each way where the shapes and the backend let it
+    (``pallas_gdn_conv.takes_kernel``), which reads ``x`` once; XLA's passes
+    otherwise; the counters ``gdn.conv.kernel`` and ``gdn.conv.xla`` say which
+    a trace took."""
+    b, t, _ = x.shape
+    key_dim = hk * dk
+    heads = lambda a: a.reshape(b, t, hk, dk)  # noqa: E731
+    if pallas_gdn_conv.takes_kernel(x.shape, x.dtype, w.shape, hk, dk, hv, dv):
+        telemetry.get_registry().add("gdn.conv.kernel")
+        q, k, v = pallas_gdn_conv.conv_silu_norm(x, w, hk, dk, pallas_gdn_conv.ROWS, False)
+        q, k = heads(q), heads(k)
+    else:
+        telemetry.get_registry().add("gdn.conv.xla")
+        qkv = conv_silu(x, w)
+        q = l2_normalise(heads(qkv[..., :key_dim])) * dk**-0.5
+        k = l2_normalise(heads(qkv[..., key_dim: 2 * key_dim]))
+        v = qkv[..., 2 * key_dim:]
+    return q, k, v.reshape(b, t, hv, dv)
+
+
 class GatedDeltaNet(nn.Module):
     """The Gated DeltaNet mixer, ``(B, T, D_model)`` in and out::
 
@@ -255,10 +289,7 @@ class GatedDeltaNet(nn.Module):
                 z = project(x, p["in_qkvz"][:, 2 * key_dim + value_dim:])
                 ba = project(x, p["in_ba"]).astype(jnp.float32)
             with jax.named_scope("gdn.conv"):
-                qkv = conv_silu(qkv, p["conv"])
-                q = l2_normalise(qkv[..., :key_dim].reshape(n, t, hk, dk)) * dk**-0.5
-                k = l2_normalise(qkv[..., key_dim: 2 * key_dim].reshape(n, t, hk, dk))
-                v = qkv[..., 2 * key_dim:].reshape(n, t, hv, dv)
+                q, k, v = conv_silu_norm(qkv, p["conv"], hk, dk, hv, dv)
             with jax.named_scope("gdn.scan"):
                 beta = jax.nn.sigmoid(ba[..., :hv])
                 g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])

@@ -224,6 +224,30 @@ def _run(kernel, name, operands, results, dtype, interpret):
     )(*operands)
 
 
+def same_trace_context():
+    """A context in which every call of a jitted function finds the one trace
+    of it. JAX keys that trace on the abstract mesh in context, and an equation
+    evaluated again under a transformation (a checkpoint's recomputation, a
+    custom VJP's forward rule) brings the empty mesh where the program's first
+    pass saw none: two keys, two traces of the same function. With the mesh in
+    context named outright, none reads as the empty one from the first."""
+    return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
+
+
+# Each kernel is called through a module-level ``jax.jit``: the mixers of a model, in each
+# of their passes, then share one trace of the kernel's body and one lowering to Mosaic,
+# where a bare ``pallas_call`` is traced and lowered anew at every call site (a train step
+# of three mixers, each run forward three times, holds twelve)
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def _step_forward(state, q, k, v, run, beta, *, dtype, interpret):
+    return tuple(_run(_fwd_kernel, "delta_chunk_fwd", (state, q, k, v, run, beta), ("state", "value"), dtype, interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def _step_backward(*inputs_and_cotangents, dtype, interpret):
+    return tuple(_run(_bwd_kernel, "delta_chunk_bwd", inputs_and_cotangents, _STEP_INPUTS, dtype, interpret))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def kernel_chunk_step(state, q, k, v, run, beta, dtype, interpret):
     """:func:`chunk_step` for every sequence and head of one chunk, as one
@@ -231,7 +255,8 @@ def kernel_chunk_step(state, q, k, v, run, beta, dtype, interpret):
     ``i`` serves value heads ``i r .. i r + r - 1``), ``v (B, C, H Dv)``,
     ``run`` and ``beta`` ``(B, H, C)``, float32. Returns the states after the
     chunk and the outputs ``(B, C, H Dv)``."""
-    return tuple(_run(_fwd_kernel, "delta_chunk_fwd", (state, q, k, v, run, beta), ("state", "value"), dtype, interpret))
+    with same_trace_context():
+        return _step_forward(state, q, k, v, run, beta, dtype=dtype, interpret=interpret)
 
 
 def _kernel_chunk_step_fwd(state, q, k, v, run, beta, dtype, interpret):
@@ -239,7 +264,8 @@ def _kernel_chunk_step_fwd(state, q, k, v, run, beta, dtype, interpret):
 
 
 def _kernel_chunk_step_bwd(dtype, interpret, inputs, cotangents):
-    return tuple(_run(_bwd_kernel, "delta_chunk_bwd", inputs + tuple(cotangents), _STEP_INPUTS, dtype, interpret))
+    with same_trace_context():
+        return _step_backward(*inputs, *cotangents, dtype=dtype, interpret=interpret)
 
 
 kernel_chunk_step.defvjp(_kernel_chunk_step_fwd, _kernel_chunk_step_bwd)

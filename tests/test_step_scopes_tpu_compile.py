@@ -41,10 +41,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # rows gone over are counted (13,213,087,744 and 14,986,435,072 bytes before).
 # All three are PR 42's since: one fused flash backward kernel a layer where a dq
 # and a dk/dv kernel stood (15,751,272,448, 13,212,765,696 and 14,987,403,264
-# bytes before: the totals moved by under a megabyte).
+# bytes before: the totals moved by under a megabyte). Qwen3-Next's is PR 45's: the mixers' pass before the rule is a
+# kernel each way where XLA's fusions stood, and all four mixer kernels are functions of the module, called from their
+# sites (13,212,765,696 bytes before: what the fusions held between them went).
 PARENT = {
     "olmoe-train-4k-1chip": (15_752_046_592, "4870ab2ef18dc83a9eef742703c6b877772a1d3cb84ca9013c3c0f3c787968ca"),
-    "qwen3next-train-8k-1chip": (13_212_765_696, "96d18dadac17eb553a64839a7a581c719fd0036262b1c581a53eacca4ee459b3"),
+    "qwen3next-train-8k-1chip": (12_601_269_248, "f4e5a5af550fe762d4f309068aa2743d2b033283e1b2b468488438de833bdb83"),
     "trinity-train-16k-1chip": (14_987_274_240, "de6292831a94d0bb6228ff98d8b5d8d1cf05441d159a14384f20447e1c282e31"),
 }
 
@@ -117,13 +119,12 @@ def step(request, topo):
     backend = jax.default_backend
     jax.default_backend = lambda: "tpu"  # the flash and delta kernels ask it whether to run in the interpreter
     try:
-        program = train_step.lower(
-            placed(params), placed(jax.eval_shape(opt.init, {"params": params["params"]})), tokens
-        ).compile()
+        lowered = train_step.lower(placed(params), placed(jax.eval_shape(opt.init, {"params": params["params"]})), tokens)
+        program = lowered.compile()
     finally:
         jax.default_backend = backend
     text = program.as_text()
-    return request.param, program, text, hlo.scope_rows(text)
+    return request.param, program, text, hlo.scope_rows(text), lowered.as_text()
 
 
 def _instruction_lines(text):
@@ -134,26 +135,37 @@ def _instruction_lines(text):
 
 
 def test_every_kernel_and_loop_falls_in_its_piece(step):
-    cell, _, text, rows = step
+    cell, _, text, rows, _ = step
     lines = _instruction_lines(text)
     pieces = {name: scope_trace.piece_of(row) for name, row in rows.items()}
     # the Mosaic kernels, wherever they stand (XLA fuses the delta rule's into its loop's update of the stacked outputs)
-    kernels = {}
+    kernels, passes = {}, {}
     for name, line in lines.items():
         if " custom-call(" in line and "tpu_custom_call" in line and not name.startswith("ragged-dot"):
-            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-            kernels[name] = scope_trace.piece_of(hlo.split_op_name(op_name))
+            split = hlo.split_op_name(re.search(r'op_name="([^"]*)"', line).group(1))
+            kernels[name] = scope_trace.piece_of(split)
+            passes.setdefault(re.sub(r"\.\d+$", "", name), []).append(split["pass"])
     flash = {n: p for n, p in kernels.items() if re.match(r"(flash|swa)_", n)}
     delta = {n: p for n, p in kernels.items() if n.startswith("delta_chunk_")}
+    conv = {n: p for n, p in kernels.items() if n.startswith("gdn_conv_")}
     assert flash and set(flash.values()) == {"attention_core"}
     assert all(n in rows and pieces[n] == "attention_core" for n in flash)  # events of their own
-    assert len(flash) + len(delta) == len(kernels)  # no kernel the table has not heard of
+    assert len(flash) + len(delta) + len(conv) == len(kernels)  # no kernel the table has not heard of
     if cell == "qwen3next-train-8k-1chip":
         assert len(delta) == 12 and set(delta.values()) == {"delta_rule"}  # nine forward, three backward
         holding = [n for n, r in rows.items() if r["op"] == "fusion" and any("delta_chunk" in m for m, _ in r["fused"])]
         assert len(holding) == 12 and {pieces[n] for n in holding} == {"delta_rule"}
+        # the pass before the rule (PR 45): as many, events of their own in the mixers' loops
+        assert len(conv) == 12 and all(n in rows and pieces[n] == "mixer_glue" for n in conv)
+        # a kernel lowered once a program is inlined at every call site under that site's own name: three mixers
+        # in the step's pass, each again in its block's rematerialisation and in its sequence's checkpoint, once backward
+        forward = sorted(["forward"] * 3 + ["recomputed"] * 6)
+        assert {k: sorted(v) for k, v in passes.items() if not k.startswith("flash")} == {
+            "delta_chunk_fwd": forward, "gdn_conv_fwd": forward,
+            "delta_chunk_bwd": ["backward"] * 3, "gdn_conv_bwd": ["backward"] * 3,
+        }
     else:
-        assert not delta
+        assert not delta and not conv
     grouped = {n: p for n, p in pieces.items() if n.startswith("ragged-dot")}  # XLA:TPU's own kernel, metadata and product
     assert len(grouped) >= 9 and set(grouped.values()) == {"experts"}
     # the head's loop and every instruction of its body
@@ -163,8 +175,31 @@ def test_every_kernel_and_loop_falls_in_its_piece(step):
     assert {p for n, p in pieces.items() if "train.optimizer" in rows[n]["scopes"]} == {"optimizer"}
 
 
+def test_a_mixer_kernel_is_lowered_once_a_call_path(step):
+    """The lowered module, before the compiler inlines it (PR 45): a mixer
+    kernel called through its module-level ``jax.jit`` is a function of the
+    module, called from every site, where a bare ``pallas_call`` left its
+    Mosaic body at each (nine ``delta_chunk_fwd`` and three ``delta_chunk_bwd``
+    in this step before). The two backward kernels stand once; a forward kernel
+    once for the step's pass and once more for each of the two checkpoints a
+    mixer's pass lies under (the block's, the sequence's): partial evaluation
+    writes the jitted call's outer jaxpr anew, and the lowering knows a
+    function by its jaxpr. The body inside is traced once
+    (``tests/test_gdn_conv_kernel.py``)."""
+    cell, _, _, _, lowered = step
+    bodies = {}
+    for name in re.findall(r'kernel_name = "([^"]*)"', lowered):
+        bodies[name] = bodies.get(name, 0) + 1
+    assert lowered.count("tpu_custom_call") == sum(bodies.values())
+    mixers = {k: n for k, n in bodies.items() if not re.match(r"(flash|swa)_", k)}
+    if cell == "qwen3next-train-8k-1chip":
+        assert mixers == {"gdn_conv_fwd": 3, "delta_chunk_fwd": 3, "gdn_conv_bwd": 1, "delta_chunk_bwd": 1}
+    else:
+        assert not mixers
+
+
 def test_the_passes_and_scopes_are_the_models_own(step):
-    cell, _, _, rows = step
+    cell, _, _, rows, _ = step
     passes = {r["pass"] for r in rows.values()} - {""}
     scopes = {s for r in rows.values() for s in r["scopes"]}
     common = {"lm.body", "lm.head_loss", "lm.targets", "lm.loss", "train.optimizer", "moe.route", "moe.experts",
@@ -187,7 +222,7 @@ def test_the_passes_and_scopes_are_the_models_own(step):
 
 
 def test_few_instructions_that_can_run_are_left_without_a_piece(step):
-    _, _, text, rows = step
+    _, _, text, rows, _ = step
     lines = _instruction_lines(text)
     runs = [n for n, r in rows.items() if r["op"] in RUNS and not NO_KERNEL.search(lines[n])]
     assert len(runs) > 150
@@ -200,7 +235,7 @@ def test_few_instructions_that_can_run_are_left_without_a_piece(step):
 
 
 def test_the_program_is_the_parents_but_for_metadata(step):
-    cell, program, text, _ = step
+    cell, program, text, _, _ = step
     m = program.memory_analysis()
     total = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
     body = text[text.index("\n%"):]  # the computations, without the header's table of source files
@@ -242,7 +277,7 @@ def test_a_first_window_of_two_shares_is_moved_whole(step):
     a whole window: blocks lose at 2 shares); the three steps compiled here
     hold no loop over live blocks, and the layer that holds every expert none
     either."""
-    cell, _, text, _ = step
+    cell, _, text, _, _ = step
     found = _computations(text)
     assert not _live_loops(found)
     if cell != "olmoe-train-4k-1chip":  # a window's one scatter-add back into the tokens, in no loop over blocks
