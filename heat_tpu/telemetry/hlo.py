@@ -654,7 +654,7 @@ def audit_call(
 # An ``op_name`` is the name stack JAX kept for the equation an instruction
 # came from, ``/``-joined, the primitive last. The spellings below are those of
 # the three published-width train steps compiled for a TPU v5e on jax 0.9.0
-# (pinned in ``tests/test_step_scopes_tpu_compile.py``):
+# (pinned in ``tests/test_train_steps_tpu_compile.py``):
 #
 #   jit(dp_train_step)/jvp(lm.body)/TransformerLM/block3/attn/attn.window/slice
 #       forward;

@@ -9,7 +9,6 @@ The tests lower the jitted function the public calls launch, on shapes
 placed on the described devices: there is no device here to hold an array.
 """
 
-import os
 import re
 
 import numpy as np
@@ -21,24 +20,6 @@ HALF_A_CHIP = 8 * GIB  # of a v5e chip's 16 GiB
 GAMMA = {"dist": None, "rbf": np.float32(0.5)}
 
 
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
 
 
 def _lower(m, n, k, epilogue, x_sharding, y_sharding):

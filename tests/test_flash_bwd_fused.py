@@ -6,7 +6,6 @@ counters, and the kernel compiled alone for a described TPU v5e at two of the
 training cells' shapes. A compile is not a run: nothing here is a time.
 """
 
-import os
 import re
 
 import jax
@@ -119,23 +118,10 @@ def test_the_default_is_the_rule_and_a_forced_path_stays_forced(monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
+def one_chip(topo):
     from jax.sharding import SingleDeviceSharding
 
-    try:
-        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(desc.devices[0])
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_backward(one_chip, batch, heads, kv_heads, t, d, window, impl):

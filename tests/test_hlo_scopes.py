@@ -210,7 +210,7 @@ def test_a_fusion_over_two_scopes_lists_both_and_branches_are_reached():
 
 
 @pytest.mark.parametrize("path, modules, scopes, which", [
-    # the spellings of the three published-width steps compiled for a v5e (tests/test_step_scopes_tpu_compile.py)
+    # the spellings of the three published-width steps compiled for a v5e (tests/test_train_steps_tpu_compile.py)
     ("jit(dp_train_step)/jvp(lm.body)/TransformerLM/block3/attn/attn.window/slice",
      "TransformerLM/block3/attn", ("lm.body", "attn.window"), "forward"),
     ("jit(dp_train_step)/transpose(jvp(lm.body))/TransformerLM/block0/ln1/mul",

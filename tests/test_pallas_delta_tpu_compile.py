@@ -5,31 +5,16 @@ a described TPU v5e: Mosaic accepts what the interpreter ran, also under the
 A compile is not a run: nothing here is a time or a result.
 """
 
-import os
 import re
 
 import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
+def one_chip(topo):
     from jax.sharding import SingleDeviceSharding
 
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.mark.parametrize("precision", [None, "highest"])

@@ -9,7 +9,6 @@ in the sandbox, so the tests lower the jitted fits it dispatches to on a
 TPU, on shapes placed on the described devices.
 """
 
-import os
 import re
 
 import pytest
@@ -18,24 +17,6 @@ GIB = 2**30
 USABLE = 15.75 * GIB  # of a v5e chip's 16 GiB, what the runtime leaves a program
 
 
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
 
 
 def _one_chip(topo, rows, d, k=8, max_iter=30):

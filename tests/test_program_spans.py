@@ -99,7 +99,6 @@ def test_on_records_phases_that_tile_their_root(data, path, phases, enable, prof
     recs = [s for s in telemetry.spans() if s["name"].startswith("heat_tpu.")]
     roots = [s for s in recs if s["name"] == phases[0].rsplit(".", 1)[0]]
     assert len(roots) == len(window.calls) >= 1
-    shares = []
     for root in roots:
         assert root["parent_id"] is None and root["root_id"] == root["id"] and root["depth"] == 0
         kids = sorted((s for s in recs if s["parent_id"] == root["id"]), key=lambda s: s["t0_ns"])
@@ -110,13 +109,7 @@ def test_on_records_phases_that_tile_their_root(data, path, phases, enable, prof
             assert root["t0_ns"] <= k["t0_ns"] <= k["t1_ns"] <= root["t1_ns"]
             assert k["seconds"] == pytest.approx((k["t1_ns"] - k["t0_ns"]) / 1e9)
         assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(kids, kids[1:])), "consecutive"
-        own = (root["t1_ns"] - root["t0_ns"]) - sum(k["t1_ns"] - k["t0_ns"] for k in kids)
-        call = next(c for c in window.calls if c.t0 * 1e9 <= root["t0_ns"] <= c.t1 * 1e9)
-        shares.append(own / (call.ms * 1e6))
-    # a distance matrix is not waited for inside its root, so the root's
-    # own time is held against the call the harness timed around it; the
-    # quietest call decides, since a busy test host stalls any thread
-    assert min(shares) < 0.02, f"root self time {shares} of the call"
+    # how little of a call the root keeps to itself is a time: the chip's traced runs read it (``idle_ms.*``), no CPU test
     # the initial centres a fit is given pass through ht.array: a root of its own
     if path is _fit:
         arrays = [s for s in recs if s["name"] == "heat_tpu.array.prepare"]

@@ -1,30 +1,53 @@
-"""The scope map of the three published-width train steps, compiled for a
-described TPU v5e: the spellings ``telemetry.hlo.split_op_name`` reads are the
-compiler's own here (custom VJPs, scans, ``nn.remat`` with a policy, a
-``jax.checkpoint`` inside a ``lax.map`` inside a rematerialised block), every
-kernel falls in its piece of ``chipbench/scope_trace.PIECES``, few of the
-instructions that can be a device event are left without a piece, the
-programs are the ones recorded below (PR 35's scopes moved metadata alone;
-PR 40 moved the two share steps on purpose and left OLMoE's as it was), and
-the rows round the held experts move whole at a first window of 2 even shares
-and in loops by the live rows at a longer one (one expert layer at the LFM2
-cell's widths, compiled by itself). A compile is not a run: nothing here is a
-time.
+"""The three oldest published-width train steps (OLMoE, Qwen3-Next,
+Trinity-Mini), compiled for a described TPU v5e once each: the one place
+outside ``tests/chipbench/`` that compiles a cell's step. A new cell's compile
+test outside the benchmark's directories is one more key of ``PARENT``, never
+a fixture of its own: a step takes a minute or two of a suite that has none
+to spare.
 
-The steps are built as ``tests/chipbench/test_chipbench_{lm,qnext,trinity}_tpu_compile.py``
-build them (those fixtures also compile each cell's check; here the step alone).
+Read from them: the scope map (the spellings ``telemetry.hlo.split_op_name``
+reads are the compiler's own here: custom VJPs, scans, ``nn.remat`` with a
+policy, a ``jax.checkpoint`` inside a ``lax.map`` inside a rematerialised
+block; every kernel falls in its piece of ``chipbench/scope_trace.PIECES``; few
+of the instructions that can be a device event are left without a piece); the
+programs are the ones recorded below (PR 35's scopes moved metadata alone;
+PR 40 moved the two share steps on purpose and left OLMoE's as it was); the
+rows round the held experts move whole at a first window of 2 even shares and
+in loops by the live rows at a longer one (one expert layer at the LFM2 cell's
+widths, compiled by itself); every flash forward kernel is in a rematerialised
+step once (a block's checkpoint keeps the attention core's output and its
+log-sum-exp, one float a row, so the backward pass runs the block again
+without the kernel) and the step still fits the chip with the kept arrays;
+OLMoE's head is one loop (the pass that forms a block's logits forms both
+gradients from them: one ``while`` whose carry is the kernel's float32
+gradient, three products a block with the vocabulary in them, no array of
+every position by the vocabulary). A compile is not a run: nothing here is a
+time or a result.
+
+The steps are built as ``chipbench/kinds/{lm,qnext,trinity}_step.py`` build
+them, from the cells' configurations. The benchmark's own compile tests
+(``tests/chipbench/test_chipbench_*_tpu_compile.py``) compile each cell's step
+and its check for themselves; nothing is imported from them (the last case
+here holds that for every file outside ``tests/chipbench/``).
 """
 
+import collections
+import contextlib
+import functools
 import hashlib
 import os
+import pathlib
 import re
 
 import pytest
 
 from chipbench import manifest, scope_trace
 from heat_tpu.telemetry import hlo
+from tests.test_olmoe import loops, products_over
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+GIB = 2**30
+OLMOE, QNEXT, TRINITY = "olmoe-train-4k-1chip", "qwen3next-train-8k-1chip", "trinity-train-16k-1chip"
 
 # ``memory_analysis`` totals and a digest of the instruction list (described
 # v5e:2x2, jax 0.9.0, libtpu 0.0.34): the text's computations with
@@ -45,9 +68,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # kernel each way where XLA's fusions stood, and all four mixer kernels are functions of the module, called from their
 # sites (13,212,765,696 bytes before: what the fusions held between them went).
 PARENT = {
-    "olmoe-train-4k-1chip": (15_752_046_592, "4870ab2ef18dc83a9eef742703c6b877772a1d3cb84ca9013c3c0f3c787968ca"),
-    "qwen3next-train-8k-1chip": (12_601_269_248, "f4e5a5af550fe762d4f309068aa2743d2b033283e1b2b468488438de833bdb83"),
-    "trinity-train-16k-1chip": (14_987_274_240, "de6292831a94d0bb6228ff98d8b5d8d1cf05441d159a14384f20447e1c282e31"),
+    OLMOE: (15_752_046_592, "4870ab2ef18dc83a9eef742703c6b877772a1d3cb84ca9013c3c0f3c787968ca"),
+    QNEXT: (12_601_269_248, "f4e5a5af550fe762d4f309068aa2743d2b033283e1b2b468488438de833bdb83"),
+    TRINITY: (14_987_274_240, "de6292831a94d0bb6228ff98d8b5d8d1cf05441d159a14384f20447e1c282e31"),
 }
 
 # what a device trace of these steps shows as an event of its own (my chip runs, PR 35)
@@ -59,29 +82,26 @@ RUNS = {
 # custom calls that are no kernel: the compiler's own markers, no event
 NO_KERNEL = re.compile(r'custom_call_target="(ConcatBitcast|AssumeGatherIndicesInBound|GatherScatterIndicesBitpacked|X64Combine)"')
 
+# what ``steps`` keeps of a cell: the compiled step, its text, ``hlo.scope_rows`` of it, the text as lowered, the configuration
+Step = collections.namedtuple("Step", "cell program text rows lowered config")
 
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+@contextlib.contextmanager
+def _answering_tpu():
+    """The flash and delta kernels ask ``jax.default_backend()`` whether to run in the interpreter."""
     import jax
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
 
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
     try:
-        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no libtpu, or another process holds it
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep it out
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+        yield
+    finally:
+        jax.default_backend = backend
 
 
-@pytest.fixture(scope="module", params=sorted(PARENT))
-def step(request, topo):
+def _build(topo, cell):
+    """The cell's configuration, its loss, its train step and the step's
+    arguments as shapes placed on the described chip: nothing is compiled."""
     import jax
     import jax.numpy as jnp
 
@@ -89,7 +109,7 @@ def step(request, topo):
     from heat_tpu.core.communication import MeshCommunication
 
     parts = manifest.load(REPO)
-    config = parts.config(parts.cell(request.param))
+    config = parts.config(parts.cell(cell))
     kind = parts.module("kinds", config["kind"])
     comm = MeshCommunication(devices=topo.devices[:1])
     if config["kind"] == "lm_step":
@@ -116,15 +136,34 @@ def step(request, topo):
     tokens = jax.ShapeDtypeStruct(
         (config["sequences_per_step"], config["sequence_length"]), jnp.int32, sharding=comm.sharding(0, 2)
     )
-    backend = jax.default_backend
-    jax.default_backend = lambda: "tpu"  # the flash and delta kernels ask it whether to run in the interpreter
-    try:
-        lowered = train_step.lower(placed(params), placed(jax.eval_shape(opt.init, {"params": params["params"]})), tokens)
+    opt_state = placed(jax.eval_shape(opt.init, {"params": params["params"]}))
+    return config, loss_fn, train_step, (placed(params), opt_state, tokens)
+
+
+def _compile(topo, cell):
+    config, _, train_step, arguments = _build(topo, cell)
+    with _answering_tpu():
+        lowered = train_step.lower(*arguments)
         program = lowered.compile()
-    finally:
-        jax.default_backend = backend
     text = program.as_text()
-    return request.param, program, text, hlo.scope_rows(text), lowered.as_text()
+    return Step(cell, program, text, hlo.scope_rows(text), lowered.as_text(), config)
+
+
+@pytest.fixture(scope="module")
+def steps(topo):
+    """``steps(cell)``: the cell's step, compiled at its first use and kept to
+    the module's end, so that a case of one cell and the cases of every cell
+    (``step``) read one compile."""
+    return functools.cache(functools.partial(_compile, topo))
+
+
+@pytest.fixture(scope="module", params=sorted(PARENT))
+def step(request, steps):
+    return steps(request.param)[:5]
+
+
+def _total(m):
+    return m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
 
 
 def _instruction_lines(text):
@@ -151,7 +190,7 @@ def test_every_kernel_and_loop_falls_in_its_piece(step):
     assert flash and set(flash.values()) == {"attention_core"}
     assert all(n in rows and pieces[n] == "attention_core" for n in flash)  # events of their own
     assert len(flash) + len(delta) + len(conv) == len(kernels)  # no kernel the table has not heard of
-    if cell == "qwen3next-train-8k-1chip":
+    if cell == QNEXT:
         assert len(delta) == 12 and set(delta.values()) == {"delta_rule"}  # nine forward, three backward
         holding = [n for n, r in rows.items() if r["op"] == "fusion" and any("delta_chunk" in m for m, _ in r["fused"])]
         assert len(holding) == 12 and {pieces[n] for n in holding} == {"delta_rule"}
@@ -192,7 +231,7 @@ def test_a_mixer_kernel_is_lowered_once_a_call_path(step):
         bodies[name] = bodies.get(name, 0) + 1
     assert lowered.count("tpu_custom_call") == sum(bodies.values())
     mixers = {k: n for k, n in bodies.items() if not re.match(r"(flash|swa)_", k)}
-    if cell == "qwen3next-train-8k-1chip":
+    if cell == QNEXT:
         assert mixers == {"gdn_conv_fwd": 3, "delta_chunk_fwd": 3, "gdn_conv_bwd": 1, "delta_chunk_bwd": 1}
     else:
         assert not mixers
@@ -204,9 +243,9 @@ def test_the_passes_and_scopes_are_the_models_own(step):
     scopes = {s for r in rows.values() for s in r["scopes"]}
     common = {"lm.body", "lm.head_loss", "lm.targets", "lm.loss", "train.optimizer", "moe.route", "moe.experts",
               "moe.combine", "attn.full", "attn.lse"}
-    if cell == "olmoe-train-4k-1chip":  # no checkpoint: nothing is run again
+    if cell == OLMOE:  # no checkpoint: nothing is run again
         assert passes == {"forward", "backward"} and scopes == common
-    elif cell == "qwen3next-train-8k-1chip":
+    elif cell == QNEXT:
         assert passes == {"forward", "recomputed", "backward"}
         assert scopes == common | {"attn.gate", "moe.shared", "gdn.project", "gdn.conv", "gdn.scan", "gdn.gate_norm"}
     else:
@@ -236,8 +275,7 @@ def test_few_instructions_that_can_run_are_left_without_a_piece(step):
 
 def test_the_program_is_the_parents_but_for_metadata(step):
     cell, program, text, _, _ = step
-    m = program.memory_analysis()
-    total = m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes + m.temp_size_in_bytes
+    total = _total(program.memory_analysis())
     body = text[text.index("\n%"):]  # the computations, without the header's table of source files
     body = re.sub(r", metadata=\{[^{}]*\}", "", body)
     body = re.sub(r'"body":\s*"[^"]*"', "", body)
@@ -280,7 +318,7 @@ def test_a_first_window_of_two_shares_is_moved_whole(step):
     cell, _, text, _, _ = step
     found = _computations(text)
     assert not _live_loops(found)
-    if cell != "olmoe-train-4k-1chip":  # a window's one scatter-add back into the tokens, in no loop over blocks
+    if cell != OLMOE:  # a window's one scatter-add back into the tokens, in no loop over blocks
         whole = re.compile(r"moe\.combine\)*/scatter-add$")
         assert [x for lines in found.values() for x in lines if " scatter(" in x and whole.search(_op_name(x))]
 
@@ -339,3 +377,99 @@ def test_the_rows_round_the_held_experts_move_in_loops_by_the_live_rows(long_win
         if moves.search(_op_name(x)) and whole(x.split(", metadata=")[0])
     ]
     assert not outside, outside[:3]
+
+
+# ---- what a block's checkpoint keeps of the attention core (PR 34) ---------------------------------------
+
+
+def kernels(text: str) -> dict:
+    """How many Mosaic calls of each flash kernel the compiled text holds."""
+    found = re.findall(r"^\s*%((?:swa|flash)_\w+?)(?:\.\d+)? = .*custom-call\(", text, re.M)
+    return {name: found.count(name) for name in set(found)}
+
+
+def test_the_trinity_step_runs_each_forward_kernel_once(steps):
+    """Six sliding layers and two full ones: a forward and a fused backward kernel each."""
+    assert kernels(steps(TRINITY).text) == {"swa_fwd": 6, "swa_bwd_fused": 6, "flash_fwd": 2, "flash_bwd_fused": 2}
+
+
+def test_the_trinity_step_keeps_a_column_of_log_sum_exp_and_fits(steps):
+    trinity = steps(TRINITY)
+    # the kernels write and read the lane-broadcast layout; what lives from the forward pass to the backward
+    # is its first lane, sliced out once a block in the forward pass (2 MB beside the output's 134 MB)
+    assert "f32[1,32,16384,128]" in trinity.text
+    columns = re.findall(
+        r'= f32\[32,16384\]\S* reduce\(.*op_name="[^"]*?jvp\(lm\.body\)/TransformerLM/(block\d)/attn/attn\.\w+/slice"',
+        trinity.text,
+    )
+    assert sorted(columns) == [f"block{i}" for i in range(8)]
+    # eight outputs and columns at most over the step that kept nothing (13.34 GiB, PR 32); with the
+    # log-sum-exp kept as the kernel writes it (268 MB a block) it would be 3.2 GB over, and past the chip
+    assert _total(trinity.program.memory_analysis()) < min(14_318_943_744 + 8 * 136_314_880, 15 * GIB)
+    # that the check's evaluation fits too is held where it is compiled:
+    # tests/chipbench/test_chipbench_trinity_tpu_compile.py::test_the_checks_evaluation_fits_once_the_moments_step_aside
+
+
+def test_the_qwen3_next_step_runs_its_forward_kernel_once_and_fits(steps):
+    """One attention block a period of four: one kernel of each kind."""
+    qnext = steps(QNEXT)
+    assert kernels(qnext.text) == {"flash_fwd": 1, "flash_bwd_fused": 1}
+    assert _total(qnext.program.memory_analysis()) < 15 * GIB
+    # that the check's gradients fit beside both AdamW moments (8 bytes a parameter) is held where they are compiled:
+    # tests/chipbench/test_chipbench_qnext_tpu_compile.py::test_the_checks_gradients_fit_beside_the_optimizer_state
+
+
+# ---- OLMoE's head: the loss and both gradients in one loop (PR 29) ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def olmoe_gradients(topo):
+    """The text of the gradients program of the cell's check, as ``chipbench/kinds/lm_step.py`` takes them."""
+    import jax
+
+    _, loss_fn, _, (params, _, tokens) = _build(topo, OLMOE)
+
+    def grads(params, tokens):
+        return jax.grad(lambda p: loss_fn(p, tokens)[0])(params)
+
+    with _answering_tpu():
+        return jax.jit(grads).lower(params, tokens).compile().as_text()
+
+
+def test_the_step_holds_one_loop_whose_carry_is_the_kernels_gradient(steps, olmoe_gradients):
+    for text in (steps(OLMOE).text, olmoe_gradients):
+        found = loops(text)
+        assert len(found) == 1, found
+        assert "f32[2048,50304]" in found[0]  # the (D, V) float32 sum over the blocks
+
+
+def test_a_block_takes_three_products_with_the_vocabulary_in_them(steps):
+    """Logits, the hidden states' gradient, the kernel's gradient: the
+    logits are not formed a second time."""
+    products = products_over(steps(OLMOE).text, 50304)
+    assert len(products) == 3, products
+
+
+def test_no_array_of_every_position_by_the_vocabulary_and_the_step_fits(steps):
+    olmoe = steps(OLMOE)
+    assert "[16384,50304]" not in olmoe.text and "[4,4096,50304]" not in olmoe.text and "[8,2048,50304]" not in olmoe.text
+    total = _total(olmoe.program.memory_analysis())
+    assert total < 15 * GIB
+    assert abs(total - olmoe.config["memory_analysis"]["total_bytes"]) < 0.01 * total
+
+
+# ---- the rule this module exists for ----------------------------------------------------------------------
+
+
+def test_no_file_outside_the_benchmarks_tests_imports_from_its_compile_tests():
+    """A module-scoped fixture imported from another test file is built again
+    in the worker that runs the importer: until PR 46 two files here imported
+    the ``compiled`` fixtures of the benchmark's compile tests, and each paid
+    a cell's step and its check a second time (590 s of tier-1)."""
+    imports = re.compile(r"^\s*(?:from|import)\s+tests\.chipbench(?:\.|\s+import\s+)test_chipbench_\w*_tpu_compile\b", re.M)
+    tests = pathlib.Path(__file__).parent
+    found = [
+        str(path.relative_to(tests)) for path in sorted(tests.rglob("*.py"))
+        if path.relative_to(tests).parts[0] != "chipbench" and imports.search(path.read_text())
+    ]
+    assert not found, found
