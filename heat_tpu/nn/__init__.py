@@ -19,10 +19,13 @@ from .transformer import (
     TransformerBlock,
     TransformerLM,
     causal_lm_loss,
+    exit_distribution,
     glm_4_7_flash,
     lfm2_24b_a2b,
     olmoe_1b_7b,
+    ouro_2_6b,
     qwen3_next_80b_a3b,
+    read_exits,
     trinity_mini,
 )
 from .deltanet import GatedDeltaNet, gated_delta_rule
@@ -48,10 +51,13 @@ __all__ = [
     "TransformerBlock",
     "TransformerLM",
     "causal_lm_loss",
+    "exit_distribution",
     "glm_4_7_flash",
     "lfm2_24b_a2b",
     "olmoe_1b_7b",
+    "ouro_2_6b",
     "qwen3_next_80b_a3b",
+    "read_exits",
     "read_routing",
     "trinity_mini",
 ]

@@ -45,10 +45,12 @@ and ``dense_d_ff`` (leading blocks with a SwiGLU of that width before the
 expert blocks), ``tie_embeddings`` (the head is the embedding table: no
 ``lm_head``), ``mtp_modules`` (multi-token-prediction modules behind the
 trunk, each a merge with the next token's embedding, one more block and a pass
-through the same head), ``init_std`` and ``accum_dtype``. The defaults are the
+through the same head), ``passes`` (a looped stack: the blocks and the final
+norm run ``passes`` times on one set of weights, a one-output exit gate read
+after every pass but the last), ``init_std`` and ``accum_dtype``. The defaults are the
 pre-LN, learned-position, SwiGLU model this module began with, parameter tree
 and numerics unchanged. :func:`olmoe_1b_7b`, :func:`qwen3_next_80b_a3b`,
-:func:`trinity_mini`, :func:`lfm2_24b_a2b` and :func:`glm_4_7_flash` name the published configurations;
+:func:`trinity_mini`, :func:`lfm2_24b_a2b`, :func:`glm_4_7_flash` and :func:`ouro_2_6b` name the published configurations;
 :func:`causal_lm_loss` is their training loss.
 
 Weights are plain flax params — shard them with `jax.sharding` NamedSharding
@@ -70,7 +72,7 @@ from .. import telemetry
 from ..parallel.pallas_attention import KEPT_RESIDUALS
 from .deltanet import GatedDeltaNet, gated_short_conv
 from .functional import blocked_cross_entropy
-from .moe import DroplessMoE
+from .moe import DroplessMoE, read_routing
 
 
 def _dot_general(accum_dtype):
@@ -622,6 +624,12 @@ class TransformerLM(nn.Module):
     # before it with the embedding of token i + j + 1, runs one more block (``block<num_layers + j>``, the
     # pattern continued) and reads the trunk's head behind a norm of its own: ``__call__(..., mtp=True)``
     mtp_modules: int = 0
+    # a looped stack (Ouro, arXiv:2510.25741): above 1, the ``num_layers`` blocks and ``ln_f`` run ``passes`` times on
+    # one set of weights, pass t + 1 reading pass t's ``ln_f``; every pass's ``ln_f`` output is an exit's hidden state,
+    # and one exit gate (``Linear(d_model, 1)`` with bias) is read after each pass but the last: with ``head=False``
+    # the call gives ``(hidden (passes, B, T, D), gate logits (passes - 1, B, T))``, what :func:`causal_lm_loss`
+    # weighs the exits by
+    passes: int = 1
 
     def mixer_of(self, i: int) -> str:
         return self.mixers[i % len(self.mixers)]
@@ -703,9 +711,14 @@ class TransformerLM(nn.Module):
                 self.latent, name=f"block{i}",
             )(x)
 
-        for i in range(self.num_layers):
-            x = block(i, x)
-        outs = [_norm(self.norm, self.norm_eps, stream, "ln_f")(x)]
+        gates = None
+        if self.passes == 1:
+            for i in range(self.num_layers):
+                x = block(i, x)
+            outs = [_norm(self.norm, self.norm_eps, stream, "ln_f")(x)]
+        else:
+            exits, gates = self._looped(block, x, stream)
+            outs = [exits[-1]]
         if self.mtp_modules and (mtp or self.is_initializing()):
             telemetry.get_registry().add("lm.mtp.modules", self.mtp_modules)
             out_init = _matrix_init(self.init_std if self.out_init_std is None else self.out_init_std)
@@ -739,7 +752,40 @@ class TransformerLM(nn.Module):
                 project(outs[0][:, :1])
         if head:
             outs = [project(x) for x in outs]
+        elif gates is not None:
+            return exits, gates
         return (outs[0], outs[1:]) if mtp else outs[0]
+
+    def _looped(self, block, x, stream):
+        """The stack run ``passes`` times on the same parameters as one loop
+        (``nn.scan`` with the parameters broadcast: the compiled program holds
+        each block's body once, and a shared weight's gradient is the loop's
+        sum over its uses), under the scope ``lm.loop``: every pass's ``ln_f``
+        output stacked ``(passes, B, T, D)``, and the exit gate's logits of all
+        but the last ``(passes - 1, B, T)`` in float32 (one product a position,
+        taken as a multiply and a sum so that no matrix unit rounds it), under
+        ``lm.exit_gate``. Counter, once a trace: ``lm.loop.passes``."""
+        if self.passes < 1:
+            raise ValueError(f"passes={self.passes}: the stack runs once or more")
+        if self.ffn == "moe" or self.mtp_modules:
+            raise ValueError("a looped stack (passes > 1) takes SwiGLU blocks and no prediction module")
+        telemetry.get_registry().add("lm.loop.passes", self.passes)
+
+        def one_pass(mdl, x, _):
+            for i in range(self.num_layers):
+                x = block(i, x)
+            h = _norm(self.norm, self.norm_eps, stream, "ln_f")(x)
+            return h, h
+
+        with jax.named_scope("lm.loop"):
+            _, exits = nn.scan(
+                one_pass, variable_broadcast="params", split_rngs={"params": False}, length=self.passes,
+            )(self, x, None)
+        w = self.param("exit_gate_kernel", nn.initializers.normal(0.02 if self.init_std is None else self.init_std),
+                       (self.d_model, 1), jnp.float32)
+        b = self.param("exit_gate_bias", nn.initializers.zeros, (1,), jnp.float32)
+        with jax.named_scope("lm.exit_gate"):
+            return exits, jnp.sum(exits[:-1].astype(jnp.float32) * w[:, 0], axis=-1) + b[0]
 
 
 def olmoe_1b_7b(num_layers: int = 16, **fields) -> TransformerLM:
@@ -923,8 +969,65 @@ def glm_4_7_flash(
     return TransformerLM(**{**arch, **fields})
 
 
+def ouro_2_6b(num_layers: int = 48, **fields) -> TransformerLM:
+    """Ouro-2.6B (ByteDance; ``config.json`` of ``ByteDance/Ouro-2.6B``,
+    ``model_type`` ``ouro``; "Scaling Latent Reasoning via Looped Language
+    Models", arXiv:2510.25741) at its published widths: hidden 2048, 16 heads of
+    128 on as many key-value heads, no bias and no query or key norm, rotary
+    positions (rotate-half, theta 1e6), a SwiGLU of width 5,632, four RMSNorms a
+    block (eps 1e-6, before **and after** each sublayer: ``sandwich_norm``), an
+    untied 49,152-row embedding and head, and **the whole stack run four times
+    on one set of weights** (``passes``, the config's ``total_ut_steps``): the
+    final norm after every pass, whose output is that exit's hidden state and
+    the next pass's input, and **one exit gate**
+    (``sigmoid(h . w_g + b_g)`` after every pass but the last), by whose
+    distribution :func:`causal_lm_loss` weighs the four exits' cross-entropies.
+    ``model.apply(params, tokens)`` gives the last exit's logits (the config's
+    ``early_exit_threshold`` 1: inference leaves at the last pass). bfloat16
+    matmul operands, float32 everything else, the gate and the exit
+    distribution among it; matrices drawn at 0.02, those that write into the
+    residual stream at ``0.02 / sqrt(2 * 48 * 4)`` (every block writes four
+    times), the gate's weights at 0.02 and its bias 0.
+
+    ``num_layers`` is the one size a chip forces down (a pipeline stage's
+    blocks); ``fields`` passes what is not architecture (``attn_impl``,
+    ``comm``, ``remat``, ...)."""
+    arch = dict(
+        vocab_size=49152, d_model=2048, num_heads=16, num_layers=num_layers, max_len=65536,
+        norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1e6, d_ff=5632, sandwich_norm=True,
+        passes=4, init_std=0.02, out_init_std=0.02 / math.sqrt(2 * 48 * 4),
+        dtype=jnp.bfloat16, accum_dtype=jnp.float32, attn_impl="flash",
+    )
+    return TransformerLM(**{**arch, **fields})
+
+
+def exit_distribution(gates):
+    """The logarithm of the exit distribution ``(passes, ...)`` from the gate's
+    logits ``(passes - 1, ...)``, float32: ``p_t = lam_t prod_{j<t} (1 -
+    lam_j)``, ``p_last = prod_j (1 - lam_j)``, ``lam = sigmoid(gates)``, taken
+    as sums of ``log_sigmoid`` so that no product underflows; ``exp`` of it
+    sums to 1 over the passes."""
+    gates = gates.astype(jnp.float32)
+    none = jnp.zeros_like(gates[:1])
+    stayed = jnp.concatenate([none, jnp.cumsum(jax.nn.log_sigmoid(-gates), axis=0)])  # sum_{j<t} log(1 - lam_j)
+    return stayed + jnp.concatenate([jax.nn.log_sigmoid(gates), none])
+
+
+def read_exits(loss, aux):
+    """:func:`~heat_tpu.nn.moe.read_routing` for a looped model's step: the
+    loss and ``aux`` on the host in the one transfer, and the exit gate counted
+    there: ``lm.exit.steps``, and ``lm.exit.expected_pass`` summed over them
+    (divide by the steps)."""
+    loss, aux = read_routing(loss, aux)
+    reg = telemetry.get_registry()
+    reg.add("lm.exit.steps", 1)
+    reg.add("lm.exit.expected_pass", float(aux["expected_pass"]))
+    return loss, aux
+
+
 def causal_lm_loss(
     model: TransformerLM, *, load_balance_coef: float = 0.0, router_z_coef: float = 0.0, mtp_coef: float = 0.3,
+    exit_beta: float = 0.05,
 ):
     """``loss_fn(params, tokens) -> (loss, aux)`` for ``make_train_step(...,
     has_aux=True)``: mean next-token cross-entropy over the ``T - 1`` targets
@@ -951,8 +1054,22 @@ def causal_lm_loss(
     against the token ``j + 2`` ahead, averaged over the ``T - j - 2`` positions
     that have one, through the same head (whose gradient is the sum of its
     uses), and its expert layers stand after the trunk's in everything that
-    goes by layer. ``nn.read_routing(loss, aux)`` brings both to the host and
-    counts the routing."""
+    goes by layer. A looped model (``passes`` above 1, :func:`ouro_2_6b`)
+    is trained on the expectation of its exits' cross-entropies under the
+    gate's own distribution less ``exit_beta`` x that distribution's entropy
+    (stage I of arXiv:2510.25741: an ELBO under a uniform prior up to a
+    constant)::
+
+        loss = mean_i [ sum_t p_t(i) CE(h(t)_i W_head, x_{i+1}) - exit_beta H(p(i)) ]
+
+    over the ``T - 1`` positions that have a next token, all ``passes`` exits
+    through the one head as ``passes x N`` rows of one
+    :func:`blocked_cross_entropy` loop whose weights are ``p_t(i)`` over the
+    count (their cotangent, the cross-entropy a row, is what reaches the gate);
+    ``aux`` then holds ``ce`` (the expectation), ``exit_entropy`` and
+    ``expected_pass`` (the mean of ``sum_t t p_t``, passes counted from 1)
+    beside the two zero scalars, and :func:`read_exits` reads it. ``nn.read_routing(loss, aux)`` brings both to
+    the host and counts the routing."""
 
     def head_loss(params, hidden, tokens, ahead, scope):
         """The mean cross-entropy of ``hidden (B, T, D)`` through the head
@@ -974,7 +1091,33 @@ def causal_lm_loss(
                     return head(kernel=params["params"]["embed"]["embedding"].T)
             return head(kernel=params["params"]["lm_head"]["kernel"])
 
+    def exits_loss(params, tokens):
+        with jax.named_scope("lm.body"):
+            exits, gates = model.apply(params, tokens, head=False)
+        b, t = tokens.shape
+        passes = exits.shape[0]
+        with jax.named_scope("lm.exit_gate"):
+            log_p = exit_distribution(gates)
+            p = jnp.exp(log_p)
+            counted = (jnp.arange(t) < t - 1).astype(jnp.float32) / (b * (t - 1))  # the last position has no next token
+            entropy = -jnp.sum(p * log_p * counted)
+            expected_pass = jnp.sum(p * jnp.arange(1, passes + 1, dtype=jnp.float32)[:, None, None] * counted)
+            weights = (p * counted).reshape(passes * b * t)
+        with jax.named_scope("lm.targets"):
+            targets = jnp.tile(jnp.roll(tokens, -1, axis=1).reshape(b * t), passes)
+        with jax.named_scope("lm.head_loss"):
+            p_ = params["params"]
+            kernel = p_["embed"]["embedding"].T if model.tie_embeddings else p_["lm_head"]["kernel"]
+            ce = blocked_cross_entropy(exits.reshape(passes * b * t, -1), kernel, targets, weights, dtype=model.dtype)
+        with jax.named_scope("lm.loss"):
+            zero = jnp.zeros((), jnp.float32)
+            aux = {"ce": ce, "load_balance": zero, "router_z": zero, "exit_entropy": entropy,
+                   "expected_pass": expected_pass}
+            return ce - exit_beta * entropy, aux
+
     def loss_fn(params, tokens):
+        if model.passes > 1:
+            return exits_loss(params, tokens)
         with jax.named_scope("lm.body"):
             (hidden, ahead), state = model.apply(params, tokens, head=False, mtp=True, mutable=["aux"])
         b, t = tokens.shape

@@ -689,6 +689,9 @@ _IDENTIFIER_RE = re.compile(r"^[A-Za-z_]\w*$")
 _BRANCH_FRAME_RE = re.compile(r"^branch_\d+_fun$")
 # JAX's own frames in a name stack: control flow, closed calls, checkpoints
 _FRAMES = frozenset({"while", "body", "cond", "closed_call", "checkpoint", "rematted_computation"})
+# flax's own frame for a module's method other than ``__call__`` or for a function it lifts over a module
+# (``TransformerLM._looped``, ``TransformerLM.one_pass``): a class name before the dot, where a scope's is lower case
+_METHOD_FRAME_RE = re.compile(r"^[A-Z]\w*\.\w+$")
 # the instructions whose called computations hold instructions of their own
 # that run (and show in a device trace); a fusion's are folded into its row
 _REACHES = {
@@ -740,7 +743,7 @@ def split_op_name(op_name: str) -> Dict[str, Any]:
     scopes: List[str] = []
     for c in parts[:-1]:  # the last is the primitive
         wrappers, name = _unwrap(c)
-        if "jit" in wrappers or name in _FRAMES or _BRANCH_FRAME_RE.match(name):
+        if "jit" in wrappers or name in _FRAMES or _BRANCH_FRAME_RE.match(name) or _METHOD_FRAME_RE.match(name):
             continue
         if "." in name:
             if name not in scopes:

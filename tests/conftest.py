@@ -55,7 +55,7 @@ import pytest
 # compile files (4 cases, 240 to 300 s each) at the tail: 6% longer from an
 # empty cache, 18% with a warm one.
 _LONG_BY_NAME = ("_tpu_compile", "_step")
-_LONG_MODEL_FILES = ("test_trinity", "test_glm", "test_qwen3_next", "test_lfm2")
+_LONG_MODEL_FILES = ("test_trinity", "test_glm", "test_qwen3_next", "test_lfm2", "test_ouro")
 
 
 def _start_rank(item):

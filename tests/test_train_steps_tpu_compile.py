@@ -1,5 +1,5 @@
 """The three oldest published-width train steps (OLMoE, Qwen3-Next,
-Trinity-Mini), compiled for a described TPU v5e once each: the one place
+Trinity-Mini) and the looped one (Ouro, PR 48), compiled for a described TPU v5e once each: the one place
 outside ``tests/chipbench/`` that compiles a cell's step. A new cell's compile
 test outside the benchmark's directories is one more key of ``PARENT``, never
 a fixture of its own: a step takes a minute or two of a suite that has none
@@ -48,6 +48,7 @@ from tests.test_olmoe import loops, products_over
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GIB = 2**30
 OLMOE, QNEXT, TRINITY = "olmoe-train-4k-1chip", "qwen3next-train-8k-1chip", "trinity-train-16k-1chip"
+OURO = "ouro-train-4k-1chip"  # PR 48: the one dense step, and the one whose blocks stand in a loop
 
 # ``memory_analysis`` totals and a digest of the instruction list (described
 # v5e:2x2, jax 0.9.0, libtpu 0.0.34): the text's computations with
@@ -66,11 +67,12 @@ OLMOE, QNEXT, TRINITY = "olmoe-train-4k-1chip", "qwen3next-train-8k-1chip", "tri
 # and a dk/dv kernel stood (15,751,272,448, 13,212,765,696 and 14,987,403,264
 # bytes before: the totals moved by under a megabyte). Qwen3-Next's is PR 45's: the mixers' pass before the rule is a
 # kernel each way where XLA's fusions stood, and all four mixer kernels are functions of the module, called from their
-# sites (13,212,765,696 bytes before: what the fusions held between them went).
+# sites (13,212,765,696 bytes before: what the fusions held between them went). Ouro's is PR 48's own, the first of its cell.
 PARENT = {
     OLMOE: (15_752_046_592, "4870ab2ef18dc83a9eef742703c6b877772a1d3cb84ca9013c3c0f3c787968ca"),
     QNEXT: (12_601_269_248, "f4e5a5af550fe762d4f309068aa2743d2b033283e1b2b468488438de833bdb83"),
     TRINITY: (14_987_274_240, "de6292831a94d0bb6228ff98d8b5d8d1cf05441d159a14384f20447e1c282e31"),
+    OURO: (15_805_405_184, "c11ae6a66c8d4a71670734c35b75b38840ffd5576b2c0a96b360540fb2716edf"),
 }
 
 # what a device trace of these steps shows as an event of its own (my chip runs, PR 35)
@@ -117,7 +119,9 @@ def _build(topo, cell):
     else:
         model = kind.build_model(config, comm)
     rule, collections = {}, ("params",)
-    if config["kind"] == "trinity_step":
+    if config["kind"] == "ouro_step":
+        loss_fn = nn.causal_lm_loss(model, exit_beta=config["loss"]["beta"])
+    elif config["kind"] == "trinity_step":
         loss_fn = nn.causal_lm_loss(model)
         rule, collections = {"state_rule": nn.balance_bias_rule(config["bias_rate"])}, ("params", "route_bias")
     else:
@@ -206,7 +210,10 @@ def test_every_kernel_and_loop_falls_in_its_piece(step):
     else:
         assert not delta and not conv
     grouped = {n: p for n, p in pieces.items() if n.startswith("ragged-dot")}  # XLA:TPU's own kernel, metadata and product
-    assert len(grouped) >= 9 and set(grouped.values()) == {"experts"}
+    if cell == OURO:  # dense, and looped: eight forward and eight backward kernels stand for thirty-two each
+        assert not grouped and len(flash) == 16
+    else:
+        assert len(grouped) >= 9 and set(grouped.values()) == {"experts"}
     # the head's loop and every instruction of its body
     head = [n for n, r in rows.items() if "lm.head_loss" in r["scopes"]]
     assert sum(rows[n]["op"] == "while" for n in head) == 1
@@ -245,6 +252,9 @@ def test_the_passes_and_scopes_are_the_models_own(step):
               "moe.combine", "attn.full", "attn.lse"}
     if cell == OLMOE:  # no checkpoint: nothing is run again
         assert passes == {"forward", "backward"} and scopes == common
+    elif cell == OURO:  # no expert layer; the loop and the gate (flax's own frames for ``_looped`` and the scanned function are no scope)
+        assert passes == {"forward", "recomputed", "backward"}
+        assert scopes == {s for s in common if not s.startswith("moe.")} | {"lm.loop", "lm.exit_gate"}
     elif cell == QNEXT:
         assert passes == {"forward", "recomputed", "backward"}
         assert scopes == common | {"attn.gate", "moe.shared", "gdn.project", "gdn.conv", "gdn.scan", "gdn.gate_norm"}
@@ -318,7 +328,7 @@ def test_a_first_window_of_two_shares_is_moved_whole(step):
     cell, _, text, _, _ = step
     found = _computations(text)
     assert not _live_loops(found)
-    if cell != OLMOE:  # a window's one scatter-add back into the tokens, in no loop over blocks
+    if cell in (QNEXT, TRINITY):  # a window's one scatter-add back into the tokens, in no loop over blocks
         whole = re.compile(r"moe\.combine\)*/scatter-add$")
         assert [x for lines in found.values() for x in lines if " scatter(" in x and whole.search(_op_name(x))]
 
