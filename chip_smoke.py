@@ -54,13 +54,17 @@ array    the reference's workloads at bench.py's sizes on split DNDarrays,
          iterations of KMeans(64) on 2M x 64; five Lasso sweeps. What mean,
          var, cdist, rbf and KMeans.fit dispatched is read back from JAX's
          own dump of the modules it lowered: mean, var and the fit each hold
-         a Mosaic call of their Pallas kernel, so no gate sent the call to
+         a Mosaic call of their Pallas kernel (the fit two: ``lloyd_update``
+         and the final pass ``lloyd_assign``), so no gate sent the call to
          the XLA form; cdist and rbf each lowered one program,
          ``_local_dist``, with no Mosaic call.
 kernels  each of the five Pallas kernels lowered at its production block
          sizes with ``interpret`` left to the library, the lowering checked
          for a Mosaic custom call (nothing resolved ``interpret=True``),
-         run, and compared with the XLA form it replaces; the flash
+         run, and compared with the XLA form it replaces (the Lloyd fit's
+         final pass also alone: ``lloyd_assign``'s labels and inertia
+         against XLA's on the same centres, at a block multiple and at
+         ragged rows with a tail past the last valid one); the flash
          kernels also with 16 query heads on 2 key-value heads of 256
          (forward and both backward forms, against the XLA form on repeated
          K and V), and the gated delta rule at the Qwen3-Next cell's 16 key
@@ -604,14 +608,16 @@ def _kmeans_lasso(ht, cfg, took_mosaic):
     # both orientations of the Lloyd kernel carry one name; the counter
     # says which the fit took: FEATURES is no lane multiple, so X lies
     # feature-major on the chip and the blocks follow it
-    form = "kmeans.lloyd.feature_major"
+    # and the labels and inertia come from the kernel's own final pass
+    said = ("kmeans.lloyd.feature_major", "kmeans.assign.kernel")
     counters = telemetry.get_registry().counters
-    before = counters.get(form, 0)
+    before = [counters.get(c, 0) for c in said]
     km.fit(x)
-    took_mosaic("KMeans.fit", "lloyd_update")
+    took_mosaic("KMeans.fit", "lloyd_update", "lloyd_assign")
     _check(
-        jax.default_backend() != "tpu" or counters.get(form, 0) == before + 1,
-        f"KMeans.fit did not count {form}",
+        jax.default_backend() != "tpu"
+        or [counters.get(c, 0) for c in said] == [b + 1 for b in before],
+        f"KMeans.fit did not count {said}",
     )
     jax.block_until_ready(km.cluster_centers_.larray)
     _check(km.n_iter_ == iters, f"Lloyd ran {km.n_iter_} of {iters} iterations")
@@ -657,21 +663,22 @@ def stage_array(ht, cfg, devices, on_tpu):
         _check(new, f"{call} lowered no module")
         return {f: open(os.path.join(ir_dir, f)).read() for f in new}
 
-    def took_mosaic(call, kernel):
+    def took_mosaic(call, *kernels):
         """Among the modules lowered since the last look there is a Mosaic
-        custom call named ``kernel`` (the ``pallas_call``'s ``name=``, or
-        its kernel function's where it gives none): the call took the
-        Pallas path, compiled. (Off the TPU the library's gates choose the
-        XLA forms.)"""
+        custom call named by each of ``kernels`` (the ``pallas_call``'s
+        ``name=``, or its kernel function's where it gives none): the call
+        took the Pallas path, compiled. (Off the TPU the library's gates
+        choose the XLA forms.)"""
         new = lowered(call)
-        _check(
-            not on_tpu or re.search(
-                rf'@tpu_custom_call\(.*kernel_name = "{kernel}"',
-                "".join(new.values()),
-            ),
-            f"{call}: no Mosaic call of {kernel} in the "
-            f"{len(new)} modules it lowered",
-        )
+        for kernel in kernels:
+            _check(
+                not on_tpu or re.search(
+                    rf'@tpu_custom_call\(.*kernel_name = "{kernel}"',
+                    "".join(new.values()),
+                ),
+                f"{call}: no Mosaic call of {kernel} in the "
+                f"{len(new)} modules it lowered",
+            )
 
     def one_program(call, program, fn):
         """Run ``fn``: it lowered one module, the jitted ``program``, with
@@ -888,6 +895,25 @@ def stage_kernels(cfg, on_tpu):
             xs, jnp.ones((n,), jnp.float32), c0, iters, tol)[0],
         (xs, xs[:kc]), 1e-3,
     )
+    # the final pass alone (no iteration), on a feature-major width: the
+    # kernel's labels and inertia against XLA's pass on the same centres, at
+    # a block multiple and at ragged rows with a tail past the last valid one.
+    # A label that differs reads 1 / (kc - 1) or more. XLA's three-pass
+    # product reads every x.c low by ~2^-16 of itself, the kernel's split
+    # rounds its halves: with x.c ~ 1,024 against distances of ~64 here the
+    # two inertias are 2.5e-4 apart on the chip (the kernel's is the nearer
+    # to float64: PERF.md, Findings, PR 49)
+    for name, rows, valid in (("lloyd_assign", n, n), ("lloyd_assign_ragged", n - 37, n - 42)):
+        def kernel_pass(xs, c0, valid=valid):
+            _, labels, inertia, _ = lloyd_fit_pallas(xs, c0, valid, 0, tol, **rehearse)
+            return labels[:valid], inertia
+
+        def xla_pass(xs, c0, valid=valid):
+            w = (jnp.arange(xs.shape[0]) < valid).astype(jnp.float32)
+            labels, inertia = _kmeans._lloyd_final(xs, w, c0)
+            return labels[:valid], inertia
+
+        run(name, kernel_pass, xla_pass, (xs[:rows], means.astype(jnp.float32)), 1e-3, relative=True)
 
     n = cfg["kernel_rows"]
     xm = jax.random.normal(key, (n, FEATURES), jnp.float32) + 3.0

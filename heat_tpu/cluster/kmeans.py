@@ -181,6 +181,7 @@ class KMeans(_KCluster):
         if x.ndim != 2:
             raise ValueError("input needs to be 2D")
         from .pallas_lloyd import (
+            FINAL_PASS,
             lloyd_fit_pallas,
             lloyd_fit_pallas_sharded,
             lloyd_form,
@@ -192,6 +193,7 @@ class KMeans(_KCluster):
             tol = jnp.asarray(self.tol, xb.dtype)
 
         with telemetry.span("heat_tpu.kmeans.fit.launch"):
+            assign = "xla"  # what forms labels and inertia: `_d2`, or the kernel
             if self.checkpoint_every is not None:
                 # checkpointed fit: exact iteration windows (the pallas path
                 # is a whole-fit program with no resumable carry, so the
@@ -208,9 +210,9 @@ class KMeans(_KCluster):
             else:
                 # fused single-pass-over-X Lloyd update, its blocks in the
                 # orientation X has on the chip (see pallas_lloyd)
-                telemetry.get_registry().add(
-                    f"kmeans.lloyd.{lloyd_form(x.shape[1])}"
-                )
+                form = lloyd_form(x.shape[1])
+                telemetry.get_registry().add(f"kmeans.lloyd.{form}")
+                assign = FINAL_PASS[form]
                 if x.comm.size > 1:
                     centers, labels, inertia, n_iter = lloyd_fit_pallas_sharded(
                         x.comm, xb, centers, x.shape[0], self.max_iter, tol
@@ -219,6 +221,12 @@ class KMeans(_KCluster):
                     centers, labels, inertia, n_iter = lloyd_fit_pallas(
                         xb, centers, x.shape[0], self.max_iter, tol
                     )
+            telemetry.get_registry().add(f"kmeans.assign.{assign}")
+            # the kernel's labels are int32: widened here, the program is
+            # enqueued behind the fit and runs while the host reads the
+            # scalars back; in `wrap` it starts ~0.9 ms after the readback
+            # has waited for the fit, on an idle chip (PERF.md, PR 49)
+            labels = labels.astype(jnp.int64)
 
         with telemetry.span("heat_tpu.kmeans.fit.readback"):
             # the host waits for the device here
@@ -230,7 +238,7 @@ class KMeans(_KCluster):
                 centers, None, x.device, x.comm, dt
             )
             self._labels = DNDarray(
-                labels.astype(jnp.int64), (x.shape[0],), types.int64,
+                labels, (x.shape[0],), types.int64,
                 x.split, x.device, x.comm, True,
             )
         return self

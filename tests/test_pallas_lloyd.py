@@ -9,7 +9,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from heat_tpu.cluster.kmeans import _lloyd_fit
+from heat_tpu.cluster.kmeans import _lloyd_final, _lloyd_fit
 from heat_tpu.cluster.pallas_lloyd import (
     _lloyd_operands,
     lloyd_fit_pallas,
@@ -283,10 +283,81 @@ class TestLloydForms:
         np.testing.assert_allclose(float(got_i), float(want_i), rtol=1e-3)
 
     @pytest.mark.parametrize(
-        "d,counter",
-        [(64, "kmeans.lloyd.feature_major"), (128, "kmeans.lloyd.row_major")],
+        "n,pad,d,k,block,precision",
+        [
+            (300, 0, 64, 3, 128, "HIGHEST"),  # rows no block multiple
+            (1000, 0, 64, 8, 256, "HIGHEST"),
+            (500, 0, 64, 130, 128, "HIGHEST"),  # 17 sublane tiles of scores
+            (333, 51, 64, 8, 128, "HIGHEST"),  # rows past lim, inside a block and past it
+            (257, 7, 18, 9, None, "HIGHEST"),  # the form's own block
+            (300, 0, 128, 3, 64, "HIGHEST"),
+            (1000, 24, 128, 8, 256, "HIGHEST"),
+            (200, 0, 128, 130, None, "HIGHEST"),
+            # the split product as the chip runs it: the same rows, the
+            # products to ~2^-16 of |x||c| where the XLA pass on a CPU is exact
+            (1000, 24, 64, 8, 256, "bf16x3"),
+            (1000, 24, 128, 8, 256, "bf16x3"),
+        ],
     )
-    def test_fit_counts_the_form_it_took(self, monkeypatch, d, counter):
+    def test_final_pass_against_the_xla_pass(self, n, pad, d, k, block, precision):
+        # no iteration: labels and inertia against the centres given, two of
+        # them equal (the first of a tie wins) and one of them a row of X
+        x, protos = _blobs(n, d, k, seed=n + d + k)
+        xp = np.vstack([x, np.full((pad, d), 1e3, np.float32)])
+        c0 = (protos + 0.25).astype(np.float32)
+        c0[1] = c0[0]
+        c0[2] = x[5]
+        w = (np.arange(n + pad) < n).astype(np.float32)
+        want_l, want_i = _lloyd_final(jnp.asarray(xp), jnp.asarray(w), jnp.asarray(c0))
+        got_c, got_l, got_i, got_it = lloyd_fit_pallas(
+            jnp.asarray(xp), jnp.asarray(c0), n, 0, jnp.float32(-1.0),
+            block_m=block, interpret=True, precision=precision,
+        )
+        assert int(got_it) == 0 and got_l.shape == (n + pad,)
+        np.testing.assert_array_equal(np.asarray(got_c), c0)
+        np.testing.assert_array_equal(np.asarray(got_l)[:n], np.asarray(want_l)[:n])
+        assert 1 not in np.asarray(got_l)[:n] and np.asarray(got_l)[5] == 2
+        np.testing.assert_allclose(
+            float(got_i), float(want_i), rtol=1e-6 if precision == "HIGHEST" else 2e-4)
+
+    @pytest.mark.parametrize("d", [64, 128])
+    def test_sharded_final_pass_on_a_mesh_of_four(self, d):
+        # the pass inside the shard_map: labels leave split by rows, the
+        # inertia by one psum; 4 x 41 buffer rows for 163, so the last
+        # shard is ragged
+        import jax
+
+        import heat_tpu as ht
+        from heat_tpu.core.communication import MeshCommunication
+
+        comm = MeshCommunication(devices=jax.devices()[:4])
+        n, k = 163, 5
+        x, protos = _blobs(n, d, k, seed=d + 1)
+        xb = ht.array(x, split=0, comm=comm)._masked(0)
+        m = xb.shape[0]
+        assert m == 164
+        c0 = (protos + 0.25).astype(np.float32)
+        c0[1] = c0[0]
+        want_l, want_i = _lloyd_final(
+            jnp.asarray(np.pad(x, ((0, m - n), (0, 0)))),
+            jnp.asarray((np.arange(m) < n).astype(np.float32)), jnp.asarray(c0),
+        )
+        _, got_l, got_i, _ = lloyd_fit_pallas_sharded(
+            comm, xb, jnp.asarray(c0), n, 0, jnp.float32(-1.0),
+            block_m=16, interpret=True, precision="HIGHEST",
+        )
+        assert got_l.sharding.spec == comm.spec(0, 1)
+        np.testing.assert_array_equal(np.asarray(got_l)[:n], np.asarray(want_l)[:n])
+        np.testing.assert_allclose(float(got_i), float(want_i), rtol=1e-6)
+
+    @pytest.mark.parametrize(
+        "d,counter,assign",
+        [
+            (64, "kmeans.lloyd.feature_major", "kmeans.assign.kernel"),
+            (128, "kmeans.lloyd.row_major", "kmeans.assign.xla"),
+        ],
+    )
+    def test_fit_counts_the_form_it_took(self, monkeypatch, d, counter, assign):
         # KMeans.fit as on a TPU: the gate open, the kernels interpreted
         import heat_tpu as ht
         from heat_tpu import telemetry
@@ -300,25 +371,31 @@ class TestLloydForms:
             )
         x, protos = _blobs(160, d, 3, seed=2)
         counters = telemetry.get_registry().counters
-        before = {c: counters.get(c, 0) for c in (
-            "kmeans.lloyd.feature_major", "kmeans.lloyd.row_major")}
+        forms = ("kmeans.lloyd.feature_major", "kmeans.lloyd.row_major")
+        assigns = ("kmeans.assign.kernel", "kmeans.assign.xla")
+        before = {c: counters.get(c, 0) for c in forms + assigns}
         km = ht.cluster.KMeans(
             n_clusters=3, init=ht.array(protos + 0.25), max_iter=3, tol=-1.0
         ).fit(ht.array(x, split=0))
-        assert km.n_iter_ == 3
+        assert km.n_iter_ == 3 and km.labels_.dtype == ht.int64
         after = {c: counters.get(c, 0) for c in before}
-        assert after[counter] == before[counter] + 1  # once a fit
-        assert sum(after.values()) == sum(before.values()) + 1
+        for said, among in ((counter, forms), (assign, assigns)):
+            assert after[said] == before[said] + 1  # once a fit
+            assert sum(after[c] - before[c] for c in among) == 1
+        km.predict(ht.array(x, split=0))
+        assert {c: counters.get(c, 0) for c in before} == after  # none on predict
 
     def test_xla_fit_counts_no_form(self):
-        # off the TPU the gate is shut: the XLA fit, neither counter
+        # off the TPU the gate is shut: the XLA fit, no form, and its own
+        # pass for the labels
         import heat_tpu as ht
         from heat_tpu import telemetry
 
         x, protos = _blobs(160, 8, 3, seed=4)
         counters = telemetry.get_registry().counters
-        names = ("kmeans.lloyd.feature_major", "kmeans.lloyd.row_major")
+        names = ("kmeans.lloyd.feature_major", "kmeans.lloyd.row_major",
+                 "kmeans.assign.kernel", "kmeans.assign.xla")
         before = [counters.get(c, 0) for c in names]
         ht.cluster.KMeans(n_clusters=3, init=ht.array(protos), max_iter=2).fit(
             ht.array(x, split=0))
-        assert [counters.get(c, 0) for c in names] == before
+        assert [counters.get(c, 0) - b for c, b in zip(names, before)] == [0, 0, 0, 1]

@@ -59,22 +59,51 @@ def _relayouts_of_x(text, rows, d):
     return [line.strip()[:160] for line in text.splitlines() if shaped.search(line)]
 
 
+def _kernels(text):
+    """The Mosaic calls of a compiled text, by the ``pallas_call``'s name."""
+    return sorted(set(re.findall(r"%(lloyd_\w+?)(?:\.\d+)? = ", text)))
+
+
+def _as_large_as(text, rows, least):
+    """Lines that define an array of ``rows`` (in either orientation) times
+    at least ``least`` elements a row, whatever its type: the (n, 8)
+    distances, lane-padded or not, or a second X."""
+    shaped = re.compile(rf"= \w+\[(?:{rows},(\d+)|(\d+),{rows})\]\S* (?!parameter|bitcast|get-tuple-element)")
+    return [
+        line.strip()[:160] for line in text.splitlines()
+        for m in [shaped.search(line)] if m and int(m.group(1) or m.group(2)) >= least
+    ]
+
+
+# memory_analysis' temporaries of the fits at PR 49's parent (one chip, a chip
+# of four): XLA's final pass held the (n, 8) distances and a column of norms
+PARENT_TEMPORARIES = (604_205_568, 604_237_824)
+
+
 def test_one_chip_takes_x_as_it_lies(topo):
+    """The whole fit is 4,362,086,400 bytes on the described chip (X
+    4,294,967,296, int32 labels 67,108,864, no temporaries; 5,033,400,832
+    with XLA's final pass, PR 49's parent)."""
     rows, d = 2**24, 64
     compiled = _one_chip(topo, rows, d)
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert _kernels(text) == ["lloyd_assign", "lloyd_update"]
     assert _relayouts_of_x(text, rows, d) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 * GIB
+    assert _as_large_as(text, rows, 2) == []  # no f32[16777216,8], no second X
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes <= PARENT_TEMPORARIES[0] and m.temp_size_in_bytes < 1 * GIB
+    assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < 4.1 * GIB
 
 
 def test_a_chip_of_four_takes_its_shard_as_it_lies(topo):
     rows, d = 2**26, 64
     compiled = _four_chips(topo, rows, d)
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert _kernels(text) == ["lloyd_assign", "lloyd_update"] and "all-reduce" in text
     assert _relayouts_of_x(text, rows // 4, d) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 * GIB  # a chip
+    assert _as_large_as(text, rows // 4, 2) == []
+    m = compiled.memory_analysis()  # a chip
+    assert m.temp_size_in_bytes <= PARENT_TEMPORARIES[1] and m.temp_size_in_bytes < 1 * GIB
 
 
 def test_heats_own_rows_fit_one_chip(topo):
