@@ -70,6 +70,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..parallel.pallas_attention import KEPT_RESIDUALS
+from . import pallas_qk_prep
 from .deltanet import GatedDeltaNet, gated_short_conv
 from .functional import blocked_cross_entropy
 from .moe import DroplessMoE, read_routing
@@ -108,6 +109,21 @@ def _norm(kind, eps, dtype, name, **kw):
     raise ValueError(f"norm must be 'layernorm', 'rmsnorm' or 'rmsnorm_zero', got {kind!r}")
 
 
+class _NormGain(nn.Module):
+    """The gain of the RMS norm ``kind`` names, for a caller that runs the norm
+    itself (:mod:`heat_tpu.nn.pallas_qk_prep`): the parameter ``scale`` the
+    norm's own module would hold under this name, of that ``shape``, and for
+    ``"rmsnorm_zero"`` one more than it."""
+
+    kind: str
+
+    @nn.compact
+    def __call__(self, shape):
+        zero = self.kind == "rmsnorm_zero"
+        scale = self.param("scale", nn.initializers.zeros if zero else nn.initializers.ones, shape, jnp.float32)
+        return 1.0 + scale if zero else scale
+
+
 def _matrix_init(std):
     """normal(0, std) where a model states its matrices' deviation; None
     where it leaves them to each layer's own default."""
@@ -138,9 +154,11 @@ def rotary(x, theta, fraction: float = 1.0):
     return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang)
 
 
-def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window=None):
+def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window=None, head_major=False):
     """The attention core under the scope ``attn.full`` or, with a ``window``,
-    ``attn.window``; the counters ``attn.full.kernel``, ``attn.window.kernel``
+    ``attn.window``, ``(B, T, H, D)`` in and out; ``head_major``: ``q`` and
+    ``k`` come ``(B, H, T, D)``, as :mod:`heat_tpu.nn.pallas_qk_prep` writes
+    them for the flash kernels. The counters ``attn.full.kernel``, ``attn.window.kernel``
     (the Pallas kernels) and ``attn.window.xla`` (the masked XLA form) say once
     a trace which ran, ``attn.window.blocks_visited`` / ``.blocks_live`` what
     the windowed grid covers (:func:`heat_tpu.parallel.pallas_attention.window_grid`),
@@ -149,18 +167,18 @@ def _attend(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window=N
     with jax.named_scope("attn.full" if window is None else "attn.window"):
         return _attend_scoped(
             q, k, v, impl=impl, causal=causal, comm=comm, block_size=block_size,
-            flash_bwd_impl=flash_bwd_impl, window=window,
+            flash_bwd_impl=flash_bwd_impl, window=window, head_major=head_major,
         )
 
 
-def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window):
+def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, window, head_major):
     from ..parallel import (
         flash_attention,
         local_attention,
         ring_attention,
         ulysses_attention,
     )
-    from ..parallel.pallas_attention import causal_grid, window_grid
+    from ..parallel.pallas_attention import causal_grid, flash_attention_head_major, window_grid
 
     count = telemetry.get_registry().add
     if impl in ("ring", "ulysses") and window is not None:
@@ -175,20 +193,23 @@ def _attend_scoped(q, k, v, *, impl, causal, comm, block_size, flash_bwd_impl, w
         blocks = {} if block_size is None else {
             "block_q": block_size, "block_k": block_size,
         }
-        attend = functools.partial(
-            flash_attention, causal=causal, bwd_impl=flash_bwd_impl, window=window, **blocks
-        )
+        form = dict(causal=causal, bwd_impl=flash_bwd_impl, window=window, **blocks)
+        attend = functools.partial(flash_attention, **form)
+        t_q, t_k = (a.shape[2 if head_major else 1] for a in (q, k))
         if window is None:
             count("attn.full.kernel")
             if causal:
-                _, streamed, live = causal_grid(q.shape[1], k.shape[1], block_size, block_size)
+                _, streamed, live = causal_grid(t_q, t_k, block_size, block_size)
                 count("attn.full.blocks_streamed", streamed)
                 count("attn.full.blocks_live", live)
         else:
             count("attn.window.kernel")
-            visited, live = window_grid(q.shape[1], k.shape[1], window, block_size, block_size)
+            visited, live = window_grid(t_q, t_k, window, block_size, block_size)
             count("attn.window.blocks_visited", visited)
             count("attn.window.blocks_live", live)
+        if head_major:  # one device (``pallas_qk_prep.takes_kernel``); the values' transpose and the output's stay XLA's
+            out = flash_attention_head_major(q, k, v.transpose(0, 2, 1, 3), **form)
+            return out.transpose(0, 2, 1, 3)
         if comm is not None and comm.size > 1:
             # data parallel: the kernel runs on each chip's batch shard. A
             # bare pallas_call is opaque to the SPMD partitioner, which
@@ -261,28 +282,60 @@ class MultiHeadAttention(nn.Module):
             (heads, width), axis=-1, use_bias=False, dtype=self.dtype, name=name,
             dot_general=_dot_general(self.accum_dtype), **_given(self.matrix_init),
         )
+        norm_over = None if self.qk_norm_eps is None else "head" if self.qk_norm_over == "head" else "row"
+        norm_kind = self.norm if norm_over == "head" else "rmsnorm"
+        count = telemetry.get_registry().add
+        # queries and keys from their projections to the flash kernels in one pass each (``pallas_qk_prep``), where the
+        # call's own shapes and backend admit it; XLA's float32 passes, a cast and the kernels' transposes otherwise
+        fused = pallas_qk_prep.takes_kernel(
+            self.attn_impl, self.comm, d_head, norm_over, norm_kind, self.rope_theta is not None
+        )
         if self.gate:
             with jax.named_scope("attn.gate"):
-                q, g = jnp.split(dense("query", width=2 * d_head)(x), 2, axis=-1)
+                q = dense("query", width=2 * d_head)(x)  # a head's query beside its gate
+                if fused:
+                    g = q[..., d_head:]  # the pass reads the queries' lanes of the projection as it stands
+                else:
+                    q, g = jnp.split(q, 2, axis=-1)
         else:
             q = dense("query")(x)
         k, v = dense("key", kv_heads)(x), dense("value", kv_heads)(x)
-        if self.qk_norm_eps is not None:
-            if self.qk_norm_over == "head":
+        if fused:
+            prep = pallas_qk_prep.Pass(
+                d_head, norm_over, self.qk_norm_eps, self.rope_theta, self.rotary_fraction, self.dtype,
+                pallas_qk_prep.ROWS, jax.default_backend() != "tpu",
+            )
+
+            def gain(name, heads):
+                """``<name>/scale`` as the norm's own module holds it (over a head ``(d,)``, over the row
+                ``(heads, d)``), as rows of ``d`` for the kernels; nothing without a norm."""
+                if norm_over is None:
+                    return None
+                shape = (d_head,) if norm_over == "head" else (heads, d_head)
+                return _NormGain(norm_kind, name=name)(shape).reshape(-1, d_head)
+
+            with jax.named_scope("attn.qk_prep"):
+                q = pallas_qk_prep.qk_prep(q, gain("q_norm", self.num_heads), prep)
+                k = pallas_qk_prep.qk_prep(k, gain("k_norm", kv_heads), prep)
+            count("attn.qk_prep.kernel", 2)
+        else:
+            if norm_over == "head":
                 q = _norm(self.norm, self.qk_norm_eps, jnp.float32, "q_norm")(q)
                 k = _norm(self.norm, self.qk_norm_eps, jnp.float32, "k_norm")(k)
-            else:
+            elif norm_over == "row":
                 over_heads = dict(reduction_axes=(-2, -1), feature_axes=(-2, -1))
                 q = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "q_norm", **over_heads)(q)
                 k = _norm("rmsnorm", self.qk_norm_eps, jnp.float32, "k_norm", **over_heads)(k)
-        if self.rope_theta is not None:
-            q = rotary(q, self.rope_theta, self.rotary_fraction)
-            k = rotary(k, self.rope_theta, self.rotary_fraction)
-        q, k, v = (a.astype(self.dtype) for a in (q, k, v))
+            if self.rope_theta is not None:
+                q = rotary(q, self.rope_theta, self.rotary_fraction)
+                k = rotary(k, self.rope_theta, self.rotary_fraction)
+            if norm_over is not None or self.rope_theta is not None:
+                count("attn.qk_prep.xla", 2)
+            q, k = q.astype(self.dtype), k.astype(self.dtype)
         o = _attend(
-            q, k, v, impl=self.attn_impl, causal=self.causal, comm=self.comm,
+            q, k, v.astype(self.dtype), impl=self.attn_impl, causal=self.causal, comm=self.comm,
             flash_bwd_impl=self.flash_bwd_impl,
-            block_size=self.block_size, window=self.window,
+            block_size=self.block_size, window=self.window, head_major=fused,
         )
         if self.gate:
             with jax.named_scope("attn.gate"):

@@ -952,14 +952,40 @@ def flash_attention(
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, T, H, D) inputs, got {q.shape}")
-    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+    # kernel works in (B, H, T, D); public layout is (B, T, H, D)
+    out = flash_attention_head_major(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+        causal=causal, scale=scale, kv_valid=kv_valid, block_q=block_q, block_k=block_k, interpret=interpret,
+        bwd_impl=bwd_impl, window=window,
+    )
+    return out.transpose(0, 2, 1, 3)
+
+
+def flash_attention_head_major(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = False,
+    scale: Optional[float] = None,
+    kv_valid: Optional[int] = None,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    bwd_impl: str = "auto",
+    window: Optional[int] = None,
+) -> jax.Array:
+    """:func:`flash_attention` in the kernels' own layout, ``(B, H, T, D)`` in
+    and out, for a caller that holds its operands so already
+    (:mod:`heat_tpu.nn.pallas_qk_prep` writes queries and keys head-major)."""
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
         raise ValueError(
-            f"{q.shape[2]} query heads do not divide over {k.shape[2]} key and {v.shape[2]} value heads"
+            f"{q.shape[1]} query heads do not divide over {k.shape[1]} key and {v.shape[1]} value heads"
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     d = q.shape[-1]
-    t_k = k.shape[1]
+    t_k = k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     kv_valid = t_k if kv_valid is None else int(kv_valid)
     if window is not None:
@@ -967,17 +993,11 @@ def flash_attention(
             raise ValueError(f"a window (got {window!r}) is a whole number of positions >= 1 and needs causal=True")
         window = int(window)
     block_q, block_k = _tiles(window, block_q, block_k)
-    # kernel works in (B, H, T, D); public layout is (B, T, H, D)
     if bwd_impl not in ("two_pass", "fused", "auto"):
         raise ValueError(
             f"bwd_impl must be 'two_pass', 'fused' or 'auto', got {bwd_impl!r}"
         )
-    out = _flash(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3),
-        scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window,
-    )
-    return out.transpose(0, 2, 1, 3)
+    return _flash(q, k, v, scale, causal, kv_valid, block_q, block_k, interpret, bwd_impl, window)
 
 
 def causal_grid(t_q: int, t_k: int, block_q: Optional[int] = None, block_k: Optional[int] = None, window: Optional[int] = None):

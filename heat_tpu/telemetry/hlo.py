@@ -741,7 +741,10 @@ def split_op_name(op_name: str) -> Dict[str, Any]:
             break
     modules: List[str] = []
     scopes: List[str] = []
-    for c in parts[:-1]:  # the last is the primitive
+    named = parts[:-1]  # the last is the primitive
+    if parts[-1] == "pallas_call":
+        named = named[:-1]  # and a Pallas kernel stands behind its own ``name=``, which is no module of the model
+    for c in named:
         wrappers, name = _unwrap(c)
         if "jit" in wrappers or name in _FRAMES or _BRANCH_FRAME_RE.match(name) or _METHOD_FRAME_RE.match(name):
             continue

@@ -580,3 +580,23 @@ def test_a_bfloat16_router_changes_the_chosen_experts(weights, tokens):
     # with no slack for rounding, the control's choices are not the reference's
     assert ref.routing_disagreement(np.asarray(low["chosen"][0]), probs, 2, 0.0) > 0.0
     assert ref.routing_disagreement(np.asarray(parts["chosen"][0]), probs, 2, 0.0) == 0.0
+
+
+# -- queries and keys to the flash kernels in one pass (PR 50) ------------------------------
+
+
+@highest
+def test_the_pass_before_the_flash_kernels_is_the_xla_lines_and_holds_the_same_parameters(weights, tokens, monkeypatch):
+    """``nn/pallas_qk_prep.py`` in the interpreter: the norm over all of hidden
+    and rotary, two layers."""
+    from tests.test_pallas_qk_prep import both_forms
+
+    model = tiny(2)
+    (logits, grads, passes), (k_logits, k_grads, _) = both_forms(
+        monkeypatch, model, lm_step.to_system(ref.init_params(SEED, {**C, "num_hidden_layers": 2}), 4), tokens,
+        causal_lm_loss(model, load_balance_coef=0.01, router_z_coef=0.001),
+    )
+    assert passes["xla"] >= 4
+    assert rel(k_logits, logits) < F32
+    for (path, g), w in zip(jax.tree.leaves_with_path(k_grads["params"]), jax.tree.leaves(grads["params"])):
+        assert rel(g, w) < 5 * F32, jax.tree_util.keystr(path)

@@ -408,3 +408,22 @@ def test_counters_and_scopes_of_the_loop(weights, tokens):
 def test_what_a_looped_stack_refuses(tokens, fields, message):
     with pytest.raises(ValueError, match=message):
         tiny(**fields).init(jax.random.PRNGKey(0), tokens)
+
+
+# -- queries and keys to the flash kernels in one pass (PR 50) ------------------------------
+
+
+def test_the_pass_before_the_flash_kernels_is_the_xla_lines_and_holds_the_same_parameters(weights, tokens, monkeypatch):
+    """``nn/pallas_qk_prep.py`` in the interpreter: rotary alone, in a looped
+    stack under its checkpoint."""
+    from tests.test_pallas_qk_prep import both_forms
+
+    model = tiny(attn_impl="flash", remat=True)
+    with jax.default_matmul_precision("highest"):
+        (exits, grads, passes), (k_exits, k_grads, _) = both_forms(
+            monkeypatch, model, ouro_step.to_system(weights, C), jnp.asarray(tokens),
+            causal_lm_loss(model, exit_beta=COEF["beta"]), apply=lambda p: model.apply(p, jnp.asarray(tokens), head=False)[0],
+        )
+    assert passes["xla"] >= 4  # two blocks' queries and keys a trace of the loop's body
+    assert ref.rel_gap(k_exits, exits) < F32
+    assert worst(k_grads["params"], grads["params"]) < 10 * F32

@@ -537,3 +537,21 @@ def test_qwen3_next_is_the_published_configuration():
     # the reference's tree is the same tree
     c = {k: config[k] for k in qnext_step.MODEL_KEYS}
     assert sum(int(np.prod(s)) for s in jax.tree.leaves(ref.param_shapes(c), is_leaf=lambda s: isinstance(s, tuple))) == 625_667_136
+
+
+# -- queries and keys to the flash kernels in one pass (PR 50) ------------------------------
+
+
+@highest
+def test_the_pass_before_the_flash_kernels_is_the_xla_lines_and_holds_the_same_parameters(weights, tokens, monkeypatch):
+    """``nn/pallas_qk_prep.py`` in the interpreter: the zero-centred head norm,
+    rotary on a quarter of a head, queries beside their gates."""
+    from tests.test_pallas_qk_prep import both_forms
+
+    model = tiny(attn_impl="flash")
+    (logits, grads, passes), (k_logits, k_grads, _) = both_forms(
+        monkeypatch, model, qnext_step.to_system(weights, C), tokens, causal_lm_loss(model, load_balance_coef=0.001)
+    )
+    assert passes["xla"] >= 2  # the one attention layer of four
+    assert rel(k_logits, logits) < F32
+    grads_close(k_grads["params"], grads["params"], 1e-4)

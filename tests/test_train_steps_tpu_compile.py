@@ -68,12 +68,23 @@ OURO = "ouro-train-4k-1chip"  # PR 48: the one dense step, and the one whose blo
 # bytes before: the totals moved by under a megabyte). Qwen3-Next's is PR 45's: the mixers' pass before the rule is a
 # kernel each way where XLA's fusions stood, and all four mixer kernels are functions of the module, called from their
 # sites (13,212,765,696 bytes before: what the fusions held between them went). Ouro's is PR 48's own, the first of its cell.
+# All four are PR 50's: a layer's queries and keys go from their projections to the flash kernels through ``qk_prep_fwd``
+# / ``qk_prep_bwd`` where XLA's norm, rotary, cast and transpose fusions stood (15,752,046,592, 12,601,269,248,
+# 14,987,274,240 and 15,805,405,184 bytes before: a gated query's float32 cotangent now stands as an array of its own
+# before it is padded to the projection's width beside its gate's, 268 MB in Trinity-Mini; OLMoE's total fell).
 PARENT = {
-    OLMOE: (15_752_046_592, "4870ab2ef18dc83a9eef742703c6b877772a1d3cb84ca9013c3c0f3c787968ca"),
-    QNEXT: (12_601_269_248, "f4e5a5af550fe762d4f309068aa2743d2b033283e1b2b468488438de833bdb83"),
-    TRINITY: (14_987_274_240, "de6292831a94d0bb6228ff98d8b5d8d1cf05441d159a14384f20447e1c282e31"),
-    OURO: (15_805_405_184, "c11ae6a66c8d4a71670734c35b75b38840ffd5576b2c0a96b360540fb2716edf"),
+    OLMOE: (15_751_719_424, "e0b7efac829645b3e9fa0ecdfffd2c2c067eea5215dd95c4d51160d1e7db7e98"),
+    QNEXT: (12_722_094_080, "13c9d24b2a758d74a4505e6d75aa6e372b4612f827f542a8874755cb8ca86a1b"),
+    TRINITY: (15_268_546_560, "921afecbf5f1690e9fbde2022dc2100e205d4b88afcc3346363bbb0e45323837"),
+    OURO: (15_958_382_080, "fae40da7b6b49175b46a7a28afc2f4913fea6b356af3ffcffd6a5fb1cab7a811"),
 }
+
+# Queries and keys from their projections to the flash kernels as one kernel each way (``nn/pallas_qk_prep.py``, PR 50),
+# in the cells whose layers ``takes_kernel`` admits: (query and key passes of the step's forward pass: two a layer; bodies
+# of the forward kernel in the lowered module; of the backward kernel). A body a shape (Trinity-Mini: queries and keys,
+# rotated in the six sliding layers and not in the two full ones; Ouro's queries and keys are one shape, and its 32 block
+# applications stand in a loop of 8), the forward's once more under the block's checkpoint (OLMoE's step has none).
+QK_PREP = {TRINITY: (16, 8, 4), QNEXT: (2, 4, 2), OURO: (16, 2, 1), OLMOE: (2, 1, 1)}
 
 # what a device trace of these steps shows as an event of its own (my chip runs, PR 35)
 RUNS = {
@@ -191,19 +202,30 @@ def test_every_kernel_and_loop_falls_in_its_piece(step):
     flash = {n: p for n, p in kernels.items() if re.match(r"(flash|swa)_", n)}
     delta = {n: p for n, p in kernels.items() if n.startswith("delta_chunk_")}
     conv = {n: p for n, p in kernels.items() if n.startswith("gdn_conv_")}
+    prep = {n: p for n, p in kernels.items() if n.startswith("qk_prep_")}
     assert flash and set(flash.values()) == {"attention_core"}
     assert all(n in rows and pieces[n] == "attention_core" for n in flash)  # events of their own
-    assert len(flash) + len(delta) + len(conv) == len(kernels)  # no kernel the table has not heard of
+    assert len(flash) + len(delta) + len(conv) + len(prep) == len(kernels)  # no kernel the table has not heard of
+    # the pass before the flash kernels (PR 50): events of their own in the stream, outside the attention core's scopes;
+    # a layer's queries and keys in the step's pass, in its block's rematerialisation (OLMoE's blocks have none) and backward
+    made = QK_PREP[cell][0]
+    again = 0 if cell == OLMOE else made
+    assert len(prep) == 2 * made + again and all(n in rows and pieces[n] == "stream" for n in prep)
+    assert {k: sorted(v) for k, v in passes.items() if k.startswith("qk_prep")} == {
+        "qk_prep_fwd": sorted(["forward"] * made + ["recomputed"] * again), "qk_prep_bwd": ["backward"] * made,
+    }
     if cell == QNEXT:
         assert len(delta) == 12 and set(delta.values()) == {"delta_rule"}  # nine forward, three backward
-        holding = [n for n, r in rows.items() if r["op"] == "fusion" and any("delta_chunk" in m for m, _ in r["fused"])]
+        found = _computations(text)  # a kernel's ``name=`` is no module of the scope map (PR 50): the fusions' own bodies say
+        calls = {n: re.search(r"calls=%([\w.\-]+)", lines[n]) for n, r in rows.items() if r["op"] == "fusion"}
+        holding = [n for n, m in calls.items() if m and any("%delta_chunk_" in x for x in found.get(m.group(1), ()))]
         assert len(holding) == 12 and {pieces[n] for n in holding} == {"delta_rule"}
         # the pass before the rule (PR 45): as many, events of their own in the mixers' loops
         assert len(conv) == 12 and all(n in rows and pieces[n] == "mixer_glue" for n in conv)
         # a kernel lowered once a program is inlined at every call site under that site's own name: three mixers
         # in the step's pass, each again in its block's rematerialisation and in its sequence's checkpoint, once backward
         forward = sorted(["forward"] * 3 + ["recomputed"] * 6)
-        assert {k: sorted(v) for k, v in passes.items() if not k.startswith("flash")} == {
+        assert {k: sorted(v) for k, v in passes.items() if not re.match(r"flash|qk_prep", k)} == {
             "delta_chunk_fwd": forward, "gdn_conv_fwd": forward,
             "delta_chunk_bwd": ["backward"] * 3, "gdn_conv_bwd": ["backward"] * 3,
         }
@@ -237,11 +259,15 @@ def test_a_mixer_kernel_is_lowered_once_a_call_path(step):
     for name in re.findall(r'kernel_name = "([^"]*)"', lowered):
         bodies[name] = bodies.get(name, 0) + 1
     assert lowered.count("tpu_custom_call") == sum(bodies.values())
-    mixers = {k: n for k, n in bodies.items() if not re.match(r"(flash|swa)_", k)}
+    mixers = {k: n for k, n in bodies.items() if not re.match(r"(flash|swa|qk_prep)_", k)}
     if cell == QNEXT:
         assert mixers == {"gdn_conv_fwd": 3, "delta_chunk_fwd": 3, "gdn_conv_bwd": 1, "delta_chunk_bwd": 1}
     else:
         assert not mixers
+    # the pass before the flash kernels (PR 50) by the same rule: a body a shape, not a layer and pass (Trinity-Mini's
+    # step calls the forward kernel at 32 sites and the backward at 16)
+    prep = {k: n for k, n in bodies.items() if k.startswith("qk_prep_")}
+    assert prep == dict(zip(("qk_prep_fwd", "qk_prep_bwd"), QK_PREP[cell][1:]))
 
 
 def test_the_passes_and_scopes_are_the_models_own(step):
@@ -249,7 +275,7 @@ def test_the_passes_and_scopes_are_the_models_own(step):
     passes = {r["pass"] for r in rows.values()} - {""}
     scopes = {s for r in rows.values() for s in r["scopes"]}
     common = {"lm.body", "lm.head_loss", "lm.targets", "lm.loss", "train.optimizer", "moe.route", "moe.experts",
-              "moe.combine", "attn.full", "attn.lse"}
+              "moe.combine", "attn.full", "attn.lse", "attn.qk_prep"}
     if cell == OLMOE:  # no checkpoint: nothing is run again
         assert passes == {"forward", "backward"} and scopes == common
     elif cell == OURO:  # no expert layer; the loop and the gate (flax's own frames for ``_looped`` and the scanned function are no scope)

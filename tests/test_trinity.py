@@ -515,3 +515,22 @@ def test_trinity_mini_is_the_published_configuration():
         jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32),
     )
     assert set(other) == {"params", "aux"} and set(other["params"]["block0"]) == {"attn", "ln1", "ln2", "moe"}
+
+
+# -- queries and keys to the flash kernels in one pass (PR 50) ------------------------------
+
+
+@highest
+def test_the_pass_before_the_flash_kernels_is_the_xla_lines_and_holds_the_same_parameters(weights, tokens, monkeypatch):
+    """``nn/pallas_qk_prep.py`` in the interpreter: a head norm with and without
+    rotary, queries beside their gates, two key-value heads, 40 positions."""
+    from tests.test_pallas_qk_prep import both_forms
+
+    model = tiny(attn_impl="flash")
+    (logits, grads, passes), (k_logits, k_grads, _) = both_forms(
+        monkeypatch, model, trinity_step.to_system(weights, C), tokens, causal_lm_loss(model)
+    )
+    assert passes["xla"] >= 16  # eight layers' queries and keys a trace
+    assert rel(k_logits, logits) < F32
+    for (path, g), w in zip(jax.tree.leaves_with_path(k_grads["params"]), jax.tree.leaves(grads["params"])):
+        assert g.shape == w.shape and rel(g, w) < 1e-4, jax.tree_util.keystr(path)
